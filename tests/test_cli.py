@@ -240,3 +240,27 @@ def test_daemon_validate_config(tmp_path):
     cfgfile.write_text("interval: '10s'\nnum_workers: 2\n")
     assert veneur_cli.main(["-f", str(cfgfile),
                             "--validate-config"]) == 0
+
+
+def test_daemon_refuses_tpu_backend_off_chip(tmp_path):
+    """`aggregation_backend: tpu` (the default) on a machine whose JAX
+    hands back the CPU must not start serving: the daemon exits
+    non-zero naming the platform it found. This process is pinned to
+    the CPU, which is exactly that machine."""
+    import pytest
+
+    from veneur_tpu.cli import veneur as veneur_cli
+
+    cfgfile = tmp_path / "v.yaml"
+    cfgfile.write_text("interval: '10s'\n"
+                       "statsd_listen_addresses: ['udp://127.0.0.1:0']\n")
+    with pytest.raises(SystemExit) as exc:
+        veneur_cli.main(["-f", str(cfgfile)])
+    assert exc.value.code not in (0, None)
+    assert "'cpu'" in str(exc.value.code)
+    assert "aggregation_backend: tpu" in str(exc.value.code)
+
+    bad = tmp_path / "bad.yaml"
+    bad.write_text("aggregation_backend: gpu\n")
+    with pytest.raises(ValueError, match="aggregation_backend"):
+        veneur_cli.main(["-f", str(bad)])

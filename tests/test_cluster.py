@@ -8,8 +8,6 @@ import time
 import numpy as np
 import pytest
 
-from envprobes import needs_mesh_shard_map
-
 from veneur_tpu.cluster import wire
 from veneur_tpu.cluster.discovery import StaticDiscoverer
 from veneur_tpu.cluster.forward import GrpcForwarder
@@ -122,6 +120,51 @@ def test_two_servers_grpc_forward():
             local.stop()
     finally:
         glob.stop()
+
+
+def test_grpc_forward_chunks_fit_the_message_limit():
+    """A gRPC receiver refuses a message over 4 MiB unless told
+    otherwise, and chunks used to close on metric COUNT alone: 300
+    HLL p=14 sets (16 KiB of registers each) made one 4.7 MiB
+    MetricList and the whole forward died RESOURCE_EXHAUSTED. Chunks
+    now also close on bytes; re-chunking a tail from any chunk start
+    lands on the original boundaries (replays keep their chunk ids)."""
+    from veneur_tpu.cluster import forward
+    from veneur_tpu.cluster.importsrv import start_import_server
+    from veneur_tpu.ingest.parser import MetricKey
+    from veneur_tpu.models.pipeline import ForwardExport
+
+    rng = np.random.default_rng(5)
+    exp = ForwardExport()
+    for i in range(300):
+        exp.sets.append((MetricKey(f"big.set{i}", "set", "env:prod"),
+                         rng.integers(0, 30, 1 << 14).astype(np.uint8)))
+    exp.counters.append((MetricKey("big.count", "counter", ""), 7.0))
+    metrics = wire.export_to_metrics(exp)
+    bounds = forward._chunk_bounds(metrics, 10_000)
+    assert len(bounds) == 2 and bounds[0][0] == 0
+    assert bounds[-1][1] == len(metrics)
+    for a, b in bounds:
+        assert sum(m.ByteSize() for m in metrics[a:b]) \
+            <= forward.MAX_CHUNK_BYTES
+    # greedy from a chunk start reproduces the boundaries after it
+    a = bounds[1][0]
+    assert [(x + a, y + a) for x, y in
+            forward._chunk_bounds(metrics[a:], 10_000)] == bounds[1:]
+    # count still closes chunks too
+    assert forward._chunk_bounds(metrics[:5], 2) == [(0, 2), (2, 4),
+                                                     (4, 5)]
+
+    got = []
+    server, port = start_import_server(
+        "127.0.0.1:0", lambda _digest, imported: got.append(imported))
+    try:
+        fw = GrpcForwarder(f"127.0.0.1:{port}")
+        fw(exp)          # default limits on both ends
+        fw.close()
+    finally:
+        server.stop(0)
+    assert len(got) == 301
 
 
 def test_ring_distribution_and_stability():
@@ -313,7 +356,6 @@ def test_http_proxy_front_distributes_consistently():
         proxy.stop()
 
 
-@needs_mesh_shard_map
 def test_two_servers_grpc_forward_to_mesh_global():
     """local Server --forwardrpc--> GLOBAL Server whose engine is
     sharded over the 8-device mesh: the full multi-chip global tier,

@@ -321,10 +321,45 @@ def test_hot_slot_batch_accuracy_and_count():
         assert abs(by[f"cold.{q*100:g}percentile"] - exp) / exp < 0.02
 
 
+def test_hot_slots_in_a_batch_wider_than_batch_size():
+    """The native pump lands batches at native_pump_batch, wider than
+    the staging batch_size. The hot-slot sidestep must size its pad
+    arrays from the batch it was handed: sized from batch_size, six
+    hot slots in a 2048-wide batch overran a 2-slot pad, the landing
+    raised after its first (donating) dispatch, and the samples were
+    gone."""
+    import numpy as np
+
+    from veneur_tpu.ingest.parser import MetricKey
+
+    eng = AggregationEngine(EngineConfig(
+        histogram_slots=64, counter_slots=8, gauge_slots=8, set_slots=8,
+        batch_size=512, buffer_depth=256, percentiles=(0.5,),
+        aggregates=("min", "max", "count")))
+    eng.warm_ingest_kernels(2048)      # what Server.start() does
+    slots = [eng.histo_keys.lookup(MetricKey(f"hot{i}", "timer", ""), 0)
+             for i in range(6)]
+    rng = np.random.default_rng(3)
+    batch_slots = np.repeat(np.asarray(slots, np.int32), 300)
+    pad = np.full(2048 - batch_slots.size, -1, np.int32)
+    batch_slots = np.concatenate([batch_slots, pad])
+    vals = rng.gamma(2.0, 20.0, 2048).astype(np.float32)
+    eng.ingest_histo_batch(batch_slots, vals, np.ones(2048, np.float32),
+                           count=1800)
+    by = {m.name: m.value for m in eng.flush(timestamp=1).metrics}
+    for i, s in enumerate(slots):
+        mine = vals[batch_slots == s]
+        assert by[f"hot{i}.count"] == 300.0
+        assert by[f"hot{i}.min"] == float(mine.min())
+        assert by[f"hot{i}.max"] == float(mine.max())
+        exp = float(np.quantile(mine.astype(np.float64), 0.5))
+        assert abs(by[f"hot{i}.50percentile"] - exp) / exp < 0.02
+
+
 @pytest.mark.parametrize("mode", ["sync", "staged", "host", "async"])
 def test_flush_fetch_modes_identical(mode):
     """Every flush_fetch mode must produce identical results (the modes
-    only change HOW outputs leave the device — TPU_EVIDENCE_r04.md §4).
+    only change HOW outputs leave the device).
     "host" falls back to "staged" where pinned_host is unsupported."""
     lines = [b"c.hits:7|c", b"g.temp:70|g", b"s.u:alice|s", b"s.u:bob|s"]
     lines += [f"t.req:{v}|ms".encode() for v in range(1, 201)]

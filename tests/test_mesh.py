@@ -6,14 +6,10 @@ import jax
 import numpy as np
 import pytest
 
-from envprobes import needs_mesh_shard_map
 from veneur_tpu.parallel.mesh import MeshEngine, make_mesh
 
-pytestmark = [
-    pytest.mark.skipif(len(jax.devices()) < 8,
-                       reason="needs 8 virtual devices"),
-    needs_mesh_shard_map,   # environmental jax.shard_map API drift
-]
+pytestmark = pytest.mark.skipif(len(jax.devices()) < 8,
+                                reason="needs 8 virtual devices")
 
 
 def make_engine(n_dp=2, n_shard=4, **kw):

@@ -14,7 +14,6 @@ import time
 import numpy as np
 import pytest
 
-from envprobes import needs_mesh_shard_map
 from veneur_tpu.config import Config
 from veneur_tpu.ingest.parser import MetricKey
 from veneur_tpu.models.pipeline import EngineConfig
@@ -23,7 +22,6 @@ from veneur_tpu.server import Server
 from veneur_tpu.sinks.basic import CaptureMetricSink
 
 
-@needs_mesh_shard_map
 def test_mesh_engine_unit_all_types():
     """Direct engine test across every bank type and many slots, so
     samples land on every shard column."""
@@ -60,7 +58,6 @@ def test_mesh_engine_unit_all_types():
     assert len(eng.flush(timestamp=8).metrics) == 0
 
 
-@needs_mesh_shard_map
 def test_mesh_server_end_to_end_udp():
     cap = CaptureMetricSink()
     cfg = Config(statsd_listen_addresses=["udp://127.0.0.1:0"],
@@ -108,7 +105,6 @@ def test_mesh_engine_rejects_forwarding():
                               n_devices=8)
 
 
-@needs_mesh_shard_map
 def test_mesh_hot_slot_batch():
     """A batch overfilling one slot's buffer takes the host pre-cluster
     sidestep on the mesh path too: exact count/sum/min/max, tail
@@ -139,7 +135,6 @@ def test_mesh_hot_slot_batch():
     assert by["cold.count"] == float((slots == cold).sum())
 
 
-@needs_mesh_shard_map
 def test_mesh_global_tier_imports():
     """The mesh engine as GLOBAL tier: 32 shards' forwarded digests,
     sets, counters and gauges Combine over the 8-device mesh and flush
@@ -198,7 +193,6 @@ def test_mesh_global_tier_imports():
     assert abs(by["u"] - 160) / 160 < 0.1
 
 
-@needs_mesh_shard_map
 def test_mesh_global_tier_adversarial_landing():
     """The global tier's exact-stats delta correction (engine.py
     host-replicates the device's f32 per-term arithmetic so the deltas
@@ -266,7 +260,6 @@ def test_mesh_global_tier_adversarial_landing():
             assert abs(got - exp) / exp < 0.02, (k, q, got, exp)
 
 
-@needs_mesh_shard_map
 @pytest.mark.parametrize("mode", ["staged", "async"])
 def test_mesh_flush_fetch_modes(mode):
     """Mesh flush under non-sync fetch modes matches sync results (the
@@ -292,3 +285,26 @@ def test_mesh_flush_fetch_modes(mode):
     assert got.keys() == ref.keys()
     for k in ref:
         np.testing.assert_allclose(got[k], ref[k], rtol=1e-6, err_msg=k)
+
+
+def test_mesh_import_keeps_forwarded_extremes_exact():
+    """A forwarded digest's centroid means are cumsum differences and
+    can sit a few ulp outside its exact [min, max]. The mesh engine
+    lands centroids as samples, whose values feed the extremes scatter
+    — so an unclamped mean moved the slot's max off the forwarded
+    exact one (the single-device engine merges extremes separately)."""
+    eng = MeshAggregationEngine(EngineConfig(
+        histogram_slots=64, counter_slots=32, gauge_slots=32,
+        set_slots=16, buffer_depth=32, batch_size=256,
+        percentiles=(0.5,), aggregates=("min", "max", "count"),
+        is_global=True), n_devices=8)
+    vmin, vmax = float(np.float32(88.1)), float(np.float32(99.45))
+    over = float(np.nextafter(np.float32(vmax), np.float32(np.inf)))
+    under = float(np.nextafter(np.float32(vmin), np.float32(-np.inf)))
+    eng.import_histogram(
+        MetricKey("t", "timer", ""), [under, 91.0, 95.0, over],
+        [1.0, 1.0, 1.0, 1.0], vmin, vmax, 373.55, 4.0)
+    by = {m.name: m.value for m in eng.flush(timestamp=1).metrics}
+    assert by["t.min"] == vmin
+    assert by["t.max"] == vmax
+    assert by["t.count"] == 4.0

@@ -281,6 +281,61 @@ class TestNativeServer:
         assert len(native_ev) == len(py_ev)
 
 
+    def test_native_local_forwards_full_then_delta(self):
+        """A native-ingest local tier that forwards: the FULL export
+        build reads the interner's whole table (all_items), which the
+        bridge-backed key view did not have — the first flush of any
+        native_ingest + forward_address server died in
+        _flush_bookkeeping. Full ships idle keys too; the delta after
+        it only what was touched."""
+        from veneur_tpu.config import Config
+        from veneur_tpu.server import Server
+        from veneur_tpu.sinks.basic import CaptureMetricSink
+
+        sent = []
+        # forward_address makes the engine build forward exports; the
+        # injected callable stands in for the wire
+        cfg = Config(statsd_listen_addresses=["udp://127.0.0.1:0"],
+                     interval="3600s", hostname="h", native_ingest=True,
+                     forward_address="127.0.0.1:1",
+                     tpu_histogram_slots=256, tpu_counter_slots=128,
+                     tpu_gauge_slots=128, tpu_set_slots=64,
+                     tpu_batch_size=256, native_pump_batch=256)
+        srv = Server(cfg, sinks=[CaptureMetricSink()], span_sinks=[],
+                     forwarder=lambda exp: sent.append(exp))
+        srv.start()
+        try:
+            sock = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+            port = srv.bound_port()
+
+            def send(lines):
+                base = int(srv.native_bridge.stats()["lines"])
+                for ln in lines:
+                    sock.sendto(ln, ("127.0.0.1", port))
+                deadline = time.monotonic() + 5
+                while int(srv.native_bridge.stats()["lines"]) \
+                        < base + len(lines):
+                    assert time.monotonic() < deadline
+                    time.sleep(0.01)
+                assert srv.drain()
+
+            send([b"g.hits:3|c|#veneurglobalonly",
+                  b"g.idle:1|c|#veneurglobalonly",
+                  b"users:alice|s", b"api.t:5|ms"])
+            srv.flush_once(timestamp=1000)
+            full = sent[-1]
+            assert full.kind == "full"
+            assert {k.name: v for k, v in full.counters} == {
+                "g.hits": 3.0, "g.idle": 1.0}
+            send([b"g.hits:4|c|#veneurglobalonly"])
+            srv.flush_once(timestamp=1010)
+            assert {k.name: v for k, v in sent[-1].counters}[
+                "g.hits"] == 4.0
+            sock.close()
+        finally:
+            srv.stop()
+
+
 class TestAdvisorRegressions:
     def test_thread_local_cache_is_bridge_scoped(self):
         """A thread that ingested into bridge A must not reuse A's

@@ -379,3 +379,43 @@ def test_native_listeners_receive_configured_rcvbuf(monkeypatch):
         srv.stop()
     assert calls["statsd"] == 5 << 20
     assert calls["ssf"] == 5 << 20
+
+
+def test_import_enqueue_waits_for_room_then_sheds_counted():
+    """The import entry points hand metrics to the workers with
+    backpressure, not drop-on-full: a burst larger than the queue (one
+    local's flush of 100k sketches against 65,536 slots) waits for the
+    worker instead of losing the overflow. The wait is bounded by
+    flush_timeout, a timed-out queue sheds without waiting for that
+    long, and every shed metric is counted."""
+    import queue
+    import threading
+
+    from veneur_tpu.observe import SERVER_SCOPE
+
+    srv, _sink = make_server(flush_timeout="0.3s")   # never started
+    try:
+        q = srv.worker_queues[0] = queue.Queue(maxsize=2)
+        srv._enqueue_import(0, "a")
+        srv._enqueue_import(0, "b")                  # full now
+        # a consumer frees a slot a moment later: the put waits for it
+        threading.Timer(0.05, q.get).start()
+        srv._enqueue_import(0, "c")
+        assert q.qsize() == 2
+        assert srv.telemetry.total(SERVER_SCOPE, "worker.dropped") == 0
+        # nobody consumes: one bounded wait, then counted shedding ...
+        t0 = time.monotonic()
+        srv._enqueue_import(0, "d", n=5)
+        waited = time.monotonic() - t0
+        assert 0.25 <= waited < 2.0
+        assert srv.telemetry.total(SERVER_SCOPE, "worker.dropped") == 5
+        # ... and the next items do not wait again
+        t0 = time.monotonic()
+        srv._enqueue_import(0, "e")
+        assert time.monotonic() - t0 < 0.1
+        assert srv.telemetry.total(SERVER_SCOPE, "worker.dropped") == 6
+        # the other queue is unaffected
+        srv._enqueue_import(1, "x")
+        assert srv.worker_queues[1].qsize() == 1
+    finally:
+        srv.stop()

@@ -8,8 +8,6 @@ import time
 
 import pytest
 
-from envprobes import needs_cryptography
-
 from veneur_tpu.config import Config
 from veneur_tpu.server import Server
 from veneur_tpu.sinks.basic import CaptureMetricSink
@@ -105,7 +103,6 @@ def _self_signed(tmp_path, name):
     return str(kp), str(cp)
 
 
-@needs_cryptography
 def test_tls_statsd(tmp_path):
     key, cert = _self_signed(tmp_path, "server")
     srv, cap = make_server(tmp_path, "tcp://127.0.0.1:0",
@@ -124,7 +121,6 @@ def test_tls_statsd(tmp_path):
         srv.stop()
 
 
-@needs_cryptography
 def test_mutual_tls_rejects_certless_client(tmp_path):
     skey, scert = _self_signed(tmp_path, "server")
     ckey, ccert = _self_signed(tmp_path, "client")

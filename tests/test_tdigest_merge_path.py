@@ -171,3 +171,48 @@ def test_compress_output_prefix_is_cluster_ordered():
         assert np.all(weight[r, n:] == 0), "empties must be a suffix"
         assert np.all(np.diff(mean[r, :n]) >= 0), \
             "positive-weight means must be non-decreasing"
+
+
+def test_tpu_forms_equal_the_gather_forms():
+    """The compress picks, per platform, between forms that must give
+    ONE result: a binary search or a count for the cluster end
+    positions, a gather or a select-and-sum to read the cumulative
+    rows there, the merge path or the plain row sort. The TPU forms run
+    here on the CPU, next to the forms the CPU serves."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from veneur_tpu.ops import tdigest
+
+    rng = np.random.default_rng(11)
+    K, M, C = 23, 512, 256
+    steps = (rng.random((K, M)) < 0.3).astype(np.int32)
+    cluster = np.clip(np.cumsum(steps, axis=1) - 1, 0, C - 1)
+    cluster[3] = 0                      # one cluster takes the row
+    cluster[4] = C - 1                  # everything parked on the last
+    cluster = jnp.asarray(cluster.astype(np.int32))
+    ends_s = jax.jit(tdigest._ends_by_search, static_argnums=1)(cluster, C)
+    ends_c = jax.jit(tdigest._ends_by_count, static_argnums=1)(cluster, C)
+    np.testing.assert_array_equal(np.asarray(ends_s), np.asarray(ends_c))
+
+    cum = np.cumsum(rng.lognormal(0, 2, (K, M)), axis=1).astype(np.float32)
+    cum[5, 100:] = np.inf               # a real +inf rides through
+    padded = jnp.asarray(np.concatenate(
+        [np.zeros((K, 1), np.float32), cum], axis=1))
+    got_g = jax.jit(tdigest._lanes_by_gather)(padded, ends_s)
+    got_s = jax.jit(tdigest._lanes_by_select)(padded, ends_s)
+    np.testing.assert_array_equal(
+        np.asarray(got_g).view(np.uint32), np.asarray(got_s).view(np.uint32))
+
+    vals = rng.normal(0, 50, (K, M)).astype(np.float32)
+    vals[1, 5], vals[1, M - 1] = 0.0, -0.0                 # signed zeros
+    vals[:, :M // 2] = np.sort(vals[:, :M // 2], axis=1)   # ordered prefix
+    vals[0, M // 2] = vals[0, 3]                           # a tie
+    wts = np.ones((K, M), np.float32)
+    a = jax.jit(tdigest._row_sort)(jnp.asarray(vals), jnp.asarray(wts))
+    b = jax.jit(tdigest._merge_path_sort, static_argnames="S")(
+        jnp.asarray(vals), jnp.asarray(wts), S=M // 2)
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(np.asarray(x).view(np.uint32),
+                                      np.asarray(y).view(np.uint32))

@@ -8,13 +8,12 @@ absolute paths.
 DEFAULT_CONFIG = {
     # JX03: modules allowed to synchronise with the device. The flush /
     # fetch layer owns every legitimate device_get/block_until_ready in
-    # the serving path; the native/*.py entries are offline validation
-    # harnesses, not servers.
+    # the serving path; the native/*.py entry is an offline stress
+    # harness, not a server.
     "jx03_allow": (
         "veneur_tpu/models/pipeline.py",
         "veneur_tpu/parallel/mesh.py",
         "veneur_tpu/parallel/engine.py",
-        "native/pallas_validate.py",
         "native/tsan_stress.py",
     ),
     # TH01: files whose classes run methods from multiple threads

@@ -425,9 +425,8 @@ def _read_after_donation(call, target: str, parents) -> int | None:
 
 def check_jx03(mod: PyModule, config: dict) -> list[Violation]:
     """Host synchronisation outside the flush/fetch layer. device_get /
-    block_until_ready / copy_to_host_async stall the dispatch pipeline
-    (and on relayed backends invalidate the serving executable); every
-    legitimate sync point lives in the allowlisted modules or carries an
+    block_until_ready / copy_to_host_async stall the dispatch
+    pipeline; every legitimate sync point lives in the allowlisted modules or carries an
     inline suppression explaining itself."""
     if any(mod.path.endswith(a) for a in config["jx03_allow"]):
         return []

@@ -33,9 +33,15 @@ def main(argv=None):
         print("config ok")
         return 0
 
+    from ..utils import platform
     if cfg.aggregation_backend == "cpu":
-        from ..utils.platform import pin_cpu
-        pin_cpu()
+        platform.pin_cpu()
+    else:
+        # libtpu without a chip makes JAX warn and hand back the CPU;
+        # a daemon configured for the TPU must not serve on that
+        platform.require_tpu(
+            f"aggregation_backend: {cfg.aggregation_backend}")
+    platform.setup_compile_cache()
 
     from ..server import Server
     srv = Server(cfg)

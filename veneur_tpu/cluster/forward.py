@@ -73,6 +73,33 @@ def _count_forward_bytes(egress: Egress, nbytes: int, kind: str):
              else "forward.bytes_full", nbytes)
 
 
+# A MetricList must fit the receiver's gRPC message limit, 4 MiB unless
+# it was raised: 1,000 HLL p=14 sets are 16 MiB of registers, far past
+# it at 10,000 metrics a chunk. Chunks close at this many payload
+# bytes, leaving room for the envelope, the stamp and the advisory rows.
+MAX_CHUNK_BYTES = 3 << 20
+
+
+def _chunk_bounds(metrics: list, max_count: int,
+                  max_bytes: int = MAX_CHUNK_BYTES) -> list:
+    """[(start, end)) chunk boundaries over `metrics`: greedy, at most
+    `max_count` metrics and `max_bytes` serialized bytes per chunk (a
+    single larger metric rides alone). Greedy from any chunk START
+    reproduces the original boundaries after it, so a replayed tail
+    re-chunks to the chunk ids it was first sent under."""
+    bounds, start, size = [], 0, 0
+    for i, m in enumerate(metrics):
+        b = m.ByteSize()
+        if i > start and (i - start >= max_count
+                          or size + b > max_bytes):
+            bounds.append((start, i))
+            start, size = i, 0
+        size += b
+    if start < len(metrics):
+        bounds.append((start, len(metrics)))
+    return bounds
+
+
 class GrpcForwarder:
     """Callable handed to Server.forwarder: ships a flush's exports
     upstream over the forwardrpc contract."""
@@ -117,16 +144,15 @@ class GrpcForwarder:
         metrics = wire.export_to_metrics(export,
                                          codec=self.centroid_codec)
         deadline = self._egress.deadline()
-        n_chunks = -(-len(metrics) // self.max_per_batch)
+        bounds = _chunk_bounds(metrics, self.max_per_batch)
+        n_chunks = len(bounds)
         total = 0
         kind = envelope.kind if envelope is not None else "full"
         if envelope is not None:
             total = envelope.chunk_count or (envelope.chunk_offset
                                              + n_chunks)
-        for j in range(n_chunks):
-            i = j * self.max_per_batch
-            batch = forward_pb2.MetricList(
-                metrics=metrics[i:i + self.max_per_batch])
+        for j, (i, end) in enumerate(bounds):
+            batch = forward_pb2.MetricList(metrics=metrics[i:end])
             if self.engine_stamp:
                 batch.sketch_engines = self.engine_stamp
             if j == 0 and export.prefix_sketches:

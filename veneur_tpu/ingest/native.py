@@ -50,14 +50,18 @@ class NativeUnavailable(RuntimeError):
 
 
 def build(force: bool = False) -> str:
-    """Compile the shared library if missing. Returns its path."""
+    """Compile the shared library if missing or older than its source;
+    `force` rebuilds it regardless (make -B) — a copied tree's mtimes
+    say nothing about which source a library on disk was built from.
+    Returns its path."""
     src = os.path.join(_NATIVE_DIR, "vtpu_ingest.cpp")
     if not os.path.exists(src):
         raise NativeUnavailable(f"source missing: {src}")
     if force or not os.path.exists(_LIB_PATH) or (
             os.path.getmtime(_LIB_PATH) < os.path.getmtime(src)):
-        proc = subprocess.run(["make", "-C", _NATIVE_DIR],
-                              capture_output=True, text=True)
+        proc = subprocess.run(
+            ["make", "-C", _NATIVE_DIR] + (["-B"] if force else []),
+            capture_output=True, text=True)
         if proc.returncode != 0:
             raise NativeUnavailable(
                 f"native build failed:\n{proc.stdout}\n{proc.stderr}")
@@ -444,11 +448,11 @@ class BridgeKeyView:
     def scope_of(self, slot: int) -> int:
         return int(self._scopes[slot])
 
-    def active_items(self):
+    def _items(self, slots):
         self.refresh_scopes()
         out = []
         scopes = self._scopes
-        for slot in np.nonzero(self.touched)[0].tolist():
+        for slot in slots:
             key = self.mirror.get(slot)
             if key is not None:
                 holder = self._holders.get(slot)
@@ -456,6 +460,17 @@ class BridgeKeyView:
                     holder = self._holders[slot] = SlotInfo(slot, 0, 0)
                 out.append((key, slot, int(scopes[slot]), holder))
         return out
+
+    def active_items(self):
+        return self._items(np.nonzero(self.touched)[0].tolist())
+
+    def all_items(self):
+        """Every key the mirror holds, touched or idle — what a FULL
+        forward resync ships (KeyInterner.all_items parity). The C++
+        interner evicts idle keys without telling the mirror, so a key
+        whose slot was freed and not yet reassigned still rides along:
+        its bank row is fresh-init, one more zero/empty liveness row."""
+        return self._items(list(self.mirror))
 
     def advance_interval(self):
         self.touched[:] = False

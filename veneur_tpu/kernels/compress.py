@@ -20,9 +20,10 @@ TWO IN-KERNEL SORT ARMS, one numeric pipeline:
     same bits AND same speed as the XLA program (the "no slower than
     XLA on CPU-interpret" gate), with the whole compress living in
     one pallas_call.
-  * `network=True` (the Mosaic/TPU arm, also what `probe_compiled`
-    compiles): `lax.sort` has no Mosaic lowering, so the sort/merge
-    stages are explicit compare-exchange NETWORKS — a bitonic full
+  * `network=True` (the form written for Mosaic — which refuses it
+    today, see TPU_AUTO_ARM): `lax.sort` has no Mosaic lowering, so
+    the sort/merge stages are explicit compare-exchange NETWORKS — a
+    bitonic full
     sort of the buffer run carrying the payload lanes, then
     `_merge_sorted_runs`' exchange network replicated literally (same
     pad placement, same reversed run, same lexicographic predicate).
@@ -42,8 +43,8 @@ reproduce `_compress_impl` bit-for-bit under `interpret=True` on CPU —
 ±0.0/NaN key canonicalization, duplicate-key stability, NaN payload
 bits, cluster-id overflow clipping, and the cummax clamp included.
 The network arm is additionally fuzzed as plain jnp against
-`_stable_sort_perm`/`_merge_sorted_runs` directly, so the TPU-compiled
-arm's order math carries a CPU proof even before the TPU capture.
+`_stable_sort_perm`/`_merge_sorted_runs` directly, so its order math
+carries a CPU proof of its own.
 
 The row axis is embarrassingly parallel, so the grid blocks rows:
 `_BLOCK_ROWS` per program when compiled (VMEM-bounded),
@@ -63,7 +64,26 @@ from . import count_fallback
 from ..ops import tdigest as _td
 
 _INF = jnp.inf
-_BLOCK_ROWS = 256        # compiled-arm row block: ~5 MB VMEM at M=512
+
+# Mosaic's verdict (jax 0.9.0 / jaxlib 0.9.0 / libtpu 0.0.34, TPU v5e,
+# the serving shape C=256 B=256 over the 256-row block; chip_smoke.py's
+# kernel leg asks again on every run and prints the answer):
+#
+#   NotImplementedError: Unimplemented primitive in Pallas TPU lowering
+#   for KernelType.TC: rev. Please file an issue on
+#   https://github.com/jax-ml/jax/issues.
+#
+# `rev` (the reversed buffer run feeding the bitonic merge) is only the
+# first: the same lowering has no rule for asin (the k1 scale), cumsum
+# and cummax (the shared cluster tail), nor dynamic_slice /
+# dynamic_update_slice (the boundary loop) — operations this kernel is
+# built on, not a matter of block size or placement. So on a TPU `auto`
+# serves the XLA compress; `on` raises (require_engine_kernels). The
+# interpret arm on the CPU is unaffected. ROADMAP carries the rewrite.
+TPU_AUTO_ARM = "xla"
+
+_BLOCK_ROWS = 256        # compiled-arm row block (VMEM need unmeasured:
+#                          Mosaic refuses the kernel before allocating)
 # interpret-arm row block: the simulator holds every intermediate of a
 # block live at once, so an unbounded block over a 100k bank would
 # peak at GBs of [K, M] temporaries; 4096 rows bounds it at the
@@ -178,15 +198,25 @@ def _fused_cluster_network(vals, wts, compression: float, C: int,
     P = 1 << (M - 1).bit_length()
     pad = P - M
     atag = jax.lax.broadcasted_iota(jnp.int32, (R, S), 1)
-    ptag = jax.lax.broadcasted_iota(jnp.int32, (R, pad), 1) + M
     sbt = jax.lax.broadcasted_iota(jnp.int32, (R, nb), 1) + S
-    mk = jnp.concatenate(
-        [key[:, :S], jnp.full((R, pad), jnp.uint32(0xFFFFFFFF)),
-         bk[:, ::-1]], axis=1)
-    mt = jnp.concatenate([atag, ptag, sbt[:, ::-1]], axis=1)
-    zp = jnp.zeros((R, pad), vals.dtype)
-    mv = jnp.concatenate([vals[:, :S], zp, bv[:, ::-1]], axis=1)
-    mw = jnp.concatenate([wts[:, :S], zp, bw[:, ::-1]], axis=1)
+
+    # [prefix | pads | reversed run]. Mosaic has no zero-width vectors,
+    # and at the default shape (M = C + B = 512, already a power of
+    # two) there is no pad piece to build
+    pk = pt = pz = None
+    if pad:
+        pk = jnp.full((R, pad), jnp.uint32(0xFFFFFFFF))
+        pt = jax.lax.broadcasted_iota(jnp.int32, (R, pad), 1) + M
+        pz = jnp.zeros((R, pad), vals.dtype)
+
+    def bitonic(prefix, pads, run):
+        mid = [pads] if pad else []
+        return jnp.concatenate([prefix, *mid, run[:, ::-1]], axis=1)
+
+    mk = bitonic(key[:, :S], pk, bk)
+    mt = bitonic(atag, pt, sbt)
+    mv = bitonic(vals[:, :S], pz, bv)
+    mw = bitonic(wts[:, :S], pz, bw)
     _mk, _mt, mv, mw = _bitonic_merge(mk, mt, mv, mw)
     vals, wts = mv[:, :M], mw[:, :M]
 

@@ -32,8 +32,16 @@ zero-weight empties. quantile() always relied on this to skip a defensive
 re-sort; the merge-path compress now relies on it for CORRECTNESS, not just
 speed. Only this module may write those fields (vlint SR02 enforces it);
 writes elsewhere need a documented suppression proving the order survives.
-The old full-row sort stays available for A/B (VENEUR_TPU_TDIGEST_FULL_SORT=1
-or the full_sort= argument) until a TPU-live capture confirms the win.
+WHICH FORM RUNS IS THE PLATFORM'S BUSINESS (_sort_rows, _cluster_ends,
+_lanes_at; jax.lax.platform_dependent, one result either way): the
+merge path trades comparator-sort work for row gathers, the right trade
+on XLA-CPU and the wrong one on the TPU, where a [131072, 512] row sort
+takes 22 ms and ONE row gather of that size 1.9 s. As first brought up
+on the v5e a full-bank compress took 16.6 s (the flush interval is
+10 s); with the row sort, a counted binary search and select-and-sum
+reads it takes 112 ms (my chip runs, PR 23). The full-row sort also
+stays selectable everywhere for the CPU A/B
+(VENEUR_TPU_TDIGEST_FULL_SORT=1 or the full_sort= argument).
 
 State layout (per bank):
   mean, weight : f32[K, C]   merged centroids (weight 0 == empty slot)
@@ -85,10 +93,10 @@ _INF = jnp.inf
 # each program first compiles, not at import), so setting it any time
 # before the first compile works; already-compiled programs keep the arm
 # they were traced with. DEPRECATED (ISSUE 11): the merge path has been
-# the serving default since ISSUE 3 with a pinned 1.97x win and bitwise
-# A/B equivalence; the legacy arm is slated for removal once a TPU-live
-# capture (capture_tpu_window.sh) confirms the win on hardware — setting
-# the flag now warns loudly so deployments migrate off it first.
+# the serving default since ISSUE 3 with a 1.97x win on XLA-CPU and
+# bitwise A/B equivalence. On a TPU the switch changes nothing — both
+# arms are the plain row sort there (_sort_rows). Setting the flag
+# warns loudly so deployments migrate off it; ROADMAP has its removal.
 _warned_full_sort = False
 
 
@@ -103,10 +111,9 @@ def _full_sort_default() -> bool:
         msg = ("VENEUR_TPU_TDIGEST_FULL_SORT=1 forces the DEPRECATED "
                "legacy full-row comparator sort in every t-digest "
                "compress (~2x the merge-path cost, bitwise-identical "
-               "output). The flag and the legacy arm will be removed "
-               "after a TPU-live capture confirms the merge-path win "
-               "on hardware (ROADMAP flush item); unset it unless "
-               "running the bench A/B.")
+               "output) on XLA-CPU; on a TPU both arms are the row "
+               "sort. The flag is slated for removal (ROADMAP); unset "
+               "it unless running the bench A/B.")
         warnings.warn(msg, DeprecationWarning, stacklevel=2)
         logging.getLogger(__name__).warning(msg)
     return on
@@ -313,6 +320,44 @@ def _merge_sorted_runs(akey, bkey, S: int, M: int):
     return tag[:, :M].astype(jnp.int32)
 
 
+def _row_sort(vals, wts):
+    """The full stable row sort of (value, weight) rows by value."""
+    return tuple(jax.lax.sort((vals, wts), dimension=-1, num_keys=1))
+
+
+def _merge_path_sort(vals, wts, S: int):
+    """The same order as _row_sort for rows whose first S lanes are
+    already cluster-ordered: sort only the suffix (packed radix passes),
+    rank-merge the two runs, gather the payloads once."""
+    M = vals.shape[1]
+    akey = _canonical_sort_key(vals[:, :S])
+    bkey, perm = _stable_sort_perm(_canonical_sort_key(vals[:, S:]))
+    tags = _merge_sorted_runs(akey, bkey, S, M)
+    # tag t: prefix lane t when t < S, else sorted-buffer position
+    # t-S -> original buffer lane through stage 1's permutation
+    src = jnp.where(
+        tags < S, tags,
+        S + jnp.take_along_axis(
+            perm, jnp.clip(tags - S, 0, M - S - 1), axis=1))
+    return (jnp.take_along_axis(vals, src, axis=1),
+            jnp.take_along_axis(wts, src, axis=1))
+
+
+def _sort_rows(vals, wts, S: int):
+    """Row-sort rows with an ordered S-lane prefix, in the form the
+    platform is good at — one result, bit for bit (the merge path
+    reproduces the stable row sort exactly; tests/
+    test_tdigest_merge_path.py). On XLA-CPU the comparator sort is the
+    cost and the merge path halves it. On the v5e it is the reverse:
+    per full [131072, 512] bank the row sort takes 22 ms while the
+    merge path's pieces take 2.6 s (radix passes with their gathers)
+    plus three more row gathers at 1.9 s each — a lane gather there
+    costs ~28 ns an element (my chip run, PR 23)."""
+    return jax.lax.platform_dependent(
+        vals, wts, tpu=_row_sort,
+        default=partial(_merge_path_sort, S=S))
+
+
 def _cluster_core(vals, wts, compression: float, C: int,
                   sorted_prefix: int = 0):
     """Greedy k1 clustering of arbitrary [K, M] (value, weight) rows into
@@ -321,10 +366,14 @@ def _cluster_core(vals, wts, compression: float, C: int,
 
     `sorted_prefix=S` asserts vals[:, :S] is already cluster-ordered
     (positive-weight values non-decreasing, zero-weight entries last —
-    the module's ordering invariant); then only vals[:, S:] is row-sorted
-    and the runs are rank-merged, bit-identical to the full sort. Callers
-    must only pass S > 0 for prefixes they can PROVE ordered — an
-    unordered prefix silently mis-clusters."""
+    the module's ordering invariant); then only vals[:, S:] needs
+    sorting and the runs are rank-merged, bit-identical to the full
+    sort. Callers must only pass S > 0 for prefixes they can PROVE
+    ordered — an unordered prefix silently mis-clusters. Whether the
+    claim is USED is the platform's business (_sort_rows): the merge
+    path is the cheap form where a comparator sort is dear and a gather
+    free (XLA-CPU), the plain row sort where it is the other way round
+    (the TPU)."""
     K, M = vals.shape
     vals = jnp.where(wts > 0, vals, _INF)
 
@@ -338,23 +387,11 @@ def _cluster_core(vals, wts, compression: float, C: int,
     # (The ingest kernel's packed sort, scatter.sort_by_slot, is
     # different — its key is the integer slot id, packed losslessly.)
     if 0 < sorted_prefix < M:
-        S = sorted_prefix
-        akey = _canonical_sort_key(vals[:, :S])
-        bkey, perm = _stable_sort_perm(
-            _canonical_sort_key(vals[:, S:]))
-        tags = _merge_sorted_runs(akey, bkey, S, M)
-        # tag t: prefix lane t when t < S, else sorted-buffer position
-        # t-S -> original buffer lane through stage 1's permutation
-        src = jnp.where(
-            tags < S, tags,
-            S + jnp.take_along_axis(
-                perm, jnp.clip(tags - S, 0, M - S - 1), axis=1))
-        vals = jnp.take_along_axis(vals, src, axis=1)
-        wts = jnp.take_along_axis(wts, src, axis=1)
+        vals, wts = _sort_rows(vals, wts, sorted_prefix)
     elif sorted_prefix >= M:
         pass  # the whole row is one ordered run — nothing to do
     else:
-        vals, wts = jax.lax.sort((vals, wts), dimension=-1, num_keys=1)
+        vals, wts = _row_sort(vals, wts)
 
     def boundaries(k_left, k_right, wts):
         # Greedy cluster boundaries, scanned over the sorted axis
@@ -378,6 +415,51 @@ def _cluster_core(vals, wts, compression: float, C: int,
         return is_new.T                                  # [K, M] bool
 
     return _cluster_tail(vals, wts, compression, C, boundaries)
+
+
+def _ends_by_search(cluster, C: int):
+    targets = jnp.arange(C, dtype=jnp.int32)
+    return jax.vmap(lambda row: jnp.searchsorted(
+        row, targets, side="right"))(cluster).astype(jnp.int32)
+
+
+def _ends_by_count(cluster, C: int):
+    targets = jnp.arange(C, dtype=jnp.int32)
+    return jnp.sum(cluster[:, None, :] <= targets[None, :, None],
+                   axis=2, dtype=jnp.int32)
+
+
+def _cluster_ends(cluster, C: int):
+    """ends[k, c] = how many lanes of row k carry a cluster id <= c —
+    the end position of cluster c in the (non-decreasing) id row. A
+    per-row binary search where gathers are cheap; on the TPU, where
+    each of its ~10 probes is a row gather, a plain count over the row
+    (compare + reduce, which XLA fuses without materializing the
+    [K, C, M] mask). Integers either way: the same `ends`."""
+    return jax.lax.platform_dependent(
+        cluster, tpu=partial(_ends_by_count, C=C),
+        default=partial(_ends_by_search, C=C))
+
+
+def _lanes_by_gather(padded, idx):
+    return jnp.take_along_axis(padded, idx, axis=1)
+
+
+def _lanes_by_select(padded, idx):
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, padded.shape[1]), 2)
+    return jnp.sum(jnp.where(lane == idx[:, :, None],
+                             padded[:, None, :], 0), axis=2)
+
+
+def _lanes_at(c, idx):
+    """c_padded[k, idx[k, j]] with c_padded = [0 | c] — a cumulative
+    row read at cluster end positions. A gather where gathers are
+    cheap; on the TPU a select-and-sum over the row, whose only
+    non-zero term is the wanted lane (exact: x + 0 is x)."""
+    padded = jnp.concatenate(
+        [jnp.zeros((c.shape[0], 1), c.dtype), c], axis=1)
+    return jax.lax.platform_dependent(
+        padded, idx, tpu=_lanes_by_select, default=_lanes_by_gather)
 
 
 def _cluster_tail(vals, wts, compression: float, C: int, boundary_fn):
@@ -411,18 +493,9 @@ def _cluster_tail(vals, wts, compression: float, C: int, boundary_fn):
     # the next compress's ordering comparator-undefined in both arms.
     cw = jnp.cumsum(wts, axis=1)
     cwv = jnp.cumsum(wts * jnp.where(wts > 0, vals, 0.0), axis=1)
-    targets = jnp.arange(C, dtype=jnp.int32)
-
-    ends = jax.vmap(lambda row: jnp.searchsorted(row, targets, side="right"))(
-        cluster
-    )                                                    # [K, C] in [0, M]
-
-    def gather_at(c, idx):
-        padded = jnp.concatenate([jnp.zeros((K, 1), c.dtype), c], axis=1)
-        return jnp.take_along_axis(padded, idx, axis=1)
-
-    w_upto = gather_at(cw, ends)
-    wv_upto = gather_at(cwv, ends)
+    ends = _cluster_ends(cluster, C)                     # [K, C] in [0, M]
+    w_upto = _lanes_at(cw, ends)
+    wv_upto = _lanes_at(cwv, ends)
     w_c = jnp.diff(w_upto, axis=1, prepend=jnp.zeros((K, 1), cw.dtype))
     wv_c = jnp.diff(wv_upto, axis=1, prepend=jnp.zeros((K, 1), cw.dtype))
 

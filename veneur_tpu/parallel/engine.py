@@ -97,6 +97,13 @@ class MeshAggregationEngine(AggregationEngine):
             hll_precision=cfg.hll_precision,
             percentiles=tuple(cfg.percentiles))
         self.S = self.me.S
+        # the mesh flush is its own sharded program: XLA compress and
+        # insert whatever the knob says, and the estimate reduction
+        # through the Pallas kernel exactly where the MeshEngine
+        # placed it (hll.will_use_pallas)
+        self._kernel_arms = {
+            "histogram": "xla", "set": "xla",
+            "estimate": "fused" if self.me.pallas_estimate else "xla"}
 
     def _setup_flush_exec(self):
         # the MeshEngine owns the compiled flush; the single-device
@@ -358,6 +365,11 @@ class MeshAggregationEngine(AggregationEngine):
             B = self.cfg.buffer_depth - 2
             if len(means) > B:
                 means, weights = _precluster_k1(means, weights, B)
+            # a centroid mean comes out of a cumsum difference and can
+            # sit a few ulp outside the digest's exact [vmin, vmax];
+            # staged as a sample it would then move this slot's
+            # extremes off the forwarded exact ones
+            means = np.clip(means, vmin, vmax)
             self._import_centroids.append(
                 (slot, means, weights, float(vmin), float(vmax)))
             self._import_h_points += len(means) + 2

@@ -64,7 +64,9 @@ class HLLEngine:
         return hll.host_hash_to_updates(hashes64, self.precision)
 
     def estimate_device(self, bank, pallas_ok: bool) -> dict:
-        return {"s_est": hll.estimate(bank, force_jnp=not pallas_ok)}
+        # the caller's resolved "estimate" arm decides, not the platform
+        est = hll._estimate_pallas if pallas_ok else hll._estimate_jnp
+        return {"s_est": est(bank)}
 
     def estimate_finalize(self, host: dict) -> None:
         host["s_est"] = np.asarray(host["s_est"])
