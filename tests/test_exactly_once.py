@@ -48,7 +48,8 @@ class TestEnvelopeEncodeDecodeParity:
         sent = []
         fwd = GrpcForwarder("127.0.0.1:1", max_per_batch=2,
                             egress=h.egress("g"))
-        fwd._send = lambda req, timeout=None: sent.append(req)
+        fwd._send = lambda req, timeout=None: sent.append(
+            forward_pb2.MetricList.FromString(req))
         env = ForwardEnvelope("sender-a", 7)
         fwd(export_of(n_counters=5), envelope=env)
         assert len(sent) == 3
@@ -68,7 +69,7 @@ class TestEnvelopeEncodeDecodeParity:
             if len(sent) == 1:      # second chunk dies terminally
                 from veneur_tpu.resilience import TerminalEgressError
                 raise TerminalEgressError("boom")
-            sent.append(req)
+            sent.append(forward_pb2.MetricList.FromString(req))
 
         fwd = GrpcForwarder("127.0.0.1:1", max_per_batch=2,
                             egress=h.egress("g"))
@@ -81,7 +82,8 @@ class TestEnvelopeEncodeDecodeParity:
         # replay the tail under the resumed envelope
         fwd2 = GrpcForwarder("127.0.0.1:1", max_per_batch=2,
                              egress=h.egress("g2"))
-        fwd2._send = lambda req, timeout=None: sent.append(req)
+        fwd2._send = lambda req, timeout=None: sent.append(
+            forward_pb2.MetricList.FromString(req))
         fwd2(ei.value.undelivered,
              envelope=ForwardEnvelope("s", 9, chunk_offset=1,
                                       chunk_count=3))

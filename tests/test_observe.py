@@ -784,7 +784,7 @@ def test_import_observer_parents_spans_on_remote_trace():
         # the import tick recorded the request's phases
         snap = srv.import_observer.flight.snapshot()
         names = {p["name"] for p in snap[0]["phases"]}
-        assert {"decode", "dedupe", "apply", "request"} <= names
+        assert {"decode", "dedupe", "route", "request"} <= names
         reqmeta = next(p for p in snap[0]["phases"]
                        if p["name"] == "request")["meta"]
         assert reqmeta["sender"] == "remote-snd"
@@ -796,7 +796,7 @@ def test_import_observer_parents_spans_on_remote_trace():
                     if s.name == "veneur.import")
         assert root.parent_id == 888_000
         child = next(s for s in client.spans
-                     if s.name == "veneur.import.apply")
+                     if s.name == "veneur.import.route")
         assert child.parent_id == root.id
         # a replayed chunk dedupes (200) and still records its phases
         with urllib.request.urlopen(req, timeout=5) as resp:
@@ -1261,3 +1261,421 @@ flush_phase_timers: false
         assert "overload.shed" not in names
     finally:
         srv.stop()
+
+
+# ------------------------- ISSUE 26: forward.send and the import, opened
+
+def _rows(tick, name):
+    """[(t0, t1, parent, idx)] of a tick's completed phases of one
+    name, in start order."""
+    return sorted((t0, t1, par, i)
+                  for i, (n, t0, t1, par) in enumerate(tick.phases())
+                  if n == name and t1 > t0)
+
+
+def test_stamp_log_budget_merge_and_take():
+    from veneur_tpu.observe import StampLog
+
+    log = StampLog({"a": 3, "b": 2})
+    for k in range(5):                      # 5 stamps, budget 3
+        log.add("a", 100 * k, 100 * k + 10)
+    log.add("b", 0, 50)
+    log.add("b", 60, 90, merge_gap_ns=20)   # 10 ns after: one busy run
+    log.add("b", 200, 210, merge_gap_ns=20)  # too late: a row of its own
+    log.add("b", 205, 215, merge_gap_ns=20)  # overlaps, over budget
+    got = log.take()
+    a = [r for r in got if r[0] == "a"]
+    b = [r for r in got if r[0] == "b"]
+    # past the budget the last row lengthens: seconds stay exact
+    assert [r[1] for r in a] == [0, 100, 200] and len(a) == 3
+    assert sum(t1 - t0 for _n, t0, t1 in a) == 50
+    assert b == [("b", 0, 90), ("b", 200, 220)]
+    assert log.take() == []                 # a take empties the log
+    log.add("a", 7, 9)
+    assert log.take() == [("a", 7, 9)]
+
+
+def test_graft_nests_children_and_clips_roots_out_of_coverage():
+    fr = FlightRecorder(capacity=2, max_phases=32)
+    t = fr.begin_tick(ts=1)
+    own = t.start("engine")
+    base = t.mono_start
+    rows = [("import.land", base - 900, base - 500),
+            ("import.land.stage", base - 900, base - 800),
+            ("import.land.cluster", base - 800, base - 600),
+            ("import.route", base - 850, base - 840),   # another thread
+            ("import.land", base - 400, base - 300),
+            ("import.apply", base - 1000, base - 100)]
+    root = t.graft(rows, root="import")
+    t.finish(own)
+    fr.end_tick(t)
+    ph = t.phases()
+    assert ph[root][:3] == ("import", base - 1000, base - 100)
+    assert ph[root][3] == -1
+    by = {n: [p for p in ph if p[0] == n] for n in {p[0] for p in ph}}
+    first_land = ph.index(("import.land", base - 900, base - 500, root))
+    assert by["import.land.stage"][0][3] == first_land
+    assert by["import.land.cluster"][0][3] == first_land
+    assert by["import.route"][0][3] == root     # same window, no prefix
+    assert all(p[3] == root for p in by["import.land"])
+    # the grafted root lies before the tick: none of its seconds are
+    # the tick's, so coverage is the own phase's share and <= 1
+    assert t.attributed_ns() <= t.duration_ns()
+    assert t.attributed_ns() == ph[own][2] - ph[own][1]
+    d = t.to_dict()
+    assert d["phases"][root]["start_ns"] == -1000     # before the tick
+    assert t.graft([], root="ingest") == -1 and t.n == len(ph)
+    # phase timers: the root's extent, like any top-level phase
+    names = {m.key.name for m in phase_timer_samples(t)}
+    assert "veneur.flush.phase.import" in names
+
+
+def _two_tier_grpc(monkeypatch, stage_digests=8, per_batch=8):
+    """A local forwarding over real gRPC, `per_batch` metrics a chunk,
+    to a global whose import landing fires every `stage_digests`
+    digests (so some landings run mid-interval, on the worker)."""
+    from veneur_tpu.cluster.forward import GrpcForwarder
+    from veneur_tpu.models import pipeline
+
+    monkeypatch.setattr(pipeline, "_IMPORT_STAGE_DIGESTS", stage_digests)
+    cfg_g = read_config(text=_YAML)
+    cfg_g.grpc_listen_addresses = ["127.0.0.1:0"]
+    glob = Server(cfg_g, sinks=[CaptureMetricSink()], plugins=[],
+                  span_sinks=[])
+    glob.start()
+    fwd = ResilientForwarder(
+        GrpcForwarder(f"127.0.0.1:{glob.grpc_port}", timeout_s=10.0,
+                      max_per_batch=per_batch),
+        destination="t26-global", sender_id="t26-sender",
+        registry=TelemetryRegistry())
+    cfg_l = read_config(text=_YAML)
+    cfg_l.forward_address = "placeholder:1"
+    local = Server(cfg_l, sinks=[CaptureMetricSink()], plugins=[],
+                   span_sinks=[], forwarder=fwd)
+    local.start()
+    return local, glob
+
+
+def test_forward_send_and_import_are_open_in_both_flush_ticks(
+        monkeypatch):
+    """One forwarded interval, read from the two flush ticks alone (as
+    the benchmark and /debug/flush read them): the local's tick splits
+    forward.send per chunk; the global's NEXT tick carries the import
+    work done since its previous flush under one `import` root."""
+    local, glob = _two_tier_grpc(monkeypatch)
+    try:
+        lines = [b"t26.lat%d:%d|ms|#veneurglobalonly" % (k, 10 + v)
+                 for k in range(20) for v in range(6)]
+        local.handle_packet(b"\n".join(lines))
+        assert local.drain(10.0)
+        local.flush_once(timestamp=2000)
+        assert glob.drain(10.0)
+        drained_ns = time.monotonic_ns()
+        merged = glob.flush_once(timestamp=2005)
+        assert sum(m.name.endswith(".count") and m.value == 6.0
+                   for m in merged if m.name.startswith("t26.")) == 20
+
+        # ---- local tier: forward.send, opened
+        lt = local.flight.last_tick()
+        assert lt.dropped == 0
+        (s0, s1, fwd_par, _i), = _rows(lt, "forward.send")
+        export, = _rows(lt, "forward.export")
+        plan, = _rows(lt, "forward.chunk.plan")
+        builds = _rows(lt, "forward.chunk.build")
+        sers = _rows(lt, "forward.chunk.serialize")
+        atts = _rows(lt, "egress.attempt")
+        assert len(builds) == len(sers) == len(atts) >= 3    # 20 / 8
+        assert s0 <= export[0] and export[1] <= plan[0] \
+            and plan[1] <= builds[0][0]
+        for b, s, a in zip(builds, sers, atts):
+            assert b[1] <= s[0] and s[1] <= a[0]
+            assert s0 <= b[0] and a[1] <= s1       # inside forward.send
+        # the same scope as the ladder: all of them under `forward`
+        assert {r[2] for r in [export, plan] + builds + sers + atts} \
+            == {fwd_par}
+        release, = _rows(lt, "forward.release")
+        assert atts[-1][1] <= release[0] and release[1] <= s1
+        # the byte counter reads the serialized chunks' lengths: what
+        # ByteSize() said before the chunks were serialized up front
+        sent = sum(sl.meta["nbytes"] for sl in lt._slots[:lt.n]
+                   if sl.name == "forward.chunk.serialize")
+        egress = local.forwarder.inner._egress
+        assert sent == egress.registry.total(
+            egress.destination, "forward.bytes") > 0
+        split = sum(r[1] - r[0] for r in [export, plan, release] + builds
+                    + sers + atts)
+        assert split <= s1 - s0
+
+        # ---- global tier: the import, grafted into the flush tick
+        gt = glob.flight.last_tick()
+        assert gt.dropped == 0
+        (i0, i1, ipar, root), = _rows(gt, "import")
+        assert ipar == -1 and i0 < gt.mono_start
+        decs = _rows(gt, "import.decode")
+        deds = _rows(gt, "import.dedupe")
+        routes = _rows(gt, "import.route")
+        assert len(decs) == len(deds) == len(routes) == len(atts)
+        for d, e, r in zip(decs, deds, routes):
+            assert d[1] <= e[0] and e[1] <= r[0]
+            assert d[2] == e[2] == r[2] == root
+        applies = _rows(gt, "import.apply")
+        assert applies and all(a[2] == root for a in applies)
+        assert routes[0][0] <= applies[0][0]
+        assert applies[-1][1] <= drained_ns
+        assert i0 == decs[0][0] and i1 == max(
+            r[1] for r in applies + routes + _rows(gt, "import.land")
+            if r[2] == root)
+        # landings: 20 digests, one every 8 on the worker (under the
+        # root), the rest at flush time inside engine.drain
+        (d0, d1, _p, drain), = _rows(gt, "engine.drain")
+        lands = _rows(gt, "import.land")
+        assert [ld[2] for ld in lands] == [root, root, drain]
+        assert lands[0][0] >= applies[0][0] and d0 <= lands[2][0] \
+            and lands[2][1] <= d1
+        for name in ("import.land.stage", "import.land.cluster"):
+            kids = _rows(gt, name)
+            assert [k[2] for k in kids] == [ld[3] for ld in lands]
+        # coverage stays a share of the tick: the root is clipped out
+        cov = gt.attributed_ns() / gt.duration_ns()
+        assert 0.95 <= cov <= 1.0, cov
+        # /debug/flush shape: offsets before the tick are negative
+        d = gt.to_dict()["phases"][root]
+        assert d["name"] == "import" and d["start_ns"] < 0
+
+        # the next interval's tick is its own: nothing carried over
+        glob.flush_once(timestamp=2010)
+        assert not _rows(glob.flight.last_tick(), "import")
+        # ... but the dogfood timer of the import root flushed there
+        assert glob.drain(10.0)
+        out = {m.name for m in glob.flush_once(timestamp=2015)}
+        assert any(n.startswith("veneur.flush.phase.import") for n in out)
+    finally:
+        local.stop()
+        glob.stop()
+
+
+def test_http_forward_uses_the_same_phase_names():
+    """The jsonmetric-v1 arm: same vocabulary for the same steps, and
+    the import ring's `route` reaches the global's tick too."""
+    from veneur_tpu.cluster.forward import HttpJsonForwarder
+
+    cfg_g = read_config(text=_YAML)
+    cfg_g.http_address = "127.0.0.1:0"
+    cfg_g.is_global = True
+    glob = Server(cfg_g, sinks=[CaptureMetricSink()], plugins=[],
+                  span_sinks=[])
+    glob.start()
+    fwd = ResilientForwarder(
+        HttpJsonForwarder(f"http://127.0.0.1:{glob.http_api.port}",
+                          timeout_s=5.0, max_per_body=4),
+        destination="t26-http", sender_id="t26-http-sender",
+        registry=TelemetryRegistry())
+    cfg_l = read_config(text=_YAML)
+    cfg_l.forward_address = "placeholder:1"
+    local = Server(cfg_l, sinks=[CaptureMetricSink()], plugins=[],
+                   span_sinks=[], forwarder=fwd)
+    local.start()
+    try:
+        local.handle_packet(b"\n".join(
+            b"t26h.c%d:1|c|#veneurglobalonly" % k for k in range(10)))
+        assert local.drain(10.0)
+        local.flush_once(timestamp=3000)
+        lt = local.flight.last_tick()
+        assert len(_rows(lt, "forward.export")) == 1
+        n = len(_rows(lt, "egress.attempt"))
+        assert n == 3 == len(_rows(lt, "forward.chunk.build")) \
+            == len(_rows(lt, "forward.chunk.serialize"))
+        assert len(_rows(lt, "forward.release")) == 1
+        assert glob.drain(10.0)
+        # the ring record (and its stamps) publish after the reply
+        deadline = time.monotonic() + 5
+        while glob.import_observer.flight.tick_count < n and \
+                time.monotonic() < deadline:
+            time.sleep(0.005)
+        glob.flush_once(timestamp=3005)
+        gt = glob.flight.last_tick()
+        for name in ("import.decode", "import.dedupe", "import.route"):
+            assert len(_rows(gt, name)) == n, name
+        assert _rows(gt, "import.apply")
+    finally:
+        local.stop()
+        glob.stop()
+
+
+def test_flooded_tick_coalesces_within_its_budgets():
+    """Far more import requests, apply runs and landings than a tick
+    has rows for: every kind stops at its budget, the seconds stay
+    exact, and the tick drops nothing."""
+    cfg = read_config(text=_YAML)
+    cfg.is_global = True
+    srv = Server(cfg, sinks=[CaptureMetricSink()], plugins=[],
+                 span_sinks=[])
+    srv.start()
+    try:
+        now = time.monotonic_ns()
+        eng = srv.engines[0]
+        budget = Server.GRAFT_BUDGET
+        for k in range(500):
+            t0 = now - 10_000_000 + 10_000 * k
+            for name in ("import.decode", "import.dedupe",
+                         "import.route"):
+                srv._import_stamps.add(name, t0, t0 + 700)
+            # busy runs 5 us apart in time: far closer than the merge gap
+            srv._import_stamps.add("import.apply", t0 + 1000, t0 + 6000,
+                                   Server.APPLY_MERGE_GAP_NS)
+            eng.land_stamps.add("import.land", t0, t0 + 900)
+            eng.land_stamps.add("import.land.stage", t0, t0 + 300)
+            eng.land_stamps.add("import.land.cluster", t0 + 300, t0 + 600)
+        srv.flush_once(timestamp=4000)
+        t = srv.flight.last_tick()
+        assert t.dropped == 0 and t.n < srv.flight.max_phases
+        for name, per, want in (
+                ("import.decode", 700, budget["import.request"]),
+                ("import.route", 700, budget["import.request"]),
+                ("import.land", 900, budget["import.land"]),
+                ("import.land.cluster", 300, budget["import.land"])):
+            rows = _rows(t, name)
+            assert len(rows) == want, name
+            assert sum(r[1] - r[0] for r in rows) == 500 * per, name
+        # 500 adjacent busy runs are ONE run, idle slivers included
+        (a0, a1, _p, _i), = _rows(t, "import.apply")
+        assert a1 - a0 == 499 * 10_000 + 5000
+    finally:
+        srv.stop()
+
+
+def test_mesh_engine_stamps_the_device_phases():
+    """engine.device.* means the same on both engines: dispatch, exec
+    (bounded by block_until_ready) and fetch of the one collective
+    flush program, in that order, inside engine.flush. Four virtual
+    CPU devices (tests/conftest.py pins eight)."""
+    cfg = read_config(text=_YAML)
+    cfg.tpu_num_devices = 4
+    cfg.grpc_listen_addresses = ["127.0.0.1:0"]
+    srv = Server(cfg, sinks=[CaptureMetricSink()], plugins=[],
+                 span_sinks=[])
+    assert type(srv.engines[0]).__name__ == "MeshAggregationEngine"
+    srv.start()
+    try:
+        from veneur_tpu.ingest.parser import MetricKey
+        _feed(srv, n_keys=16, n_per_key=8)
+        # an imported digest lands through the routed SPMD ingest
+        srv.engines[0].import_histogram(
+            MetricKey("t26.mesh", "timer", ""), [1.0, 2.0], [1.0, 1.0],
+            1.0, 2.0, 3.0, 2.0)
+        srv.flush_once(timestamp=5000)
+        t = srv.flight.last_tick()
+        (f0, f1, _p, _i), = _rows(t, "engine.flush")
+        (a0, a1, _p, _i), = _rows(t, "engine.device.dispatch")
+        (b0, b1, _p, _i), = _rows(t, "engine.device.exec")
+        (c0, c1, _p, _i), = _rows(t, "engine.device.fetch")
+        assert f0 <= a0 and a1 == b0 and b1 == c0 and c1 <= f1
+        (d0, d1, _p, drain), = _rows(t, "engine.drain")
+        (l0, l1, lpar, _i), = _rows(t, "import.land")
+        assert lpar == drain and d0 <= l0 and l1 <= d1
+        assert not _rows(t, "import.land.stage")    # no cluster step
+    finally:
+        srv.stop()
+
+
+def test_pump_stamps_one_phase_per_dispatch():
+    """The native pump leaves one `ingest.pump.batch` per dispatch for
+    the local's next flush tick, under one `ingest` root that begins
+    before the tick."""
+    cfg = Config(statsd_listen_addresses=["udp://127.0.0.1:0"],
+                 interval="3600s", hostname="h", native_ingest=True,
+                 tpu_histogram_slots=256, tpu_counter_slots=128,
+                 tpu_gauge_slots=128, tpu_set_slots=64,
+                 tpu_batch_size=256, native_pump_batch=64)
+    srv = Server(cfg, sinks=[CaptureMetricSink()], span_sinks=[])
+    eng = srv.engines[0]
+    calls = []
+    for bank in ("histo", "counter", "gauge", "set"):
+        inner = getattr(eng, f"ingest_{bank}_batch")
+
+        def counting(*a, _inner=inner, _bank=bank, **kw):
+            calls.append(_bank)
+            return _inner(*a, **kw)
+
+        setattr(eng, f"ingest_{bank}_batch", counting)
+    srv.start()
+    try:
+        sock = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+        lines = [b"t26p.t%d:%d|ms" % (k % 7, k) for k in range(300)] \
+            + [b"t26p.c:1|c"] * 5
+        for ln in lines:
+            sock.sendto(ln, ("127.0.0.1", srv.bound_port()))
+        sock.close()
+        deadline = time.monotonic() + 10
+        while int(srv.native_bridge.stats()["lines"]) < len(lines):
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+        time.sleep(0.05)
+        assert srv.drain()
+        n_calls = len(calls)
+        assert n_calls >= 300 // 64           # a 64-wide pump, 300 timers
+        srv.flush_once(timestamp=6000)
+        t = srv.flight.last_tick()
+        (r0, r1, rpar, root), = _rows(t, "ingest")
+        batches = _rows(t, "ingest.pump.batch")
+        assert len(batches) == n_calls
+        assert rpar == -1 and r0 < t.mono_start
+        assert all(b[2] == root for b in batches)
+        assert (r0, r1) == (batches[0][0], max(b[1] for b in batches))
+        assert t.attributed_ns() <= t.duration_ns()
+        # an idle interval grafts no root
+        srv.flush_once(timestamp=6010)
+        assert not _rows(srv.flight.last_tick(), "ingest")
+        # more dispatches than the pump's budget, and more of those
+        # than the tick has slots left: the rows stop at what fits, the
+        # seconds stay exact, the tick drops nothing
+        now = time.monotonic_ns()
+        for k in range(600):
+            srv.native_pump.stamps.add("ingest.pump.batch",
+                                       now + 100 * k, now + 100 * k + 40)
+        srv.flush_once(timestamp=6020)
+        t = srv.flight.last_tick()
+        batches = _rows(t, "ingest.pump.batch")
+        assert Server.GRAFT_BUDGET["ingest.pump.batch"] > len(batches) > 96
+        assert t.n == srv.flight.max_phases and t.dropped == 0
+        assert sum(b[1] - b[0] for b in batches) == 600 * 40
+    finally:
+        srv.stop()
+
+
+def test_graft_folds_rows_into_the_slots_that_are_left():
+    fr = FlightRecorder(capacity=1, max_phases=12)
+    t = fr.begin_tick(ts=1)
+    t.finish(t.start("engine"))
+    t.finish(t.start("fanout"))
+    base = t.mono_start
+    rows = [("a", base - 1000 + 10 * k, base - 1000 + 10 * k + 4)
+            for k in range(20)] + [("b", base - 500, base - 450),
+                                   ("b.c", base - 490, base - 480)]
+    root = t.graft(rows, root="r")
+    assert t.dropped == 0 and t.n == 12           # 2 own + root + 9 rows
+    ph = t.phases()
+    a = [p for p in ph if p[0] == "a"]
+    assert len(a) == 7 and sum(p[2] - p[1] for p in a) == 20 * 4
+    assert [p[1] for p in a] == [base - 1000 + 10 * k for k in range(7)]
+    b = ph.index(("b", base - 500, base - 450, root))
+    assert ("b.c", base - 490, base - 480, b) in ph
+    # no room even for one row a name: those are counted as dropped
+    t = fr.begin_tick(ts=2)
+    for _ in range(10):
+        t.finish(t.start("own"))
+    t.graft(rows, root="r")
+    assert t.n == 12 and t.dropped == 2
+
+
+def test_recorder_off_stamps_nothing_anywhere():
+    """flight_recorder: false arms none of the stamp logs: the
+    engines', the import observer's and the pump's stay None."""
+    cfg = read_config(text=_YAML)
+    cfg.flight_recorder = False
+    cfg.grpc_listen_addresses = ["127.0.0.1:0"]
+    srv = Server(cfg, sinks=[CaptureMetricSink()], plugins=[],
+                 span_sinks=[])
+    assert srv.flight is None and srv._import_stamps is None
+    assert srv.import_observer.stamps is None
+    assert all(e.land_stamps is None for e in srv.engines)

@@ -213,11 +213,11 @@ def test_send_metrics_envelope_bearing_metric_list_golden_bytes():
     fwd._send = lambda req, timeout=None: sent.append(req)
     fwd(export, envelope=ForwardEnvelope("s1", 7, chunk_offset=1,
                                          chunk_count=3))
-    (ml,) = sent
+    (data,) = sent          # the forwarder hands gRPC serialized bytes
     inner = _s(1, "c") + _ld(4, _vi(1, 7)) + _vi(8, 2)
     golden = (_ld(1, inner)                       # metrics = 1
               + _ld(2, _golden_envelope_bytes()))  # envelope = 2
-    assert ml.SerializeToString() == golden
+    assert data == golden
     back = forward_pb2.MetricList.FromString(golden)
     assert back.HasField("envelope")
     assert back.envelope.sender_id == "s1"

@@ -18,6 +18,7 @@ import logging
 import urllib.request
 
 from ..models.pipeline import ForwardExport
+from ..observe.recorder import stamping_scope
 from ..resilience import (DeltaGapRefusedError, Egress, EgressPolicy,
                           ForwardEnvelope, HTTPStatusError,
                           PartialDeliveryError, accepts_envelope,
@@ -125,9 +126,12 @@ class GrpcForwarder:
         self._egress = egress or Egress(f"grpc://{address}",
                                         policy=egress_policy)
         self._channel = grpc_channel(address)
+        # takes a SERIALIZED MetricList: every chunk is serialized once
+        # up front (its own flight-recorder phase, apart from the wait
+        # for the far end), so a retry re-sends the same bytes and the
+        # byte counter reads their length
         self._send = self._channel.unary_unary(
-            SEND_METRICS,
-            request_serializer=forward_pb2.MetricList.SerializeToString,
+            SEND_METRICS, request_serializer=None,
             response_deserializer=forward_pb2.Empty.FromString)
 
     def __call__(self, export: ForwardExport,
@@ -140,18 +144,31 @@ class GrpcForwarder:
         receiver's dedupe ledger drop anything it already Combined
         during an ambiguous failure. All batches share ONE deadline
         budget — N batches cannot stall the flush tick for
-        N x retry_deadline."""
+        N x retry_deadline.
+
+        Flight-recorder phases, beside the ladder's `egress.attempt`
+        (what is left of a chunk: wire, far end, reply):
+        `forward.export` and `forward.chunk.plan` once a send,
+        `forward.chunk.build` and `forward.chunk.serialize` per
+        chunk, `forward.release` once more at the end."""
+        tick, par = stamping_scope()
+        ph = tick.start("forward.export", par)
         metrics = wire.export_to_metrics(export,
                                          codec=self.centroid_codec)
+        tick.finish(ph, n_metrics=len(metrics))
         deadline = self._egress.deadline()
+        ph = tick.start("forward.chunk.plan", par)
         bounds = _chunk_bounds(metrics, self.max_per_batch)
+        tick.finish(ph)
         n_chunks = len(bounds)
         total = 0
         kind = envelope.kind if envelope is not None else "full"
         if envelope is not None:
             total = envelope.chunk_count or (envelope.chunk_offset
                                              + n_chunks)
+        batch = None
         for j, (i, end) in enumerate(bounds):
+            ph = tick.start("forward.chunk.build", par)
             batch = forward_pb2.MetricList(metrics=metrics[i:end])
             if self.engine_stamp:
                 batch.sketch_engines = self.engine_stamp
@@ -167,8 +184,12 @@ class GrpcForwarder:
                     span_id=envelope.span_id,
                     close_ns=envelope.close_ns,
                     kind=kind))
+            tick.finish(ph)
+            ph = tick.start("forward.chunk.serialize", par)
+            data = batch.SerializeToString()
+            tick.finish(ph, nbytes=len(data))
             try:
-                self._egress.call(self._send, batch,
+                self._egress.call(self._send, data,
                                   timeout_s=self.timeout_s,
                                   deadline=deadline)
             except Exception as e:
@@ -187,7 +208,13 @@ class GrpcForwarder:
                 raise PartialDeliveryError(
                     _export_tail(export, i), e, delivered_chunks=j,
                     chunk_count=total or n_chunks) from e
-            _count_forward_bytes(self._egress, batch.ByteSize(), kind)
+            _count_forward_bytes(self._egress, len(data), kind)
+        # the per-sketch protobuf objects die here, under a name of
+        # their own, not at the return: freeing 100k of them is 0.1 s
+        # to 0.9 s of a send (PERF.md §5)
+        ph = tick.start("forward.release", par)
+        del metrics, batch
+        tick.finish(ph)
 
     def send_metrics(self, metrics: list, envelope=None,
                      sketch_engines=None, prefix_sketches=None):
@@ -211,11 +238,12 @@ class GrpcForwarder:
                 batch.sketch_engines = sketch_engines
             if prefix_sketches:
                 wire.prefix_sketches_to_pb(batch, prefix_sketches)
-            self._egress.call(self._send, batch,
+            data = batch.SerializeToString()
+            self._egress.call(self._send, data,
                               timeout_s=self.timeout_s,
                               deadline=deadline)
             _count_forward_bytes(
-                self._egress, batch.ByteSize(),
+                self._egress, len(data),
                 "delta" if envelope.forward_kind == 1 else "full")
             return
         for j, i in enumerate(range(0, len(metrics),
@@ -226,10 +254,11 @@ class GrpcForwarder:
                 batch.sketch_engines = sketch_engines
             if j == 0 and prefix_sketches:
                 wire.prefix_sketches_to_pb(batch, prefix_sketches)
-            self._egress.call(self._send, batch,
+            data = batch.SerializeToString()
+            self._egress.call(self._send, data,
                               timeout_s=self.timeout_s,
                               deadline=deadline)
-            _count_forward_bytes(self._egress, batch.ByteSize(), "full")
+            _count_forward_bytes(self._egress, len(data), "full")
 
     def close(self):
         self._channel.close()
@@ -336,8 +365,12 @@ class HttpJsonForwarder:
         one shared deadline budget, PartialDeliveryError carrying the
         unsent tail + delivered chunk count); each chunk's envelope
         rides as the X-Veneur-* headers of the jsonmetric-v1
-        contract."""
+        contract. Same flight-recorder phases as the gRPC arm for the
+        same steps (a count-only plan is not worth one)."""
+        tick, par = stamping_scope()
+        ph = tick.start("forward.export", par)
         body = self._body_entries(export)
+        tick.finish(ph, n_metrics=len(body))
         deadline = self._egress.deadline()
         n_chunks = -(-len(body) // self.max_per_body)
         total = 0
@@ -346,8 +379,10 @@ class HttpJsonForwarder:
         if envelope is not None:
             total = envelope.chunk_count or (envelope.chunk_offset
                                              + n_chunks)
+        data = None
         for j in range(n_chunks):
             i = j * self.max_per_body
+            ph = tick.start("forward.chunk.build", par)
             headers = dict(base_headers)
             if j == 0 and export.prefix_sketches:
                 # headers have practical size limits: cap the advisory
@@ -363,7 +398,10 @@ class HttpJsonForwarder:
                     span_id=envelope.span_id,
                     close_ns=envelope.close_ns,
                     kind=kind))
+            tick.finish(ph)
+            ph = tick.start("forward.chunk.serialize", par)
             data = json.dumps(body[i:i + self.max_per_body]).encode()
+            tick.finish(ph, nbytes=len(data))
             req = urllib.request.Request(
                 self.url, data=data, headers=headers, method="POST")
             try:
@@ -382,6 +420,9 @@ class HttpJsonForwarder:
                     _export_tail(export, i), e, delivered_chunks=j,
                     chunk_count=total or n_chunks) from e
             _count_forward_bytes(self._egress, len(data), kind)
+        ph = tick.start("forward.release", par)
+        del body, data
+        tick.finish(ph)
 
 
 class DiscoveringForwarder:

@@ -37,6 +37,16 @@ from .protos import forward_pb2
 log = logging.getLogger("veneur_tpu.cluster.importsrv")
 
 
+def _decode_metric_list(data: bytes):
+    """SendMetrics' request_deserializer: the parsed MetricList with
+    the parse's edges on the monotonic clock. gRPC deserializes on its
+    own polling thread before any handler runs, so this is the only
+    place the import's `decode` phase can be stamped."""
+    t0 = time.monotonic_ns()
+    request = forward_pb2.MetricList.FromString(data)
+    return request, t0, time.monotonic_ns()
+
+
 class ImportedMetric:
     """Worker-queue envelope for a forwarded metricpb.Metric."""
 
@@ -294,7 +304,7 @@ class ForwardHandler(grpc.GenericRpcHandler):
         the Server provides a queue-backed implementation. `ledger`
         (optional) dedupes envelope-bearing requests. `observer`
         (optional, an observe.ImportObserver) records each request's
-        dedupe/apply phases in the import ring, replays them as SSF
+        decode/dedupe/route phases in the import ring, replays them as SSF
         spans parented on the remote sender's flush span, and feeds
         the per-sender fleet view — observability only, it never
         changes what is admitted or applied. `submit_batch` (optional,
@@ -326,8 +336,9 @@ class ForwardHandler(grpc.GenericRpcHandler):
         from .forward import SEND_METRICS, SEND_METRICS_V2
         if details.method == SEND_METRICS:
             return grpc.unary_unary_rpc_method_handler(
-                self._send_metrics,
-                request_deserializer=forward_pb2.MetricList.FromString,
+                lambda decoded, context: self._send_metrics(
+                    decoded[0], context, decode_ns=decoded[1:]),
+                request_deserializer=_decode_metric_list,
                 response_serializer=forward_pb2.Empty.SerializeToString)
         if details.method == SEND_METRICS_V2:
             return grpc.stream_unary_rpc_method_handler(
@@ -418,19 +429,22 @@ class ForwardHandler(grpc.GenericRpcHandler):
         return not self._ledger.check_delta(env[0], env[1])
 
     def _apply(self, scope, env, metrics) -> None:
-        """The shared admit-then-route tail, phase-attributed."""
+        """The shared admit-then-route tail, phase-attributed: `route`
+        is key digest + enqueue; the Combine itself runs on a worker
+        thread after the acknowledgement (`import.apply` in the flush
+        tick)."""
         ph = scope.start("dedupe")
         ok = self._admit(env)
         scope.finish(ph, admitted=ok)
         scope.admitted = ok
         if not ok:
             return
-        ph = scope.start("apply")
+        ph = scope.start("route")
         n = self._route_all(metrics, env)
         scope.finish(ph, n_metrics=n)
         scope.n_metrics = n
 
-    def _send_metrics(self, request, context):
+    def _send_metrics(self, request, context, decode_ns=None):
         env = wire.envelope_from_metric_list(request)
         trace = wire.trace_from_metric_list(request)
         remote = wire.sketch_stamp_from_metric_list(request)
@@ -453,6 +467,8 @@ class ForwardHandler(grpc.GenericRpcHandler):
             return forward_pb2.Empty()
         kw = {} if self._engine_stamp is None else {"stamp": remote}
         with obs.request(env, trace, "grpc", **kw) as scope:
+            if decode_ns is not None:
+                scope.add("decode", *decode_ns)
             self._apply(scope, env, request.metrics)
         return forward_pb2.Empty()
 
@@ -481,7 +497,7 @@ class ForwardHandler(grpc.GenericRpcHandler):
                 return forward_pb2.Empty()
             with obs.request(env, trace, "grpc-stream", **kw) as scope:
                 scope.admitted = True
-                ph = scope.start("apply")
+                ph = scope.start("route")
                 n = self._route_all(request_iterator)
                 scope.finish(ph, n_metrics=n)
                 scope.n_metrics = n

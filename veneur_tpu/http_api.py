@@ -381,7 +381,7 @@ class HttpApi:
                         {"imported": 0, "deduped": True}).encode(),
                         "application/json")
                     return
-                ph = -1 if scope is None else scope.start("apply")
+                ph = -1 if scope is None else scope.start("route")
                 if api._submit_batch is not None:
                     api._submit_batch(decoded, env)
                     count = len(decoded)

@@ -26,6 +26,7 @@ import os
 import struct
 import subprocess
 import threading
+import time
 
 import numpy as np
 
@@ -489,7 +490,7 @@ class NativePump:
 
     def __init__(self, bridge: NativeBridge, engine, views: dict,
                  slow_path, batch: int = 8192, idle_sleep: float = 0.002,
-                 ssf_slow_path=None):
+                 ssf_slow_path=None, stamps=None):
         self.bridge = bridge
         self.engine = engine
         self.views = views
@@ -499,6 +500,13 @@ class NativePump:
         self.ssf_slow_path = ssf_slow_path
         self.batch = batch
         self.idle_sleep = idle_sleep
+        # flight recorder (an observe.StampLog, or None): one
+        # `ingest.pump.batch` row per dispatch — poll returned ->
+        # ingest_*_batch returned, i.e. copy, engine-lock wait,
+        # dispatch — which the server's next flush tick grafts under
+        # its `ingest` root. Preallocated; past its budget a tick's
+        # later dispatches lengthen the last row, so seconds stay exact
+        self.stamps = stamps
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         # pump_once may be called by both the pump thread and
@@ -523,7 +531,6 @@ class NativePump:
 
     def _run(self):
         import logging
-        import time
         while not self._stop.is_set():
             try:
                 moved = self.pump_once()
@@ -555,7 +562,6 @@ class NativePump:
     def drain(self, timeout: float = 10.0) -> bool:
         """Pump until the bridge is empty (deterministic test settling:
         the analogue of Server.drain's queue accounting)."""
-        import time
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:
             moved = self.pump_once()
@@ -576,11 +582,13 @@ class NativePump:
 
     def _pump_bank(self, bank: str) -> int:
         slots, a, b, c = self._bufs[bank]
+        stamps = self.stamps
         total = 0
         while True:
             n = self.bridge.poll(bank, slots, a, b, c)
             if n <= 0:
                 break
+            t0 = time.monotonic_ns()
             if n < self.batch:
                 slots[n:] = -1  # pad rows are dropped by the kernels
             # Sync key records BEFORE marking/dispatching this batch: the
@@ -617,6 +625,8 @@ class NativePump:
                 # aliasing contract for the rho column by itself
                 eng.ingest_set_batch(sl, c.copy(), a.astype(np.uint8),
                                      count=n, mark=mark)
+            if stamps is not None:
+                stamps.add("ingest.pump.batch", t0, time.monotonic_ns())
             total += n
             if n < self.batch:
                 break

@@ -62,6 +62,12 @@ _IMPORT_W_CAP = 4096
 _IMPORT_STAGE_CENTROIDS = 1 << 20
 _IMPORT_STAGE_DIGESTS = 8192
 
+# Flight-recorder phases one import landing stamps (into
+# engine.land_stamps, when the server armed it): the whole landing;
+# host piles + the [S, W] fill; the cluster_rows dispatch + fetch. The
+# merge/compress dispatches are the rest of `import.land`.
+LAND_PHASES = ("import.land", "import.land.stage", "import.land.cluster")
+
 
 def _precluster_k1(v, w, n_points, keep_extremes=False):
     """Sort one hot slot's (value, weight) samples and cluster them into
@@ -918,6 +924,9 @@ class AggregationEngine:
         # not one per key.
         self._import_centroids: list = []
         self._import_centroid_total = 0
+        # observe.StampLog for LAND_PHASES, set by a Server whose
+        # flight recorder is on; flush() hands its rows to the tick
+        self.land_stamps = None
         self._import_sets: list = []          # (slot, registers u8[m])
         self._import_counter_acc: dict = {}   # slot -> host f64 sum
         self._import_gauge_acc: dict = {}     # slot -> last value
@@ -1502,11 +1511,25 @@ class AggregationEngine:
         pile to <= C centroids with ONE batched cluster_rows program,
         then one merge + one compress) or "direct" (compactor engines —
         the items re-insert as weighted points in fixed-width batches;
-        the engine's own compaction bounds memory, no preclustering)."""
+        the engine's own compaction bounds memory, no preclustering).
+        One `import.land` stamp per landing (LAND_PHASES), whichever
+        thread runs it: a worker mid-interval, the flusher at flush."""
         if not items:
             return bank
+        stamps = self.land_stamps
+        t0 = time.monotonic_ns()
         if self._heng.import_strategy == "direct":
-            return self._land_imports_direct(bank, items, dirty)
+            bank = self._land_imports_direct(bank, items, dirty)
+        else:
+            bank = self._land_imports_clustered(bank, items, dirty,
+                                                stamps, t0)
+        if stamps is not None:
+            stamps.add("import.land", t0, time.monotonic_ns())
+        return bank
+
+    def _land_imports_clustered(self, bank, items, dirty, stamps, t0):
+        """The "cluster" import strategy; `t0` is where the landing's
+        `import.land.stage` phase began."""
         C = bank.num_centroids
 
         by_slot: dict[int, list] = {}
@@ -1594,9 +1617,13 @@ class AggregationEngine:
                 vals[row, off:off + n] = m
                 wts[row, off:off + n] = w
                 off += n
+        t1 = time.monotonic_ns()
         cmeans, cwts = self._heng.cluster_rows(
             vals, wts, num_centroids=C)
         cmeans, cwts = np.asarray(cmeans), np.asarray(cwts)
+        if stamps is not None:
+            stamps.add("import.land.stage", t0, t1)
+            stamps.add("import.land.cluster", t1, time.monotonic_ns())
         # land the clustered centroids; merge_centroids drops on buffer
         # overflow, so chunk the C columns to the buffer depth (one
         # iteration in the default config where B >= C)
@@ -2225,6 +2252,10 @@ class AggregationEngine:
             "merge_ns": t_device - t_swap,
             "assembly_ns": t_end - t_device,
             "phases": phases,
+            # import landings since the previous flush (LAND_PHASES
+            # rows, this flush's own among them)
+            "import_phases": ([] if self.land_stamps is None
+                              else self.land_stamps.take()),
             # which device path ran (full vs incremental + dirty/pile
             # counts) — bench/test introspection, also what an
             # operator correlates the gather/scatter phases against

@@ -18,9 +18,9 @@ into a subsystem):
     back into the server's own engine as `veneur.flush.phase.*` timers.
 """
 
-from .fleet import FleetView, ImportObserver
-from .recorder import (FlightRecorder, TickRecord, current_scope,
-                       current_tick, reset_current_tick,
+from .fleet import REQUEST_PHASES, FleetView, ImportObserver
+from .recorder import (FlightRecorder, StampLog, TickRecord,
+                       current_scope, current_tick, reset_current_tick,
                        set_current_tick)
 from .registry import (DEFAULT_REGISTRY, SERVER_SCOPE, TelemetryRegistry,
                        e2e_timer_samples, fanout_timer_sample,
@@ -29,7 +29,8 @@ from .registry import (DEFAULT_REGISTRY, SERVER_SCOPE, TelemetryRegistry,
 __all__ = [
     "DEFAULT_REGISTRY", "SERVER_SCOPE", "TelemetryRegistry",
     "phase_timer_samples", "e2e_timer_samples", "fanout_timer_sample",
-    "FlightRecorder", "TickRecord", "FleetView", "ImportObserver",
+    "FlightRecorder", "StampLog", "TickRecord", "FleetView",
+    "ImportObserver", "REQUEST_PHASES",
     "current_tick", "current_scope", "set_current_tick",
     "reset_current_tick",
 ]

@@ -17,11 +17,13 @@ consumers live here:
 
   * `ImportObserver` — per-request import observation. Each import
     request (gRPC SendMetrics/V2, HTTP /import) records its
-    dedupe/apply phases as a TickRecord in a bounded ring (the same
-    flight-recorder machinery as flush ticks, served under
+    decode/dedupe/route phases as a TickRecord in a bounded ring (the
+    same flight-recorder machinery as flush ticks, served under
     /debug/fleet) and — when the sender propagated a trace context —
     replays them as SSF spans PARENTED ON THE REMOTE SENDER'S FLUSH
     SPAN, yielding one span tree per interval across both processes.
+    The same edges go to the server's `StampLog`, which its next
+    flush tick grafts under the `import` root.
 
 Thread model: handler threads call both concurrently; FleetView takes
 one lock per call, the import ring reuses the recorder's locking. The
@@ -35,7 +37,11 @@ import time
 from collections import OrderedDict, deque
 
 from . import registry as _registry
-from .recorder import FlightRecorder, TickRecord
+from .recorder import FlightRecorder, StampLog, TickRecord
+
+# a request's phases in the import ring; the same edges reach the
+# owning server's flush tick as import.<name>
+REQUEST_PHASES = ("decode", "dedupe", "route")
 
 # a storm of admissions between two global flushes must not grow the
 # pending set unboundedly; overflow drops the OLDEST pending sample
@@ -229,11 +235,22 @@ class _ImportScope:
         if self.tick is not None:
             self.tick.finish(idx, **meta)
 
+    def add(self, name: str, t0_ns: int, t1_ns: int):
+        """A phase stamped before the scope opened (gRPC's decode)."""
+        if self.tick is not None:
+            self.tick.add(name, t0_ns, t1_ns)
+
     def __enter__(self):
         return self
 
     def __exit__(self, exc_type, exc, tb):
         obs = self._obs
+        if self.tick is not None and obs.stamps is not None:
+            # the same edges, for the flush tick of the server that
+            # did the work (grafted there under the `import` root)
+            for name, t0, t1, _parent in self.tick.phases():
+                if name in REQUEST_PHASES and t1 > t0:
+                    obs.stamps.add("import." + name, t0, t1)
         if self.tick is not None:
             # zero-length summary phase: the request identity/verdict,
             # readable from the ring and the emitted span tree alike
@@ -281,10 +298,13 @@ class ImportObserver:
 
     def __init__(self, fleet: FleetView | None = None,
                  flight: FlightRecorder | None = None,
-                 client=None):
+                 client=None, stamps: StampLog | None = None):
         self.fleet = fleet
         self.flight = flight
         self._client = client            # callable -> trace client|None
+        # where finished requests leave their decode/dedupe/route edges
+        # for the owning server's next flush tick (None: ring only)
+        self.stamps = stamps
 
     def client(self):
         c = self._client

@@ -28,6 +28,7 @@ chain via the cluster tier's importsrv).
 from __future__ import annotations
 
 import logging
+import time
 
 import jax
 import jax.numpy as jnp
@@ -290,10 +291,16 @@ class MeshAggregationEngine(AggregationEngine):
         contract the shared assembly consumes. `phases` (the flight
         recorder's stamp list) and `dirty` (always None here — the
         mesh engine carries no per-slot bitmaps) are accepted for
-        signature parity with the single-device engine; the mesh
-        program is one collective dispatch+fetch, recorded by the
-        caller as the merge phase."""
-        dev = self._fetch_flush(self.me.flush_device(snap))
+        signature parity with the single-device engine. With `phases`
+        the one collective program is stamped device.dispatch /
+        device.exec (bounded by block_until_ready) / device.fetch, as
+        the single-device engine's is (_timed_fetch)."""
+        if phases is None:
+            dev = self._fetch_flush(self.me.flush_device(snap))
+        else:
+            t0 = time.monotonic_ns()
+            out = self.me.flush_device(snap)
+            dev = self._timed_fetch(out, t0, time.monotonic_ns(), phases)
         agg = dev["agg"]
         host = {
             "q": dev["quantiles"],
@@ -424,6 +431,14 @@ class MeshAggregationEngine(AggregationEngine):
     def _flush_import_centroids_locked(self):
         if not self._import_centroids:
             return
+        t0 = time.monotonic_ns()
+        self._land_staged_centroids()
+        if self.land_stamps is not None:
+            # the routed SPMD ingest is this engine's whole landing:
+            # no stage / cluster children
+            self.land_stamps.add("import.land", t0, time.monotonic_ns())
+
+    def _land_staged_centroids(self):
         items, self._import_centroids = self._import_centroids, []
         self._import_h_points = 0
         # schedule landing so each slot contributes at most one item

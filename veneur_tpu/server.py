@@ -32,7 +32,8 @@ from . import observe, resilience
 from .config import Config, _parse_interval
 from .ingest import parser
 from .metrics import FrameSet, InterMetric, MetricType
-from .models.pipeline import AggregationEngine, EngineConfig, ForwardExport
+from .models.pipeline import (LAND_PHASES, AggregationEngine, EngineConfig,
+                              ForwardExport)
 from .sinks import MetricSink
 from .sinks.basic import (BlackholeMetricSink, DebugMetricSink,
                           LocalFilePlugin)
@@ -56,6 +57,19 @@ def _fold_rewrite(pb, fr) -> int:
 
 
 class Server:
+    # Flight-recorder rows ONE flush tick keeps of each kind of work
+    # done between ticks (observe.StampLog budgets: past them a kind's
+    # rows coalesce and its seconds stay exact). The import kinds are
+    # sized for 100k keys a tick (~16 requests x 3 phases, ~13 landings
+    # x 3) next to the tick's own ~35 phases in the default
+    # flight_recorder_max_phases (192); the pump's for a local, whose
+    # tick has the forward's 3 phases a chunk besides — TickRecord.graft
+    # folds what the tick has no slots left for, so none is dropped.
+    GRAFT_BUDGET = {"import.request": 16, "import.apply": 12,
+                    "import.land": 16, "ingest.pump.batch": 256}
+    # a worker's busy runs closer than this are one `import.apply` run
+    APPLY_MERGE_GAP_NS = 1_000_000
+
     def __init__(self, cfg: Config, sinks: list[MetricSink] | None = None,
                  plugins=None, forwarder=None, span_sinks=None):
         self.cfg = cfg
@@ -109,6 +123,12 @@ class Server:
         else:
             self.engines = [AggregationEngine(EngineConfig(**ecfg_kw))
                             for _ in range(n_workers)]
+        if cfg.flight_recorder:
+            # import landings stamp `import.land` (+ children) here;
+            # the engine's flush hands them to the tick
+            for eng in self.engines:
+                eng.land_stamps = observe.StampLog(dict.fromkeys(
+                    LAND_PHASES, self.GRAFT_BUDGET["import.land"]))
         self.worker_queues: list[queue.Queue] = [
             queue.Queue(maxsize=65536) for _ in range(n_workers)]
         # per queue: until when a full queue sheds imports without
@@ -385,6 +405,7 @@ class Server:
         # apply behavior is identical with it on or off.
         self.fleet = None
         self.import_observer = None
+        self._import_stamps = None
         if cfg.grpc_listen_addresses or cfg.http_address or cfg.is_global:
             self.fleet = observe.FleetView(
                 max_senders=cfg.fleet_max_senders,
@@ -393,9 +414,17 @@ class Server:
             if cfg.flight_recorder:
                 import_ring = observe.FlightRecorder(
                     capacity=cfg.flight_recorder_ticks, max_phases=16)
+                # import work done between flushes — per request on
+                # handler threads, per busy run on worker threads —
+                # leaves its edges here for the next flush tick
+                self._import_stamps = observe.StampLog({
+                    "import." + n: self.GRAFT_BUDGET["import.request"]
+                    for n in observe.REQUEST_PHASES}
+                    | {"import.apply": self.GRAFT_BUDGET["import.apply"]})
             self.import_observer = observe.ImportObserver(
                 fleet=self.fleet, flight=import_ring,
-                client=lambda: self.trace_client)
+                client=lambda: self.trace_client,
+                stamps=self._import_stamps)
         self._grpc_servers = []
         # tags_exclude strips tag names BEFORE key construction (metrics
         # differing only in an excluded tag aggregate together), in both
@@ -599,7 +628,10 @@ class Server:
         self.native_pump = NativePump(
             self.native_bridge, eng, views, slow_path,
             batch=self.cfg.native_pump_batch,
-            ssf_slow_path=ssf_slow_path)
+            ssf_slow_path=ssf_slow_path,
+            stamps=(observe.StampLog(
+                {"ingest.pump.batch": self.GRAFT_BUDGET["ingest.pump.batch"]})
+                if self.cfg.flight_recorder else None))
 
     def _sinks_from_config(self) -> list[MetricSink]:
         out: list[MetricSink] = []
@@ -1828,6 +1860,12 @@ class Server:
         from .models import pipeline
 
         eng = self.engines[idx]
+        # flight recorder: one `import.apply` stamp per busy run of
+        # imported items (first one dequeued -> nothing left unfinished
+        # on the queue), closed BEFORE the last task_done so a drain()
+        # that returns finds the run stamped
+        stamps = self._import_stamps
+        run_t0 = 0
         while True:
             item = q.get()
             try:
@@ -1836,6 +1874,8 @@ class Server:
                 if isinstance(item, parser.UDPMetric):
                     eng.process(item)
                 elif isinstance(item, ImportedBatch):
+                    if stamps is not None and not run_t0:
+                        run_t0 = time.monotonic_ns()
                     # durable import path: one journaled op's share for
                     # this engine, applied atomically so the engine's
                     # applied-op watermark is an exact replay cut
@@ -1859,6 +1899,8 @@ class Server:
                             "rejected corrupted imported metric "
                             "%r: %s", getattr(pb, "name", "?"), e)
                 elif isinstance(item, ImportedMetric):
+                    if stamps is not None and not run_t0:
+                        run_t0 = time.monotonic_ns()
                     # poison-pill guard: a corrupted forwarded payload
                     # (bad HLL blob, malformed centroid list) must
                     # reject THAT metric, not kill this worker loop —
@@ -1888,6 +1930,11 @@ class Server:
                 else:
                     eng.process_service_check(item)
             finally:
+                if run_t0 and q.unfinished_tasks <= 1:
+                    stamps.add("import.apply", run_t0,
+                               time.monotonic_ns(),
+                               self.APPLY_MERGE_GAP_NS)
+                    run_t0 = 0
                 q.task_done()
 
     def drain(self, timeout: float = 10.0, *, clock=time.monotonic,
@@ -1951,8 +1998,18 @@ class Server:
         t0 = time.monotonic()
         ts = int(timestamp if timestamp is not None else time.time())
         tick = token = None
+        grafts: dict = {}
         if self.flight is not None:
             tick = self.flight.begin_tick(ts)
+            # the cut: import and pump work stamped up to here is this
+            # tick's (taken now, grafted when the tick ends); the
+            # engines' flushes add the landings they hold
+            grafts = {
+                "import": ([] if self._import_stamps is None
+                           else self._import_stamps.take()),
+                "ingest": ([] if self.native_pump is None
+                           or self.native_pump.stamps is None
+                           else self.native_pump.stamps.take())}
             if timestamp is not None:
                 # scripted/explicit timestamps stay scripted all the
                 # way through the e2e accounting: the interval-close
@@ -1974,9 +2031,9 @@ class Server:
                 with trace_mod.start_span(self.trace_client,
                                           flush_span_name(),
                                           service="veneur"):
-                    frameset = self._flush_tick(ts, t0, tick)
+                    frameset = self._flush_tick(ts, t0, tick, grafts)
             else:
-                frameset = self._flush_tick(ts, t0, tick)
+                frameset = self._flush_tick(ts, t0, tick, grafts)
         finally:
             # a failing (or killed — SimulatedKill/SIGKILL chaos) tick
             # still closes its record: the ring is process-local state
@@ -1985,6 +2042,10 @@ class Server:
             if token is not None:
                 observe.reset_current_tick(token)
             if tick is not None:
+                # grafted last, so a tick short of slots drops these
+                # rows and never its own phases
+                for root, rows in grafts.items():
+                    tick.graft(rows, root=root)
                 self.flight.end_tick(tick)
                 if self.trace_client is not None:
                     self.flight.emit_spans(tick, self.trace_client)
@@ -2004,9 +2065,10 @@ class Server:
         self.telemetry.incr_level(observe.SERVER_SCOPE, "flush.count")
         return frameset
 
-    def _flush_tick(self, ts: int, t0: float, tick):
+    def _flush_tick(self, ts: int, t0: float, tick, grafts: dict):
         """The tick body (split from flush_once so recorder lifecycle
-        wraps it exactly once). `tick` is the TickRecord or None."""
+        wraps it exactly once). `tick` is the TickRecord or None;
+        `grafts` the rows flush_once grafts when the tick ends."""
         frames = []
         merged_export = ForwardExport()
         events, checks = [], []
@@ -2068,8 +2130,20 @@ class Server:
                 # graft the engine's own stamps (drain / device
                 # dispatch / device exec / fetch / materialize) under
                 # its engine.flush phase, with their real edges
+                drain = (eng_ph[i], tick.mono_start)
                 for nm, p0, p1 in res.stats.get("phases", ()):
-                    tick.add("engine." + nm, p0, p1, parent=eng_ph[i])
+                    idx = tick.add("engine." + nm, p0, p1,
+                                   parent=eng_ph[i])
+                    if nm == "drain":
+                        drain = (idx, p0)
+                # import landings since the previous flush: the
+                # flush-time one ran inside engine.drain and nests
+                # there; mid-interval ones join the `import` root
+                land = res.stats.get("import_phases", ())
+                tick.graft([r for r in land if r[1] >= drain[1]],
+                           parent=drain[0])
+                grafts["import"].extend(
+                    r for r in land if r[1] < drain[1])
             frames.append(res.frame)
             status_metrics.extend(res.status_metrics)
             merged_export.histograms.extend(res.export.histograms)
