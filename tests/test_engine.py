@@ -519,3 +519,99 @@ def test_dense_batch_still_uses_bincount_and_matches():
     by = {m.name: m.value for m in eng.flush(timestamp=1).metrics}
     assert by["d.t.count"] == 256.0
     assert by["d.t.sum"] == pytest.approx(256 * 257 / 2, rel=1e-6)
+
+
+# ---- the ingest's overflow counter (ISSUE 27) -------------------------
+
+@pytest.fixture
+def overflow_rows_of_four(monkeypatch):
+    """Work sets of 4 rows, so a 64-slot bank takes the row arm. The
+    ingest executables are cached process-wide by engine parameters:
+    drop them on both sides so no other test's trace is met or left."""
+    from veneur_tpu.models import pipeline
+    from veneur_tpu.ops import tdigest
+    pipeline.release_executables()
+    monkeypatch.setattr(tdigest, "_OVERFLOW_ROWS", (4,))
+    yield
+    pipeline.release_executables()
+
+
+def _overflowing_batches(eng):
+    """Three batches over three keys with buffers 16 deep: `a` fills
+    past 16 in the second, `b` fills to 16 exactly there and past it in
+    the third. Two rows compressed in all; no slot brings more than a
+    buffer in one batch (that is the host-side sidestep's case)."""
+    from veneur_tpu.ingest.parser import MetricKey
+    a, b, c = (eng.histo_keys.lookup(MetricKey(n, "timer", ""), 0)
+               for n in "abc")
+    rng = np.random.default_rng(1)
+    out = []
+    for per in ({a: 12, b: 12, c: 5}, {a: 10, b: 4, c: 5}, {a: 2, b: 3}):
+        slots = np.concatenate([np.full(n, s, np.int32)
+                                for s, n in per.items()])
+        rng.shuffle(slots)
+        out.append((slots, rng.gamma(2.0, 20.0, slots.size)
+                    .astype(np.float32), np.ones(slots.size, np.float32)))
+    return out
+
+
+@pytest.mark.parametrize("case", [
+    "incremental", "full_program", "staged_fetch", "legacy_ordering",
+    "through_the_stage"])
+def test_overflow_counter_follows_the_interval(case, overflow_rows_of_four):
+    kw = dict(histogram_slots=64, counter_slots=8, gauge_slots=8,
+              set_slots=8, buffer_depth=16, percentiles=(0.5,),
+              aggregates=("count",))
+    if case == "full_program":
+        kw["flush_incremental"] = False
+    elif case == "staged_fetch":
+        kw["flush_fetch"] = "staged"
+    elif case == "legacy_ordering":
+        kw["flush_double_buffer"] = False
+    eng = AggregationEngine(EngineConfig(**kw))
+    eng.warmup()
+    if case == "through_the_stage":
+        # one sample at a time: the stage buffer lands as ONE batch in
+        # which `a` brings 24 > 16 samples, so the hot-slot sidestep
+        # takes it on the host and the landing of the rest counts
+        # nothing; b's 19 do not overfill a batch on their own
+        from veneur_tpu.ingest.parser import parse_packet
+        for i in range(24):
+            eng.process(parse_packet(f"a:{i}|ms".encode()))
+        for i in range(16):
+            eng.process(parse_packet(f"b:{i}|ms".encode()))
+        want = (0, 0)
+    else:
+        for slots, vals, wts in _overflowing_batches(eng):
+            eng.ingest_histo_batch(slots, vals, wts)
+        want = (2, 0)
+    res = eng.flush(timestamp=1)
+    info = eng._last_flush_info
+    assert (info["overflow_rows"], info["overflow_bank"]) == want
+    assert info["path"] == ("full" if case == "full_program"
+                            else "incremental")
+    assert (res.stats["overflow_rows"], res.stats["overflow_bank"]) == want
+    assert res.stats["flush_path"]["overflow_rows"] == want[0]
+    by = {m.name: m.value for m in res.metrics}
+    assert by["a.count"] == 24.0
+    assert by["b.count"] == (16.0 if case == "through_the_stage" else 19.0)
+    # the counter is the interval's: the next one starts from nothing
+    res = eng.flush(timestamp=2)
+    info = eng._last_flush_info
+    assert (info["overflow_rows"], info["overflow_bank"]) == (0, 0)
+    assert res.stats["overflow_rows"] == 0
+
+
+def test_overflow_counter_counts_whole_bank_passes():
+    """No work set is smaller than a 64-slot bank (decided from the
+    static shape), so every overflow there is a pass over the bank:
+    `a`'s, which empties `b`'s full buffer with it, so `b` never
+    overflows."""
+    eng = AggregationEngine(EngineConfig(
+        histogram_slots=64, counter_slots=8, gauge_slots=8, set_slots=8,
+        buffer_depth=16, percentiles=(0.5,), aggregates=("count",)))
+    for slots, vals, wts in _overflowing_batches(eng):
+        eng.ingest_histo_batch(slots, vals, wts)
+    eng.flush(timestamp=1)
+    info = eng._last_flush_info
+    assert (info["overflow_rows"], info["overflow_bank"]) == (0, 1)

@@ -143,3 +143,50 @@ def test_route_batch_helper():
     assert 1 in rs[0, 4:8].tolist()
     # shard 3: 49 -> local 1
     assert 1 in rs[0, 12:16].tolist()
+
+
+def test_hot_slot_through_the_sharded_ingest(monkeypatch):
+    """The second caller of _add_batch_impl: a hot slot (five buffer
+    depths in one batch, among slots that fit) through the mesh
+    engine's shard_map ingest, its overflow compressed row by row (a
+    work set of 4 rows against shards of 16), held to the one-chip
+    bank's answer: exact fields exact, quantiles the same."""
+    from veneur_tpu.ops import tdigest
+    monkeypatch.setattr(tdigest, "_OVERFLOW_ROWS", (4,))
+    eng = make_engine(n_dp=1, n_shard=4)
+    rng = np.random.default_rng(27)
+    K, S, B = eng.histogram_slots, eng.S, eng.buf_size
+    per_shard = K // S
+    counts = {37: 5 * B + 9, 38: B // 2, 3: B + 1, 60: 7}
+    n = sum(counts.values())
+    batches = _empty_batches(eng, n)
+    one = tdigest.init(K, buf_size=B)
+    data = {}
+    for g, c in counts.items():
+        vals = rng.lognormal(np.log(100.0), 0.1, c).astype(np.float32)
+        data[g] = vals
+        shard, at = g // per_shard, sum(
+            len(v) for h, v in data.items()
+            if h != g and h // per_shard == g // per_shard)
+        base = shard * n + at
+        batches["h_slots"][0, base:base + c] = g % per_shard
+        batches["h_vals"][0, base:base + c] = vals
+        batches["h_wts"][0, base:base + c] = 1.0
+        one = tdigest.add_batch(one, np.full(c, g, np.int32), vals,
+                                np.ones(c, np.float32),
+                                overflow_rows=(4,))
+    eng.ingest(**batches)
+    out = eng.flush_merged()
+    one = tdigest.compress(one, compression=100.0)
+    want_q = np.asarray(tdigest.quantile(
+        one, np.asarray([0.5, 0.9], np.float32)))
+    for g, vals in data.items():
+        assert out["agg"]["count"][g] == len(vals)
+        assert out["agg"]["min"][g] == vals.min()
+        assert out["agg"]["max"][g] == vals.max()
+        assert out["agg"]["sum"][g] == pytest.approx(
+            float(vals.astype(np.float64).sum()), rel=1e-6)
+        np.testing.assert_allclose(out["quantiles"][g], want_q[g],
+                                   rtol=1e-6)
+        np.testing.assert_allclose(
+            out["quantiles"][g], np.quantile(vals, [0.5, 0.9]), rtol=0.02)

@@ -6,6 +6,7 @@ plus exact-aggregate checks, all against (a) numpy exact quantiles and
 (b) the OracleDigest port of the Go algorithm.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -179,3 +180,234 @@ def test_empty_bank():
     agg = tdigest.aggregates(bank)
     assert np.all(np.asarray(agg["count"]) == 0.0)
     assert np.all(np.asarray(agg["min"]) == 0.0)
+
+
+# ---- the ingest's overflow compress: the rows that overflowed, not
+# the bank (ISSUE 27). The work-set sizes are handed in as the static
+# `overflow_rows`, so the row arm runs at sizes the CPU likes. ---------
+
+_COUNTED = jax.jit(tdigest._add_batch_counted,
+                   static_argnames=("compression", "full_sort",
+                                    "overflow_rows"))
+_COMPRESS = jax.jit(tdigest._compress_impl,
+                    static_argnames=("compression", "full_sort"))
+_OV_K, _OV_B = 64, 16
+_BUFFERED = ("mean", "weight", "buf_value", "buf_weight", "buf_n")
+
+
+def _ov_batch(rng, per_slot: dict, pad: int = 0):
+    slots = np.concatenate(
+        [np.full(n, s, np.int32) for s, n in per_slot.items()]
+        + [np.full(pad, -1, np.int32)])
+    rng.shuffle(slots)
+    vals = rng.lognormal(np.log(100.0), 0.1, slots.size).astype(np.float32)
+    return slots, vals, np.ones(slots.size, np.float32)
+
+
+def _ov_prestate(rng):
+    """A bank whose rows hold centroids AND part-filled buffers."""
+    bank = tdigest.init(_OV_K, buf_size=_OV_B)
+    first = {s: int(n) for s, n in enumerate(
+        rng.integers(0, 3 * _OV_B, _OV_K))}
+    bank, _ = _COUNTED(bank, *_ov_batch(rng, first), overflow_rows=())
+    return bank
+
+
+def _ov_reference(bank, slots, vals, wts):
+    """The loop in numpy: write what fits, compress ONLY the rows with
+    samples still waiting — each alone, through _compress_impl on a
+    one-row bank — and go round. Returns the buffered leaves and the
+    set of rows compressed."""
+    leaves = {f: np.array(getattr(bank, f)) for f in _BUFFERED}
+    order = np.argsort(np.where(slots < 0, 2 ** 30, slots), kind="stable")
+    waiting = {}
+    for i in order:
+        if slots[i] >= 0:
+            waiting.setdefault(int(slots[i]), []).append(i)
+    compressed = set()
+    while True:
+        for s, idx in waiting.items():
+            n = int(leaves["buf_n"][s])
+            fit, waiting[s] = idx[:_OV_B - n], idx[_OV_B - n:]
+            leaves["buf_value"][s, n:n + len(fit)] = vals[fit]
+            leaves["buf_weight"][s, n:n + len(fit)] = wts[fit]
+            leaves["buf_n"][s] = n + len(fit)
+        waiting = {s: idx for s, idx in waiting.items() if idx}
+        if not waiting:
+            return leaves, compressed
+        for s in waiting:
+            one = jax.tree.map(lambda a: a[s:s + 1], bank)._replace(
+                **{f: leaves[f][s:s + 1] for f in _BUFFERED})
+            one = _COMPRESS(one, compression=100.0)
+            for f in _BUFFERED:
+                leaves[f][s] = np.asarray(getattr(one, f))[0]
+            compressed.add(s)
+
+
+def _same_bits(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    return a.shape == b.shape and a.tobytes() == b.tobytes()
+
+
+@pytest.mark.parametrize("case", [
+    "compressed_rows_are_compress_of_the_row_alone",
+    "other_rows_are_the_plain_write",
+    "more_rows_than_the_work_set_take_the_whole_bank",
+    "two_work_sets_pick_the_smallest_that_holds",
+    "three_buffer_depths_in_one_batch",
+    "padding_rows",
+    "all_padding_batch",
+    "ids_past_the_bank_end_the_loop",
+])
+def test_overflow_compresses_the_rows_that_overflowed(case):
+    rng = np.random.default_rng(27)
+    pre = _ov_prestate(rng)
+    room = _OV_B - np.asarray(pre.buf_n)
+    # rows 3, 7 and 40 overfill by a few samples, 11 exactly fills,
+    # the rest of the batch fits
+    over = {3: int(room[3]) + 5, 7: int(room[7]) + 1,
+            40: int(room[40]) + _OV_B, 11: int(room[11])}
+    fits = {s: int(room[s]) // 2 for s in (0, 5, 12, 33, 63)}
+    slots, vals, wts = _ov_batch(rng, {**over, **fits})
+
+    if case in ("compressed_rows_are_compress_of_the_row_alone",
+                "other_rows_are_the_plain_write"):
+        got, counted = _COUNTED(pre, slots, vals, wts, overflow_rows=(4,))
+        ref, compressed = _ov_reference(pre, slots, vals, wts)
+        assert compressed == {3, 7, 40}
+        assert [int(c) for c in counted] == [3, 0]
+        rows = sorted(compressed) \
+            if case.startswith("compressed") \
+            else sorted(set(range(_OV_K)) - compressed)
+        for f in _BUFFERED:
+            assert _same_bits(np.asarray(getattr(got, f))[rows],
+                              ref[f][rows]), f
+        if case.startswith("other"):
+            # nobody's centroids moved but the compressed rows'
+            for f in ("mean", "weight"):
+                assert _same_bits(np.asarray(getattr(got, f))[rows],
+                                  np.asarray(getattr(pre, f))[rows]), f
+            assert int(np.asarray(got.buf_n)[11]) == _OV_B
+        # exact fields are computed before the loop: the whole-bank
+        # arm's, bit for bit
+        whole, _ = _COUNTED(pre, slots, vals, wts, overflow_rows=())
+        for f in set(tdigest.TDigestBank._fields) - set(_BUFFERED):
+            assert _same_bits(getattr(got, f), getattr(whole, f)), f
+    elif case == "more_rows_than_the_work_set_take_the_whole_bank":
+        got, counted = _COUNTED(pre, slots, vals, wts, overflow_rows=(2,))
+        whole, counted_whole = _COUNTED(pre, slots, vals, wts,
+                                        overflow_rows=())
+        # three rows wait after the first pass: over the set of two
+        assert [int(c) for c in counted] == [0, 1]
+        assert [int(c) for c in counted_whole] == [0, 1]
+        for f in tdigest.TDigestBank._fields:
+            assert _same_bits(getattr(got, f), getattr(whole, f)), f
+        # which is what every overflow did before this change: a row
+        # the batch never touched had its buffer merged too
+        cold = next(r for r in range(_OV_K) if r not in {**over, **fits}
+                    and int(pre.buf_n[r]) > 0)
+        assert int(whole.buf_n[cold]) == 0
+        rows_only, _ = _COUNTED(pre, slots, vals, wts, overflow_rows=(4,))
+        assert int(rows_only.buf_n[cold]) == int(pre.buf_n[cold])
+    elif case == "two_work_sets_pick_the_smallest_that_holds":
+        # 3 rows wait: past the set of 2, inside the set of 8; a bank
+        # no larger than a set never takes it (static shape)
+        got, counted = _COUNTED(pre, slots, vals, wts,
+                                overflow_rows=(2, 8))
+        one, _ = _COUNTED(pre, slots, vals, wts, overflow_rows=(8,))
+        assert [int(c) for c in counted] == [3, 0]
+        for f in tdigest.TDigestBank._fields:
+            assert _same_bits(getattr(got, f), getattr(one, f)), f
+        _, counted = _COUNTED(pre, slots, vals, wts,
+                              overflow_rows=(_OV_K,))
+        assert [int(c) for c in counted] == [0, 1]
+    elif case == "three_buffer_depths_in_one_batch":
+        n = int(room[9]) + 3 * _OV_B + 5
+        slots, vals, wts = _ov_batch(rng, {9: n, **fits})
+        got, counted = _COUNTED(pre, slots, vals, wts, overflow_rows=(4,))
+        assert [int(c) for c in counted] == [4, 0]
+        mine = vals[slots == 9].astype(np.float64)
+        before = {f: float(np.asarray(getattr(pre, f))[9])
+                  for f in ("count", "vsum", "vmin", "vmax")}
+        assert float(got.count[9]) == before["count"] + n
+        assert float(got.vsum[9]) + float(got.vsum_lo[9]) == \
+            pytest.approx(before["vsum"] + mine.sum(), rel=1e-6)
+        assert float(got.vmin[9]) == min(before["vmin"], mine.min())
+        assert float(got.vmax[9]) == max(before["vmax"], mine.max())
+        held = float(np.asarray(got.weight)[9].sum()
+                     + np.asarray(got.buf_weight)[9].sum())
+        assert held == float(got.count[9])
+        assert int(got.buf_n[9]) == 5
+    elif case == "padding_rows":
+        got, counted = _COUNTED(pre, slots, vals, wts, overflow_rows=(4,))
+        at = np.sort(rng.choice(slots.size + 40, slots.size,
+                                replace=False))
+        ps = np.full(slots.size + 40, -1, np.int32)
+        pv = np.full(slots.size + 40, 1e9, np.float32)
+        pw = np.ones(slots.size + 40, np.float32)
+        ps[at], pv[at], pw[at] = slots, vals, wts
+        padded, counted_p = _COUNTED(pre, ps, pv, pw, overflow_rows=(4,))
+        assert [int(c) for c in counted_p] == [int(c) for c in counted]
+        for f in tdigest.TDigestBank._fields:
+            assert _same_bits(getattr(got, f), getattr(padded, f)), f
+    elif case == "all_padding_batch":
+        got, counted = _COUNTED(pre, np.full(32, -1, np.int32),
+                                np.full(32, 5.0, np.float32),
+                                np.ones(32, np.float32),
+                                overflow_rows=(4,))
+        assert [int(c) for c in counted] == [0, 0]
+        for f in tdigest.TDigestBank._fields:
+            assert _same_bits(getattr(got, f), getattr(pre, f)), f
+    else:
+        # an id past the bank lands nowhere and waits on nothing, so
+        # the loop ends however many of them a batch brings
+        stray = np.full(3 * _OV_B, _OV_K + 3, np.int32)
+        got, counted = _COUNTED(
+            pre, np.concatenate([slots, stray]),
+            np.concatenate([vals, np.ones(stray.size, np.float32)]),
+            np.ones(slots.size + stray.size, np.float32),
+            overflow_rows=(4,))
+        clean, _ = _COUNTED(pre, slots, vals, wts, overflow_rows=(4,))
+        assert [int(c) for c in counted] == [3, 0]
+        for f in tdigest.TDigestBank._fields:
+            assert _same_bits(getattr(got, f), getattr(clean, f)), f
+
+
+@pytest.mark.parametrize("overflow_rows", [(8,), ()],
+                         ids=["row_arm", "whole_bank_arm"])
+def test_hot_keys_at_the_cells_shape_stay_in_the_rank_contract(
+        overflow_rows):
+    """The benchmark's own shape: 2,000 samples a hot key landing about
+    30 a batch among cold keys of 4 samples, buffer 256, compression
+    100, lognormal sigma 0.1 — p50 within 1%, p99 within 2% of
+    numpy.quantile (chip_smoke.py derives both from the digest's rank
+    contract), count/min/max exact."""
+    rng = np.random.default_rng(2027)
+    K, hot, cold = 256, 10, 100
+    keys = rng.permutation(K)[:hot + cold].astype(np.int32)
+    slots = np.concatenate([np.repeat(keys[:hot], 2000),
+                            np.repeat(keys[hot:], 4)])
+    rng.shuffle(slots)
+    vals = rng.lognormal(np.log(100.0), 0.1, slots.size).astype(np.float32)
+    bank = tdigest.init(K)
+    rows = bank_passes = 0
+    for s, v in zip(np.array_split(slots, 67), np.array_split(vals, 67)):
+        bank, counted = _COUNTED(bank, s, v, np.ones(s.size, np.float32),
+                                 overflow_rows=overflow_rows)
+        rows += int(counted[0])
+        bank_passes += int(counted[1])
+    if overflow_rows:
+        assert (rows, bank_passes) == (hot * 7, 0)   # 2000 // 256 fills
+    else:
+        assert rows == 0 and bank_passes > 7
+    bank = _COMPRESS(bank, compression=100.0)
+    q = np.asarray(tdigest.quantile(bank, np.array([0.5, 0.99],
+                                                   np.float32)))
+    for k in keys[:hot]:
+        mine = vals[slots == k]
+        want = np.quantile(mine.astype(np.float64), [0.5, 0.99])
+        assert float(bank.count[k]) == 2000.0
+        assert float(bank.vmin[k]) == mine.min()
+        assert float(bank.vmax[k]) == mine.max()
+        assert abs(q[k, 0] - want[0]) / want[0] < 0.01
+        assert abs(q[k, 1] - want[1]) / want[1] < 0.02

@@ -432,3 +432,42 @@ def test_slow_sink_does_not_delay_flush_tick():
     finally:
         slow.release.set()
         srv.stop()
+
+
+def test_ingest_overflow_counters_drain_as_self_metrics():
+    """veneur.ingest.overflow_rows_total / overflow_bank_total: what
+    the histogram landings' overflow compress did in the interval,
+    drained like samples.processed (present at zero, reset a flush)."""
+    import numpy as np
+
+    from veneur_tpu.ingest.parser import MetricKey
+    cap = CaptureMetricSink()
+    cfg = Config(interval="3600s", hostname="h",
+                 tpu_histogram_slots=64, tpu_counter_slots=128,
+                 tpu_gauge_slots=128, tpu_set_slots=64,
+                 tpu_buffer_depth=16)
+    srv = Server(cfg, sinks=[cap], plugins=[], span_sinks=[])
+    srv.start()
+    try:
+        eng = srv.engines[0]
+        slot = eng.histo_keys.lookup(MetricKey("lat", "timer", ""), 0)
+        for _ in range(3):      # 36 samples into a 16-deep buffer
+            eng.ingest_histo_batch(np.full(12, slot, np.int32),
+                                   np.arange(12, dtype=np.float32),
+                                   np.ones(12, np.float32))
+        srv.flush_once(timestamp=1)
+        cap.wait_for_flush(1)
+        srv.flush_once(timestamp=2)
+        cap.wait_for_flush(2)
+        first, second = ({m.name: m.value for m in f
+                          if m.name.startswith("veneur.ingest.")}
+                         for f in cap.flushes[:2])
+        # a 64-slot bank is under every work set: whole-bank passes
+        assert first == {"veneur.ingest.overflow_rows_total": 0,
+                         "veneur.ingest.overflow_bank_total": 2}
+        assert second == {"veneur.ingest.overflow_rows_total": 0,
+                          "veneur.ingest.overflow_bank_total": 0}
+        state = srv._debug_flush_state()["registry"]["server"]
+        assert state["counters"]["_server|ingest.overflow_bank"] == 2
+    finally:
+        srv.stop()

@@ -160,8 +160,21 @@ def _ingest_executables(device, heng, seng, set_arm="xla"):
             seng.insert_fused_impl, interpret=(set_arm == "interpret"))
     else:
         set_insert = seng.insert_impl
+
+    def add_batch_impl(bank, overflow, slots, values, weights):
+        # (the name is the program's in a profile: jit_add_batch_impl)
+        # the landing keeps its own overflow counter (i32[2], see
+        # AggregationEngine._overflow) on the device, so counting
+        # costs no dispatch and no sync; not donated: eight bytes, and
+        # an interval's first landing reads the engine's shared zero
+        if hasattr(heng, "add_batch_counted_impl"):
+            bank, counted = heng.add_batch_counted_impl(
+                bank, slots, values, weights)
+            return bank, overflow + counted
+        return heng.add_batch_impl(bank, slots, values, weights), overflow
+
     return {
-        "histo": jit(heng.add_batch_impl),
+        "histo": jit(add_batch_impl),
         "counter": jit(scalar.counter_add.__wrapped__),
         "gauge": jit(scalar.gauge_set.__wrapped__),
         "set": jit(set_insert),
@@ -737,6 +750,14 @@ class AggregationEngine:
     # place) and turns both off in its constructor.
     _incremental_capable = True
     _double_buffer_capable = True
+    # What the histogram landings' overflow handling did this interval,
+    # summed on the device by the landing program itself: i32[2] = rows
+    # compressed one by one, passes over the whole bank. Retired with
+    # the banks at the swap and read with the flush's own fetch
+    # (_last_flush_info "overflow_rows" / "overflow_bank"). None on an
+    # engine whose landing counts nothing (the mesh engine).
+    _overflow = None
+    _overflow_zero = None
 
     def _setup_device(self):
         """Build the device-side state: committed banks plus the shared
@@ -767,6 +788,10 @@ class AggregationEngine:
         self._kern = _ingest_executables(self._device, self._heng,
                                          self._seng,
                                          self._kernel_arms["set"])
+        self._overflow_zero = jax.device_put(
+            np.zeros(2, np.int32),
+            jax.sharding.SingleDeviceSharding(self._device))
+        self._overflow = self._overflow_zero
 
     def _setup_flush_exec(self):
         cfg = self.cfg
@@ -1100,12 +1125,14 @@ class AggregationEngine:
     def _add_histos(self, slots, values, weights):
         """Live-bank histogram landing (ingest path; mesh overrides
         this wholesale — its landing routes the sharded ingest)."""
-        self.histo_bank = self._land_histos(
-            self.histo_bank, self._dirty, slots, values, weights)
+        self.histo_bank, self._overflow = self._land_histos(
+            self.histo_bank, self._overflow, self._dirty, slots, values,
+            weights)
 
-    def _land_histos(self, bank, dirty, slots, values, weights):
+    def _land_histos(self, bank, overflow, dirty, slots, values, weights):
         """Land one histogram batch into `bank` (live or a retired
-        double-buffer snapshot — the caller owns the rebind), marking
+        double-buffer snapshot — the caller owns the rebind of the bank
+        and of its `overflow` counter), marking
         `dirty`, sidestepping the hot-slot worst case: add_batch's
         while-loop pays a full-bank [K, C+B] sort per buffer-depth's
         worth of samples landing on ONE slot, so a batch where
@@ -1130,7 +1157,8 @@ class AggregationEngine:
         # scan a multi-MB count array per batch); there np.unique's
         # O(n log n) on the small batch is the cheaper form.
         if vs.size <= B:
-            return self._kern["histo"](bank, slots, values, weights)
+            return self._kern["histo"](bank, overflow, slots, values,
+                                       weights)
         if vs.max() > 16 * vs.size:
             uniq, cnt = np.unique(vs, return_counts=True)
             hot_ids = uniq[cnt > B]
@@ -1138,13 +1166,15 @@ class AggregationEngine:
             cnt = np.bincount(vs, minlength=1)
             hot_ids = np.nonzero(cnt > B)[0]
         if hot_ids.size == 0:
-            return self._kern["histo"](bank, slots, values, weights)
+            return self._kern["histo"](bank, overflow, slots, values,
+                                       weights)
         values = np.asarray(values)
         weights = np.asarray(weights)
         hot = set(hot_ids.tolist())
         hot_m = np.isin(slots, list(hot)) & valid
         cold_slots = np.where(hot_m, -1, slots).astype(np.int32)
-        bank = self._kern["histo"](bank, cold_slots, values, weights)
+        bank, overflow = self._kern["histo"](bank, overflow, cold_slots,
+                                             values, weights)
 
         out_s, out_m, out_w = [], [], []
         sc_s, sc_min, sc_max, sc_sum, sc_cnt, sc_rcp = \
@@ -1188,7 +1218,7 @@ class AggregationEngine:
         bank = self._kern["merge_centroids"](bank, pad_s, pad_m, pad_w)
         return self._kern["merge_scalars"](
             bank, spad, f(sc_min), f(sc_max), f(sc_sum),
-            f(sc_cnt), f(sc_rcp))
+            f(sc_cnt), f(sc_rcp)), overflow
 
     def ingest_counter_batch(self, slots, values, weights, count=None,
                              mark=None):
@@ -1303,7 +1333,7 @@ class AggregationEngine:
         self.warm_ingest_kernels(self.cfg.batch_size)
         # Run the full configured flush path (program + staging/fetch
         # mode) so flush 0 hits only warm executables.
-        self._flush_device(self._fresh_fn())
+        self._flush_device(self._fresh_fn(), overflow=self._overflow_zero)
         if self._use_incremental:
             # the incremental path too: build the empty-flush baseline
             # and compile the smallest-bucket incremental program (one
@@ -1313,7 +1343,8 @@ class AggregationEngine:
             warm_dirty = [np.zeros_like(d) for d in self._dirty]
             for d in warm_dirty:
                 d[0] = True
-            self._flush_device(self._fresh_fn(), dirty=warm_dirty)
+            self._flush_device(self._fresh_fn(), dirty=warm_dirty,
+                               overflow=self._overflow_zero)
         jax.block_until_ready(self.histo_bank)
 
     def warm_ingest_kernels(self, b: int):
@@ -1332,8 +1363,8 @@ class AggregationEngine:
             # vlint: disable=DS01 reason=all-padding warmup batches
             # (slot -1 rows dropped by the kernels) — no live data
             # lands, nothing to mark
-            self.histo_bank = self._kern["histo"](
-                self.histo_bank, pad, zf, zf)
+            self.histo_bank, self._overflow = self._kern["histo"](
+                self.histo_bank, self._overflow, pad, zf, zf)
             self.counter_bank = self._kern["counter"](
                 self.counter_bank, pad, zf, zf)
             self.gauge_bank = self._kern["gauge"](
@@ -1723,7 +1754,15 @@ class AggregationEngine:
             self._dirty = [np.zeros_like(d) for d in retired]
         return retired
 
-    def _flush_device(self, snap, phases=None, dirty=None) -> dict:
+    def _retire_overflow(self):
+        """Under the lock, with the bank swap: the retiring interval's
+        overflow counter travels with its snapshot, and the fresh
+        banks count from zero."""
+        retired, self._overflow = self._overflow, self._overflow_zero
+        return retired
+
+    def _flush_device(self, snap, phases=None, dirty=None,
+                      overflow=None) -> dict:
         """Run the flush program on the snapshot and fetch the compact
         host arrays: ONE program dispatch + ONE device_get.
         `flush_fetch` picks how the fetch is performed (see EngineConfig).
@@ -1734,7 +1773,8 @@ class AggregationEngine:
         run through the device — the ISSUE 11 tentpole
         (_flush_device_incremental); above the dirty-fraction
         threshold, or with dirty=None (warmup, bench harnesses, mesh),
-        the full program runs.
+        the full program runs. `overflow` (the retired interval's
+        overflow counter) rides the same fetch into _last_flush_info.
 
         `phases` (flight-recorder stamp list, appended in place) splits
         the merge into dispatch / device exec / fetch — but ONLY under
@@ -1742,17 +1782,19 @@ class AggregationEngine:
         host sync, which the staged/host/async modes exist to avoid, so
         those record one combined `device` phase instead."""
         if dirty is not None and self._use_incremental:
-            host = self._flush_device_incremental(snap, phases, dirty)
+            host = self._flush_device_incremental(snap, phases, dirty,
+                                                  overflow)
             if host is not None:
                 return host
         self._last_flush_info = {"path": "full"}
         hb, cb, gb, sb = snap
-        if phases is None:
-            return self._fetch_flush(
-                self._flush_exec(hb, cb, gb, sb, self._qs))
         t0 = time.monotonic_ns()
         out = self._flush_exec(hb, cb, gb, sb, self._qs)
+        if overflow is not None:
+            out["overflow"] = overflow
         t1 = time.monotonic_ns()
+        if phases is None:
+            return self._fetch_flush(out)
         return self._timed_fetch(out, t0, t1, phases)
 
     def _timed_fetch(self, out, t0, t1, phases):
@@ -1794,7 +1836,7 @@ class AggregationEngine:
                 kernel_arm=self._kernel_arms["histogram"])
         return self._flush_baseline
 
-    def _flush_device_incremental(self, snap, phases, dirty):
+    def _flush_device_incremental(self, snap, phases, dirty, overflow):
         """The incremental dirty-slot flush (ISSUE 11 tentpole):
         gather only touched piles into a compact [D, ·] work set, run
         the shared flush body over that slice, and scatter the compact
@@ -1819,7 +1861,10 @@ class AggregationEngine:
         }
         if all(i.size == 0 for i in ids):
             # an idle interval: every output IS the baseline — no
-            # device dispatch at all
+            # device dispatch at all (and nothing landed to overflow)
+            if overflow is not None:
+                self._last_flush_info.update(overflow_rows=0,
+                                             overflow_bank=0)
             host = self._scatter_host({}, ids, dirty, base)
             t1 = time.monotonic_ns()
             if phases is not None:
@@ -1839,6 +1884,8 @@ class AggregationEngine:
             phases.append(("gather", t0, t1))
         t2 = time.monotonic_ns()
         out = exec_(hb, cb, gb, sb, self._qs, *idx)
+        if overflow is not None:
+            out["overflow"] = overflow
         t3 = time.monotonic_ns()
         if phases is not None:
             host_c = self._timed_fetch(out, t2, t3, phases)
@@ -1884,6 +1931,11 @@ class AggregationEngine:
         # engines whose device program emits the finished estimate)
         if "s_est" in host or "s_counts" in host:
             self._seng.estimate_finalize(host)
+        counted = host.pop("overflow", None)
+        if counted is not None:
+            self._last_flush_info.update(
+                overflow_rows=int(counted[0]),
+                overflow_bank=int(counted[1]))
         return host
 
     def _flush_bookkeeping(self, full_export: bool = False) -> tuple:
@@ -1920,7 +1972,7 @@ class AggregationEngine:
             ki.advance_interval()
         return active, status, stats_samples, dropped, histo_key_count
 
-    def _land_retired(self, snap, dirty, stages, imports,
+    def _land_retired(self, snap, overflow, dirty, stages, imports,
                       gauge_seq) -> tuple:
         """Outside the lock (double-buffered flush): drain the retired
         interval's stage buffers and land its staged imports into the
@@ -1935,8 +1987,9 @@ class AggregationEngine:
         hb, cb, gb, sb = snap
         a = stages.get("histo")
         if a is not None:
-            hb = self._land_histos(hb, dirty, a["slots"], a["values"],
-                                   a["weights"])
+            hb, overflow = self._land_histos(
+                hb, overflow, dirty, a["slots"], a["values"],
+                a["weights"])
         a = stages.get("counter")
         if a is not None:
             cb = self._land_counters(cb, dirty, a["slots"], a["values"],
@@ -1954,7 +2007,7 @@ class AggregationEngine:
         sb = self._land_import_sets(sb, sets, dirty)
         cb, gb, _seq = self._land_import_scalars(
             cb, gb, counters, gauges, dirty, gauge_seq)
-        return hb, cb, gb, sb
+        return (hb, cb, gb, sb), overflow
 
     def flush(self, timestamp: int | None = None,
               forward_kind: str = "full") -> FlushResult:
@@ -2007,6 +2060,7 @@ class AggregationEngine:
                 self._gauge_seq = 0
                 snap = self._swap_banks()
                 dirty = self._retire_dirty()
+                overflow = self._retire_overflow()
                 # the applied-op watermark AT THE SWAP: per-queue
                 # application is FIFO, so every op <= this id is in the
                 # retiring snapshot and every later one in the shadow
@@ -2020,8 +2074,8 @@ class AggregationEngine:
             # shared monotonic_ns clock, returned in stats["phases"]
             # so the server grafts them into the tick's phase tree
             phases = [("swap", t_start, t_swap)]
-            snap = self._land_retired(snap, dirty, stages, imports,
-                                      retired_seq)
+            snap, overflow = self._land_retired(
+                snap, overflow, dirty, stages, imports, retired_seq)
             t_drain = time.monotonic_ns()
             phases.append(("drain", t_swap, t_drain))
         else:
@@ -2032,6 +2086,7 @@ class AggregationEngine:
                 self._flush_import_scalars()
                 snap = self._swap_banks()
                 dirty = self._retire_dirty()
+                overflow = self._retire_overflow()
                 self._gauge_seq = 0
                 retired_wm = self.last_import_op
                 (active, status, stats_samples, dropped,
@@ -2040,7 +2095,8 @@ class AggregationEngine:
             phases = [("drain", t_start, t_swap)]
 
         fwd_out = self._fwd_out
-        host = self._flush_device(snap, phases=phases, dirty=dirty)
+        host = self._flush_device(snap, phases=phases, dirty=dirty,
+                                  overflow=overflow)
         t_device = time.monotonic_ns()
 
         # Delta export build (ISSUE 13): honor the request only when
@@ -2260,6 +2316,10 @@ class AggregationEngine:
             # counts) — bench/test introspection, also what an
             # operator correlates the gather/scatter phases against
             "flush_path": dict(self._last_flush_info),
+            # what the histogram landings' overflow handling did this
+            # interval (veneur.ingest.overflow_*_total)
+            "overflow_rows": self._last_flush_info.get("overflow_rows", 0),
+            "overflow_bank": self._last_flush_info.get("overflow_bank", 0),
             # what the export build actually shipped (delta requests
             # degrade to full when no bitmap exists — mesh, tracking
             # off — or the engine does not forward)

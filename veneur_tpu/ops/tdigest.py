@@ -545,24 +545,54 @@ def cluster_rows(values, weights, compression: float = 100.0,
                          sorted_prefix=sorted_prefix)
 
 
-def _add_batch_impl(bank: TDigestBank, slots, values, weights,
-                    compression: float = 100.0,
-                    full_sort: bool | None = None) -> TDigestBank:
+# Work-set sizes of the ingest's overflow compress, ascending: a batch
+# that leaves samples waiting on n rows compresses the smallest set
+# that holds n, and the whole bank when none does. On the v5e at the
+# north-star bank ([131072, 256+256]) a whole-bank pass costs 111.5 ms
+# and an overflowing batch's row pass 1.7-2.0 ms all told. One set of
+# 1,024 covers what the benchmark's cells bring a pump batch (a dozen
+# rows in steady_10k, 40 in hot_1k, 1,000 in its first batches); a
+# second set of 128 saved 4% of a steady_10k tick's landing time and a
+# set of 4,096 cost 13% (builder's chip runs, PR 27; PERF.md 5b).
+_OVERFLOW_ROWS = (1024,)
+
+
+def _add_batch_counted(bank: TDigestBank, slots, values, weights,
+                       compression: float = 100.0,
+                       full_sort: bool | None = None,
+                       overflow_rows: tuple | None = None):
     """Scatter a batch of (slot, value, weight) samples into the bank.
 
     Batched equivalent of Histo.Sample -> MergingDigest.Add. Samples append
-    to per-slot buffers; rows that would overflow trigger a (batched)
-    compress and the leftover samples are re-scattered, looping until the
-    batch is fully absorbed (ceil(max_per_slot / B) iterations worst case).
+    to per-slot buffers; a row whose buffer fills is compressed and its
+    leftover samples are re-scattered, looping until the batch is fully
+    absorbed (ceil(max_per_slot / B) iterations worst case). Like the
+    reference, which merges the temp buffer of the ONE digest that
+    filled it, the compress runs over the rows that still have samples
+    waiting: they are gathered into the smallest `overflow_rows` work
+    set that holds them, clustered by the same _compress_impl, and
+    scattered back; every other row keeps its buffer. More waiting
+    rows than the largest set (or a bank no larger than a set — decided
+    from the static shape) compress the whole bank, as every overflow
+    did before. `overflow_rows` is for tests (no caller sets it): None
+    is the module's _OVERFLOW_ROWS.
     slot == -1 marks padding and is dropped via out-of-bounds scatter.
     `full_sort` reaches the overflow loop's compress (A/B arm selection).
-    """
+
+    Returns (bank, i32[2]): rows the row arms compressed, and passes of
+    the whole-bank arm."""
     K = bank.num_slots
     B = bank.buf_size
+    if full_sort is None:
+        full_sort = _full_sort_default()
+    if overflow_rows is None:
+        overflow_rows = _OVERFLOW_ROWS
 
     s, v, w = scatter.sort_by_slot(slots, values, weights, num_slots=K)
     rank = scatter.run_ranks(s)
-    valid = s >= 0
+    # an id past the bank lands nowhere: were it `valid`, its samples
+    # would wait on a row no compress can empty and the loop not end
+    valid = (s >= 0) & (s < K)
     sd = jnp.where(valid, s, K)  # OOB -> dropped by mode="drop"
 
     # Exact scalar statistics never need the buffer: pure segment reduces.
@@ -584,72 +614,90 @@ def _add_batch_impl(bank: TDigestBank, slots, values, weights,
         vsum_lo=vsum_lo, count_lo=count_lo, recip_lo=recip_lo,
     )
 
-    def write_pass(bank, written):
-        """One buffer-write pass: land every not-yet-written sample
-        whose position fits its slot's buffer. Returns the updated
-        bank and written mask."""
-        # Rank among the not-yet-written samples of each slot: ranks are
-        # consumed in order, so subtracting the per-slot written count
-        # re-bases them.
-        done_per_slot = scatter.segment_count(s, written & valid, K)
-        pos = bank.buf_n[jnp.where(valid, s, 0)] + rank - done_per_slot[
-            jnp.where(valid, s, 0)]
-        can = valid & ~written & (pos < B)
-        row = jnp.where(can, s, K)
-        col = jnp.clip(pos, 0, B - 1)
-        new_bv = bank.buf_value.at[row, col].set(v, mode="drop")
-        new_bw = bank.buf_weight.at[row, col].set(w, mode="drop")
-        wrote = scatter.segment_count(s, can, K)
-        bank = bank._replace(buf_value=new_bv, buf_weight=new_bw,
-                             buf_n=bank.buf_n + wrote)
-        return bank, written | can
+    # Where each sample goes follows from the fills at entry alone:
+    # every compress the loop makes empties the rows that still wait
+    # (and a waiting row's buffer is full), so with `at` the sample's
+    # place in its row's stream — fill at entry + rank in the batch —
+    # it lands in turn at // B, lane at % B, and the rows that wait
+    # before turn j are those holding more than j buffers' worth. No
+    # pass needs a per-slot count of its own.
+    fill = bank.buf_n + scatter.segment_count(s, valid, K)
+    at = bank.buf_n[jnp.where(valid, s, 0)] + rank
+    turn = jnp.where(valid, at // B, -1)
+    lane = at % B
 
-    def cond(state):
-        _, written = state
-        return jnp.any(valid & ~written)
+    def write(bank, j):
+        row = jnp.where(turn == j, s, K)
+        return bank._replace(
+            buf_value=bank.buf_value.at[row, lane].set(v, mode="drop"),
+            buf_weight=bank.buf_weight.at[row, lane].set(w, mode="drop"),
+            buf_n=jnp.where(fill > j * B, jnp.minimum(fill - j * B, B),
+                            bank.buf_n))
+
+    def compress_bank(bank, waiting):
+        return _compress_impl(bank, compression, full_sort)
+
+    def compress_rows(R):
+        def arm(bank, waiting):
+            # `waiting` marks one sample of each row to compress; the
+            # batch is sorted by slot, so a sort brings their ids to
+            # the front. Padding ids are K: gathered clamped, dropped
+            # at the scatter.
+            rows = jnp.sort(jnp.where(waiting, s, K))[:R]
+            take = jnp.minimum(rows, K - 1)
+            part = _compress_impl(jax.tree.map(lambda a: a[take], bank),
+                                  compression, full_sort)
+            put = lambda leaf, new: leaf.at[rows].set(
+                new, mode="drop", indices_are_sorted=True)
+            return bank._replace(
+                mean=put(bank.mean, part.mean),
+                weight=put(bank.weight, part.weight),
+                buf_value=put(bank.buf_value, part.buf_value),
+                buf_weight=put(bank.buf_weight, part.buf_weight),
+                buf_n=put(bank.buf_n, part.buf_n))
+        return arm
+
+    # a work set as large as the bank saves nothing over the bank arm
+    sizes = tuple(R for R in overflow_rows if R < K)
+    arms = tuple(compress_rows(R) for R in sizes) + (compress_bank,)
+    last = scatter.run_lasts(s)
 
     def body(state):
-        bank, written = state
-        bank, written = write_pass(bank, written)
-        leftover = jnp.any(valid & ~written)
-        bank = jax.lax.cond(
-            leftover,
-            lambda b: _compress_impl(b, compression, full_sort),
-            lambda b: b,
-            bank,
-        )
-        return bank, written
+        bank, j, counted = state
+        # a slot's samples are placed in rank order, so the last of its
+        # run has the latest turn: it stands for the row
+        waiting = last & (turn >= j)
+        n = jnp.sum(waiting, dtype=jnp.int32)
+        arm = jnp.sum(n > jnp.asarray(sizes, jnp.int32), dtype=jnp.int32)
+        bank = write(jax.lax.switch(arm, arms, bank, waiting), j)
+        whole = arm == len(sizes)
+        counted = counted + jnp.stack(
+            [jnp.where(whole, 0, n), whole.astype(jnp.int32)])
+        return bank, j + 1, counted
 
-    def loop_path(bank):
-        bank, _ = jax.lax.while_loop(
-            cond, body, (bank, jnp.zeros_like(valid)))
-        return bank
-
-    def fast_path(bank):
-        # the overflow predicate guarantees every valid sample fits, so
-        # positions are direct (no done/wrote segment scatters needed —
-        # the per-slot batch counts were already materialized for the
-        # predicate itself)
-        pos = bank.buf_n[jnp.where(valid, s, 0)] + rank
-        row = jnp.where(valid, s, K)
-        col = jnp.clip(pos, 0, B - 1)
-        return bank._replace(
-            buf_value=bank.buf_value.at[row, col].set(v, mode="drop"),
-            buf_weight=bank.buf_weight.at[row, col].set(w, mode="drop"),
-            buf_n=bank.buf_n + batch_per_slot)
-
-    # The common case — no slot's buffer overflows — needs exactly one
-    # write pass; the while_loop's carried-state machinery costs ~25%
-    # of the dispatch on the CPU backend even when it runs one
-    # iteration. Branch on the actual overflow condition (per-slot
-    # batch count + current fill vs capacity) and keep the loop for
-    # the hot-slot case only.
-    batch_per_slot = scatter.segment_count(s, valid, K)
-    overflows = jnp.any(bank.buf_n + batch_per_slot > B)
-    return jax.lax.cond(overflows, loop_path, fast_path, bank)
+    # The common case — no slot's buffer overflows — is the one write
+    # and a loop of no turns. The carry starts from the data, not from
+    # constants: inside shard_map a constant would lack the varying
+    # mesh-axes type the body gives it back with.
+    zero = 0 * s[:1]
+    turns = jnp.max(turn)
+    bank, _, counted = jax.lax.while_loop(
+        lambda state: state[1] <= turns, body,
+        (write(bank, 0), 1 + zero[0], jnp.zeros((2,), jnp.int32) + zero))
+    return bank, counted
 
 
-add_batch = partial(jax.jit, static_argnames=("compression", "full_sort"),
+def _add_batch_impl(bank: TDigestBank, slots, values, weights,
+                    compression: float = 100.0,
+                    full_sort: bool | None = None,
+                    overflow_rows: tuple | None = None) -> TDigestBank:
+    """_add_batch_counted without its counts."""
+    return _add_batch_counted(bank, slots, values, weights, compression,
+                              full_sort, overflow_rows)[0]
+
+
+add_batch = partial(jax.jit, static_argnames=("compression", "full_sort",
+                                              "overflow_rows"),
                     donate_argnames=("bank",))(_add_batch_impl)
 
 

@@ -286,11 +286,13 @@ class MeshAggregationEngine(AggregationEngine):
         self.me.banks = self.me._fresh_fn()
         return snap
 
-    def _flush_device(self, snap, phases=None, dirty=None) -> dict:
+    def _flush_device(self, snap, phases=None, dirty=None,
+                      overflow=None) -> dict:
         """Collective merge over the mesh, mapped onto the host-dict
         contract the shared assembly consumes. `phases` (the flight
-        recorder's stamp list) and `dirty` (always None here — the
-        mesh engine carries no per-slot bitmaps) are accepted for
+        recorder's stamp list), `dirty` and `overflow` (always None
+        here — the mesh engine carries no per-slot bitmaps and its
+        sharded landing counts nothing) are accepted for
         signature parity with the single-device engine. With `phases`
         the one collective program is stamped device.dispatch /
         device.exec (bounded by block_until_ready) / device.fetch, as

@@ -2074,6 +2074,7 @@ class Server:
         events, checks = [], []
         status_metrics = []
         eng_stats = {"samples": 0, "dropped_no_slot": 0,
+                     "overflow_rows": 0, "overflow_bank": 0,
                      "swap_ns": 0, "merge_ns": 0, "assembly_ns": 0}
         # Engines flush concurrently so their device programs and
         # device→host transfers overlap instead of queueing behind
@@ -2822,6 +2823,10 @@ class Server:
             tel.mark(S, "samples.processed", eng_stats["samples"])
             tel.mark(S, "samples.dropped_no_slot",
                      eng_stats["dropped_no_slot"])
+            # the ingest's overflow compress: rows compressed one by
+            # one, and passes over a whole histogram bank (the dear arm)
+            tel.mark(S, "ingest.overflow_rows", eng_stats["overflow_rows"])
+            tel.mark(S, "ingest.overflow_bank", eng_stats["overflow_bank"])
             tel.set_gauge(S, "flush.swap_duration_ns",
                           eng_stats["swap_ns"])
             tel.set_gauge(S, "flush.merge_duration_ns",

@@ -26,6 +26,10 @@ HISTOGRAM ENGINES — own a bank NamedTuple with:
   Methods (pure, jit-composable unless noted):
     init(num_slots) -> bank
     add_batch_impl(bank, slots, values, weights) -> bank
+    add_batch_counted_impl(...) -> (bank, i32[2])  (optional: the same
+        landing plus what its overflow handling did — rows compressed
+        one by one, passes over the whole bank; an engine without it
+        counts nothing)
     compress_impl(bank) -> bank
     merge_centroids_impl(bank, slots, means, weights) -> bank
     merge_scalars_impl(bank, slots, mins, maxs, sums, counts, recips)

@@ -46,6 +46,13 @@ class TDigestEngine:
         return tdigest._add_batch_impl(bank, slots, values, weights,
                                        self.compression)
 
+    def add_batch_counted_impl(self, bank, slots, values, weights):
+        """add_batch_impl plus i32[2]: rows its overflow compressed
+        one by one, and passes over the whole bank (the landing
+        program sums them into the engine's overflow counter)."""
+        return tdigest._add_batch_counted(bank, slots, values, weights,
+                                          self.compression)
+
     def compress_impl(self, bank):
         return tdigest._compress_impl(bank, self.compression)
 
