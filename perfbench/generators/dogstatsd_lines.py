@@ -1,4 +1,5 @@
-"""The load generator: one general reader of traffic-mix files.
+"""The generator `dogstatsd_lines`: one general reader of the mix files
+whose traffic is DogStatsD text for a tier that listens on UDP.
 
 A mix (`perfbench/mixes/<name>.json`) is data: how many keys of each
 kind a tick touches, how many samples each takes, the latency
@@ -9,35 +10,44 @@ every value, the send order. The same seed gives the same datagrams,
 byte for byte; another seed gives the same sizes and counts over other
 keys and values, so the seed never changes the amount of work.
 
-Copied out of `chip_smoke.py` (Window / touched_keys / datagrams) so
-that a later change to the smoke cannot move the yardstick.
+`reference(payload)` is numpy over the generated samples and imports
+nothing of the program: what the two tiers must emit for one tick, each
+series where veneur's scoping emits it (`perfbench/reference.py` holds
+a tick's answers against it).
+
+Copied out of `chip_smoke.py` (Window / touched_keys / datagrams /
+reference) so that a later change to the smoke cannot move the
+yardstick.
 """
 
 from __future__ import annotations
 
-import json
-import os
+import time
 
 import numpy as np
 
-HERE = os.path.dirname(os.path.abspath(__file__))
+MAKES = "datagrams"
 
 
-def load_data(kind: str, name: str, rehearsal: bool = False,
-              root: str = HERE) -> dict:
-    """`<root>/<kind>/<name>.json`; in a rehearsal the file's own
-    `rehearsal` block overrides its groups key by key (tiny sizes)."""
-    with open(os.path.join(root, kind, name + ".json")) as f:
-        data = json.load(f)
-    if rehearsal:
-        for group, over in data.get("rehearsal", {}).items():
-            data[group] = ({**data.get(group, {}), **over}
-                           if isinstance(over, dict) else over)
-    return data
-
-
-def load_mix(name: str, rehearsal: bool = False, root: str = HERE) -> dict:
-    return load_data("mixes", name, rehearsal, root)
+def build(cfg: dict, mix: dict, seed: int, log) -> tuple:
+    """Every datagram the run will send and what the tiers must answer,
+    built during set-up: `distinct_ticks` payloads over the same keys
+    with values of their own, cycled through by the window. The
+    reference's seconds are kept apart: they are not set-up."""
+    touched = touched_keys(mix, cfg["population"], seed)
+    dg = mix["datagram"]
+    payloads, ref_s = [], 0.0
+    for k in range(mix["distinct_ticks"]):
+        p = Payload(mix, touched, seed, k + 1)
+        lines = p.lines()
+        grams = datagrams(lines, dg["max_lines"], dg["max_bytes"])
+        r0 = time.monotonic()
+        ref = reference(p, cfg["percentiles"])
+        ref_s += time.monotonic() - r0
+        payloads.append({"datagrams": grams, "n_lines": len(lines),
+                         "timer_lines": int(p.t_key.size), "ref": ref})
+        log(f"payload {k + 1}: {len(lines)} lines in {len(grams)} datagrams")
+    return payloads, ref_s
 
 
 def timer_name(i: int) -> str:
@@ -162,3 +172,37 @@ def datagrams(lines: list, max_lines: int, max_bytes: int) -> list:
     if cur:
         out.append(b"\n".join(cur))
     return out
+
+
+def reference(p, percentiles) -> dict:
+    ref = {"timer": {}, "hot": {}, "counter_local": {},
+           "counter_global": {}, "gauge": {}, "set": {},
+           "percentiles": tuple(percentiles)}
+    val64 = p.t_milli / 1000.0                 # == strtod("123.456")
+    val32 = val64.astype(np.float32)
+    order = np.argsort(p.t_key, kind="stable")
+    keys, starts = np.unique(p.t_key[order], return_index=True)
+    ends = np.append(starts[1:], order.size)
+    v32, v64 = val32[order], val64[order]
+    mins = np.minimum.reduceat(v32, starts)
+    maxs = np.maximum.reduceat(v32, starts)
+    hot = set(p.touched["hot"].tolist())
+    for k, a, b, lo, hi in zip(keys.tolist(), starts.tolist(),
+                               ends.tolist(), mins.tolist(), maxs.tolist()):
+        ref["timer"][timer_name(k)] = (float(b - a), lo, hi)
+        if k in hot:
+            ref["hot"][timer_name(k)] = np.quantile(v64[a:b], percentiles)
+    c_tot = np.bincount(p.c_key, weights=p.c_val.astype(np.float64))
+    for k in np.unique(p.c_key).tolist():
+        side = "counter_global" if k % 2 else "counter_local"
+        ref[side][f"smoke.counter.c{k:04d}"] = float(c_tot[k])
+    for k in np.unique(p.g_key).tolist():
+        last = p.g_milli[np.nonzero(p.g_key == k)[0][-1]]
+        ref["gauge"][f"smoke.gauge.g{k:04d}"] = float(
+            np.float32(last / 1000.0))
+    if p.s_key.size:
+        pairs = np.unique((p.s_key.astype(np.int64) << 40) | p.s_member)
+        sk, n = np.unique(pairs >> 40, return_counts=True)
+        for k, c in zip(sk.tolist(), n.tolist()):
+            ref["set"][f"smoke.set.s{k:04d}"] = float(c)
+    return ref

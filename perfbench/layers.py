@@ -29,19 +29,58 @@ that needs other arithmetic brings `perfbench/metrics/<name>.py` with
 `read(ctx) -> float | None`; the harness finds it by the metric's name.
 A reader that finds nothing to read returns None and the metric is left
 out of the line.
+
+**The tick record** is the contract between a driver and these readers:
+what `Driver.tick` returns for one tick, and `run.py` adds `index`,
+`timed`, `payload` and `compared` to. Every topology gives `TICK_KEYS`:
+
+  t_first_ns, t_last_ns, t_end_ns   the tick's edges on the monotonic
+                  clock: its first operation offered, its last, and the
+                  global's sink holding its flush. The window's clock
+                  and the trace count first -> end
+  emit_latency_s  (t_end - t_last) / 1e9;  wall_s  (t_end - t_first) / 1e9
+  attempted       operations the tick offered (the driver's `OPS`)
+  spans           {name: seconds} of the benchmark's own `bench.*` spans
+  phase_rows      [(tier:name, t0_ns, t1_ns)] flight recorder phases of
+                  each server's flush tick, behind `local:` / `global:`
+  counters        {name: delta}, `compile.programs` always among them
+  compiled        the names of the programs compiled in the tick
+  gc_n, gc_s      collections inside last -> end, and their seconds
+
+and a topology gives these where it has the thing (`OPTIONAL_TICK_KEYS`;
+a reader of one finds nothing to read elsewhere and reports nothing):
+`lines`, `ingest_s`, `gen_wait_s`, `t_landed_ns`,
+`counters["bridge.lost_lines"]`, `counters["forward.bytes"]`,
+`flush_path` (`{"local": ..., "global": ...}`, the engines' own notes on
+the flush they ran) with a local tier; `acks_s` from the fan-in's
+driver, and in a study's run (`--ticks-out`) `landing_shapes`, every
+`[S, W]` the import landing clustered in the tick. `cpu_s`, `threads`,
+`loadavg` ride along for the noise study.
 """
 
 from __future__ import annotations
 
-import importlib.util
-import os
 import statistics
 
-from perfbench.tiers import phase_seconds
-from perfbench.traffic import HERE, load_data
+from perfbench.harness import HERE, load_code, load_data, phase_seconds
 
 REDUCTIONS = {"sum": sum, "mean": statistics.fmean,
               "median": statistics.median, "max": max}
+
+TICK_KEYS = ("t_first_ns", "t_last_ns", "t_end_ns", "emit_latency_s",
+             "wall_s", "attempted", "spans", "phase_rows", "counters",
+             "compiled", "gc_n", "gc_s")
+OPTIONAL_TICK_KEYS = ("lines", "ingest_s", "gen_wait_s", "t_landed_ns",
+                      "flush_path", "landing_shapes", "acks_s", "cpu_s",
+                      "threads", "loadavg")
+
+
+def missing_keys(rec: dict) -> list:
+    """The keys of the contract a tick record lacks."""
+    out = [k for k in TICK_KEYS if k not in rec]
+    if "compile.programs" not in rec.get("counters", {}):
+        out.append("counters[compile.programs]")
+    return out
 
 
 def load_metric(name: str, root: str = HERE) -> dict:
@@ -49,14 +88,8 @@ def load_metric(name: str, root: str = HERE) -> dict:
 
 
 def _custom_reader(name: str, root: str):
-    path = os.path.join(root, "metrics", name + ".py")
-    if not os.path.exists(path):
-        return None
-    spec = importlib.util.spec_from_file_location(
-        "perfbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    mod = load_code("metrics", name, root)
+    return None if mod is None else mod.read
 
 
 def _per_tick(tick: dict, src: str, names: list):

@@ -3,11 +3,15 @@
   python3 perfbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell names a deployment (`perfbench/configs/`) and a traffic mix
-(`perfbench/mixes/`); BENCHMARK.json says which end-to-end metrics
+(`perfbench/mixes/`); the deployment's file names the driver that builds
+and drives its servers (`perfbench/drivers/`), the mix's file the
+generator that makes its payloads and their plain reference
+(`perfbench/generators/`); BENCHMARK.json says which end-to-end metrics
 (`--trace 0`) and which per-layer metrics (`--trace 1`, each read by
-`perfbench/metrics/<name>`) the cell reports. Set-up starts both tiers,
-builds every datagram and its numpy reference from the seed, and runs
-the cell's own tick untimed until a whole cycle of payloads compiles
+`perfbench/metrics/<name>`) the cell reports. `perfbench/harness.py` has
+what a driver and a generator must give. Set-up starts the servers,
+builds every payload and its reference from the seed, and runs the
+cell's own tick untimed until a whole cycle of payloads compiles
 nothing. The window then runs whole ticks until `--seconds` of ticks
 have passed: a tick that starts inside the window is finished and timed.
 Every tick, timed or not, is checked against the reference between
@@ -23,6 +27,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import shutil
 import sys
@@ -75,50 +80,6 @@ def reexec_with_process_env(want: dict):
               + sys.argv[1:], env)
 
 
-def build_payloads(cfg, mix, seed, log):
-    """Every datagram the run will send and what the tiers must answer,
-    built during set-up: `distinct_ticks` payloads over the same keys
-    with values of their own, cycled through by the window. The
-    reference's seconds are kept apart: they are not set-up."""
-    from perfbench import reference, traffic
-    touched = traffic.touched_keys(mix, cfg["population"], seed)
-    dg = mix["datagram"]
-    payloads, ref_s = [], 0.0
-    for k in range(mix["distinct_ticks"]):
-        p = traffic.Payload(mix, touched, seed, k + 1)
-        lines = p.lines()
-        grams = traffic.datagrams(lines, dg["max_lines"], dg["max_bytes"])
-        r0 = time.monotonic()
-        ref = reference.reference(p, cfg["percentiles"])
-        ref_s += time.monotonic() - r0
-        payloads.append({"datagrams": grams, "n_lines": len(lines),
-                         "timer_lines": int(p.t_key.size), "ref": ref})
-        log(f"payload {k + 1}: {len(lines)} lines in {len(grams)} datagrams")
-    return payloads, ref_s
-
-
-def degrade(answers: dict, how: dict) -> dict:
-    """A control's lower precision applied to a tier's answers: the
-    named series rounded through a narrower float type."""
-    import ml_dtypes
-    import numpy as np
-    dt = np.dtype(getattr(ml_dtypes, how["round_through"]))
-    return {k: (float(np.float32(v).astype(dt).astype(np.float32))
-                if k.endswith(tuple(how["suffixes"])) else v)
-            for k, v in answers.items()}
-
-
-def check(tiers, payload, tol, control=None):
-    from perfbench import reference
-    local = reference.sink_values(tiers.lsink.flushes[-1])
-    glob = reference.sink_values(tiers.gsink.flushes[-1])
-    tiers.lsink.flushes, tiers.gsink.flushes = [[]], [[]]
-    if control and "answers" in control:
-        local = degrade(local, control["answers"])
-        glob = degrade(glob, control["answers"])
-    return reference.check_tick(payload["ref"], local, glob, tol)
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
@@ -146,18 +107,23 @@ def main(argv=None) -> int:
                          f"BENCHMARK.json ({sorted(cells)})")
     cell = cells[args.workload]
 
-    from perfbench import reference, traffic
-    from perfbench import tiers as tiers_mod
-    cfg = tiers_mod.load_config(cell["config"], args.rehearsal)
-    mix = traffic.load_mix(cell["traffic"], args.rehearsal)
-    control = None
+    from perfbench import harness, layers, reference
+    log = harness.log
+    cfg = harness.load_config(cell["config"], args.rehearsal)
+    mix = harness.load_mix(cell["traffic"], args.rehearsal)
+    cfg["control"], cfg["study"] = None, bool(args.ticks_out)
     if args.control:
-        control = cfg["controls"][args.control]
+        cfg["control"] = control = cfg["controls"][args.control]
         cfg["common"] = {**cfg["common"], **control.get("common", {})}
     process = cfg.get("assumed", {}).get("process", {})
     reexec_with_process_env(process.get("env", {}))
     t_start = float(os.environ.get("PERFBENCH_T0", _T0))
-    log = tiers_mod.log
+    driver, generator = harness.load_driver(cfg), harness.load_generator(mix)
+    if driver.Driver.TAKES != generator.MAKES:
+        raise SystemExit(
+            f"perfbench: the driver {cfg['driver']!r} sends "
+            f"{driver.Driver.TAKES!r} and the generator "
+            f"{mix['generator']!r} makes {generator.MAKES!r}")
 
     # first contact with JAX — and the only process that has any
     from veneur_tpu.utils import platform
@@ -190,17 +156,14 @@ def main(argv=None) -> int:
         f"compile cache {cache_dir}; PYTHONHASHSEED "
         f"{os.environ.get('PYTHONHASHSEED')}")
 
-    meter = tiers_mod.CompileMeter()
-    gcm = tiers_mod.GcMeter()
-    spans = tiers_mod.Spans()
-    from veneur_tpu.ingest import native
-    native.build()
+    meter = harness.CompileMeter()
+    gcm = harness.GcMeter()
+    spans = harness.Spans()
 
-    payloads, ref_s = build_payloads(cfg, mix, args.seed, log)
+    payloads, ref_s = generator.build(cfg, mix, args.seed, log)
     tol = cfg["guarantees"]["tolerances"]
-    t = tiers_mod.Tiers(cfg, args.rehearsal)
+    t = driver.Driver(cfg, args.rehearsal)
     verdicts, records = [], []
-    attempted = 0
     try:
         want_devices = int(cfg["global"].get("tpu_num_devices", 1))
         got_devices = t.mesh_devices()
@@ -208,13 +171,13 @@ def main(argv=None) -> int:
             f"distinct device(s): found {got_devices}")
 
         def one_tick(i, timed):
-            nonlocal attempted
             p = payloads[i % len(payloads)]
             rec = t.tick(p, 1_000 + 10 * i, spans, gcm, meter)
-            attempted += p["n_lines"]
-            v = check(t, p, tol, control)
-            v["failed_lines"] = (p["timer_lines"] - v["accounted_lines"]
-                                 + max(0, rec["counters"]["bridge.lost_lines"]))
+            if not records and layers.missing_keys(rec):
+                raise RuntimeError(
+                    f"the driver {cfg['driver']!r} gave a tick record "
+                    f"without {layers.missing_keys(rec)}")
+            v = t.check(p, rec, tol)
             rec.update(index=i, timed=timed, payload=i % len(payloads) + 1,
                        compared={k: val for k, (val, _lim)
                                  in v["numbers"].items()})
@@ -222,8 +185,10 @@ def main(argv=None) -> int:
             records.append(rec)
             nums = "  ".join(f"{k} {val:.6g} (limit {lim:g})"
                              for k, (val, lim) in v["numbers"].items())
+            took = "  ".join(f"{k.removeprefix('bench.')} {s:.3f}s"
+                             for k, s in rec["spans"].items())
             log(f"tick {i} {'timed' if timed else 'warm-up'}: "
-                f"{rec['lines']} lines  ingest {rec['ingest_s']:.3f}s  "
+                f"{rec['attempted']} {t.OPS}  {took}  "
                 f"emit {rec['emit_latency_s']:.3f}s  compiled "
                 f"{rec['counters']['compile.programs']}  | {nums}")
             for m in v["mismatches"]:
@@ -234,9 +199,9 @@ def main(argv=None) -> int:
 
         # warm-up: the cell's own ticks, untimed, until a whole cycle of
         # payloads after the first tick (the first forward is a full
-        # resync, later ones deltas) has compiled nothing; then the
-        # import landing's other lane widths (tiers.watch_landing)
-        t.watch_landing()
+        # resync, later ones deltas) has compiled nothing; then what the
+        # driver warms besides (the import landing's other lane widths)
+        t.watch_warmup()
         n, quiet = 0, 0
         while quiet < len(payloads):
             if n >= MAX_WARMUP_TICKS:
@@ -247,7 +212,7 @@ def main(argv=None) -> int:
             quiet = quiet + 1 if (n > 0 and not rec["counters"][
                 "compile.programs"]) else 0
             n += 1
-        warmed = t.warm_landing_widths()
+        warmed = t.finish_warmup()
         log(f"import landing: warmed {warmed} besides what the warm-up "
             f"ticks met")
         setup_s = time.monotonic() - t_start - ref_s
@@ -296,17 +261,19 @@ def main(argv=None) -> int:
             worst = numbers.get(name, (0.0, lim))[0]
             numbers[name] = (reference.worse(val, worst), lim)
     in_window = sum(r["counters"]["compile.programs"] for r in timed)
-    lost = sum(r["counters"]["bridge.lost_lines"] for r in records)
     numbers["compile.in_window"] = (float(in_window), 0.0)
-    numbers["bridge.lost_lines"] = (float(abs(lost)), 0.0)
     numbers["drop_and_error_counters"] = (float(sum(counters.values())), 0.0)
     numbers["mesh_devices_missing"] = (
         float(abs(want_devices - got_devices)), 0.0)
-    for name, (val, lim) in numbers.items():
-        log(f"compared: {name} = {val:.6g}  limit {lim:g}  "
-            f"{'ok' if reference.within({name: (val, lim)}) else 'FAIL'}")
+    compared = [
+        f"compared: {name} = {val:.6g}  limit {lim:g}  "
+        f"{'ok' if reference.within({name: (val, lim)}) else 'FAIL'}"
+        for name, (val, lim) in numbers.items()]
+    for line in compared:
+        log(line)
     correct = reference.within(numbers)
-    failed = int(sum(v["failed_lines"] for v in verdicts))
+    attempted = int(sum(v["attempted"] for v in verdicts))
+    failed = int(sum(v["failed"] for v in verdicts))
 
     if args.ticks_out:
         os.makedirs(os.path.dirname(os.path.abspath(args.ticks_out)),
@@ -314,7 +281,7 @@ def main(argv=None) -> int:
         with open(args.ticks_out, "a") as f:
             for r in records:
                 row = {k2: v for k2, v in r.items() if k2 != "phase_rows"}
-                row["phases"] = tiers_mod.phase_seconds(r["phase_rows"])
+                row["phases"] = harness.phase_seconds(r["phase_rows"])
                 row.update(cell=cell["name"], seed=args.seed,
                            pid=os.getpid(), setup_s=setup_s)
                 f.write(json.dumps(row) + "\n")
@@ -326,14 +293,13 @@ def main(argv=None) -> int:
            "peaks": peaks, "run": {"setup_s": setup_s},
            "device": {} if args.rehearsal else {
                "peak_hbm_bytes": float(peak)}}
-    result = {"correct": bool(correct), "attempted": int(attempted),
+    result = {"correct": bool(correct), "attempted": attempted,
               "failed": failed, "metrics": {}, "device": device}
     if args.rehearsal:
         result["rehearsal"] = True
     if args.control:
         result["control"] = args.control
     group = "per_layer" if args.trace else "end_to_end"
-    from perfbench import layers
     if args.trace and not args.rehearsal:
         from perfbench import tracered
         tr = tracered.reduce_trace(
@@ -356,6 +322,12 @@ def main(argv=None) -> int:
         v = layers.read_metric(m["name"], ctx)
         if v is not None:
             result["metrics"][m["name"]] = {"value": v, "unit": m["unit"]}
+    # every number compared beside its limit: the last lines of standard
+    # error, and the last key of the result
+    result["compared"] = {
+        name: {"value": val if math.isfinite(val) else repr(val),
+               "limit": lim} for name, (val, lim) in numbers.items()}
+    print("\n".join(compared), file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -51,11 +51,12 @@ def unstamped(phases):
 def main(argv):
     if os.environ.get("PYTHONHASHSEED") != "0":
         raise SystemExit("phase_budget: run with PYTHONHASHSEED=0")
-    from perfbench import run, tiers
+    from perfbench import harness, run
     from veneur_tpu.observe import StampLog
     from veneur_tpu.server import Server
     seen, taken = [], []
-    inner = tiers.Tiers.tick
+    two_tier = harness.load_code("drivers", "two_tier")
+    inner = two_tier.Driver.tick
     take = StampLog.take
 
     def counting_take(self):
@@ -82,7 +83,8 @@ def main(argv):
         seen.append(row)
         return rec
 
-    tiers.Tiers.tick = tick
+    two_tier.Driver.tick = tick
+    harness.load_driver = lambda cfg, root=harness.HERE: two_tier
     rc = run.main(argv)
     for tier in ("local", "global"):
         rows = [r[tier] for r in seen]

@@ -16,7 +16,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-from perfbench import layers, run, tiers, traffic  # noqa: E402
+from perfbench import harness, layers, run  # noqa: E402
+from perfbench.generators import dogstatsd_lines as traffic  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -119,7 +120,7 @@ def test_every_cell_reports_what_the_contract_asks(manifest):
 def test_every_entry_has_its_files(manifest):
     cells = {w["name"] for w in manifest["workloads"]}
     for c in manifest["configs"]:
-        cfg = tiers.load_config(c["name"])
+        cfg = harness.load_config(c["name"])
         assert cfg["name"] == c["name"] and cfg["source"] == c["source"]
         assert sorted(cfg["reduced"]) == sorted(c["reduced"])
         assert all(k in cfg for k in c["reduced"])
@@ -127,9 +128,9 @@ def test_every_entry_has_its_files(manifest):
         assert os.path.join(REPO, c["file"]) == os.path.join(
             REPO, "perfbench", "configs", c["name"] + ".json")
     for w in manifest["workloads"]:
-        mix = traffic.load_mix(w["traffic"])
+        mix = harness.load_mix(w["traffic"])
         assert mix["name"] == w["traffic"]
-        assert tiers.load_config(w["config"])["chips"] == w["chips"]
+        assert harness.load_config(w["config"])["chips"] == w["chips"]
     for m in manifest["end_to_end"] + manifest["per_layer"]:
         assert set(m.get("workloads", [])) <= cells
         base = os.path.join(REPO, "perfbench", "metrics", m["name"])
@@ -183,22 +184,49 @@ def test_fixed_reductions():
         assert put({"from": "trace", "what": "idle_share"}) is None
 
 
+DRIVER = '''
+class Driver:
+    TAKES = "pings"
+    OPS = "pings"
+
+    def __init__(self, cfg, rehearsal):
+        self.sets = cfg["common"]["tpu_set_slots"]
+
+    def tick(self, payload, ts, spans, gcm, meter):
+        return {"attempted": len(payload["pings"]), "sets": self.sets}
+'''
+GENERATOR = '''
+MAKES = "pings"
+
+
+def build(cfg, mix, seed, log):
+    n = mix["timers"]["keys"]
+    return [{"pings": [seed] * n, "ref": n}], 0.0
+'''
+
+
 def test_a_config_a_mix_and_a_metric_arrive_as_new_files(tmp_path):
-    """Copy the benchmark's data directories, add one file of each kind
-    and one manifest entry each; nothing that was there is edited, and
-    the harness's loaders find the new ones by name."""
+    """Copy the benchmark's directories of data and of code found by
+    name, add one file of each kind (a configuration, a mix, a metric's
+    reader, and a driver and a generator for them) and one manifest
+    entry each; nothing that was there is edited, and the harness's
+    loaders find the new ones by name."""
     root = tmp_path / "perfbench"
-    for d in ("configs", "mixes", "metrics"):
-        shutil.copytree(os.path.join(REPO, "perfbench", d), root / d)
+    for d in ("configs", "mixes", "metrics", "drivers", "generators"):
+        shutil.copytree(os.path.join(REPO, "perfbench", d), root / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
 
-    cfg = tiers.load_config("two_tier_1chip")
-    cfg.update(name="two_tier_small_sets", source="a test's own")
+    cfg = harness.load_config("two_tier_1chip")
+    cfg.update(name="two_tier_small_sets", source="a test's own",
+               driver="pinger")
     cfg["common"] = {**cfg["common"], "tpu_set_slots": 2048}
     (root / "configs" / "two_tier_small_sets.json").write_text(
         json.dumps(cfg))
-    mix = traffic.load_mix("steady_10k")
-    mix.update(name="steady_1k")
+    mix = harness.load_mix("steady_10k")
+    mix.update(name="steady_1k", generator="pings")
+    (root / "drivers" / "pinger.py").write_text(DRIVER)
+    (root / "generators" / "pings.py").write_text(GENERATOR)
     mix["timers"] = {**mix["timers"], "keys": 1000, "hot_keys": 10}
     (root / "mixes" / "steady_1k.json").write_text(json.dumps(mix))
     (root / "metrics" / "sink.flush_ms.json").write_text(json.dumps({
@@ -226,11 +254,26 @@ def test_a_config_a_mix_and_a_metric_arrive_as_new_files(tmp_path):
             "source": "program_span", "layer": "global flush",
             "moves": "emit_latency_s"})
 
-    got = tiers.load_config("two_tier_small_sets", root=str(root))
+    got = harness.load_config("two_tier_small_sets", root=str(root))
     assert got["common"]["tpu_set_slots"] == 2048
-    got = traffic.load_mix("steady_1k", root=str(root))
+    driver = harness.load_driver(got, root=str(root))
+    got = harness.load_mix("steady_1k", root=str(root))
     touched = traffic.touched_keys(got, cfg["population"], 7)
     assert touched["timers"].size == 1000 and touched["hot"].size == 10
+    # the new driver and generator are found by the names the new files
+    # give them, and fit each other; the old ones are still found
+    generator = harness.load_generator(got, root=str(root))
+    assert driver.Driver.TAKES == generator.MAKES == "pings"
+    payloads, ref_s = generator.build(cfg, got, 7, print)
+    rec = driver.Driver(cfg, True).tick(payloads[0], 0, None, None, None)
+    assert rec == {"attempted": 1000, "sets": 2048} and ref_s == 0.0
+    assert harness.load_driver({"name": "x", "driver": "two_tier"},
+                               root=str(root)).Driver.OPS == "lines"
+    with pytest.raises(SystemExit, match="no perfbench/drivers/nope.py"):
+        harness.load_driver({"name": "x", "driver": "nope"}, root=str(root))
+    with pytest.raises(SystemExit, match="no perfbench/generators/nope.py"):
+        harness.load_generator({"name": "x", "generator": "nope"},
+                               root=str(root))
     names = [m["name"] for m in run.cell_metrics(
         manifest, "two_tier_small_sets.steady_1k", "per_layer")]
     assert "sink.flush_ms" in names and "tick.emit_range_s" in names
@@ -245,9 +288,77 @@ def test_a_config_a_mix_and_a_metric_arrive_as_new_files(tmp_path):
     assert all(p.read_bytes() == b for p, b in before.items())
 
 
+DRIVER_METHODS = ("mesh_devices", "watch_warmup", "finish_warmup", "tick",
+                  "check", "drop_counters", "stop")
+
+
+def all_configs_and_mixes(manifest):
+    """Every configuration and mix under perfbench/, the manifest's and
+    those that wait for a later PR's entries."""
+    def names(d):
+        return sorted(f[:-5] for f in os.listdir(os.path.join(
+            REPO, "perfbench", d)) if f.endswith(".json"))
+
+    assert {c["name"] for c in manifest["configs"]} <= set(names("configs"))
+    assert {w["traffic"] for w in manifest["workloads"]} <= set(
+        names("mixes"))
+    return names("configs"), names("mixes")
+
+
+def test_every_configuration_names_a_driver_and_every_mix_a_generator(
+        manifest):
+    configs, mixes = all_configs_and_mixes(manifest)
+    makes = {}
+    for name in mixes:
+        mix = harness.load_mix(name)
+        gen = harness.load_generator(mix)     # no default, no fallback
+        assert isinstance(gen.MAKES, str) and callable(gen.build)
+        makes[name] = gen.MAKES
+    takes = {}
+    for name in configs:
+        cfg = harness.load_config(name)
+        drv = harness.load_driver(cfg).Driver
+        assert isinstance(drv.TAKES, str) and isinstance(drv.OPS, str)
+        assert all(callable(getattr(drv, m)) for m in DRIVER_METHODS)
+        takes[name] = drv.TAKES
+    with open(os.path.join(REPO, "perfbench", "study",
+                           "fanin32.entries.json")) as f:
+        waiting = json.load(f)["workloads"]
+    for w in manifest["workloads"] + waiting:
+        assert takes[w["config"]] == makes[w["traffic"]], w["name"]
+    # every driver and generator under perfbench/ has a user
+    used = {harness.load_config(n)["driver"] for n in configs}
+    have = {f[:-3] for f in os.listdir(os.path.join(
+        REPO, "perfbench", "drivers")) if f.endswith(".py")}
+    assert used == have
+    used = {harness.load_mix(n)["generator"] for n in mixes}
+    have = {f[:-3] for f in os.listdir(os.path.join(
+        REPO, "perfbench", "generators")) if f.endswith(".py")}
+    assert used == have
+
+
+def test_the_four_cells_report_exactly_what_they_reported_at_pr27(manifest):
+    """Eight per-layer metrics of the local tier and the forward list
+    their cells since PR 28 (a cell without a local tier is not asked
+    for them); what each of the four cells reports did not move."""
+    with open(os.path.join(os.path.dirname(__file__),
+                           "golden_rehearsal_pr27.json")) as f:
+        was = json.load(f)["reports"]
+    assert set(was) == {w["name"] for w in manifest["workloads"]}
+    for cell, groups in was.items():
+        for group, names in groups.items():
+            assert [m["name"] for m in run.cell_metrics(
+                manifest, cell, group)] == names, (cell, group)
+    listed = {m["name"]: m["workloads"] for m in manifest["per_layer"]
+              if m["name"].startswith(("local.", "forward."))}
+    assert len(listed) == 8
+    assert all(cells == [w["name"] for w in manifest["workloads"]]
+               for cells in listed.values())
+
+
 def test_the_seed_changes_keys_and_values_never_sizes():
-    cfg = tiers.load_config("two_tier_1chip", rehearsal=True)
-    mix = traffic.load_mix("steady_10k", rehearsal=True)
+    cfg = harness.load_config("two_tier_1chip", rehearsal=True)
+    mix = harness.load_mix("steady_10k", rehearsal=True)
     shapes = set()
     texts = []
     for seed in (1, 2, 2**31 + 12345):

@@ -17,7 +17,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-from perfbench import reference, run, tiers, traffic  # noqa: E402
+from perfbench import harness, reference  # noqa: E402
+from perfbench.generators import dogstatsd_lines as traffic  # noqa: E402
 
 TOL = {"p50": 0.01, "p99": 0.02, "set": 0.03, "pct_outside": 1e-6}
 PCT = (0.5, 0.75, 0.99)
@@ -27,11 +28,11 @@ PCT = (0.5, 0.75, 0.99)
 def served():
     """A payload, its reference, and the answers a faultless pair of
     tiers would give (built from the reference itself)."""
-    cfg = tiers.load_config("two_tier_1chip", rehearsal=True)
-    mix = traffic.load_mix("wide_100k", rehearsal=True)
+    cfg = harness.load_config("two_tier_1chip", rehearsal=True)
+    mix = harness.load_mix("wide_100k", rehearsal=True)
     p = traffic.Payload(mix, traffic.touched_keys(
         mix, cfg["population"], 11), 11, 1)
-    ref = reference.reference(p, PCT)
+    ref = traffic.reference(p, PCT)
     local, glob = {}, {}
     for name, (count, lo, hi) in ref["timer"].items():
         for tier in (local, glob):
@@ -81,7 +82,8 @@ def test_reference_is_plain_numpy_over_the_samples(served):
 def test_bfloat16_extremes_fail_the_exact_fields(served):
     _p, ref, local, glob = served
     how = {"suffixes": [".min", ".max"], "round_through": "bfloat16"}
-    n = numbers(ref, run.degrade(local, how), run.degrade(glob, how))
+    n = numbers(ref, reference.degrade(local, how),
+                reference.degrade(glob, how))
     # 8 bits of mantissa: nearly every extreme of nearly every key moves
     assert n["exact_mismatches"] > len(ref["timer"])
     assert n["worst_p99_rel"] <= TOL["p99"]
@@ -158,9 +160,9 @@ BROKEN_RUN = r"""
 import dataclasses, sys
 import numpy as np
 sys.path.insert(0, sys.argv.pop(1))
-from perfbench import run, tiers
+from perfbench import harness, run
 
-make, made = tiers.make_sink, []
+make, made = harness.make_sink, []
 
 
 def broken_sink():
@@ -183,7 +185,7 @@ def broken_sink():
     return sink
 
 
-tiers.make_sink = broken_sink
+harness.make_sink = broken_sink
 sys.exit(run.main(sys.argv[1:]))
 """
 
@@ -241,25 +243,21 @@ def test_set_up_warms_the_landing_widths_the_warm_up_ticks_did_not_meet(
     class Engine:
         _heng = Adapter()
 
-    t = object.__new__(tiers.Tiers)
-    t.geng = Engine()
-    t.watch_landing()
+    t = harness.LandingWatch(Engine())
     for shape in met:
         z = np.zeros(shape, np.float32)
         Engine._heng.cluster_rows(z, z, num_centroids=256)
     assert calls == [(s, 256, 0) for s in met]
-    assert sorted(t.warm_landing_widths()) == sorted(warmed)
+    assert sorted(t.warm_other_widths()) == sorted(warmed)
     assert sorted(calls[len(met):]) == sorted((s, 256, 0) for s in warmed)
     # the adapter is the program's own again, and nothing is recorded
     assert "cluster_rows" not in vars(Engine._heng)
-    assert t.warm_landing_widths() == []
+    assert t.warm_other_widths() == []
 
 
 def test_an_engine_without_a_lane_width_ladder_has_nothing_to_warm():
     class MeshEngine:
         pass
 
-    t = object.__new__(tiers.Tiers)
-    t.geng = MeshEngine()
-    t.watch_landing()
-    assert t.warm_landing_widths() == []
+    t = harness.LandingWatch(MeshEngine())
+    assert t.warm_other_widths() == []
