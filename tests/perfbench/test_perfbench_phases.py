@@ -1,9 +1,12 @@
 """The per-layer metrics that read the flight recorder's phases of
-`forward.send`, the import, the mesh flush and the pump (PR 26): each
-reader file loads, agrees with its BENCHMARK.json entry, names only
-phases a rehearsal of its cells really produced, and returns nothing
-(never raises) on ticks of a program that lacks the phases. A rehearsal
-is a CPU run: it proves names and counts, never a time."""
+`forward.send`, the import, the mesh flush and the pump (PR 26; the
+landing's two child phases since PR 29): each reader file loads, agrees
+with its BENCHMARK.json entry, is reported by the cells its entry says
+(its `workloads` list, or every cell that reports the end-to-end metric
+it moves: however many cells the manifest has), names only phases a
+rehearsal of its cells really produced, and returns nothing (never
+raises) on ticks of a program that lacks the phases. A rehearsal is a
+CPU run: it proves names and counts, never a time."""
 
 import json
 import os
@@ -17,6 +20,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
+import contract_checks as checks  # noqa: E402
 from perfbench import layers, run  # noqa: E402
 
 RUN = os.path.join(REPO, "perfbench", "run.py")
@@ -25,7 +29,10 @@ ONE_CHIP, MESH = "two_tier_1chip.steady_10k", "mesh_global_4chip.steady_10k"
 NEW = ["forward.export_ms", "forward.serialize_ms", "forward.rpc_ms",
        "import.decode_ms", "import.route_ms", "import.apply_ms",
        "import.land_ms", "mesh.flush_device_ms", "ingest.pump_dispatch_ms",
-       "ingest.pump_batches"]
+       "ingest.pump_batches", "import.land_stage_ms",
+       "import.land_cluster_ms"]
+# the mesh engine stamps `import.land` and neither of its children
+LANDING_CHILDREN = ("import.land_stage_ms", "import.land_cluster_ms")
 ENTRY = {m["name"]: m for m in MANIFEST["per_layer"]}
 
 
@@ -52,23 +59,16 @@ def rehearsed(tmp_path_factory):
     return out
 
 
-def cells_of(name):
-    return [w["name"] for w in MANIFEST["workloads"]
-            if any(m["name"] == name for m in run.cell_metrics(
-                MANIFEST, w["name"], "per_layer"))]
-
-
 @pytest.mark.parametrize("name", NEW)
 def test_reader_agrees_with_its_entry_and_reads_real_phases(name, rehearsed):
     entry = ENTRY[name]
-    cells = cells_of(name)
-    assert cells, name
+    cells = checks.check_reported_where_it_says(MANIFEST, name)
     if name == "mesh.flush_device_ms":
-        assert cells == [MESH]
-    elif entry["moves"] == "ingest_rate":
+        assert MESH in cells and ONE_CHIP not in cells
+    elif entry["moves"] == "ingest_rate" or name in LANDING_CHILDREN:
         assert MESH not in cells and ONE_CHIP in cells
     else:
-        assert len(cells) == len(MANIFEST["workloads"])
+        assert MESH in cells and ONE_CHIP in cells
     base = os.path.join(REPO, "perfbench", "metrics", name)
     if os.path.exists(base + ".py"):
         assert entry["source"] == "program_counter"

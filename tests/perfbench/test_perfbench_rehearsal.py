@@ -1,10 +1,14 @@
 """Each cell of BENCHMARK.json, rehearsed on the CPU the way the driver
 runs it (one subprocess a run): the cell's file pair loads and drives
-whole ticks end to end at tiny size — real sockets, the C++ bridge, the
-gRPC forward, both flushes, every tick checked against the numpy
-reference; the mesh cell on four virtual devices. A rehearsal is marked
-as one and prints no time, rate or device metric. Off the chip the
-measuring path fails and prints no result."""
+whole ticks end to end at tiny size, every tick checked against the
+plain reference: with a local tier real sockets, the C++ bridge, the
+gRPC forward and both flushes; the mesh cell on four virtual devices.
+The test is parametrised over the manifest's cells, so a cell is
+rehearsed in tier-1 the day it is added, and asks of a cell only what
+that cell has (`contract_checks.rehearsal_expectations`): forwarded
+bytes of a cell with a forward, a landing ladder of a one-device global.
+A rehearsal is marked as one and prints no time, rate or device metric.
+Off the chip the measuring path fails and prints no result."""
 
 import json
 import os
@@ -13,14 +17,14 @@ import sys
 
 import pytest
 
+import contract_checks as checks
+
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 RUN = os.path.join(REPO, "perfbench", "run.py")
 with open(os.path.join(REPO, "BENCHMARK.json")) as f:
     MANIFEST = json.load(f)
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
-COUNTS = {m["name"] for m in MANIFEST["per_layer"]
-          if m["source"] == "program_counter"}
 
 
 @pytest.fixture(scope="module")
@@ -57,8 +61,7 @@ def failing(p):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_rehearses_end_to_end(cell, cache_dir):
-    chips = next(w["chips"] for w in MANIFEST["workloads"]
-                 if w["name"] == cell)
+    want = checks.rehearsal_expectations(MANIFEST, cell)
     p = run_cell(["--workload", cell, "--seed", str(2**31 + 77),
                   "--seconds", "1", "--trace", "1", "--rehearsal"],
                  cache_dir)
@@ -67,23 +70,27 @@ def test_cell_rehearses_end_to_end(cell, cache_dir):
     assert "compared: exact_mismatches = 0 " in p.stdout
     assert out["failed"] == 0 and out["attempted"] > 0
     assert out["device"]["platform"] == "cpu"
-    assert out["device"]["count"] == chips
+    assert out["device"]["count"] == want["chips"]
     assert "busy_s" not in out["device"] and "breakdown" not in out
     # counts only: never a time, a rate or a device metric from a CPU
-    assert out["metrics"] and set(out["metrics"]) <= COUNTS
+    assert out["metrics"] and set(out["metrics"]) <= want["counts"]
     assert "compile.in_window" in out["metrics"]
-    assert out["metrics"]["forward.tick_bytes"]["value"] > 0
-    assert "PYTHONHASHSEED 0" in p.stdout      # re-executed itself
+    if "forward.tick_bytes" in want["counts"]:  # a cell with a forward
+        assert out["metrics"]["forward.tick_bytes"]["value"] > 0
+    else:
+        assert "forward.tick_bytes" not in out["metrics"]
+    for name, value in want["env"].items():    # re-executed itself
+        assert f"{name} {value}" in p.stdout
     assert "timed ticks" in p.stdout
-    # the one-chip global lands imports through a lane-width ladder,
+    # a one-device global lands imports through a lane-width ladder,
     # whose widths the warm-up ticks did not meet set-up warms (none is
-    # left where they met both); the mesh global has no ladder
+    # left where they met them all); the mesh global has no ladder
     warmed = next(ln for ln in p.stdout.splitlines()
                   if ln.startswith("import landing: warmed"))
-    assert chips == 1 or "warmed []" in warmed
-    if chips == 4:
-        assert ("every bank leaf of the global on 4 distinct device(s): "
-                "found 4") in p.stdout
+    assert want["global_devices"] == 1 or "warmed []" in warmed
+    n = want["global_devices"]
+    assert (f"every bank leaf of the global on {n} distinct device(s): "
+            f"found {n}") in p.stdout
 
 
 def test_untraced_rehearsal_prints_no_end_to_end_number(cache_dir):
