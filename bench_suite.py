@@ -1653,7 +1653,7 @@ tpu_buffer_depth: 256
                 pairs = mk_pbs()
                 if srv._engine_journal is not None:
                     # the durable admission path: WAL + grouped apply
-                    srv._submit_import_batch(pairs,
+                    srv._submit_import_batch([pb for _d, pb in pairs],
                                              ("bench", seq, 0, 1))
                 else:
                     for digest, pb in pairs:

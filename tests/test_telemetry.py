@@ -471,3 +471,59 @@ def test_ingest_overflow_counters_drain_as_self_metrics():
         assert state["counters"]["_server|ingest.overflow_bank"] == 2
     finally:
         srv.stop()
+
+
+@pytest.mark.parametrize("workers", [1, 2])
+def test_import_batch_counters_drain_as_self_metrics(workers):
+    """veneur.import.batches_total / batch_metrics_total: the batches
+    the engines applied in the interval and the forwarded metrics in
+    them (one batch a request and engine), drained like
+    samples.processed (present at zero, reset a flush); the same two
+    numbers in each engine's _last_flush_info. A metric routed alone
+    (ImportedMetric) is no batch."""
+    from veneur_tpu.cluster.importsrv import ImportedMetric
+    from veneur_tpu.cluster.protos import metric_pb2
+
+    def counters(n, start=0):
+        out = []
+        for i in range(start, start + n):
+            m = metric_pb2.Metric(name=f"imp.c{i}",
+                                  type=metric_pb2.Counter)
+            m.counter.value = i + 1
+            out.append(m)
+        return out
+
+    cap = CaptureMetricSink()
+    cfg = Config(interval="3600s", hostname="h", num_workers=workers,
+                 tpu_histogram_slots=64, tpu_counter_slots=128,
+                 tpu_gauge_slots=128, tpu_set_slots=64)
+    srv = Server(cfg, sinks=[cap], plugins=[], span_sinks=[])
+    srv.start()
+    try:
+        assert srv._submit_import_batch(counters(40)) == 40
+        assert srv._submit_import_batch(counters(25, start=40)) == 25
+        srv._route_metric(ImportedMetric(counters(1, start=99)[0]))
+        assert srv.drain(10.0)
+        srv.flush_once(timestamp=1)
+        cap.wait_for_flush(1)
+        infos = [eng._last_flush_info for eng in srv.engines]
+        # two requests, each one batch an engine that had a share
+        assert [i["import_batches"] for i in infos] == [2] * workers
+        assert sum(i["import_metrics"] for i in infos) == 65
+        srv.flush_once(timestamp=2)
+        cap.wait_for_flush(2)
+        first, second = ({m.name: m.value for m in f
+                          if m.name.startswith("veneur.import.batch")}
+                         for f in cap.flushes[:2])
+        assert first == {"veneur.import.batches_total": 2 * workers,
+                         "veneur.import.batch_metrics_total": 65}
+        assert second == {"veneur.import.batches_total": 0,
+                          "veneur.import.batch_metrics_total": 0}
+        assert all(eng._last_flush_info["import_batches"] == 0
+                   and eng._last_flush_info["import_metrics"] == 0
+                   for eng in srv.engines)
+        totals = {m.name: m.value for m in cap.flushes[0]}
+        assert sum(v for k, v in totals.items()
+                   if k.startswith("imp.c")) == sum(range(1, 66)) + 100
+    finally:
+        srv.stop()

@@ -30,7 +30,6 @@ from concurrent import futures
 import grpc
 
 from ..resilience import DEFAULT_REGISTRY, ResilienceRegistry
-from ..utils.hashing import metric_digest
 from . import wire
 from .protos import forward_pb2
 
@@ -48,7 +47,9 @@ def _decode_metric_list(data: bytes):
 
 
 class ImportedMetric:
-    """Worker-queue envelope for a forwarded metricpb.Metric."""
+    """Worker-queue envelope for ONE forwarded metricpb.Metric: what a
+    handler built without `submit_batch` routes (tests, embedders).
+    The Server's handlers route ImportedBatch."""
 
     __slots__ = ("pb",)
 
@@ -57,14 +58,14 @@ class ImportedMetric:
 
 
 class ImportedBatch:
-    """Worker-queue envelope for one journaled import op's share of
-    metrics for ONE engine (durability/ ISSUE 9): the worker applies
-    the group atomically (engine.import_list) and the op id advances
-    that engine's applied-op watermark — the consistent cut the
-    engine checkpoint's replay filter depends on. Only the durable
-    submit path (Server._submit_import_batch) produces these; the
-    per-metric ImportedMetric path is unchanged when the engine
-    journal is off."""
+    """Worker-queue envelope for one import request's share of metrics
+    for ONE engine: the unit that travels from the request handler to
+    the engine (Server._submit_import_batch makes them, journal armed
+    or not). The worker applies the group as a unit
+    (engine.import_list: one decode pass, one lock hold) and the op id
+    advances that engine's applied-op watermark in the same critical
+    section — the consistent cut the engine checkpoint's replay filter
+    depends on (durability/ ISSUE 9)."""
 
     __slots__ = ("op_id", "pbs")
 
@@ -308,10 +309,11 @@ class ForwardHandler(grpc.GenericRpcHandler):
         spans parented on the remote sender's flush span, and feeds
         the per-sender fleet view — observability only, it never
         changes what is admitted or applied. `submit_batch` (optional,
-        `submit_batch([(digest, pb), ...])`) routes one request's
-        metrics as a unit — the durable path: the Server's
-        implementation write-aheads the batch to the engine journal
-        BEFORE any worker queue sees it, so an admitted-and-acked
+        `submit_batch(metrics, envelope) -> routed count`) routes one
+        request's metrics as a unit and replaces `submit` when given:
+        the Server's implementation puts ONE ImportedBatch an engine
+        on the worker queues, after write-aheading the request to the
+        engine journal where that is armed, so an admitted-and-acked
         interval survives a receiver crash.
 
         `engine_stamp` (the server's sketch-engine/wire stamp, ISSUE
@@ -354,8 +356,7 @@ class ForwardHandler(grpc.GenericRpcHandler):
         # Combine guard in server._worker_loop covers decode errors
         # that only surface at apply time)
         try:
-            key = wire.metric_key_of(m)
-            digest = metric_digest(key.name, key.type, key.joined_tags)
+            digest = wire.metric_digest_of(m)
         except Exception as e:
             self._registry.incr("import", "import.rejected")
             log.warning("rejected unroutable imported metric: %s", e)
@@ -363,30 +364,21 @@ class ForwardHandler(grpc.GenericRpcHandler):
         self._submit(digest, ImportedMetric(m))
 
     def _route_all(self, metrics, env=None) -> int:
-        """Digest + route one request's metrics: a single batch-submit
-        call when the server provided one (the write-ahead journal
-        must see the request as ONE op — with its admitted envelope —
-        before any queue does), else the legacy per-metric submit.
-        Returns the routed count."""
+        """Route one request's metrics: ONE batch-submit call when the
+        server provided one — the request travels to the engines as a
+        unit, grouped by target engine there, and the write-ahead
+        journal sees it as ONE op with its admitted envelope before
+        any queue does — else the per-metric submit. Returns the
+        routed count."""
         if self._submit_batch is None:
             n = 0
             for m in metrics:
                 self._route(m)
                 n += 1
             return n
-        pairs = []
-        for m in metrics:
-            try:
-                key = wire.metric_key_of(m)
-                digest = metric_digest(key.name, key.type,
-                                       key.joined_tags)
-            except Exception as e:
-                self._registry.incr("import", "import.rejected")
-                log.warning("rejected unroutable imported metric: %s", e)
-                continue
-            pairs.append((digest, m))
-        self._submit_batch(pairs, env)
-        return len(pairs)
+        if not hasattr(metrics, "__len__"):
+            metrics = list(metrics)     # an unmaterialized V2 stream
+        return self._submit_batch(metrics, env)
 
     def _check_stamp(self, remote, env) -> bool:
         """Engine-stamp verdict for one request; on False the verdict
@@ -430,9 +422,10 @@ class ForwardHandler(grpc.GenericRpcHandler):
 
     def _apply(self, scope, env, metrics) -> None:
         """The shared admit-then-route tail, phase-attributed: `route`
-        is key digest + enqueue; the Combine itself runs on a worker
-        thread after the acknowledgement (`import.apply` in the flush
-        tick)."""
+        is grouping by engine (a key digest a metric only where there
+        is more than one) + enqueue; the Combine itself runs on a
+        worker thread after the acknowledgement (`import.apply` in the
+        flush tick)."""
         ph = scope.start("dedupe")
         ok = self._admit(env)
         scope.finish(ph, admitted=ok)

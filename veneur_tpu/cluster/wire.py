@@ -17,6 +17,7 @@ from .. import sketches
 from ..ingest.parser import (GLOBAL_ONLY, LOCAL_ONLY, MIXED_SCOPE,
                              MetricKey)
 from ..models.pipeline import ForwardExport
+from ..utils.hashing import metric_digest
 from .protos import forward_pb2, metric_pb2
 
 HLL_VERSION = 1
@@ -617,6 +618,13 @@ def metric_key_of(m) -> MetricKey:
                      joined_tags=",".join(sorted(m.tags)))
 
 
+def metric_digest_of(m) -> int:
+    """The worker-sharding digest of a metricpb.Metric: FNV-1a over
+    its key's name, type and joined tags, as the packet path's."""
+    key = metric_key_of(m)
+    return metric_digest(key.name, key.type, key.joined_tags)
+
+
 def apply_metric_to_engine(engine, m) -> None:
     """metricpb.Metric -> engine.import_* (the Combine dispatch)."""
     key = metric_key_of(m)
@@ -635,27 +643,63 @@ def apply_metric_to_engine(engine, m) -> None:
         engine.import_gauge(key, m.gauge.value)
 
 
-def apply_metric_to_engine_locked(engine, m) -> None:
-    """The Combine dispatch for a caller already holding engine.lock —
-    AggregationEngine.import_list applies a whole journaled import op
-    under ONE lock hold (the durability watermark's consistent cut).
-    Decode is identical to apply_metric_to_engine; only the locking
-    discipline differs."""
-    key = metric_key_of(m)
-    which = m.WhichOneof("value")
-    if which == "histogram":
-        td = m.histogram.t_digest
-        means, weights = td_centroids(td)
-        engine._import_histogram_locked(
-            key, means, weights, td.min, td.max, td.sum, td.count,
-            td.reciprocal_sum)
-    elif which == "set":
-        eng_id, regs = decode_set_payload(m.set.hyper_log_log)
-        engine._import_set_locked(key, regs, eng_id)
-    elif which == "counter":
-        engine._import_counter_locked(key, float(m.counter.value))
-    elif which == "gauge":
-        engine._import_gauge_locked(key, m.gauge.value)
+# kinds of a decoded import record (decode_metric_batch)
+IMPORT_HISTOGRAM, IMPORT_SET, IMPORT_COUNTER, IMPORT_GAUGE = range(4)
+
+
+def decode_metric_batch(pbs) -> tuple:
+    """One request's metricpb.Metrics, decoded in one pass for
+    AggregationEngine.import_list -> (records, means, weights,
+    rejected). A record is `(kind, key, pb, ...)` in wire order:
+
+      IMPORT_HISTOGRAM  (.., start, stop, min, max, sum, count, recip)
+                        its centroids are means[start:stop] /
+                        weights[start:stop] of the batch's two flat
+                        f32 columns (one numpy conversion a batch,
+                        whichever row each digest carried)
+      IMPORT_SET        (.., registers u8[m], engine_id)
+      IMPORT_COUNTER    (.., value as float)
+      IMPORT_GAUGE      (.., value)
+
+    Decoding is identical to apply_metric_to_engine's, metric by
+    metric: a payload that one rejects the other rejects, as
+    `(pb, exception)` in `rejected`, and the rest of the batch
+    decodes. A metric with no value set yields no record."""
+    records, rejected = [], []
+    fm: list = []
+    fw: list = []
+    for m in pbs:
+        start = len(fm)
+        try:
+            key = metric_key_of(m)
+            which = m.WhichOneof("value")
+            if which == "histogram":
+                td = m.histogram.t_digest
+                if len(td.packed_centroids):
+                    means, weights = decode_q16_centroids(
+                        td.packed_centroids)
+                    fm.extend(means.tolist())
+                    fw.extend(weights.tolist())
+                else:
+                    for c in td.centroids:
+                        fm.append(c.mean)
+                        fw.append(c.weight)
+                records.append((IMPORT_HISTOGRAM, key, m, start, len(fm),
+                                td.min, td.max, td.sum, td.count,
+                                td.reciprocal_sum))
+            elif which == "set":
+                eng_id, regs = decode_set_payload(m.set.hyper_log_log)
+                records.append((IMPORT_SET, key, m, regs, eng_id))
+            elif which == "counter":
+                records.append((IMPORT_COUNTER, key, m,
+                                float(m.counter.value)))
+            elif which == "gauge":
+                records.append((IMPORT_GAUGE, key, m, m.gauge.value))
+        except Exception as e:
+            del fm[start:], fw[start:]
+            rejected.append((m, e))
+    return (records, np.array(fm, np.float32), np.array(fw, np.float32),
+            rejected)
 
 
 def _split_tags(joined: str) -> list[str]:

@@ -28,7 +28,6 @@ from . import __version__
 from .cluster import wire
 from .cluster.protos import metric_pb2
 from .ingest.parser import MetricKey
-from .utils.hashing import metric_digest
 
 log = logging.getLogger("veneur_tpu.http")
 
@@ -110,11 +109,12 @@ class HttpApi:
         runs the query on the tier's OWN executor — never this handler
         thread beyond the wait, never the ingest/flush path.
 
-        `submit_batch` (optional, `submit_batch([(digest, pb), ...])`)
-        routes one request's decoded metrics as a unit — the Server's
-        durable implementation write-aheads the batch to the engine
-        journal before any worker queue (and therefore before the 200
-        ack) sees it.
+        `submit_batch` (optional, `submit_batch(metrics, envelope) ->
+        routed count`) routes one request's decoded metrics as a unit
+        and replaces `submit` when given — the Server's implementation
+        puts one ImportedBatch an engine on the worker queues, after
+        write-aheading the request to the engine journal where that is
+        armed (before the 200 ack either way).
 
         `engine_stamp` (ISSUE 10): the server's sketch-engine/wire
         stamp; a POST /import whose declared stamp (or implied legacy
@@ -261,7 +261,7 @@ class HttpApi:
                 if self.path != "/import":
                     self._reply(404, b"not found\n")
                     return
-                if api._submit is None:
+                if api._submit is None and api._submit_batch is None:
                     self._reply(503, b"not a global veneur\n")
                     return
                 # jsonmetric-v1 contract: reject a declared format we
@@ -350,13 +350,7 @@ class HttpApi:
                     # decode the whole batch before submitting any of it
                     # (atomic like handleImport: a 400 means nothing was
                     # imported, so clients may safely re-send)
-                    decoded = []
-                    for d in body:
-                        pb = json_metric_to_pb(d)
-                        key = wire.metric_key_of(pb)
-                        digest = metric_digest(key.name, key.type,
-                                               key.joined_tags)
-                        decoded.append((digest, pb))
+                    decoded = [json_metric_to_pb(d) for d in body]
                 except (ValueError, KeyError, TypeError) as e:
                     if scope is not None:
                         scope.finish(ph, outcome="error")
@@ -383,12 +377,11 @@ class HttpApi:
                     return
                 ph = -1 if scope is None else scope.start("route")
                 if api._submit_batch is not None:
-                    api._submit_batch(decoded, env)
-                    count = len(decoded)
+                    count = api._submit_batch(decoded, env)
                 else:
                     count = 0
-                    for digest, pb in decoded:
-                        api._submit(digest, pb)
+                    for pb in decoded:
+                        api._submit(wire.metric_digest_of(pb), pb)
                         count += 1
                 if scope is not None:
                     scope.finish(ph, n_metrics=count)

@@ -937,6 +937,10 @@ class AggregationEngine:
         self._flush_baseline = None
         self._last_flush_info = {"path": "full"}
         self.last_import_op = 0
+        # import batches applied since the last flush and the metrics
+        # in them (_last_flush_info "import_batches" / "import_metrics")
+        self._import_batches = 0
+        self._import_metrics = 0
         # Overload defense (ingest/admission.py): attached by the
         # Server via attach_admission; None = every key mints freely
         # (direct engine construction, the pre-defense behavior).
@@ -1461,26 +1465,47 @@ class AggregationEngine:
         self._import_gauge_acc[slot] = float(value)  # last write wins
 
     def import_list(self, op_id: int, pbs) -> tuple:
-        """Atomically apply one journaled import op's metrics for this
-        engine (durability/ ISSUE 9): the whole group lands under ONE
-        lock hold and the applied-op watermark advances in the same
-        critical section, so a concurrent checkpoint_state() sees
-        either none of the op or all of it — the exactness the
-        watermark's replay filter depends on. Returns
-        (rerouted, rejected): fold keys homed on other engines as
-        (ImportFoldReroute, pb) pairs the worker loop re-routes, and
-        per-metric poison pills as (pb, exception) pairs it counts —
-        one corrupt metric must reject itself, not the op."""
+        """Apply one import request's metrics for this engine as a
+        unit — the one way from a request to the banks' staging (the
+        worker loop, recovery's replay and the history tier all call
+        it). The batch is decoded in one pass outside the lock
+        (wire.decode_metric_batch), then staged in wire order under ONE
+        lock hold in which the applied-op watermark also advances, so
+        a concurrent checkpoint_state() sees either none of the op or
+        all of it — the exactness the watermark's replay filter
+        depends on. Staging is the per-metric `_import_*_locked` calls,
+        so a landing still fires at the digest, centroid or set that
+        fills its stage, in the middle of a batch where that is where
+        it falls. Returns (rerouted, rejected): fold keys homed on
+        other engines as (ImportFoldReroute, pb) pairs the worker loop
+        re-routes, and per-metric poison pills as (pb, exception)
+        pairs it counts — one corrupt metric must reject itself, not
+        the op."""
         from ..cluster import wire
-        rerouted, rejected = [], []
+        records, means, weights, rejected = wire.decode_metric_batch(pbs)
+        rerouted = []
         with self.lock:
-            for pb in pbs:
+            for rec in records:
+                kind = rec[0]
                 try:
-                    wire.apply_metric_to_engine_locked(self, pb)
+                    if kind == wire.IMPORT_HISTOGRAM:
+                        (_, key, _, start, stop, vmin, vmax, vsum, count,
+                         recip) = rec
+                        self._import_histogram_locked(
+                            key, means[start:stop], weights[start:stop],
+                            vmin, vmax, vsum, count, recip)
+                    elif kind == wire.IMPORT_SET:
+                        self._import_set_locked(rec[1], rec[3], rec[4])
+                    elif kind == wire.IMPORT_COUNTER:
+                        self._import_counter_locked(rec[1], rec[3])
+                    else:
+                        self._import_gauge_locked(rec[1], rec[3])
                 except ImportFoldReroute as fr:
-                    rerouted.append((fr, pb))
+                    rerouted.append((fr, rec[2]))
                 except Exception as e:
-                    rejected.append((pb, e))
+                    rejected.append((rec[2], e))
+            self._import_batches += 1
+            self._import_metrics += len(pbs)
             if op_id > self.last_import_op:
                 self.last_import_op = op_id
         return rerouted, rejected
@@ -1970,7 +1995,10 @@ class AggregationEngine:
         for ki in (self.histo_keys, self.counter_keys,
                    self.gauge_keys, self.set_keys):
             ki.advance_interval()
-        return active, status, stats_samples, dropped, histo_key_count
+        imported = (self._import_batches, self._import_metrics)
+        self._import_batches = self._import_metrics = 0
+        return (active, status, stats_samples, dropped, histo_key_count,
+                imported)
 
     def _land_retired(self, snap, overflow, dirty, stages, imports,
                       gauge_seq) -> tuple:
@@ -2067,8 +2095,8 @@ class AggregationEngine:
                 # banks — the per-interval replay cut the time-travel
                 # history tier records (ISSUE 14)
                 retired_wm = self.last_import_op
-                (active, status, stats_samples, dropped,
-                 histo_key_count) = self._flush_bookkeeping(full_export)
+                (active, status, stats_samples, dropped, histo_key_count,
+                 imported) = self._flush_bookkeeping(full_export)
             t_swap = time.monotonic_ns()
             # flight-recorder stamps: (name, t0_ns, t1_ns) on the
             # shared monotonic_ns clock, returned in stats["phases"]
@@ -2089,14 +2117,16 @@ class AggregationEngine:
                 overflow = self._retire_overflow()
                 self._gauge_seq = 0
                 retired_wm = self.last_import_op
-                (active, status, stats_samples, dropped,
-                 histo_key_count) = self._flush_bookkeeping(full_export)
+                (active, status, stats_samples, dropped, histo_key_count,
+                 imported) = self._flush_bookkeeping(full_export)
             t_swap = time.monotonic_ns()
             phases = [("drain", t_start, t_swap)]
 
         fwd_out = self._fwd_out
         host = self._flush_device(snap, phases=phases, dirty=dirty,
                                   overflow=overflow)
+        self._last_flush_info.update(import_batches=imported[0],
+                                     import_metrics=imported[1])
         t_device = time.monotonic_ns()
 
         # Delta export build (ISSUE 13): honor the request only when
@@ -2320,6 +2350,10 @@ class AggregationEngine:
             # interval (veneur.ingest.overflow_*_total)
             "overflow_rows": self._last_flush_info.get("overflow_rows", 0),
             "overflow_bank": self._last_flush_info.get("overflow_bank", 0),
+            # import batches applied this interval and the metrics in
+            # them (veneur.import.batches_total / batch_metrics_total)
+            "import_batches": imported[0],
+            "import_metrics": imported[1],
             # what the export build actually shipped (delta requests
             # degrade to full when no bitmap exists — mesh, tracking
             # off — or the engine does not forward)

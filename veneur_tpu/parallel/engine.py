@@ -352,59 +352,60 @@ class MeshAggregationEngine(AggregationEngine):
     # ---------------- import (global tier Combine path) ----------------
     # Overrides: the single-device engine merges imports with dedicated
     # cluster/merge programs; on the mesh everything lands through the
-    # routed SPMD ingest instead (see module docstring).
+    # routed SPMD ingest instead (see module docstring). The overrides
+    # are the `_locked` halves: the base class's import_histogram /
+    # import_set take the lock and import_list holds it across a batch.
 
-    def import_histogram(self, key, means, weights, vmin, vmax,
-                         vsum, count, recip=0.0):
-        with self.lock:
-            slot = self.histo_keys.lookup(key, GLOBAL_ONLY)
-            if slot == FOLD_SLOT:
-                # overload defense: over-budget forwarded keys fold
-                # into `<prefix>.__other__` here too (the mesh server
-                # is a single engine, so the fold is always local)
-                slot = self._fold_import_slot(self.histo_keys, key)
-            if slot < 0:
-                return
-            means = np.asarray(means, np.float64)
-            weights = np.asarray(weights, np.float64)
-            # cap at B-2 so item + extreme riders never exceeds B — the
-            # landing batches are scheduled so one slot never overflows
-            # its buffer in a single scatter, keeping the hot-slot
-            # pre-cluster (whose recip is approximate) OFF this path
-            B = self.cfg.buffer_depth - 2
-            if len(means) > B:
-                means, weights = _precluster_k1(means, weights, B)
-            # a centroid mean comes out of a cumsum difference and can
-            # sit a few ulp outside the digest's exact [vmin, vmax];
-            # staged as a sample it would then move this slot's
-            # extremes off the forwarded exact ones
-            means = np.clip(means, vmin, vmax)
-            self._import_centroids.append(
-                (slot, means, weights, float(vmin), float(vmax)))
-            self._import_h_points += len(means) + 2
-            # The staged centroids flow through the ingest scatter, so
-            # they CONTRIBUTE approximate vsum/count/recip; accumulate
-            # the exact-minus-staged delta per slot (f64 host math) and
-            # fold it in via merge_histo_scalars — making the flushed
-            # sum/count/hmean match the forwarded exact values, like
-            # the single-device merge_scalars path.
-            # replicate the device's f32 per-term arithmetic so the
-            # delta cancels the staged contribution to rounding level
-            m32 = means.astype(np.float32)
-            w32 = weights.astype(np.float32)
-            staged_sum = float((m32 * w32).astype(np.float64).sum())
-            staged_cnt = float(w32.astype(np.float64).sum())
-            nz = m32 != 0
-            staged_rcp = float((w32[nz] / m32[nz])
-                               .astype(np.float64).sum())
-            d = self._import_h_deltas.setdefault(slot, [0.0, 0.0, 0.0])
-            d[0] += float(vsum) - staged_sum
-            d[1] += float(count) - staged_cnt
-            d[2] += float(recip) - staged_rcp
-            if self._import_h_points >= self.cfg.batch_size:
-                self._flush_import_centroids_locked()
+    def _import_histogram_locked(self, key, means, weights, vmin, vmax,
+                                 vsum, count, recip=0.0):
+        slot = self.histo_keys.lookup(key, GLOBAL_ONLY)
+        if slot == FOLD_SLOT:
+            # overload defense: over-budget forwarded keys fold
+            # into `<prefix>.__other__` here too (the mesh server
+            # is a single engine, so the fold is always local)
+            slot = self._fold_import_slot(self.histo_keys, key)
+        if slot < 0:
+            return
+        means = np.asarray(means, np.float64)
+        weights = np.asarray(weights, np.float64)
+        # cap at B-2 so item + extreme riders never exceeds B — the
+        # landing batches are scheduled so one slot never overflows
+        # its buffer in a single scatter, keeping the hot-slot
+        # pre-cluster (whose recip is approximate) OFF this path
+        B = self.cfg.buffer_depth - 2
+        if len(means) > B:
+            means, weights = _precluster_k1(means, weights, B)
+        # a centroid mean comes out of a cumsum difference and can
+        # sit a few ulp outside the digest's exact [vmin, vmax];
+        # staged as a sample it would then move this slot's
+        # extremes off the forwarded exact ones
+        means = np.clip(means, vmin, vmax)
+        self._import_centroids.append(
+            (slot, means, weights, float(vmin), float(vmax)))
+        self._import_h_points += len(means) + 2
+        # The staged centroids flow through the ingest scatter, so
+        # they CONTRIBUTE approximate vsum/count/recip; accumulate
+        # the exact-minus-staged delta per slot (f64 host math) and
+        # fold it in via merge_histo_scalars — making the flushed
+        # sum/count/hmean match the forwarded exact values, like
+        # the single-device merge_scalars path.
+        # replicate the device's f32 per-term arithmetic so the
+        # delta cancels the staged contribution to rounding level
+        m32 = means.astype(np.float32)
+        w32 = weights.astype(np.float32)
+        staged_sum = float((m32 * w32).astype(np.float64).sum())
+        staged_cnt = float(w32.astype(np.float64).sum())
+        nz = m32 != 0
+        staged_rcp = float((w32[nz] / m32[nz])
+                           .astype(np.float64).sum())
+        d = self._import_h_deltas.setdefault(slot, [0.0, 0.0, 0.0])
+        d[0] += float(vsum) - staged_sum
+        d[1] += float(count) - staged_cnt
+        d[2] += float(recip) - staged_rcp
+        if self._import_h_points >= self.cfg.batch_size:
+            self._flush_import_centroids_locked()
 
-    def import_set(self, key, registers, engine_id=None):
+    def _import_set_locked(self, key, registers, engine_id=None):
         # the mesh engine is hll-only (constructor guard): a wire row
         # tagged with another engine must reject THIS metric, matching
         # the single-device engine's belt check
@@ -412,16 +413,15 @@ class MeshAggregationEngine(AggregationEngine):
             raise ValueError(
                 f"set sketch engine mismatch: payload {engine_id!r}, "
                 "mesh banks run 'hll'")
-        with self.lock:
-            slot = self.set_keys.lookup(key, GLOBAL_ONLY)
-            if slot == FOLD_SLOT:
-                slot = self._fold_import_slot(self.set_keys, key)
-            if slot < 0:
-                return
-            self._import_sets.append(
-                (slot, np.asarray(registers, np.uint8)))
-            if len(self._import_sets) >= self._set_rows_chunk:
-                self._flush_import_sets_locked()
+        slot = self.set_keys.lookup(key, GLOBAL_ONLY)
+        if slot == FOLD_SLOT:
+            slot = self._fold_import_slot(self.set_keys, key)
+        if slot < 0:
+            return
+        self._import_sets.append(
+            (slot, np.asarray(registers, np.uint8)))
+        if len(self._import_sets) >= self._set_rows_chunk:
+            self._flush_import_sets_locked()
 
     # import_counter / import_gauge: the base class's host accumulation
     # works unchanged; only the landing (in _flush_import_scalars) moves

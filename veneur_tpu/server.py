@@ -56,6 +56,38 @@ def _fold_rewrite(pb, fr) -> int:
     return fr.digest
 
 
+class _WorkerQueue(queue.Queue):
+    """A worker queue bounded in metrics waiting, not in items: an
+    ImportedBatch weighs the forwarded sketches it carries, anything
+    else one. A request travels as one item an engine, and 65,536
+    requests of 6,500 sketches would be no bound (queue.Queue's
+    documented _init/_qsize/_put/_get override points, as
+    PriorityQueue uses them). A put is admitted while the weight
+    waiting is under `maxsize`, so the bound overshoots by at most one
+    batch; `unfinished_tasks` still counts items, which is what
+    drain() settles on."""
+
+    def _init(self, maxsize):
+        super()._init(maxsize)
+        self._weight = 0
+
+    def _qsize(self):
+        return self._weight
+
+    def _put(self, item):
+        self.queue.append(item)
+        self._weight += self._weigh(item)
+
+    def _get(self):
+        item = self.queue.popleft()
+        self._weight -= self._weigh(item)
+        return item
+
+    @staticmethod
+    def _weigh(item) -> int:
+        return len(getattr(item, "pbs", ())) or 1
+
+
 class Server:
     # Flight-recorder rows ONE flush tick keeps of each kind of work
     # done between ticks (observe.StampLog budgets: past them a kind's
@@ -130,7 +162,7 @@ class Server:
                 eng.land_stamps = observe.StampLog(dict.fromkeys(
                     LAND_PHASES, self.GRAFT_BUDGET["import.land"]))
         self.worker_queues: list[queue.Queue] = [
-            queue.Queue(maxsize=65536) for _ in range(n_workers)]
+            _WorkerQueue(maxsize=65536) for _ in range(n_workers)]
         # per queue: until when a full queue sheds imports without
         # waiting (see _enqueue_import)
         self._import_shed_until = [0.0] * n_workers
@@ -1297,28 +1329,51 @@ class Server:
             except Exception:
                 pass
 
-    def _submit_import_batch(self, pairs, envelope=None):
-        """The durable import submit path (wired into importsrv and the
-        HTTP /import handler when engine checkpointing is armed): one
-        admitted request = one journal op, write-ahead BEFORE any
-        worker queue — and therefore before the sender's ack — then
-        grouped per target engine so the worker applies each engine's
-        share atomically under the op id (the watermark's consistent
-        cut). The submit lock makes journal order == queue order, so
-        recovery's replay reproduces the original per-engine
-        application order exactly. `envelope` (the request's already-
-        admitted idempotency envelope) rides in the op record so
-        recovery can re-seed the dedupe ledger — recovered state plus
-        a forgotten envelope would double-count the sender's replay."""
+    def _group_imports(self, pbs) -> dict:
+        """One import request's metrics by target engine (= worker
+        queue), wire order kept inside each share: the worker-sharding
+        digest (FNV-1a over name, type and tags, as the packet path's)
+        where there is more than one engine to choose between, and no
+        work a metric where there is one. An unroutable metric (bad
+        key bytes) rejects itself, counted and logged."""
+        from .cluster import wire
+        n = len(self.engines)
+        if n == 1:
+            return {0: pbs} if len(pbs) else {}
+        groups: dict[int, list] = {}
+        for pb in pbs:
+            try:
+                digest = wire.metric_digest_of(pb)
+            except Exception as e:
+                self._count("import.rejected")
+                log.warning("rejected unroutable imported metric: %s", e)
+                continue
+            groups.setdefault(digest % n, []).append(pb)
+        return groups
+
+    def _submit_import_batch(self, pbs, envelope=None) -> int:
+        """The import submit path (importsrv and the HTTP /import
+        handler): one admitted request = one op, grouped per target
+        engine so each engine's share travels as ONE ImportedBatch and
+        the worker applies it as a unit under the op id (the
+        watermark's consistent cut). With engine checkpointing armed
+        the op is write-ahead journaled BEFORE any worker queue — and
+        therefore before the sender's ack — and the submit lock makes
+        journal order == queue order, so recovery's replay reproduces
+        the original per-engine application order exactly. `envelope`
+        (the request's already-admitted idempotency envelope) rides in
+        the op record so recovery can re-seed the dedupe ledger —
+        recovered state plus a forgotten envelope would double-count
+        the sender's replay. Returns the count routed."""
         from .cluster.importsrv import ImportedBatch
         from .durability import records as drecords
-        nq = len(self.worker_queues)
+        groups = self._group_imports(pbs)
         with self._import_submit_lock:
             op_id = self._next_import_op = self._next_import_op + 1
             if self._engine_journal is not None:
                 try:
                     payload = drecords.encode_engine_import(
-                        op_id, [pb for _d, pb in pairs], envelope)
+                        op_id, pbs, envelope)
                     self._engine_journal.append_import(payload)
                     self._recent_import_ops.append((op_id, payload))
                     if len(self._recent_import_ops) > \
@@ -1332,14 +1387,12 @@ class Server:
                         self._import_ops_evicted = True
                 except Exception:
                     self._engine_journal_failed("import write-ahead")
-            groups: dict[int, list] = {}
-            for digest, pb in pairs:
-                groups.setdefault(digest % nq, []).append(pb)
-            for qi, pbs in groups.items():
+            for qi, share in groups.items():
                 # a shed batch is journaled all the same: recovery
                 # replays it, only live processing loses it
-                self._enqueue_import(qi, ImportedBatch(op_id, pbs),
-                                     len(pbs))
+                self._enqueue_import(qi, ImportedBatch(op_id, share),
+                                     len(share))
+        return sum(len(share) for share in groups.values())
 
     def _recover_engine_state(self):
         """Recovery-before-listen: rebuild the engines from the engine
@@ -1351,10 +1404,8 @@ class Server:
         on corrupt state: a shape-fingerprint mismatch or undecodable
         group drops the WHOLE recovery loudly (fresh start) rather
         than scattering rows into wrong slots."""
-        from .cluster import wire
         from .durability import records as drecords
         from .durability.history import collect_checkpoint_groups
-        from .utils.hashing import metric_digest
         tel, S = self.telemetry, observe.SERVER_SCOPE
         t0 = time.monotonic_ns()
         recs = self._engine_journal.load_records()
@@ -1440,19 +1491,9 @@ class Server:
                 # durable watermark journal instead — the two windows
                 # interlock)
                 self.dedupe_ledger.admit(*env)
-            by_engine: dict[int, list] = {}
-            for pb in pbs:
-                try:
-                    key = wire.metric_key_of(pb)
-                    digest = metric_digest(key.name, key.type,
-                                           key.joined_tags)
-                except Exception:
-                    self._count("import.rejected")
-                    continue
-                by_engine.setdefault(digest % n, []).append(pb)
             applied = False
             reroutes: list = []
-            for ei, epbs in by_engine.items():
+            for ei, epbs in self._group_imports(pbs).items():
                 eng = self.engines[ei]
                 if op_id <= eng.last_import_op:
                     continue   # inside the restored checkpoint already
@@ -1750,8 +1791,7 @@ class Server:
         server, port = start_import_server(
             addr, submit, ledger=self.dedupe_ledger,
             observer=self.import_observer,
-            submit_batch=(self._submit_import_batch
-                          if self._engine_journal is not None else None),
+            submit_batch=self._submit_import_batch,
             engine_stamp=self.engine_stamp,
             note_stamp=self._note_sketch_stamp,
             merge_sketches=self.merge_prefix_sketches)
@@ -1762,22 +1802,15 @@ class Server:
         """Ops HTTP listener (handlers.go): healthchecks + the legacy
         POST /import path, which feeds the same Combine machinery as
         gRPC import."""
-        from .cluster.importsrv import ImportedMetric
         from .http_api import HttpApi
 
-        nq = len(self.worker_queues)
-
-        def submit(digest, pb):
-            self._enqueue_import(digest % nq, ImportedMetric(pb))
-
         self.http_api = HttpApi(
-            addr, submit=submit, ledger=self.dedupe_ledger,
+            addr, ledger=self.dedupe_ledger,
             debug_state=self._debug_flush_state,
             observer=self.import_observer,
             fleet_state=self._debug_fleet_state,
             health=self.health_state,
-            submit_batch=(self._submit_import_batch
-                          if self._engine_journal is not None else None),
+            submit_batch=self._submit_import_batch,
             engine_stamp=self.engine_stamp,
             note_stamp=self._note_sketch_stamp,
             merge_sketches=self.merge_prefix_sketches,
@@ -1876,15 +1909,17 @@ class Server:
                 elif isinstance(item, ImportedBatch):
                     if stamps is not None and not run_t0:
                         run_t0 = time.monotonic_ns()
-                    # durable import path: one journaled op's share for
-                    # this engine, applied atomically so the engine's
-                    # applied-op watermark is an exact replay cut
+                    # one import request's share for this engine,
+                    # applied as a unit so the engine's applied-op
+                    # watermark is an exact replay cut
                     rerouted, rejected = eng.import_list(item.op_id,
                                                          item.pbs)
                     for fr, pb in rerouted:
-                        # fold key homed on another engine: rewrite and
-                        # re-route under the SAME op id (single-homed
-                        # folds, as the per-metric path does)
+                        # overload defense: the fold key is homed on
+                        # another engine — rewrite the aggregate onto
+                        # it and re-route under the SAME op id
+                        # (single-homed folds; the home engine admits
+                        # it as an ordinary import)
                         digest = _fold_rewrite(pb, fr)
                         try:
                             self.worker_queues[
@@ -1901,18 +1936,16 @@ class Server:
                 elif isinstance(item, ImportedMetric):
                     if stamps is not None and not run_t0:
                         run_t0 = time.monotonic_ns()
-                    # poison-pill guard: a corrupted forwarded payload
-                    # (bad HLL blob, malformed centroid list) must
-                    # reject THAT metric, not kill this worker loop —
-                    # without the catch, one bad sender starves a
-                    # whole queue shard forever
+                    # a metric routed alone (a handler built without
+                    # submit_batch). Poison-pill guard, here as in the
+                    # batch arm: a corrupted forwarded payload (bad
+                    # HLL blob, malformed centroid list) must reject
+                    # THAT metric, not kill this worker loop — without
+                    # the catch, one bad sender starves a whole queue
+                    # shard forever
                     try:
                         apply_metric_to_engine(eng, item.pb)
                     except pipeline.ImportFoldReroute as fr:
-                        # overload defense: the fold key is homed on
-                        # another engine — rewrite the aggregate onto
-                        # it and re-route (single-homed folds; the
-                        # home engine admits it as an ordinary import)
                         digest = _fold_rewrite(item.pb, fr)
                         try:
                             self.worker_queues[
@@ -2075,6 +2108,7 @@ class Server:
         status_metrics = []
         eng_stats = {"samples": 0, "dropped_no_slot": 0,
                      "overflow_rows": 0, "overflow_bank": 0,
+                     "import_batches": 0, "import_metrics": 0,
                      "swap_ns": 0, "merge_ns": 0, "assembly_ns": 0}
         # Engines flush concurrently so their device programs and
         # device→host transfers overlap instead of queueing behind
@@ -2827,6 +2861,12 @@ class Server:
             # one, and passes over a whole histogram bank (the dear arm)
             tel.mark(S, "ingest.overflow_rows", eng_stats["overflow_rows"])
             tel.mark(S, "ingest.overflow_bank", eng_stats["overflow_bank"])
+            # the import's hand-over: batches the engines applied and
+            # the forwarded metrics in them (one batch a request and
+            # engine; a ratio near 1 means requests of one metric)
+            tel.mark(S, "import.batches", eng_stats["import_batches"])
+            tel.mark(S, "import.batch_metrics",
+                     eng_stats["import_metrics"])
             tel.set_gauge(S, "flush.swap_duration_ns",
                           eng_stats["swap_ns"])
             tel.set_gauge(S, "flush.merge_duration_ns",
