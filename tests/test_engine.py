@@ -356,11 +356,9 @@ def test_hot_slots_in_a_batch_wider_than_batch_size():
         assert abs(by[f"hot{i}.50percentile"] - exp) / exp < 0.02
 
 
-@pytest.mark.parametrize("mode", ["sync", "staged", "host", "async"])
-def test_flush_fetch_modes_identical(mode):
-    """Every flush_fetch mode must produce identical results (the modes
-    only change HOW outputs leave the device).
-    "host" falls back to "staged" where pinned_host is unsupported."""
+def test_warmed_engine_flushes_what_a_cold_one_does():
+    """warmup() runs the flush program on fresh banks before serving;
+    a warmed engine must then flush what a cold one does."""
     lines = [b"c.hits:7|c", b"g.temp:70|g", b"s.u:alice|s", b"s.u:bob|s"]
     lines += [f"t.req:{v}|ms".encode() for v in range(1, 201)]
 
@@ -369,7 +367,7 @@ def test_flush_fetch_modes_identical(mode):
     ref = {(m.name, tuple(m.tags)): m.value
            for m in ref_eng.flush(1000).metrics}
 
-    eng = AggregationEngine(small_config(flush_fetch=mode))
+    eng = AggregationEngine(small_config())
     eng.warmup()
     feed(eng, lines)
     got = {(m.name, tuple(m.tags)): m.value
@@ -379,90 +377,84 @@ def test_flush_fetch_modes_identical(mode):
         np.testing.assert_allclose(got[k], ref[k], rtol=1e-6, err_msg=k)
 
 
-@pytest.mark.parametrize("mode", ["sync", "staged"])
-def test_flush_fetch_f16_compact(mode):
-    """Compact wire mode (flush_fetch_f16): count/sum stay exact (they
-    cross as f32 hi + sentinel-gated lo), quantiles and min/max land
-    within f16 rounding of the full-precision engine."""
-    lines = [b"c.hits:7|c", b"g.temp:70|g", b"s.u:alice|s", b"s.u:bob|s"]
-    lines += [f"t.req:{v}|ms".encode() for v in range(1, 201)]
-
-    ref_eng = AggregationEngine(small_config(
-        aggregates=("min", "max", "count", "sum")))
-    feed(ref_eng, lines)
-    ref = {(m.name, tuple(m.tags)): m.value
-           for m in ref_eng.flush(1000).metrics}
-
-    eng = AggregationEngine(small_config(
-        flush_fetch=mode, flush_fetch_f16=True,
-        aggregates=("min", "max", "count", "sum")))
-    eng.warmup()
-    feed(eng, lines)
-    got = {(m.name, tuple(m.tags)): m.value
-           for m in eng.flush(1000).metrics}
-    assert got.keys() == ref.keys()
-    for k in ref:
-        exact = (k[0].endswith((".count", ".sum"))
-                 or not k[0].startswith("t."))
-        np.testing.assert_allclose(
-            got[k], ref[k], rtol=0 if exact else 1e-3, err_msg=k)
+def _engine_of(kind, **kw):
+    """The one-chip engine (its incremental or its full flush program)
+    or the mesh engine over the 8 virtual devices."""
+    if kind == "mesh":
+        from veneur_tpu.parallel.engine import MeshAggregationEngine
+        return MeshAggregationEngine(small_config(**kw), n_devices=8)
+    return AggregationEngine(small_config(
+        flush_incremental=(kind != "full_program"), **kw))
 
 
-def test_flush_fetch_f16_out_of_range_falls_back_exact():
-    """Values outside f16's safe range (here > 65504) trip the
-    overflow sentinel and the host re-fetches the full-precision
-    twins — results must match the f32 engine exactly, not as inf."""
-    lines = [f"t.big:{v}|ms".encode()
-             for v in (1e5, 2e5, 3e5, 4e5, 5e5)] * 20
-    lines += [f"t.tiny:{v}|ms".encode()
-              for v in (1e-6, 2e-6, 3e-6)] * 20
-
-    ref_eng = AggregationEngine(small_config())
-    feed(ref_eng, lines)
-    ref = {m.name: m.value for m in ref_eng.flush(1000).metrics}
-
-    eng = AggregationEngine(small_config(flush_fetch_f16=True))
-    feed(eng, lines)
+@pytest.mark.parametrize("kind", ["one_chip", "mesh"])
+def test_flush_keeps_f32_extremes_exact(kind):
+    """Timers far outside a half float's range (> 65504, < 2^-14) leave
+    the device as the f32 they are: extremes, counts and sums against
+    numpy, the tail quantile to f32 rounding."""
+    sent = {"t.big": (1e5, 2e5, 3e5, 4e5, 5e5),
+            "t.tiny": (1e-6, 2e-6, 3e-6)}
+    eng = _engine_of(kind, percentiles=(0.5, 0.99),
+                     aggregates=("min", "max", "count", "sum"))
+    feed(eng, [f"{name}:{v}|ms".encode()
+               for name, vals in sent.items() for v in vals * 20])
     got = {m.name: m.value for m in eng.flush(1000).metrics}
-    assert got.keys() == ref.keys()
-    for k in ref:
-        assert np.isfinite(got[k]), k
-        np.testing.assert_allclose(got[k], ref[k], rtol=1e-6, err_msg=k)
+    for name, vals in sent.items():
+        x = np.asarray(vals * 20, np.float32)
+        assert got[f"{name}.min"] == float(x.min())
+        assert got[f"{name}.max"] == float(x.max())
+        assert got[f"{name}.count"] == float(x.size)
+        np.testing.assert_allclose(got[f"{name}.sum"],
+                                   x.astype(np.float64).sum(), rtol=1e-6)
+        np.testing.assert_allclose(got[f"{name}.50percentile"],
+                                   np.quantile(x, 0.5), rtol=0.02)
+        np.testing.assert_allclose(got[f"{name}.99percentile"],
+                                   np.quantile(x, 0.99), rtol=1e-5)
 
 
-def test_f16_tiny_sentinel_sits_at_min_normal():
-    """_F16_TINY must equal f16's min normal (2^-14): a nonzero
-    magnitude below it encodes as an f16 SUBNORMAL on the compact wire
-    and must trigger the full-precision refetch. The old 6.1e-5
-    sentinel left a [6.1e-5, 2^-14) band that skipped the refetch yet
-    lost precision (ADVICE r5)."""
-    import jax.numpy as jnp
+@pytest.mark.parametrize("kind", ["incremental", "full_program", "mesh"])
+def test_one_flush_is_one_dispatch_and_one_fetch(kind, monkeypatch):
+    """How flush results leave the device: ONE dispatch of ONE flush
+    program and ONE device_get of its outputs, whichever program the
+    engine serves the interval with."""
+    import jax
 
     from veneur_tpu.models import pipeline
 
-    assert pipeline._F16_TINY == 2.0 ** -14
+    eng = _engine_of(kind)
+    eng.warmup()
+    feed(eng, [b"c.hits:7|c", b"g.temp:70|g", b"s.u:alice|s"]
+         + [f"t.req:{v}|ms".encode() for v in range(1, 201)])
+    calls = {"full": 0, "incremental": 0, "mesh": 0, "device_get": 0}
 
-    def fetched_keys(tiny_mag):
-        out = {
-            "lo_mag": jnp.float32(0.0),
-            "overflow_mag": jnp.float32(1.0),
-            "tiny_mag": jnp.float32(tiny_mag),
-            "q16": jnp.zeros((2, 2), jnp.float16),
-            "q32": jnp.zeros((2, 2), jnp.float32),
-        }
-        return pipeline.fetch_flush_outputs(out, "sync")
+    def counting(name, fn):
+        def call(*a, **kw):
+            calls[name] += 1
+            return fn(*a, **kw)
+        return call
 
-    # below min normal -> subnormal on the wire -> must refetch q32
-    assert "q32" in fetched_keys(6.1e-5)
-    # inside the OLD sentinel's blind band -> must refetch now
-    assert "q32" in fetched_keys(6.103e-5)
-    # at/above min normal (6.10352e-5 > 2^-14) -> no refetch needed
-    assert "q32" not in fetched_keys(6.10352e-5)
+    if kind == "mesh":
+        monkeypatch.setattr(eng.me, "flush_device",
+                            counting("mesh", eng.me.flush_device))
+    else:
+        eng._flush_exec = counting("full", eng._flush_exec)
+        inc = pipeline._inc_flush_executable
+        monkeypatch.setattr(
+            pipeline, "_inc_flush_executable",
+            lambda *a, **kw: counting("incremental", inc(*a, **kw)))
+    monkeypatch.setattr(jax, "device_get",
+                        counting("device_get", jax.device_get))
+    res = eng.flush(1000)
+    assert {m.name: m.value for m in res.metrics}["t.req.count"] == 200.0
+    want = dict.fromkeys(calls, 0)
+    want[{"full_program": "full"}.get(kind, kind)] = 1
+    want["device_get"] = 1
+    assert calls == want
 
 
 def test_sparse_high_slot_batch_skips_bincount():
     """Hot-slot detection must not allocate a max(slot)+1-sized
-    bincount for sparse high-slot-id batches (ADVICE r5): batches with
+    bincount for sparse high-slot-id batches (round-5 advisory): batches with
     <= buffer_depth valid rows skip counting entirely, and larger
     batches whose max slot id dwarfs the batch count via np.unique.
     The np.unique arm must still find the hot slot and stay exact."""
@@ -556,7 +548,7 @@ def _overflowing_batches(eng):
 
 
 @pytest.mark.parametrize("case", [
-    "incremental", "full_program", "staged_fetch", "legacy_ordering",
+    "incremental", "full_program", "legacy_ordering",
     "through_the_stage"])
 def test_overflow_counter_follows_the_interval(case, overflow_rows_of_four):
     kw = dict(histogram_slots=64, counter_slots=8, gauge_slots=8,
@@ -564,8 +556,6 @@ def test_overflow_counter_follows_the_interval(case, overflow_rows_of_four):
               aggregates=("count",))
     if case == "full_program":
         kw["flush_incremental"] = False
-    elif case == "staged_fetch":
-        kw["flush_fetch"] = "staged"
     elif case == "legacy_ordering":
         kw["flush_double_buffer"] = False
     eng = AggregationEngine(EngineConfig(**kw))

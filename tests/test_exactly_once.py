@@ -9,7 +9,6 @@ import pytest
 from veneur_tpu.cluster import wire
 from veneur_tpu.cluster.forward import (GrpcForwarder, HttpJsonForwarder)
 from veneur_tpu.cluster.importsrv import (DedupeLedger, ForwardHandler,
-                                          ImportedMetric,
                                           stop_import_server)
 from veneur_tpu.cluster.protos import forward_pb2, metric_pb2
 from veneur_tpu.ingest.parser import MetricKey
@@ -338,15 +337,23 @@ def _metric(name="m", value=1):
     return m
 
 
+def _collect(got):
+    """A submit_batch that keeps the request's metrics."""
+    def submit_batch(metrics, env=None):
+        got.extend(metrics)
+        return len(metrics)
+    return submit_batch
+
+
 class TestForwardHandlerDedupe:
     def test_send_metrics_drops_duplicate_chunk_whole(self):
         got = []
         led = DedupeLedger(registry=ResilienceRegistry())
-        h = ForwardHandler(lambda d, im: got.append(im), ledger=led)
+        h = ForwardHandler(_collect(got), ledger=led)
         ml = forward_pb2.MetricList(metrics=[_metric("a"), _metric("b")])
         ml.envelope.CopyFrom(wire.envelope_pb("s", 1, 0, 1))
         h._send_metrics(ml, _FakeContext())
-        assert [im.pb.name for im in got] == ["a", "b"]
+        assert [pb.name for pb in got] == ["a", "b"]
         h._send_metrics(ml, _FakeContext())      # ambiguous-retry replay
         assert len(got) == 2                     # dropped whole
         # a DIFFERENT chunk of the same interval still applies
@@ -357,7 +364,7 @@ class TestForwardHandlerDedupe:
 
     def test_send_metrics_without_envelope_always_applies(self):
         got = []
-        h = ForwardHandler(lambda d, im: got.append(im),
+        h = ForwardHandler(_collect(got),
                            ledger=DedupeLedger(
                                registry=ResilienceRegistry()))
         ml = forward_pb2.MetricList(metrics=[_metric("a")])
@@ -372,7 +379,7 @@ class TestForwardHandlerDedupe:
         whole-stream retry under the same envelope still applies."""
         got = []
         led = DedupeLedger(registry=ResilienceRegistry())
-        h = ForwardHandler(lambda d, im: got.append(im), ledger=led)
+        h = ForwardHandler(_collect(got), ledger=led)
         md = [(wire.ENVELOPE_METADATA_KEY,
                wire.envelope_pb("v2", 8, 0, 1).SerializeToString())]
 
@@ -386,7 +393,7 @@ class TestForwardHandlerDedupe:
         # the retry of the SAME envelope applies in full
         h._send_metrics_v2(iter([_metric("a"), _metric("b")]),
                            _FakeContext(md))
-        assert [im.pb.name for im in got] == ["a", "b"]
+        assert [pb.name for pb in got] == ["a", "b"]
 
     def test_http_bad_body_does_not_poison_ledger(self):
         """Regression (review finding): a 400 promises nothing was
@@ -402,7 +409,7 @@ class TestForwardHandlerDedupe:
         got = []
         led = DedupeLedger(registry=ResilienceRegistry())
         api = HttpApi("127.0.0.1:0",
-                      submit=lambda d, pb: got.append(pb), ledger=led)
+                      submit_batch=_collect(got), ledger=led)
         api.start()
         try:
             url = f"http://127.0.0.1:{api.port}/import"
@@ -433,7 +440,7 @@ class TestForwardHandlerDedupe:
     def test_send_metrics_v2_dedupes_via_metadata(self):
         got = []
         led = DedupeLedger(registry=ResilienceRegistry())
-        h = ForwardHandler(lambda d, im: got.append(im), ledger=led)
+        h = ForwardHandler(_collect(got), ledger=led)
         md = [(wire.ENVELOPE_METADATA_KEY,
                wire.envelope_pb("v2", 3, 0, 1).SerializeToString())]
         h._send_metrics_v2(iter([_metric("a")]), _FakeContext(md))
@@ -441,28 +448,8 @@ class TestForwardHandlerDedupe:
         h._send_metrics_v2(iter([_metric("a")]), _FakeContext(md))
         assert len(got) == 1                     # stream dropped whole
 
-    def test_route_rejects_poison_metric_and_counts(self):
-        reg = ResilienceRegistry()
-        calls = []
-
-        def explode(digest, im):
-            raise AssertionError("must not be reached")
-
-        h = ForwardHandler(calls.append, registry=reg)
-
-        class Evil:
-            name = property(lambda self: (_ for _ in ()).throw(
-                ValueError("bad name")))
-            type = metric_pb2.Counter
-            tags = ()
-
-        h._route(Evil())                         # must not raise
-        assert reg.peek("import", "import.rejected") == 1
-        del explode
-
-
 class TestWorkerPoisonGuard:
-    def _server(self):
+    def _server(self, more=""):
         from veneur_tpu.config import read_config
         from veneur_tpu.server import Server
         from veneur_tpu.sinks.basic import CaptureMetricSink
@@ -474,12 +461,37 @@ tpu_histogram_slots: 256
 tpu_counter_slots: 256
 tpu_gauge_slots: 256
 tpu_set_slots: 128
-""")
+""" + more)
         return Server(cfg, sinks=[CaptureMetricSink()], plugins=[])
+
+    def test_route_rejects_poison_metric_and_counts(self):
+        """A metric whose key cannot be digested rejects ITSELF where
+        the request is shared out over the engines, counted; the rest
+        of the request is routed."""
+        srv = self._server("num_workers: 2\n")
+
+        class Evil:
+            name = property(lambda self: (_ for _ in ()).throw(
+                ValueError("bad name")))
+            type = metric_pb2.Counter
+            tags = ()
+
+        try:
+            srv.start()
+            routed = srv._submit_import_batch(       # must not raise
+                [Evil(), _metric("good.counter", 5)])
+            assert routed == 1
+            assert srv.drain(5.0)
+            out = {m.name: m.value
+                   for m in srv.flush_once(timestamp=10)}
+            assert out.get("good.counter") == 5.0
+            assert out["veneur.import.rejected_total"] == 1.0
+        finally:
+            srv.stop()
 
     def test_corrupted_hll_rejected_worker_survives(self):
         """The poison-pill regression: a malformed HLL payload used to
-        propagate out of apply_metric_to_engine and kill the worker
+        propagate out of the engine's import and kill the worker
         loop; now it is rejected per-metric and counted."""
         srv = self._server()
         try:
@@ -488,8 +500,8 @@ tpu_set_slots: 128
                                     type=metric_pb2.Set)
             bad.set.hyper_log_log = b"\xff\x00garbage"   # bad version
             ok = _metric("good.counter", 5)
-            srv._route_metric(ImportedMetric(bad))
-            srv._route_metric(ImportedMetric(ok))
+            srv._submit_import_batch([bad])
+            srv._submit_import_batch([ok])
             assert srv.drain(5.0)
             # the worker survived the poison pill and processed the
             # good metric after it
@@ -511,16 +523,15 @@ tpu_set_slots: 128
             # monkeypatch the engine to make centroid import explode the
             # way a malformed payload does deeper in the stack
             eng = srv.engines[0]
-            orig = eng.import_histogram
-            eng.import_histogram = lambda *a, **kw: (_ for _ in ()
-                                                     ).throw(
-                ValueError("malformed centroid"))
+            orig = eng._import_histogram_locked
+            eng._import_histogram_locked = lambda *a, **kw: (
+                _ for _ in ()).throw(ValueError("malformed centroid"))
             try:
-                srv._route_metric(ImportedMetric(bad))
-                srv._route_metric(ImportedMetric(_metric("fine", 1)))
+                srv._submit_import_batch([bad])
+                srv._submit_import_batch([_metric("fine", 1)])
                 assert srv.drain(5.0)
             finally:
-                eng.import_histogram = orig
+                eng._import_histogram_locked = orig
             out = {m.name: m.value
                    for m in srv.flush_once(timestamp=10)}
             assert out.get("fine") == 1.0

@@ -20,7 +20,7 @@ import pytest
 from veneur_tpu import observe
 from veneur_tpu.cluster import wire
 from veneur_tpu.cluster.importsrv import (ForwardHandler, ImportedBatch,
-                                          ImportedMetric)
+                                          start_import_server)
 from veneur_tpu.cluster.protos import forward_pb2, metric_pb2
 from veneur_tpu.config import read_config
 from veneur_tpu.ingest import parser
@@ -377,9 +377,7 @@ def test_one_request_is_one_queue_item_an_engine(transport, workers):
         finally:
             api.stop()
     else:
-        handler = ForwardHandler(
-            lambda digest, item: pytest.fail("routed metric by metric"),
-            submit_batch=srv._submit_import_batch)
+        handler = ForwardHandler(srv._submit_import_batch)
         if transport == "grpc":
             handler._send_metrics(request, _Ctx())
         else:
@@ -406,21 +404,129 @@ def test_one_request_is_one_queue_item_an_engine(transport, workers):
                                      key.joined_tags) % 2 == qi
 
 
-def test_handler_without_submit_batch_still_routes_metric_by_metric():
-    got = []
-    handler = ForwardHandler(lambda digest, item: got.append(item))
-    request = _request(10, 5)
-    handler._send_metrics(request, _Ctx())
-    assert [type(i) for i in got] == [ImportedMetric] * len(request.metrics)
+def test_forward_handler_needs_submit_batch():
+    """submit_batch is the one routing callback: no handler or import
+    server without it, and none around a per-metric `submit`."""
+    with pytest.raises(TypeError):
+        ForwardHandler()
+    with pytest.raises(TypeError):
+        ForwardHandler(submit=lambda digest, item: None)
+    with pytest.raises(TypeError):
+        start_import_server("127.0.0.1:0")
+
+
+def _record_puts(srv):
+    """Every item put on a worker queue of `srv`, as (queue, item), in
+    order — the worker threads may take them off at once."""
+    puts = []
+    for qi, q in enumerate(srv.worker_queues):
+        def put(item, qi=qi, orig=q._put):
+            puts.append((qi, item))
+            orig(item)
+        q._put = put
+    return puts
+
+
+@pytest.mark.parametrize("transport", ["grpc", "grpc_v2_stream", "http"])
+def test_every_import_transport_hands_over_one_batch(transport):
+    """One request through each front end of a STARTED server — its
+    own listeners, real sockets — puts exactly one ImportedBatch an
+    engine that has a share on the worker queues, and nothing else."""
+    import grpc
+
+    from veneur_tpu.cluster.forward import SEND_METRICS, SEND_METRICS_V2
+
+    srv = _server('num_workers: 2\nis_global: true\n'
+                  'grpc_listen_addresses: ["127.0.0.1:0"]\n'
+                  'http_address: "127.0.0.1:0"\n')
+    request = forward_pb2.MetricList()
+    for i in range(24):
+        _counter(request, f"svc.hits.k{i}", 3)
+    want = sorted(pb.name for pb in request.metrics)
+    srv.start()
+    try:
+        puts = _record_puts(srv)
+        if transport == "http":
+            body = [{"name": pb.name, "type": "counter", "value": 3,
+                     "tags": []} for pb in request.metrics]
+            req = urllib.request.Request(
+                f"http://127.0.0.1:{srv.http_api.port}/import",
+                data=json.dumps(body).encode(),
+                headers={"Content-Type": "application/json"},
+                method="POST")
+            with urllib.request.urlopen(req, timeout=10) as resp:
+                assert json.loads(resp.read())["imported"] == len(want)
+        else:
+            with grpc.insecure_channel(
+                    f"127.0.0.1:{srv.grpc_port}") as ch:
+                empty = forward_pb2.Empty.FromString
+                if transport == "grpc":
+                    ch.unary_unary(
+                        SEND_METRICS,
+                        request_serializer=forward_pb2.MetricList
+                        .SerializeToString,
+                        response_deserializer=empty)(request, timeout=10)
+                else:
+                    ch.stream_unary(
+                        SEND_METRICS_V2,
+                        request_serializer=metric_pb2.Metric
+                        .SerializeToString,
+                        response_deserializer=empty)(
+                        iter(request.metrics), timeout=10)
+        assert srv.drain(10.0)
+        assert all(isinstance(item, ImportedBatch) for _, item in puts)
+        assert sorted(qi for qi, _ in puts) == [0, 1]
+        assert len({item.op_id for _, item in puts}) == 1
+        assert sorted(pb.name for _, item in puts
+                      for pb in item.pbs) == want
+    finally:
+        srv.stop()
+
+
+def test_fold_rerouted_from_inside_a_batch_keeps_its_op_id():
+    """Two engines, a prefix budget of two keys: an over-budget
+    forwarded counter whose fold key is homed on the OTHER engine
+    leaves the batch it came in and arrives there as an ImportedBatch
+    of one, rewritten onto the fold key, under the request's op id —
+    and the fold row counts every folded value once."""
+    srv = _server("num_workers: 2\noverload_defense_enabled: true\n"
+                  "overload_max_keys_per_prefix: 2\n")
+    request = forward_pb2.MetricList()
+    for k in range(12):
+        _counter(request, f"imp.c{k}", k + 1)
+    srv.start()
+    try:
+        puts = _record_puts(srv)
+        assert srv._submit_import_batch(list(request.metrics)) == 12
+        assert srv.drain(10.0)
+        first, rerouted = puts[:2], puts[2:]
+        assert sorted(qi for qi, _ in first) == [0, 1]
+        op_id = first[0][1].op_id
+        home = [qi for qi, eng in enumerate(srv.engines)
+                if any(k.name == "imp.__other__"
+                       for k in eng.counter_keys._map)]
+        assert len(home) == 1
+        assert rerouted                  # some fold came from the other
+        for qi, item in rerouted:
+            assert isinstance(item, ImportedBatch)
+            assert (qi, item.op_id) == (home[0], op_id)
+            assert [pb.name for pb in item.pbs] == ["imp.__other__"]
+        by = {m.name: m.value for m in srv.flush_once(timestamp=10)}
+        kept = [v for n, v in by.items() if n.startswith("imp.c")]
+        assert len(kept) == 2
+        assert sum(kept) + by["imp.__other__"] == 78.0
+        assert sum(eng.last_import_op == op_id
+                   for eng in srv.engines) == 2
+    finally:
+        srv.stop()
 
 
 @pytest.mark.parametrize("items, weight", [
     ([ImportedBatch(1, [object()] * 5)], 5),
     ([ImportedBatch(1, [])], 1),
-    ([ImportedMetric(object())], 1),
-    ([ImportedBatch(1, [object()] * 3), ImportedMetric(object()),
+    ([ImportedBatch(1, [object()] * 3), parser.parse_packet(b"x:1|c"),
       ImportedBatch(2, [object()] * 9)], 13),
-], ids=["batch", "empty_batch", "metric", "mixed"])
+], ids=["batch", "empty_batch", "mixed"])
 def test_worker_queue_counts_sketches_waiting(items, weight):
     q = _WorkerQueue(maxsize=100)
     for item in items:

@@ -260,33 +260,6 @@ def test_mesh_global_tier_adversarial_landing():
             assert abs(got - exp) / exp < 0.02, (k, q, got, exp)
 
 
-@pytest.mark.parametrize("mode", ["staged", "async"])
-def test_mesh_flush_fetch_modes(mode):
-    """Mesh flush under non-sync fetch modes matches sync results (the
-    modes only change how the merged outputs leave the mesh)."""
-    from veneur_tpu.ingest import parser
-
-    def build(m):
-        eng = MeshAggregationEngine(EngineConfig(
-            histogram_slots=64, counter_slots=32, gauge_slots=32,
-            set_slots=16, buffer_depth=32, batch_size=256,
-            percentiles=(0.5, 0.9), aggregates=("min", "max", "count"),
-            flush_fetch=m), n_devices=8)
-        eng.warmup()
-        rng = np.random.default_rng(11)
-        for k in range(8):
-            for x in rng.gamma(2.0, 20.0, 30):
-                eng.process(parser.parse_packet(
-                    f"t{k}:{x:.4f}|ms".encode()))
-        eng.process(parser.parse_packet(b"c:3|c"))
-        return {m2.name: m2.value for m2 in eng.flush(timestamp=5).metrics}
-
-    ref, got = build("sync"), build(mode)
-    assert got.keys() == ref.keys()
-    for k in ref:
-        np.testing.assert_allclose(got[k], ref[k], rtol=1e-6, err_msg=k)
-
-
 def test_mesh_import_keeps_forwarded_extremes_exact():
     """A forwarded digest's centroid means are cumsum differences and
     can sit a few ulp outside its exact [min, max]. The mesh engine

@@ -983,7 +983,7 @@ class TestDeepGroupNestingParity:
     """Unknown-field group nesting past the native depth cap must FALL
     BACK to the Python decoder (rc 0), not error (rc -1): the
     google.protobuf runtime accepts deeper well-formed groups, so a
-    native reject would be a parity divergence (ADVICE r5 / vlint NA02).
+    native reject would be a parity divergence (round-5 advisory / vlint NA02).
     The cap itself has one definition on each side, asserted equal."""
 
     def _bridge(self):
@@ -1037,7 +1037,7 @@ class TestDeepGroupNestingParity:
 class TestTagEntryFieldOmission:
     """A map<string,string> entry may omit field 1 (key) or 2 (value)
     entirely — the raw pointers stay null in the native parser. The
-    fixed path clear()s instead of assign(nullptr, 0) (UB; ADVICE r5 /
+    fixed path clear()s instead of assign(nullptr, 0) (UB; round-5 advisory /
     vlint NA01) and must agree with the Python decoder, which yields ""
     for the omitted half."""
 

@@ -211,8 +211,7 @@ def _feed(srv, n_keys=64, n_per_key=32):
 
 def test_flush_tick_phase_coverage_at_least_95pct():
     """The acceptance gate: completed top-level phases must account for
-    >= 95% of the measured tick wall time (the same accounting
-    BENCH_SUITE_r07 records at the 100k-histogram config)."""
+    >= 95% of the measured tick wall time."""
     srv, cap = _mk_server()
     try:
         _feed(srv)

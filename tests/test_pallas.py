@@ -262,7 +262,7 @@ def test_one_pallas_dispatch_per_bucket():
     heng = TDigestEngine(compression=100.0, buffer_depth=256)
     seng = HLLEngine(precision=10)
     body = pipeline._flush_program_body(
-        heng, seng, False, ("min", "max", "count"), False, False,
+        heng, seng, False, ("min", "max", "count"), False,
         kernel_arm="interpret")
     qs = np.asarray([0.5, 0.99], np.float32)
     jaxpr = jax.make_jaxpr(body)(

@@ -26,7 +26,7 @@ from veneur_tpu.models.pipeline import AggregationEngine, EngineConfig
 
 def test_flight_recorder_overhead_under_1pct_of_tick():
     """ISSUE 6 gate: recorder overhead < 1% of tick wall time at the
-    1.6k-sketch config (bench_suite c12/c13's shape). Measured as
+    1.6k-sketch config. Measured as
     (phase edges per tick) x (measured per-edge cost) against the
     measured tick, not as an on/off wall A/B — a sub-1% wall delta is
     below CI timing noise, while the per-edge cost (one monotonic_ns
@@ -98,8 +98,7 @@ tpu_buffer_depth: 256
 
 def test_admission_overhead_under_2pct_of_parse_cost():
     """ISSUE 7 gate: the DISENGAGED overload defense must cost < 2% of
-    packet-parse cost in steady state (BENCH_SUITE_r08 c14's tier-1
-    twin). Measured as an edge model, not a wall A/B (a 2% wall delta
+    packet-parse cost in steady state. Measured as an edge model, not a wall A/B (a 2% wall delta
     sits inside CI scheduler noise): the defense's entire steady-state
     footprint on the ingest hot path is one attribute-load + None check
     + shed_rate compare per DATAGRAM plus one float compare per line —
@@ -233,12 +232,10 @@ def test_fused_flush_100k_slots_under_threshold():
     """The north-star cardinality on the CPU backend (VERDICT r4 weak-6:
     the 100k regime the benchmarks headline was CI-blind). Loose gate —
     the structural cost is the single-core merge-path compress
-    (buffer-only packed radix sort + bitonic rank-merge; BENCH_r06
-    pins 9751ms vs the 19235ms full-row comparator sort it replaced on
-    the worst-case bank) plus interp/aggregates. 40s of process CPU
-    time catches a doubling (an
-    extra compress pass, a de-fused dispatch, a silent fallback to the
-    full-sort arm) without flaking on box noise."""
+    (buffer-only packed radix sort + bitonic rank-merge) plus
+    interp/aggregates. 40s of process CPU time catches a doubling (an
+    extra compress pass, a de-fused dispatch) without flaking on box
+    noise."""
     K = 100_000
     eng = AggregationEngine(EngineConfig(
         histogram_slots=K, counter_slots=64, gauge_slots=64,
@@ -281,7 +278,7 @@ def test_empty_flush_cpu_cost_does_not_grow():
 
 
 def test_engine_checkpoint_steady_state_under_10pct_of_tick():
-    """ISSUE 9 gate (BENCH_SUITE_r10 c16's tier-1 twin): the flush-
+    """ISSUE 9 gate: the flush-
     boundary engine checkpoint must cost < 10% of the flush tick at
     the ~1.6k-sketch c12 shape. The checkpoint runs AFTER the swap, so
     its steady-state work is the delta encoding's degenerate case —

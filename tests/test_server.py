@@ -172,6 +172,25 @@ def test_example_yaml_is_complete_and_loads():
     assert cfg.tpu_compression == 100.0
 
 
+def test_readme_names_only_files_that_exist():
+    """Every backticked repo-relative path in README's "Where the
+    evidence lives" and "Layout" sections is in the tree."""
+    import re
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    text = open(os.path.join(root, "README.md")).read()
+    named = []
+    for title in ("Where the evidence lives", "Layout"):
+        body = text.split(f"## {title}\n", 1)[1].split("\n## ", 1)[0]
+        named += [t for t in re.findall(r"`([\w./-]+)`", body)
+                  if "/" in t or t.endswith((".md", ".py", ".json",
+                                             ".jsonl", ".yaml"))]
+    assert len(named) > 15
+    missing = [t for t in named
+               if not os.path.exists(os.path.join(root, t))]
+    assert not missing, missing
+
+
 def test_config_validation_rejects_nonsense():
     with pytest.raises(ValueError):
         read_config(text="percentiles: [1.5]")
@@ -193,6 +212,48 @@ def test_config_validation_rejects_nonsense():
     # lenient like the reference: unknown aggregates warn, don't fail
     cfg = read_config(text="aggregates: ['count', 'p9999']")
     assert cfg.aggregates == ["count", "p9999"]
+
+
+@pytest.mark.parametrize("key, value", [
+    ("tpu_flush_fetch", "staged"), ("tpu_flush_fetch_f16", "true")])
+def test_retired_keys_warn_and_load(key, value, caplog):
+    """A config that still sets a retired fetch key loads as any
+    unknown key does — warned and ignored — and the server flushes
+    what it flushes without it."""
+    import logging
+
+    base = """
+interval: "3600s"
+statsd_listen_addresses: []
+percentiles: [0.5]
+aggregates: ["min", "max", "count"]
+hostname: testhost
+tpu_histogram_slots: 64
+tpu_counter_slots: 64
+tpu_gauge_slots: 64
+tpu_set_slots: 32
+"""
+    from veneur_tpu.ingest.parser import parse_packet
+
+    def flushed(text):
+        srv = Server(read_config(text=text), sinks=[CaptureMetricSink()],
+                     plugins=[], span_sinks=[])
+        srv.start()
+        try:
+            for line in ([b"r.hits:7|c", b"r.temp:70|g", b"r.u:a|s"]
+                         + [b"r.lat:%d|ms" % v for v in range(1, 101)]):
+                srv._route_metric(parse_packet(line))
+            assert srv.drain(10.0)
+            return {m.name: m.value
+                    for m in srv.flush_once(timestamp=10)
+                    if m.name.startswith("r.")}
+        finally:
+            srv.stop()
+
+    with caplog.at_level(logging.WARNING, logger="veneur_tpu.config"):
+        got = flushed(base + f"{key}: {value}\n")
+    assert f"unknown config key {key!r} ignored" in caplog.text
+    assert got == flushed(base) and got["r.lat.count"] == 100.0
 
 
 @pytest.mark.slow
@@ -338,7 +399,7 @@ def test_key_churn_soak_bounded_state():
 
 def test_native_listeners_receive_configured_rcvbuf(monkeypatch):
     """Both native UDP listeners — statsd AND SSF — must be started
-    with the configured read buffer size (ADVICE r5 / vlint CF01
+    with the configured read buffer size (round-5 advisory / vlint CF01
     exemplar: start_ssf_udp used to be started on the ~208KB kernel
     default while start_udp got the configured 2MB)."""
     import pytest as _pytest

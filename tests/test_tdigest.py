@@ -187,10 +187,9 @@ def test_empty_bank():
 # `overflow_rows`, so the row arm runs at sizes the CPU likes. ---------
 
 _COUNTED = jax.jit(tdigest._add_batch_counted,
-                   static_argnames=("compression", "full_sort",
-                                    "overflow_rows"))
+                   static_argnames=("compression", "overflow_rows"))
 _COMPRESS = jax.jit(tdigest._compress_impl,
-                    static_argnames=("compression", "full_sort"))
+                    static_argnames=("compression",))
 _OV_K, _OV_B = 64, 16
 _BUFFERED = ("mean", "weight", "buf_value", "buf_weight", "buf_n")
 
@@ -411,3 +410,22 @@ def test_hot_keys_at_the_cells_shape_stay_in_the_rank_contract(
         assert float(bank.vmax[k]) == mine.max()
         assert abs(q[k, 0] - want[0]) / want[0] < 0.01
         assert abs(q[k, 1] - want[1]) / want[1] < 0.02
+
+
+def test_full_sort_env_is_inert(monkeypatch):
+    """VENEUR_TPU_TDIGEST_FULL_SORT was the switch to the retired
+    full-row-sort arm: set, it changes no program and warns of
+    nothing."""
+    import warnings
+
+    bank = jax.eval_shape(lambda: tdigest.init(64))
+    unset = jax.jit(tdigest._compress_impl,
+                    static_argnames=("compression",)).lower(
+        bank, compression=100.0).as_text()
+    monkeypatch.setenv("VENEUR_TPU_TDIGEST_FULL_SORT", "1")
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        set_ = jax.jit(tdigest._compress_impl,
+                       static_argnames=("compression",)).lower(
+            bank, compression=100.0).as_text()
+    assert set_ == unset

@@ -3,14 +3,17 @@
 The sorted-run merge compress must reproduce the legacy full-row
 comparator sort BIT-FOR-BIT — value order is load-bearing for the ±1%
 accuracy contract, so the rewrite is only safe if the outputs are
-indistinguishable, not merely close. Every test here compares the two
-arms (`full_sort=True` vs the merge-path default) through the f32 bit
+indistinguishable, not merely close. Every test here compares the
+serving compress with a full-row-sort reference built here from
+`_cluster_core(..., sorted_prefix=0)` through the f32 bit
 patterns (NaN-safe, sign-of-zero-exact), on adversarial banks:
 duplicate values, ±0.0 mixes, empty rows, inf-padded empties, rows
 mid-overflow-loop. Oracle parity for the new path rides in
 tests/test_tdigest.py, whose whole suite runs through the merge arm by
 default.
 """
+
+import functools
 
 import numpy as np
 import pytest
@@ -34,11 +37,28 @@ def assert_banks_identical(old, new):
             f"bank field {field} diverged between sort arms"
 
 
+@functools.partial(jax.jit, static_argnames="comp")
+def _whole_row_cluster(bank, comp):
+    """The reference: the concatenated centroid+buffer row through the
+    full-row sort (no ordered-prefix assumption)."""
+    vals = jnp.concatenate([bank.mean, bank.buf_value], axis=1)
+    wts = jnp.concatenate([bank.weight, bank.buf_weight], axis=1)
+    return tdigest._cluster_core(vals, wts, comp, bank.mean.shape[1],
+                                 sorted_prefix=0)
+
+
+def whole_row_compress(bank, comp):
+    mean, weight = _whole_row_cluster(bank, comp)
+    return bank._replace(
+        mean=mean, weight=weight,
+        buf_value=jnp.zeros_like(bank.buf_value),
+        buf_weight=jnp.zeros_like(bank.buf_weight),
+        buf_n=jnp.zeros_like(bank.buf_n))
+
+
 def compress_both(bank, comp):
-    old = jax.jit(lambda b: tdigest._compress_impl(
-        b, comp, full_sort=True))(bank)
-    new = jax.jit(lambda b: tdigest._compress_impl(
-        b, comp, full_sort=False))(bank)
+    old = whole_row_compress(bank, comp)
+    new = jax.jit(lambda b: tdigest._compress_impl(b, comp))(bank)
     return old, new
 
 
@@ -112,19 +132,28 @@ def test_compress_arms_bitwise_identical_randomized(seed):
 
 def test_add_batch_overflow_loop_arms_identical():
     """Rows mid-overflow-loop: a batch far larger than the buffer runs
-    compress inside the while_loop body — both arms must land the
-    identical bank."""
+    compress inside the while_loop body — it must land what the
+    full-row-sort reference lands when applied buffer by buffer (write
+    a buffer's worth, compress while more waits, go round)."""
     rng = np.random.default_rng(7)
-    n = 3000
+    n, B, comp = 3000, 64, 50.0
     slots = np.zeros(n, np.int32)
     vals = np.round(rng.gamma(2.0, 20.0, n) * 2).astype(np.float32) / 2
     wts = rng.integers(1, 3, n).astype(np.float32)
-    banks = {}
-    for flag in (True, False):
-        bank = tdigest.init(2, compression=50.0, buf_size=64)
-        banks[flag] = tdigest.add_batch(
-            bank, slots, vals, wts, compression=50.0, full_sort=flag)
-    assert_banks_identical(banks[True], banks[False])
+    got = tdigest.add_batch(tdigest.init(2, compression=comp, buf_size=B),
+                            slots, vals, wts, compression=comp)
+    ref = tdigest.init(2, compression=comp, buf_size=B)
+    for at in range(0, n, B):
+        if at:
+            ref = whole_row_compress(ref, comp)
+        m = min(B, n - at)
+        ref = ref._replace(
+            buf_value=ref.buf_value.at[0, :m].set(vals[at:at + m]),
+            buf_weight=ref.buf_weight.at[0, :m].set(wts[at:at + m]),
+            buf_n=ref.buf_n.at[0].set(m))
+    for field in ("mean", "weight", "buf_value", "buf_weight", "buf_n"):
+        assert bits_eq(getattr(ref, field), getattr(got, field)), \
+            f"bank field {field} diverged from the full-sort reference"
 
 
 def test_cluster_rows_sorted_prefix_arm_identical():

@@ -479,9 +479,8 @@ def test_import_batch_counters_drain_as_self_metrics(workers):
     the engines applied in the interval and the forwarded metrics in
     them (one batch a request and engine), drained like
     samples.processed (present at zero, reset a flush); the same two
-    numbers in each engine's _last_flush_info. A metric routed alone
-    (ImportedMetric) is no batch."""
-    from veneur_tpu.cluster.importsrv import ImportedMetric
+    numbers in each engine's _last_flush_info. A request of one metric
+    is a batch of one, on the one engine it is homed on."""
     from veneur_tpu.cluster.protos import metric_pb2
 
     def counters(n, start=0):
@@ -502,21 +501,23 @@ def test_import_batch_counters_drain_as_self_metrics(workers):
     try:
         assert srv._submit_import_batch(counters(40)) == 40
         assert srv._submit_import_batch(counters(25, start=40)) == 25
-        srv._route_metric(ImportedMetric(counters(1, start=99)[0]))
+        assert srv._submit_import_batch(counters(1, start=99)) == 1
         assert srv.drain(10.0)
         srv.flush_once(timestamp=1)
         cap.wait_for_flush(1)
         infos = [eng._last_flush_info for eng in srv.engines]
-        # two requests, each one batch an engine that had a share
-        assert [i["import_batches"] for i in infos] == [2] * workers
-        assert sum(i["import_metrics"] for i in infos) == 65
+        # two requests, each one batch an engine that had a share,
+        # and the request of one
+        assert sorted(i["import_batches"] for i in infos) == \
+            [2] * (workers - 1) + [3]
+        assert sum(i["import_metrics"] for i in infos) == 66
         srv.flush_once(timestamp=2)
         cap.wait_for_flush(2)
         first, second = ({m.name: m.value for m in f
                           if m.name.startswith("veneur.import.batch")}
                          for f in cap.flushes[:2])
-        assert first == {"veneur.import.batches_total": 2 * workers,
-                         "veneur.import.batch_metrics_total": 65}
+        assert first == {"veneur.import.batches_total": 2 * workers + 1,
+                         "veneur.import.batch_metrics_total": 66}
         assert second == {"veneur.import.batches_total": 0,
                           "veneur.import.batch_metrics_total": 0}
         assert all(eng._last_flush_info["import_batches"] == 0

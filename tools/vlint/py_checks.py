@@ -441,7 +441,7 @@ def check_jx03(mod: PyModule, config: dict) -> list[Violation]:
                 mod.path, node.lineno, "JX03",
                 f"{fn}() outside the flush/fetch modules — host sync "
                 "in serving code stalls the dispatch pipeline; move it "
-                "behind the engine's flush_fetch path or suppress with "
+                "into the flush module (models/pipeline.py) or suppress with "
                 "a reason"))
     return out
 

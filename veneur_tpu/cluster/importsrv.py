@@ -46,17 +46,6 @@ def _decode_metric_list(data: bytes):
     return request, t0, time.monotonic_ns()
 
 
-class ImportedMetric:
-    """Worker-queue envelope for ONE forwarded metricpb.Metric: what a
-    handler built without `submit_batch` routes (tests, embedders).
-    The Server's handlers route ImportedBatch."""
-
-    __slots__ = ("pb",)
-
-    def __init__(self, pb):
-        self.pb = pb
-
-
 class ImportedBatch:
     """Worker-queue envelope for one import request's share of metrics
     for ONE engine: the unit that travels from the request handler to
@@ -296,25 +285,24 @@ class DedupeLedger:
 class ForwardHandler(grpc.GenericRpcHandler):
     """grpc.GenericRpcHandler serving forwardrpc.Forward."""
 
-    def __init__(self, submit, ledger: DedupeLedger | None = None,
+    def __init__(self, submit_batch,
+                 ledger: DedupeLedger | None = None,
                  registry: ResilienceRegistry | None = None,
-                 observer=None, submit_batch=None,
+                 observer=None,
                  engine_stamp: str | None = None, note_stamp=None,
                  merge_sketches=None):
-        """`submit(worker_index_hash, ImportedMetric)` routes one metric;
-        the Server provides a queue-backed implementation. `ledger`
-        (optional) dedupes envelope-bearing requests. `observer`
-        (optional, an observe.ImportObserver) records each request's
-        decode/dedupe/route phases in the import ring, replays them as SSF
-        spans parented on the remote sender's flush span, and feeds
-        the per-sender fleet view — observability only, it never
-        changes what is admitted or applied. `submit_batch` (optional,
-        `submit_batch(metrics, envelope) -> routed count`) routes one
-        request's metrics as a unit and replaces `submit` when given:
-        the Server's implementation puts ONE ImportedBatch an engine
-        on the worker queues, after write-aheading the request to the
-        engine journal where that is armed, so an admitted-and-acked
-        interval survives a receiver crash.
+        """`submit_batch(metrics, envelope) -> routed count` routes one
+        request's metrics as a unit: the Server's implementation puts
+        ONE ImportedBatch an engine on the worker queues, after
+        write-aheading the request to the engine journal where that is
+        armed, so an admitted-and-acked interval survives a receiver
+        crash. `ledger` (optional) dedupes envelope-bearing requests.
+        `observer` (optional, an observe.ImportObserver) records each
+        request's decode/dedupe/route phases in the import ring,
+        replays them as SSF spans parented on the remote sender's
+        flush span, and feeds the per-sender fleet view —
+        observability only, it never changes what is admitted or
+        applied.
 
         `engine_stamp` (the server's sketch-engine/wire stamp, ISSUE
         10): requests whose declared stamp — or implied legacy
@@ -325,7 +313,6 @@ class ForwardHandler(grpc.GenericRpcHandler):
         (counted + per-sender /debug/fleet rows); `merge_sketches`
         receives a request's advisory per-prefix cardinality rows
         (the fleet-wide cardinality satellite)."""
-        self._submit = submit
         self._submit_batch = submit_batch
         self._ledger = ledger
         self._registry = registry or DEFAULT_REGISTRY
@@ -349,33 +336,12 @@ class ForwardHandler(grpc.GenericRpcHandler):
                 response_serializer=forward_pb2.Empty.SerializeToString)
         return None
 
-    def _route(self, m):
-        # poison-pill guard: one malformed metric (bad key bytes, a
-        # decoder error) must reject THAT metric, not kill the
-        # receive path (veneur.import.rejected_total; the worker-side
-        # Combine guard in server._worker_loop covers decode errors
-        # that only surface at apply time)
-        try:
-            digest = wire.metric_digest_of(m)
-        except Exception as e:
-            self._registry.incr("import", "import.rejected")
-            log.warning("rejected unroutable imported metric: %s", e)
-            return
-        self._submit(digest, ImportedMetric(m))
-
     def _route_all(self, metrics, env=None) -> int:
-        """Route one request's metrics: ONE batch-submit call when the
-        server provided one — the request travels to the engines as a
-        unit, grouped by target engine there, and the write-ahead
-        journal sees it as ONE op with its admitted envelope before
-        any queue does — else the per-metric submit. Returns the
+        """Route one request's metrics in ONE submit_batch call: the
+        request travels to the engines as a unit, grouped by target
+        engine there, and the write-ahead journal sees it as ONE op
+        with its admitted envelope before any queue does. Returns the
         routed count."""
-        if self._submit_batch is None:
-            n = 0
-            for m in metrics:
-                self._route(m)
-                n += 1
-            return n
         if not hasattr(metrics, "__len__"):
             metrics = list(metrics)     # an unmaterialized V2 stream
         return self._submit_batch(metrics, env)
@@ -512,18 +478,18 @@ class ForwardHandler(grpc.GenericRpcHandler):
         return forward_pb2.Empty()
 
 
-def start_import_server(address: str, submit, max_workers: int = 8,
+def start_import_server(address: str, submit_batch, max_workers: int = 8,
                         ledger: DedupeLedger | None = None,
                         registry: ResilienceRegistry | None = None,
-                        observer=None, submit_batch=None,
+                        observer=None,
                         engine_stamp: str | None = None,
                         note_stamp=None, merge_sketches=None):
     """Bind a gRPC server for the Forward service; returns (server, port)."""
     server = grpc.server(
         futures.ThreadPoolExecutor(max_workers=max_workers))
     server.add_generic_rpc_handlers(
-        (ForwardHandler(submit, ledger=ledger, registry=registry,
-                        observer=observer, submit_batch=submit_batch,
+        (ForwardHandler(submit_batch, ledger=ledger, registry=registry,
+                        observer=observer,
                         engine_stamp=engine_stamp,
                         note_stamp=note_stamp,
                         merge_sketches=merge_sketches),))

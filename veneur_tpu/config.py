@@ -241,7 +241,8 @@ class Config:
     # forward ladder / journal ops) into a bounded ring served by
     # GET /debug/flush and replayed as SSF spans through the server's
     # own trace client. Overhead is one monotonic_ns stamp + index bump
-    # per phase edge (bench_suite c13 pins it under 1% of the tick).
+    # per phase edge (tests/test_perf_regression.py gates it under 1% of
+    # the tick).
     flight_recorder: bool = True
     flight_recorder_ticks: int = 32        # ring: last N ticks kept
     flight_recorder_max_phases: int = 192  # per-tick phase slot budget
@@ -338,16 +339,6 @@ class Config:
     # 0 or 1 = single-device engines on the first device JAX reports;
     # N > 1 = ONE mesh engine sharded over the first N devices.
     tpu_num_devices: int = 0
-    # Flush-result fetch strategy: "sync" (one device_get of the flush
-    # program's outputs) | "staged" (fetch a jitted copy's outputs) |
-    # "host" (copy into pinned host memory inside a program) | "async"
-    # (copy_to_host_async per leaf first). See EngineConfig.flush_fetch.
-    tpu_flush_fetch: str = "sync"
-    # Compact wire mode: quantile/min/max columns fetched as f16 with
-    # sentinel-gated full-precision fallback; count/sum stay exact.
-    # Halves the flush fetch where that transfer bounds the flush. Not
-    # supported with multi-device engines.
-    tpu_flush_fetch_f16: bool = False
     # Incremental dirty-slot flush (ISSUE 11): the flush program
     # consumes the delta-checkpoint dirty bitmap and compresses/
     # materializes ONLY the piles touched this interval — cold piles
@@ -384,12 +375,9 @@ class Config:
     native_ingest: bool = False
     native_ring_capacity: int = 1 << 20
     # Pump dispatch width (decoupled from tpu_batch_size, which sizes the
-    # per-sample staging path). Wider batches amortize per-dispatch cost
-    # (moderately on CPU — the t-digest scatter program is ~30ms/dispatch
-    # nearly flat in width; substantially on TPU, where dispatch+transfer
-    # overhead dominates the sub-ms kernel). 32k balances that against
-    # drain latency at flush time. See BENCH_SUITE c8_s5* and the
-    # buffer-aliasing note in NativePump._pump_bank.
+    # per-sample staging path). Wider batches amortize per-dispatch
+    # cost; 32k balances that against drain latency at flush time. See
+    # the buffer-aliasing note in NativePump._pump_bank.
     native_pump_batch: int = 1 << 15
 
     # populated by the loader, not a YAML key:
@@ -610,13 +598,6 @@ def _validate(cfg: Config) -> None:
             raise ValueError(
                 "non-default sketch backends are not supported with "
                 "tpu_num_devices > 1 (the mesh engine owns its banks)")
-    if cfg.tpu_flush_fetch_f16 and cfg.tpu_num_devices > 1:
-        raise ValueError(
-            "tpu_flush_fetch_f16 is not supported with tpu_num_devices > 1 "
-            "(the mesh flush program has its own wire layout)")
-    if cfg.tpu_flush_fetch not in ("sync", "staged", "host", "async"):
-        raise ValueError(
-            "tpu_flush_fetch must be one of sync/staged/host/async")
     if not (0.0 < cfg.tpu_flush_incremental_threshold <= 1.0):
         raise ValueError(
             "tpu_flush_incremental_threshold must be in (0, 1]: the "

@@ -93,7 +93,7 @@ def _crc32c_scalar(data: bytes, crc: int = 0) -> int:
 #
 # The flush tick CRCs the whole serialized interval (hundreds of KB);
 # the byte loop above runs ~4 MB/s in CPython, which would make the
-# checksum THE cost of durability (bench_suite config 12). CRC is
+# checksum THE cost of durability. CRC is
 # linear over GF(2), which buys a numpy formulation:
 #
 #   * split the message into L 64-byte lanes and run the byte loop over

@@ -75,10 +75,11 @@ def json_metric_to_pb(d: dict) -> metric_pb2.Metric:
 
 
 class HttpApi:
-    """The ops HTTP listener; `submit(digest, pb_metric)` routes an
-    imported metric onto a worker queue (the Server provides it)."""
+    """The ops HTTP listener; `submit_batch(metrics, envelope)` routes
+    a POST /import's metrics onto the worker queues (the Server
+    provides it)."""
 
-    def __init__(self, address: str, submit=None, healthy=None,
+    def __init__(self, address: str, healthy=None,
                  ledger=None, debug_state=None, profile=None,
                  observer=None, fleet_state=None, health=None,
                  submit_batch=None, engine_stamp=None, note_stamp=None,
@@ -111,10 +112,11 @@ class HttpApi:
 
         `submit_batch` (optional, `submit_batch(metrics, envelope) ->
         routed count`) routes one request's decoded metrics as a unit
-        and replaces `submit` when given — the Server's implementation
-        puts one ImportedBatch an engine on the worker queues, after
-        write-aheading the request to the engine journal where that is
-        armed (before the 200 ack either way).
+        — the Server's implementation puts one ImportedBatch an engine
+        on the worker queues, after write-aheading the request to the
+        engine journal where that is armed (before the 200 ack either
+        way). Without it the listener is ops-only and answers POST
+        /import with 503.
 
         `engine_stamp` (ISSUE 10): the server's sketch-engine/wire
         stamp; a POST /import whose declared stamp (or implied legacy
@@ -125,7 +127,6 @@ class HttpApi:
         `merge_sketches(items)`."""
         host, _, port = address.rpartition(":")
         host = host.strip("[]") or "0.0.0.0"
-        self._submit = submit
         self._submit_batch = submit_batch
         self._healthy = healthy or (lambda: True)
         self._ledger = ledger   # cluster.importsrv.DedupeLedger or None
@@ -261,7 +262,7 @@ class HttpApi:
                 if self.path != "/import":
                     self._reply(404, b"not found\n")
                     return
-                if api._submit is None and api._submit_batch is None:
+                if api._submit_batch is None:
                     self._reply(503, b"not a global veneur\n")
                     return
                 # jsonmetric-v1 contract: reject a declared format we
@@ -376,13 +377,7 @@ class HttpApi:
                         "application/json")
                     return
                 ph = -1 if scope is None else scope.start("route")
-                if api._submit_batch is not None:
-                    count = api._submit_batch(decoded, env)
-                else:
-                    count = 0
-                    for pb in decoded:
-                        api._submit(wire.metric_digest_of(pb), pb)
-                        count += 1
+                count = api._submit_batch(decoded, env)
                 if scope is not None:
                     scope.finish(ph, n_metrics=count)
                     scope.n_metrics = count

@@ -14,9 +14,8 @@ intermediates live in VMEM:
                 prefix + greedy k1 cluster/cummax-clamp — the whole
                 t-digest compress, one kernel invocation per bucket.
   ull_insert.py scatter-join insert for UltraLogLog register banks —
-                sequential lattice-join RMW replacing the XLA-CPU
-                sort + segmented-scan + gather path (~87us/member,
-                BENCH_SUITE_r11 c17).
+                sequential lattice-join RMW replacing the XLA
+                sort + segmented-scan + gather path.
   hll_stats.py  the streaming HLL estimate reduction (moved from
                 ops/pallas_hll.py — every pl.* primitive in the tree
                 now lives under this package, machine-checked by

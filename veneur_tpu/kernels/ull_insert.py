@@ -5,8 +5,7 @@ scatter-max — the ULL register state is only PARTIALLY ordered, so it
 sorts the batch by flat register address, collapses duplicates with a
 segmented associative scan of the lattice join, and lands the unique
 survivors with a gather-join-scatter. On XLA-CPU that scan is the
-single slowest sketch op in the tree (~87us/member, BENCH_SUITE_r11
-c17, vs ~1us for HLL's scatter-max).
+single slowest sketch op in the tree.
 
 This kernel is the scatter-join the lattice actually wants: ONE pass
 over the batch doing an in-place read-join-write per update against

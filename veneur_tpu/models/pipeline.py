@@ -7,7 +7,7 @@ Worker.ProcessMetric down through Server.Flush (worker.go, flusher.go):
                   batch shape) -> one scatter program per full batch
   flush tick:     ONE fused XLA program over all four banks (compress +
                   quantiles + aggregates + HLL estimate + scalar
-                  finalization) -> one device_get of compact arrays ->
+                  finalization) -> one device_get of its outputs ->
                   host assembles a columnar MetricFrame from the
                   slot->key map
 
@@ -28,7 +28,6 @@ Scope routing (flusher.go semantics):
 from __future__ import annotations
 
 import functools
-import logging
 import threading
 import time
 from dataclasses import dataclass, field as dc_field
@@ -44,8 +43,6 @@ from ..metrics import InterMetric, MetricFrame, MetricType
 from ..ops import scalar
 from ..utils import hashing
 from .worker import FOLD_SLOT, KeyInterner
-
-logger = logging.getLogger(__name__)
 
 
 # Widest per-slot centroid pile the import path will hand to one device
@@ -186,7 +183,7 @@ def _ingest_executables(device, heng, seng, set_arm="xla"):
 
 
 def _flush_program_body(heng, seng, fwd_out, agg_emit, pallas_ok,
-                        compact, kernel_arm="xla"):
+                        kernel_arm="xla"):
     """The flush computation itself — compress + quantiles + the
     configured aggregates + counter/gauge/set finalization — as a
     jit-composable closure over (hb, cb, gb, sb, qs). Shared by the
@@ -215,20 +212,6 @@ def _flush_program_body(heng, seng, fwd_out, agg_emit, pallas_ok,
                             is NOT a configured aggregate)
       c_hi/c_lo [Kc], g_value [Kg], g_seq i32[Kg], s_est [Ks]
       h_* / s_regs          raw forward-export state (fwd_out only)
-
-    `compact=True` (flush_fetch_f16) swaps the two big [K, ·] matrices
-    for a half-width wire encoding, halving the device->host fetch
-    where that transfer is what the flush waits on:
-      q16/lp16 f16          quantiles + non-exact aggregate columns
-      aggcols_hp            count/sum hi columns, f32 (exactness)
-      overflow_mag scalar   max |value| across q16/lp16's sources — the
-                            host re-fetches full precision iff any value
-                            sits in f16's saturation zone
-      lo_mag scalar         max |2Sum lo| — lo_count/lo_sum are fetched
-                            iff nonzero (they are zero in steady state)
-      q32/lp32, lo_*        full-precision twins, fetched lazily (see
-                            fetch_flush_outputs) — emitting them costs
-                            device memory, not wire
     """
     def program(hb, cb, gb, sb, qs):
         if kernel_arm != "xla" and hasattr(heng, "compress_fused_impl"):
@@ -246,50 +229,19 @@ def _flush_program_body(heng, seng, fwd_out, agg_emit, pallas_ok,
         # emits its device-side sufficient statistic and the host half
         # of estimate (estimate_finalize) finishes it after the fetch
         out.update(seng.estimate_device(sb, pallas_ok))
-        cols, hp_cols, lp_cols, lo_terms = [], [], [], []
+        cols = []
         for a in agg_emit:
             if a == "count":
-                hp_cols.append(hb.count)
                 out["lo_count"] = hb.count_lo
-                lo_terms.append(hb.count_lo)
                 cols.append(hb.count)
             elif a == "sum":
-                hp_cols.append(hb.vsum)
                 out["lo_sum"] = hb.vsum_lo
-                lo_terms.append(hb.vsum_lo)
                 cols.append(hb.vsum)
             else:
-                lp_cols.append(agg[a])
                 cols.append(agg[a])
-        if compact:
-            out["q16"] = q.astype(jnp.float16)
-            out["q32"] = q
-            mag = jnp.max(jnp.abs(q))
-            if hp_cols:
-                out["aggcols_hp"] = jnp.stack(hp_cols, axis=1)
-            if lp_cols:
-                lp = jnp.stack(lp_cols, axis=1)
-                out["lp16"] = lp.astype(jnp.float16)
-                out["lp32"] = lp
-                mag = jnp.maximum(mag, jnp.max(jnp.abs(lp)))
-            out["overflow_mag"] = mag
-            # smallest nonzero magnitude: values below f16's normal range
-            # (~6.1e-5) lose relative precision, so the host falls back
-            # to the full-precision twins for them too
-            srcs = [q] + ([lp] if lp_cols else [])
-            tiny = jnp.inf
-            for s in srcs:
-                tiny = jnp.minimum(tiny, jnp.min(
-                    jnp.where(s == 0, jnp.inf, jnp.abs(s))))
-            out["tiny_mag"] = tiny
-            out["lo_mag"] = (
-                jnp.max(jnp.stack([jnp.max(jnp.abs(t))
-                                   for t in lo_terms]))
-                if lo_terms else jnp.float32(0.0))
-        else:
-            out["q"] = q
-            if cols:
-                out["aggcols"] = jnp.stack(cols, axis=1)
+        out["q"] = q
+        if cols:
+            out["aggcols"] = jnp.stack(cols, axis=1)
         if "count" not in agg_emit:
             out["cnt"] = agg["count"]
         if fwd_out:
@@ -302,7 +254,7 @@ def _flush_program_body(heng, seng, fwd_out, agg_emit, pallas_ok,
 
 @functools.lru_cache(maxsize=None)
 def _flush_executable(device, heng, seng, fwd_out, agg_emit, pallas_ok,
-                      donate=True, compact=False, kernel_arm="xla"):
+                      kernel_arm="xla"):
     """The fused interval-flush program over the FULL banks: ONE XLA
     call over every slot (see _flush_program_body for the output
     contract). The incremental dirty-slot path (_inc_flush_executable)
@@ -311,19 +263,15 @@ def _flush_executable(device, heng, seng, fwd_out, agg_emit, pallas_ok,
     path above the dirty-fraction threshold."""
     sds = jax.sharding.SingleDeviceSharding(device)
     program = _flush_program_body(heng, seng, fwd_out, agg_emit,
-                                  pallas_ok, compact, kernel_arm)
+                                  pallas_ok, kernel_arm)
 
-    # donate=False builds a variant safe to dispatch repeatedly on the
-    # same banks (bench_suite's exec-only A/B rows); serving always
-    # donates. Donation audit (ISSUE 3 satellite): an argument is
-    # donated iff EVERY one of its leaves aliases an output of
-    # identical shape — partial donation is what made every compile
-    # warn "Some donated buffers were not usable" since r3. Counter and
-    # gauge banks always qualify (c_hi/c_lo, g_value/g_seq); nothing
-    # else does in the local-only build (the t-digest/HLL state reduces
-    # to compact [K, P']/[K] outputs).
-    if not donate:
-        return jax.jit(program, out_shardings=sds)
+    # Donation audit (ISSUE 3 satellite): an argument is donated iff
+    # EVERY one of its leaves aliases an output of identical shape —
+    # partial donation is what made every compile warn "Some donated
+    # buffers were not usable" since r3. Counter and gauge banks always
+    # qualify (c_hi/c_lo, g_value/g_seq); nothing else does in the
+    # local-only build (the t-digest/HLL state reduces to [K, P']/[K]
+    # outputs).
     if not fwd_out:
         return jax.jit(program, donate_argnums=(1, 2),
                        out_shardings=sds)
@@ -373,12 +321,10 @@ def _inc_bucket(n: int, num_slots: int) -> int:
     return min(b, num_slots)
 
 
-def pad_dirty_ids(ids, num_slots: int):
+def _pad_dirty_ids(ids, num_slots: int):
     """One bank's dirty-id vector padded to its _inc_bucket width with
     index 0 (padding rows duplicate row 0's compute; consumers read
-    only the true-D prefix) — the EXACT work-set shape
-    _flush_device_incremental dispatches, shared with bench_suite's
-    exec-only A/B so the bench can never drift to a stale shape."""
+    only the true-D prefix)."""
     b = _inc_bucket(max(ids.size, 1), num_slots)
     pad = np.zeros(b, np.int32)
     pad[:ids.size] = ids
@@ -387,7 +333,7 @@ def pad_dirty_ids(ids, num_slots: int):
 
 @functools.lru_cache(maxsize=None)
 def _inc_flush_executable(device, heng, seng, fwd_out, agg_emit,
-                          pallas_ok, compact=False, kernel_arm="xla"):
+                          pallas_ok, kernel_arm="xla"):
     """The INCREMENTAL interval-flush program (ISSUE 11 tentpole):
     gather only the dirty piles into a compact [D, ·] work set, run the
     SAME flush body (_flush_program_body) over that slice, and return
@@ -411,7 +357,7 @@ def _inc_flush_executable(device, heng, seng, fwd_out, agg_emit,
     ISSUE 3 audit pins at zero."""
     sds = jax.sharding.SingleDeviceSharding(device)
     program = _flush_program_body(heng, seng, fwd_out, agg_emit,
-                                  pallas_ok, compact, kernel_arm)
+                                  pallas_ok, kernel_arm)
 
     def gather(bank, idx):
         return jax.tree_util.tree_map(lambda leaf: leaf[idx], bank)
@@ -425,7 +371,7 @@ def _inc_flush_executable(device, heng, seng, fwd_out, agg_emit,
 
 @functools.lru_cache(maxsize=None)
 def _flush_baseline_cached(device, heng, seng, fwd_out, agg_emit,
-                           pallas_ok, compact, qs, kernel_arm="xla"):
+                           pallas_ok, qs, kernel_arm="xla"):
     """Empty-flush baseline rows (see _flush_baseline_rows), cached at
     module level so every engine with the same sketch pair + flush
     config shares one K=1 compile. Treat the returned rows as
@@ -435,13 +381,12 @@ def _flush_baseline_cached(device, heng, seng, fwd_out, agg_emit,
     accounting at /debug stays truthful)."""
     from ..ops import scalar as _scalar
     body = _flush_program_body(heng, seng, fwd_out, agg_emit,
-                               pallas_ok, compact, kernel_arm)
+                               pallas_ok, kernel_arm)
     fresh = jax.device_put(
         (heng.init(1), _scalar.init_counters(1),
          _scalar.init_gauges(1), seng.init(1)), device)
-    host = fetch_flush_outputs(
-        jax.jit(body)(*fresh, np.asarray(qs, np.float32)), "sync")
-    host = decompact_flush_host(host, agg_emit)
+    host = jax.device_get(
+        jax.jit(body)(*fresh, np.asarray(qs, np.float32)))
     if "s_est" in host or "s_counts" in host:
         seng.estimate_finalize(host)
     return {k: np.asarray(v)[0]
@@ -475,92 +420,6 @@ def _out_bank_kind(key: str) -> int:
     if key.startswith("s_"):
         return 3
     return 0
-
-
-def stage_copy_executable(sharding=None):
-    """A jitted tree-copy program used as a fetch 'staging' hop
-    (flush_fetch staged/host): the host fetch targets THIS cheap
-    executable's outputs instead of the serving program's.
-    `sharding=None` keeps the inputs' shardings (the mesh case)."""
-    kw = {} if sharding is None else {"out_shardings": sharding}
-    return jax.jit(lambda t: jax.tree_util.tree_map(jnp.copy, t), **kw)
-
-
-# compact-mode outputs that stay on device unless their sentinel scalar
-# says they're needed (full-precision twins + 2Sum lo terms)
-_LAZY_KEYS = ("q32", "lp32", "lo_count", "lo_sum")
-_F16_SAT = 61440.0      # |x| beyond this rounds into f16's overflow zone
-# f16 min normal (2^-14 exactly): below this, values encode as f16
-# subnormals with reduced relative precision, so the sentinel must sit
-# AT the boundary — 6.1e-5 (the old value) left a [6.1e-5, 2^-14) band
-# that skipped the full-precision refetch yet lost precision on the wire
-_F16_TINY = 2.0 ** -14
-
-
-def fetch_flush_outputs(out, mode: str, stage_exec=None):
-    """device_get under a flush_fetch mode — the one definition shared
-    by both engines and bench.py's mode probe.
-
-    Compact (f16 wire) outputs carry sentinel scalars; the full-precision
-    twins and 2Sum lo arrays ride along ONLY when a sentinel demands it
-    (out-of-range values, nonzero lo terms) — the common case moves half
-    the bytes. The rare second device_get is a plain sync fetch
-    whatever the mode."""
-    lazy = {}
-    if "lo_mag" in out:
-        lazy = {k: out[k] for k in _LAZY_KEYS if k in out}
-        out = {k: v for k, v in out.items() if k not in lazy}
-    if stage_exec is not None:
-        out = stage_exec(out)
-    elif mode == "async":
-        for leaf in jax.tree_util.tree_leaves(out):
-            leaf.copy_to_host_async()
-    host = jax.device_get(out)
-    if lazy:
-        need = []
-        if float(host["lo_mag"]) != 0.0:
-            need += [k for k in ("lo_count", "lo_sum") if k in lazy]
-        if (float(host["overflow_mag"]) >= _F16_SAT
-                or float(host["tiny_mag"]) < _F16_TINY):
-            need += [k for k in ("q32", "lp32") if k in lazy]
-        if need:
-            host.update(jax.device_get({k: lazy[k] for k in need}))
-    return host
-
-
-def decompact_flush_host(host: dict, agg_emit: tuple) -> dict:
-    """Rebuild the standard flush-host contract (q [K, P], aggcols
-    [K, A], lo_*) from a compact (f16 wire) fetch so the assembly code
-    is one implementation for both wire modes. No-op for standard
-    fetches."""
-    if "lo_mag" not in host:
-        return host
-    q = host.pop("q32", None)
-    host_q16 = host.pop("q16")
-    host["q"] = (np.asarray(host_q16, np.float32) if q is None
-                 else np.asarray(q))
-    lp = host.pop("lp32", None)
-    lp16 = host.pop("lp16", None)
-    if lp is None and lp16 is not None:
-        lp = np.asarray(lp16, np.float32)
-    hp = host.pop("aggcols_hp", None)
-    if agg_emit:
-        hi = li = 0
-        cols = []
-        for a in agg_emit:
-            if a in ("count", "sum"):
-                cols.append(np.asarray(hp[:, hi], np.float32))
-                hi += 1
-            else:
-                cols.append(np.asarray(lp[:, li], np.float32))
-                li += 1
-        host["aggcols"] = np.stack(cols, axis=1)
-    k = host["q"].shape[0]
-    if "count" in agg_emit and "lo_count" not in host:
-        host["lo_count"] = np.zeros(k, np.float32)
-    if "sum" in agg_emit and "lo_sum" not in host:
-        host["lo_sum"] = np.zeros(k, np.float32)
-    return host
 
 
 class ImportFoldReroute(Exception):
@@ -607,24 +466,6 @@ class EngineConfig:
     forward_enabled: bool = False
     is_global: bool = False      # global tier: emit percentiles for imports
     hostname: str = ""
-    # How flush results leave the device. "sync" is one device_get of
-    # the flush program's outputs. The alternatives move the transfer:
-    #   "staged" — a tiny jitted copy program re-materializes the outputs
-    #              and the fetch targets ITS outputs;
-    #   "host"   — the staging copy writes to pinned_host memory, putting
-    #              the D2H transfer inside the program (falls back to
-    #              "staged" when the backend lacks host memory kinds);
-    #   "async"  — copy_to_host_async on every leaf before the gather.
-    # No attached-chip run has shown any of them beating "sync"
-    # (ROADMAP: removal candidates once one shows sync is enough).
-    flush_fetch: str = "sync"
-    # Compact wire mode: quantile + inexact aggregate columns cross the
-    # device->host wire as f16 (half the fetch bytes @ >=2x fewer than
-    # the dominant [K, ·] matrices), with sentinel-gated fallback to the
-    # full-precision twins when values leave f16's safe range and to the
-    # 2Sum lo arrays when they are nonzero. count/sum stay f32+lo-exact.
-    # Worth it only where the device->host transfer bounds the flush.
-    flush_fetch_f16: bool = False
     # Incremental dirty-slot flush (ISSUE 11): the flush program
     # consumes the SAME dirty-slot bitmap the delta checkpoints mark at
     # every device-landing site, gathers only touched piles into a
@@ -799,37 +640,13 @@ class AggregationEngine:
             self._device, self._heng, self._seng, self._fwd_out,
             tuple(self._agg_emit),
             self._kernel_arms["estimate"] == "fused",
-            compact=cfg.flush_fetch_f16,
             kernel_arm=self._kernel_arms["histogram"])
-        self._stage_exec = None
-        mode = cfg.flush_fetch
-        if mode in ("staged", "host"):
-            if mode == "host":
-                # pinned_host support only shows up at compile/run time
-                # (CPU constructs the sharding fine, then fails with "no
-                # registered implementation ... for Host") — probe it.
-                try:
-                    stage = stage_copy_executable(
-                        jax.sharding.SingleDeviceSharding(
-                            self._device, memory_kind="pinned_host"))
-                    jax.device_get(stage(jnp.zeros(8, jnp.float32)))
-                    self._stage_exec = stage
-                except Exception:
-                    logger.warning("flush_fetch=host: backend lacks "
-                                   "pinned_host memory; using staged")
-            if self._stage_exec is None:
-                self._stage_exec = stage_copy_executable(
-                    jax.sharding.SingleDeviceSharding(self._device))
 
     def __init__(self, config: EngineConfig | None = None):
         self.cfg = config or EngineConfig()
         if self.cfg.buffer_depth < 8:
             raise ValueError("buffer_depth must be >= 8 (hot-slot "
                              "pre-clustering needs usable bucket room)")
-        if self.cfg.flush_fetch not in ("sync", "staged", "host", "async"):
-            raise ValueError(
-                f"flush_fetch={self.cfg.flush_fetch!r}: must be "
-                "sync/staged/host/async")
         if not (0.0 < self.cfg.flush_incremental_threshold <= 1.0):
             raise ValueError(
                 "flush_incremental_threshold must be in (0, 1]: it is "
@@ -1788,9 +1605,8 @@ class AggregationEngine:
 
     def _flush_device(self, snap, phases=None, dirty=None,
                       overflow=None) -> dict:
-        """Run the flush program on the snapshot and fetch the compact
-        host arrays: ONE program dispatch + ONE device_get.
-        `flush_fetch` picks how the fetch is performed (see EngineConfig).
+        """Run the flush program on the snapshot and fetch its outputs
+        as host arrays: ONE program dispatch + ONE device_get.
         Overridden by the mesh engine.
 
         `dirty` is the retired interval's dirty-slot bitmap set: when
@@ -1802,10 +1618,7 @@ class AggregationEngine:
         overflow counter) rides the same fetch into _last_flush_info.
 
         `phases` (flight-recorder stamp list, appended in place) splits
-        the merge into dispatch / device exec / fetch — but ONLY under
-        the sync fetch mode: the split's block_until_ready is one more
-        host sync, which the staged/host/async modes exist to avoid, so
-        those record one combined `device` phase instead."""
+        the merge into dispatch / device exec / fetch."""
         if dirty is not None and self._use_incremental:
             host = self._flush_device_incremental(snap, phases, dirty,
                                                   overflow)
@@ -1823,22 +1636,16 @@ class AggregationEngine:
         return self._timed_fetch(out, t0, t1, phases)
 
     def _timed_fetch(self, out, t0, t1, phases):
-        """Fetch flush outputs with the device.dispatch/exec/fetch (or
-        combined `device`) phase stamps — shared by the full and
-        incremental dispatch paths."""
-        if self.cfg.flush_fetch == "sync":
-            jax.block_until_ready(out)
-            t2 = time.monotonic_ns()
-            host = self._fetch_flush(out)
-            t3 = time.monotonic_ns()
-            phases.append(("device.dispatch", t0, t1))
-            phases.append(("device.exec", t1, t2))
-            phases.append(("device.fetch", t2, t3))
-        else:
-            host = self._fetch_flush(out)
-            t3 = time.monotonic_ns()
-            phases.append(("device.dispatch", t0, t1))
-            phases.append(("device", t1, t3))
+        """Fetch flush outputs with the device.dispatch/exec/fetch
+        phase stamps — shared by the full and incremental dispatch
+        paths."""
+        jax.block_until_ready(out)
+        t2 = time.monotonic_ns()
+        host = self._fetch_flush(out)
+        t3 = time.monotonic_ns()
+        phases.append(("device.dispatch", t0, t1))
+        phases.append(("device.exec", t1, t2))
+        phases.append(("device.fetch", t2, t3))
         return host
 
     def _flush_baseline_rows(self) -> dict:
@@ -1856,7 +1663,6 @@ class AggregationEngine:
                 self._device, self._heng, self._seng, self._fwd_out,
                 tuple(self._agg_emit),
                 self._kernel_arms["estimate"] == "fused",
-                self.cfg.flush_fetch_f16,
                 tuple(float(q) for q in self._qs),
                 kernel_arm=self._kernel_arms["histogram"])
         return self._flush_baseline
@@ -1896,13 +1702,12 @@ class AggregationEngine:
                 phases.append(("gather", t0, t1))
             return host
         hb, cb, gb, sb = snap
-        idx = [pad_dirty_ids(i, d.size) for d, i in zip(dirty, ids)]
+        idx = [_pad_dirty_ids(i, d.size) for d, i in zip(dirty, ids)]
         self._last_flush_info["buckets"] = [len(p) for p in idx]
         exec_ = _inc_flush_executable(
             self._device, self._heng, self._seng, self._fwd_out,
             tuple(self._agg_emit),
             self._kernel_arms["estimate"] == "fused",
-            compact=self.cfg.flush_fetch_f16,
             kernel_arm=self._kernel_arms["histogram"])
         t1 = time.monotonic_ns()
         if phases is not None:
@@ -1928,8 +1733,8 @@ class AggregationEngine:
         [D, ·] fetch: each per-slot output starts as its baseline row
         broadcast over the bank and the dirty rows overlay it — the
         assembly code downstream is one implementation for both
-        paths. Non-per-slot keys (the compact-mode sentinel scalars)
-        pass through."""
+        paths (every output of the flush body is per-slot, so every
+        key has a baseline row)."""
         out = {}
         for k, row in base.items():
             kind = _out_bank_kind(k)
@@ -1941,17 +1746,12 @@ class AggregationEngine:
             if v is not None and n:
                 full[ids[kind]] = np.asarray(v)[:n]
             out[k] = full
-        for k, v in host_c.items():
-            if k not in out:
-                out[k] = np.asarray(v)
         return out
 
     def _fetch_flush(self, out):
-        """device_get under the configured flush_fetch mode (shared with
-        the mesh engine's _flush_device)."""
-        host = fetch_flush_outputs(out, self.cfg.flush_fetch,
-                                   self._stage_exec)
-        host = decompact_flush_host(host, tuple(self._agg_emit))
+        """The flush's one device_get plus its host-side finishing
+        (shared with the mesh engine's _flush_device)."""
+        host = jax.device_get(out)
         # host half of the set estimate (ULL's ML solve; identity for
         # engines whose device program emits the finished estimate)
         if "s_est" in host or "s_counts" in host:

@@ -39,9 +39,7 @@ on XLA-CPU and the wrong one on the TPU, where a [131072, 512] row sort
 takes 22 ms and ONE row gather of that size 1.9 s. As first brought up
 on the v5e a full-bank compress took 16.6 s (the flush interval is
 10 s); with the row sort, a counted binary search and select-and-sum
-reads it takes 112 ms (my chip runs, PR 23). The full-row sort also
-stays selectable everywhere for the CPU A/B
-(VENEUR_TPU_TDIGEST_FULL_SORT=1 or the full_sort= argument).
+reads it takes 112 ms (my chip runs, PR 23).
 
 State layout (per bank):
   mean, weight : f32[K, C]   merged centroids (weight 0 == empty slot)
@@ -76,7 +74,6 @@ Semantics parity notes:
 from __future__ import annotations
 
 import math
-import os
 from functools import partial
 from typing import NamedTuple
 
@@ -87,37 +84,6 @@ from . import scatter
 from .scalar import _two_sum
 
 _INF = jnp.inf
-
-# A/B escape hatch: force the pre-merge-path full-row comparator sort in
-# every compress. Read at TRACE time (the environment is consulted when
-# each program first compiles, not at import), so setting it any time
-# before the first compile works; already-compiled programs keep the arm
-# they were traced with. DEPRECATED (ISSUE 11): the merge path has been
-# the serving default since ISSUE 3 with a 1.97x win on XLA-CPU and
-# bitwise A/B equivalence. On a TPU the switch changes nothing — both
-# arms are the plain row sort there (_sort_rows). Setting the flag
-# warns loudly so deployments migrate off it; ROADMAP has its removal.
-_warned_full_sort = False
-
-
-def _full_sort_default() -> bool:
-    on = os.environ.get("VENEUR_TPU_TDIGEST_FULL_SORT", "0") \
-        not in ("", "0")
-    global _warned_full_sort
-    if on and not _warned_full_sort:
-        _warned_full_sort = True
-        import logging
-        import warnings
-        msg = ("VENEUR_TPU_TDIGEST_FULL_SORT=1 forces the DEPRECATED "
-               "legacy full-row comparator sort in every t-digest "
-               "compress (~2x the merge-path cost, bitwise-identical "
-               "output) on XLA-CPU; on a TPU both arms are the row "
-               "sort. The flag is slated for removal (ROADMAP); unset "
-               "it unless running the bench A/B.")
-        warnings.warn(msg, DeprecationWarning, stacklevel=2)
-        logging.getLogger(__name__).warning(msg)
-    return on
-
 
 class TDigestBank(NamedTuple):
     mean: jax.Array        # f32[K, C]
@@ -182,8 +148,7 @@ def _k1(q, compression):
     return compression * (jnp.arcsin(2.0 * q - 1.0) + jnp.pi / 2.0) / jnp.pi
 
 
-def _compress_impl(bank: TDigestBank, compression: float,
-                   full_sort: bool | None = None) -> TDigestBank:
+def _compress_impl(bank: TDigestBank, compression: float) -> TDigestBank:
     """Merge every bank row's buffer into its centroid list.
 
     Equivalent of MergingDigest.mergeAllTemps, batched over K:
@@ -197,18 +162,13 @@ def _compress_impl(bank: TDigestBank, compression: float,
       3. cluster ids are non-decreasing per row, so per-cluster weighted
          sums reduce to diffs of row cumsums at cluster boundaries
          (searchsorted per row) — no sequential per-digest loop remains.
-
-    `full_sort` (or VENEUR_TPU_TDIGEST_FULL_SORT=1) forces the legacy
-    full-row sort — the A/B arm bench.py measures against.
     """
     K, C = bank.mean.shape
-    if full_sort is None:
-        full_sort = _full_sort_default()
 
     vals = jnp.concatenate([bank.mean, bank.buf_value], axis=1)
     wts = jnp.concatenate([bank.weight, bank.buf_weight], axis=1)
     new_mean, w_c = _cluster_core(vals, wts, compression, C,
-                                  sorted_prefix=0 if full_sort else C)
+                                  sorted_prefix=C)
 
     return bank._replace(
         mean=new_mean,
@@ -518,7 +478,7 @@ def _cluster_tail(vals, wts, compression: float, C: int, boundary_fn):
     return new_mean, w_c
 
 
-compress = partial(jax.jit, static_argnames=("compression", "full_sort"),
+compress = partial(jax.jit, static_argnames=("compression",),
                    donate_argnames=("bank",))(_compress_impl)
 
 
@@ -559,7 +519,6 @@ _OVERFLOW_ROWS = (1024,)
 
 def _add_batch_counted(bank: TDigestBank, slots, values, weights,
                        compression: float = 100.0,
-                       full_sort: bool | None = None,
                        overflow_rows: tuple | None = None):
     """Scatter a batch of (slot, value, weight) samples into the bank.
 
@@ -577,14 +536,11 @@ def _add_batch_counted(bank: TDigestBank, slots, values, weights,
     did before. `overflow_rows` is for tests (no caller sets it): None
     is the module's _OVERFLOW_ROWS.
     slot == -1 marks padding and is dropped via out-of-bounds scatter.
-    `full_sort` reaches the overflow loop's compress (A/B arm selection).
 
     Returns (bank, i32[2]): rows the row arms compressed, and passes of
     the whole-bank arm."""
     K = bank.num_slots
     B = bank.buf_size
-    if full_sort is None:
-        full_sort = _full_sort_default()
     if overflow_rows is None:
         overflow_rows = _OVERFLOW_ROWS
 
@@ -635,7 +591,7 @@ def _add_batch_counted(bank: TDigestBank, slots, values, weights,
                             bank.buf_n))
 
     def compress_bank(bank, waiting):
-        return _compress_impl(bank, compression, full_sort)
+        return _compress_impl(bank, compression)
 
     def compress_rows(R):
         def arm(bank, waiting):
@@ -646,7 +602,7 @@ def _add_batch_counted(bank: TDigestBank, slots, values, weights,
             rows = jnp.sort(jnp.where(waiting, s, K))[:R]
             take = jnp.minimum(rows, K - 1)
             part = _compress_impl(jax.tree.map(lambda a: a[take], bank),
-                                  compression, full_sort)
+                                  compression)
             put = lambda leaf, new: leaf.at[rows].set(
                 new, mode="drop", indices_are_sorted=True)
             return bank._replace(
@@ -689,14 +645,13 @@ def _add_batch_counted(bank: TDigestBank, slots, values, weights,
 
 def _add_batch_impl(bank: TDigestBank, slots, values, weights,
                     compression: float = 100.0,
-                    full_sort: bool | None = None,
                     overflow_rows: tuple | None = None) -> TDigestBank:
     """_add_batch_counted without its counts."""
     return _add_batch_counted(bank, slots, values, weights, compression,
-                              full_sort, overflow_rows)[0]
+                              overflow_rows)[0]
 
 
-add_batch = partial(jax.jit, static_argnames=("compression", "full_sort",
+add_batch = partial(jax.jit, static_argnames=("compression",
                                               "overflow_rows"),
                     donate_argnames=("bank",))(_add_batch_impl)
 

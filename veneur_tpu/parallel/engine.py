@@ -27,7 +27,6 @@ chain via the cluster tier's importsrv).
 
 from __future__ import annotations
 
-import logging
 import time
 
 import jax
@@ -36,11 +35,9 @@ import numpy as np
 
 from ..ingest.parser import GLOBAL_ONLY
 from ..models.pipeline import (AggregationEngine, EngineConfig,
-                               _precluster_k1, stage_copy_executable)
+                               _precluster_k1)
 from ..models.worker import FOLD_SLOT
 from .mesh import MeshEngine, make_mesh
-
-logger = logging.getLogger(__name__)
 
 
 class MeshAggregationEngine(AggregationEngine):
@@ -109,20 +106,7 @@ class MeshAggregationEngine(AggregationEngine):
     def _setup_flush_exec(self):
         # the MeshEngine owns the compiled flush; the single-device
         # _flush_executable is never built for a mesh engine
-        if self.cfg.flush_fetch_f16:
-            raise ValueError("flush_fetch_f16 is not supported on the "
-                             "mesh engine (its flush program has its own "
-                             "wire layout)")
         self._flush_exec = None
-        self._stage_exec = None
-        mode = self.cfg.flush_fetch
-        if mode in ("staged", "host"):
-            if mode == "host":
-                logger.warning("flush_fetch=host is not supported on the "
-                               "mesh engine; using staged")
-            # No out_shardings: outputs keep the mesh flush program's
-            # shardings.
-            self._stage_exec = stage_copy_executable()
     # _fetch_flush is inherited from AggregationEngine.
 
     # ---------------- ingest ----------------

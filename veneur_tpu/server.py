@@ -47,10 +47,9 @@ def _fold_rewrite(pb, fr) -> int:
     """Apply an ImportFoldReroute's rewrite to the pb IN PLACE (the
     fold key is tagless by construction) and return the fold key's
     digest — the single-homed fold routing basis. One definition for
-    all three sites that re-route a fold (per-metric worker path,
-    ImportedBatch worker path, recovery replay): the rewrite diverging
-    between live and replay would silently break the kill-restart
-    bit-identity."""
+    both sites that re-route a fold (the worker loop, recovery
+    replay): the rewrite diverging between live and replay would
+    silently break the kill-restart bit-identity."""
     pb.name = fr.key.name
     del pb.tags[:]
     return fr.digest
@@ -130,8 +129,6 @@ class Server:
             percentiles=tuple(cfg.percentiles),
             aggregates=tuple(cfg.aggregates),
             idle_ttl_intervals=cfg.tpu_slot_idle_ttl_intervals,
-            flush_fetch=cfg.tpu_flush_fetch,
-            flush_fetch_f16=cfg.tpu_flush_fetch_f16,
             flush_incremental=cfg.tpu_flush_incremental,
             flush_incremental_threshold=
             cfg.tpu_flush_incremental_threshold,
@@ -1783,15 +1780,9 @@ class Server:
         are re-hashed onto the worker queues and merged via Combine."""
         from .cluster.importsrv import start_import_server
 
-        nq = len(self.worker_queues)
-
-        def submit(digest, imported):
-            self._enqueue_import(digest % nq, imported)
-
         server, port = start_import_server(
-            addr, submit, ledger=self.dedupe_ledger,
+            addr, self._submit_import_batch, ledger=self.dedupe_ledger,
             observer=self.import_observer,
-            submit_batch=self._submit_import_batch,
             engine_stamp=self.engine_stamp,
             note_stamp=self._note_sketch_stamp,
             merge_sketches=self.merge_prefix_sketches)
@@ -1888,9 +1879,7 @@ class Server:
     def _worker_loop(self, idx: int, q: queue.Queue):
         """[HOT LOOP 2] queue -> engine (Worker.Work +
         Worker.ImportMetricGRPC for forwarded metrics)."""
-        from .cluster.importsrv import ImportedBatch, ImportedMetric
-        from .cluster.wire import apply_metric_to_engine
-        from .models import pipeline
+        from .cluster.importsrv import ImportedBatch
 
         eng = self.engines[idx]
         # flight recorder: one `import.apply` stamp per busy run of
@@ -1933,31 +1922,6 @@ class Server:
                         log.warning(
                             "rejected corrupted imported metric "
                             "%r: %s", getattr(pb, "name", "?"), e)
-                elif isinstance(item, ImportedMetric):
-                    if stamps is not None and not run_t0:
-                        run_t0 = time.monotonic_ns()
-                    # a metric routed alone (a handler built without
-                    # submit_batch). Poison-pill guard, here as in the
-                    # batch arm: a corrupted forwarded payload (bad
-                    # HLL blob, malformed centroid list) must reject
-                    # THAT metric, not kill this worker loop — without
-                    # the catch, one bad sender starves a whole queue
-                    # shard forever
-                    try:
-                        apply_metric_to_engine(eng, item.pb)
-                    except pipeline.ImportFoldReroute as fr:
-                        digest = _fold_rewrite(item.pb, fr)
-                        try:
-                            self.worker_queues[
-                                digest
-                                % len(self.worker_queues)].put_nowait(item)
-                        except queue.Full:
-                            self._count("worker.dropped")
-                    except Exception as e:
-                        self._count("import.rejected")
-                        log.warning(
-                            "rejected corrupted imported metric "
-                            "%r: %s", getattr(item.pb, "name", "?"), e)
                 elif isinstance(item, parser.Event):
                     eng.process_event(item)
                 else:
