@@ -528,3 +528,66 @@ def test_import_batch_counters_drain_as_self_metrics(workers):
                    if k.startswith("imp.c")) == sum(range(1, 66)) + 100
     finally:
         srv.stop()
+
+
+@pytest.mark.parametrize("case", ["work_set", "whole_bank"])
+def test_import_landing_counters_drain_as_self_metrics(case, monkeypatch):
+    """veneur.import.land_rows_total / land_bank_total: rows the
+    interval's clustered landings took through a work set, and
+    landings that passed over the whole bank (a bank no larger than
+    the smallest set takes nothing else), drained like
+    import.batches (present at zero, reset a flush); the same two
+    numbers in the engine's _last_flush_info."""
+    from veneur_tpu.cluster.protos import metric_pb2
+    from veneur_tpu.models import pipeline
+
+    def timers(n, start=0):
+        out = []
+        for i in range(start, start + n):
+            m = metric_pb2.Metric(name=f"imp.t{i}", type=metric_pb2.Timer)
+            td = m.histogram.t_digest
+            for v in (1.0, 2.0, 4.0):
+                td.centroids.add(mean=v + i, weight=1.0)
+            td.min, td.max, td.count = 1.0 + i, 4.0 + i, 3.0
+            td.sum = 7.0 + 3 * i
+            td.reciprocal_sum = sum(1 / (v + i) for v in (1.0, 2.0, 4.0))
+            out.append(m)
+        return out
+
+    if case == "work_set":
+        # sets of 8 and 32 rows stand in for the module's 1,024 and
+        # 8,192, which a server's smallest bank (256 slots) is under
+        monkeypatch.setattr(pipeline, "_IMPORT_LAND_ROWS", (8, 32))
+    # a second landing mid-interval, at the 20th digest
+    monkeypatch.setattr(pipeline, "_IMPORT_STAGE_DIGESTS", 20)
+    cap = CaptureMetricSink()
+    cfg = Config(interval="3600s", hostname="h", tpu_histogram_slots=256,
+                 tpu_counter_slots=128, tpu_gauge_slots=128,
+                 tpu_set_slots=64)
+    srv = Server(cfg, sinks=[cap], plugins=[], span_sinks=[])
+    srv.start()
+    try:
+        # 25 digests over 22 keys: 20 land mid-interval (20 rows, the
+        # set of 32), 5 at the flush (5 rows, the set of 8)
+        assert srv._submit_import_batch(timers(22) + timers(3)) == 25
+        assert srv.drain(10.0)
+        srv.flush_once(timestamp=1)
+        cap.wait_for_flush(1)
+        want = (25, 0) if case == "work_set" else (0, 2)
+        info = srv.engines[0]._last_flush_info
+        assert (info["import_land_rows"], info["import_land_bank"]) == want
+        srv.flush_once(timestamp=2)
+        cap.wait_for_flush(2)
+        first, second = ({m.name: m.value for m in f
+                          if m.name.startswith("veneur.import.land")}
+                         for f in cap.flushes[:2])
+        assert first == {"veneur.import.land_rows_total": want[0],
+                         "veneur.import.land_bank_total": want[1]}
+        assert second == {"veneur.import.land_rows_total": 0,
+                          "veneur.import.land_bank_total": 0}
+        info = srv.engines[0]._last_flush_info
+        assert (info["import_land_rows"], info["import_land_bank"]) == (0, 0)
+        by = {m.name: m.value for m in cap.flushes[0]}
+        assert by["imp.t0.count"] == 6.0 and by["imp.t21.count"] == 3.0
+    finally:
+        srv.stop()

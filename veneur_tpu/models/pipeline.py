@@ -59,10 +59,23 @@ _IMPORT_W_CAP = 4096
 _IMPORT_STAGE_CENTROIDS = 1 << 20
 _IMPORT_STAGE_DIGESTS = 8192
 
+# Work-set sizes of a clustered landing's bank-side device work,
+# ascending: a landing that touches S rows gathers the smallest set
+# that holds them, compresses, fills and compresses that [R, .] part
+# and scatters it back; the rows it does not touch are left alone. A
+# stage holds at most _IMPORT_STAGE_DIGESTS digests, so no more
+# distinct rows: the top set serves every landing of the default
+# stage. A bank no larger than a set (most tests, the history tier's
+# scratch engines) and a landing over the top set (only a raised
+# digest bound could bring one) take the whole-bank passes. PERF.md
+# 5f has the sizes tried on the v5e.
+_IMPORT_LAND_ROWS = (1024, _IMPORT_STAGE_DIGESTS)
+
 # Flight-recorder phases one import landing stamps (into
 # engine.land_stamps, when the server armed it): the whole landing;
 # host piles + the [S, W] fill; the cluster_rows dispatch + fetch. The
-# merge/compress dispatches are the rest of `import.land`.
+# gather/compress/fill/scatter dispatches (or the whole-bank compress
+# and merge) and merge_scalars are the rest of `import.land`.
 LAND_PHASES = ("import.land", "import.land.stage", "import.land.cluster")
 
 
@@ -758,6 +771,12 @@ class AggregationEngine:
         # in them (_last_flush_info "import_batches" / "import_metrics")
         self._import_batches = 0
         self._import_metrics = 0
+        # what the interval's clustered landings did: rows that went
+        # through a work set, and landings that took the whole-bank
+        # passes (_last_flush_info "import_land_rows" /
+        # "import_land_bank")
+        self._import_land_rows = 0
+        self._import_land_bank = 0
         # Overload defense (ingest/admission.py): attached by the
         # Server via attach_admission; None = every key mints freely
         # (direct engine construction, the pre-defense behavior).
@@ -1166,7 +1185,31 @@ class AggregationEngine:
                 d[0] = True
             self._flush_device(self._fresh_fn(), dirty=warm_dirty,
                                overflow=self._overflow_zero)
+        self._warm_import_landing()
         jax.block_until_ready(self.histo_bank)
+
+    def _warm_import_landing(self):
+        """Precompile the clustered landing's work-set programs at
+        every size that serves this bank (_land_rows: the sets smaller
+        than it): their shapes follow from the engine's configuration
+        alone. All-padding landings:
+        every id lies past the bank, every centroid weighs 0, so live
+        state is untouched. (cluster_rows' [S, W] is the data's: it
+        compiles at the first landing of a new shape, as it did.)"""
+        if self._heng.import_strategy != "cluster":
+            return
+        with self.lock:
+            bank = self.histo_bank
+            K = bank.num_slots
+            for R in _IMPORT_LAND_ROWS:
+                if R < K:
+                    zc = np.zeros((R, bank.num_centroids), np.float32)
+                    bank = self._land_work_set(
+                        bank, np.full(R, K, np.int32), zc, zc)
+            # vlint: disable=DS01 reason=all-padding warm-up landings
+            # (every id past the bank, dropped at the scatter): no live
+            # data lands, nothing to mark
+            self.histo_bank = jax.device_put(bank, self._device)
 
     def warm_ingest_kernels(self, b: int):
         """Precompile the batch-ingest kernels — the four scatters and
@@ -1375,14 +1418,41 @@ class AggregationEngine:
         items = self._import_centroids
         self._import_centroids = []
         self._import_centroid_total = 0
+        self._count_landing(items)
         self.histo_bank = self._land_import_centroids(
             self.histo_bank, items, self._dirty)
+
+    @staticmethod
+    def _land_rows(S: int, K: int):
+        """The work set for a clustered landing of S rows into a bank
+        of K: the smallest of _IMPORT_LAND_ROWS that holds them and is
+        smaller than the bank, or None for the whole-bank passes."""
+        for R in _IMPORT_LAND_ROWS:
+            if S <= R < K:
+                return R
+        return None
+
+    def _count_landing(self, items):
+        """Under the lock, where a landing of `items` is decided (a
+        full stage, the flush's swap): count what it will do into the
+        interval's tally. Not in the landing itself: the double-
+        buffered flush lands its retired stage outside the lock, beside
+        the next interval's landings."""
+        if not items or self._heng.import_strategy != "cluster":
+            return
+        S = len({it[0] for it in items})
+        if self._land_rows(S, self.histo_bank.num_slots) is None:
+            self._import_land_bank += 1
+        else:
+            self._import_land_rows += S
 
     def _land_import_centroids(self, bank, items, dirty):
         """Land staged foreign digests into `bank` under the engine's
         import strategy: "cluster" (t-digest — precluster each slot's
         pile to <= C centroids with ONE batched cluster_rows program,
-        then one merge + one compress) or "direct" (compactor engines —
+        then compress, fill the emptied buffers and compress again,
+        over the rows the landing touches) or "direct" (compactor
+        engines —
         the items re-insert as weighted points in fixed-width batches;
         the engine's own compaction bounds memory, no preclustering).
         One `import.land` stamp per landing (LAND_PHASES), whichever
@@ -1474,6 +1544,8 @@ class AggregationEngine:
                     by_slot[s].append((cm[row], cw[row]))
             trusted.update(oversized)
 
+        # rows in ascending order: the work set's scatter is told so
+        by_slot = dict(sorted(by_slot.items()))
         slot_ids = np.fromiter(by_slot.keys(), np.int32, len(by_slot))
         if dirty is not None:
             self._mark_dirty_into(dirty, 0, slot_ids)
@@ -1497,19 +1569,36 @@ class AggregationEngine:
         if stamps is not None:
             stamps.add("import.land.stage", t0, t1)
             stamps.add("import.land.cluster", t1, time.monotonic_ns())
-        # land the clustered centroids; merge_centroids drops on buffer
-        # overflow, so chunk the C columns to the buffer depth (one
-        # iteration in the default config where B >= C)
-        B = bank.buf_size
-        for c0 in range(0, C, B):
-            chunk = slice(c0, min(C, c0 + B))
-            width = chunk.stop - chunk.start
+        # land the clustered centroids: a compress empties the buffers,
+        # the centroids go into them, a compress folds them. A buffer
+        # holds B lanes, so the C columns go in chunks of B with a
+        # compress before each (one chunk in the default config, where
+        # B >= C).
+        K = bank.num_slots
+        R = self._land_rows(S, K)
+        if R is None:
+            # over the whole bank; merge_centroids drops on buffer
+            # overflow, hence the chunks
+            B = bank.buf_size
+            for c0 in range(0, C, B):
+                chunk = slice(c0, min(C, c0 + B))
+                width = chunk.stop - chunk.start
+                bank = self._heng.compress(bank)
+                rows = np.repeat(slot_ids, width)
+                bank = self._heng.merge_centroids(
+                    bank, rows, cmeans[:, chunk].reshape(-1),
+                    cwts[:, chunk].reshape(-1))
             bank = self._heng.compress(bank)
-            rows = np.repeat(slot_ids, width)
-            bank = self._heng.merge_centroids(
-                bank, rows, cmeans[:, chunk].reshape(-1),
-                cwts[:, chunk].reshape(-1))
-        bank = self._heng.compress(bank)
+        else:
+            # over the rows it touches, padded to the work set: the
+            # padding id K lies past the bank, reads its last row at
+            # the gather and is dropped at the scatter; padding
+            # centroids weigh 0
+            more = (0, R - S)
+            bank = self._land_work_set(
+                bank, np.pad(slot_ids, more, constant_values=K),
+                np.pad(cmeans, (more, (0, 0))),
+                np.pad(cwts, (more, (0, 0))))
 
         sl = np.array([it[0] for it in items], np.int32)
         bank = self._heng.merge_scalars(
@@ -1523,6 +1612,19 @@ class AggregationEngine:
         # uncommitted; recommit so the ingest kernels and the flush
         # program stay on their committed (fast) executables
         return jax.device_put(bank, self._device)
+
+    def _land_work_set(self, bank, rows, means, weights):
+        """Gather the [R, .] part of `bank` at `rows`, fold the
+        clustered centroids f32[R, C] into it row for row (compress,
+        fill the emptied buffers, compress) and scatter it back."""
+        C, B = bank.num_centroids, bank.buf_size
+        part = self._heng.gather_rows(bank, rows)
+        for c0 in range(0, C, B):
+            part = self._heng.compress(part)
+            part = self._heng.fill_buffers(
+                part, means[:, c0:c0 + B], weights[:, c0:c0 + B])
+        part = self._heng.compress(part)
+        return self._heng.scatter_rows(bank, rows, part)
 
     # fixed flat-batch width for the direct import landing: one program
     # shape however many centroids an interval staged
@@ -1795,8 +1897,10 @@ class AggregationEngine:
         for ki in (self.histo_keys, self.counter_keys,
                    self.gauge_keys, self.set_keys):
             ki.advance_interval()
-        imported = (self._import_batches, self._import_metrics)
+        imported = (self._import_batches, self._import_metrics,
+                    self._import_land_rows, self._import_land_bank)
         self._import_batches = self._import_metrics = 0
+        self._import_land_rows = self._import_land_bank = 0
         return (active, status, stats_samples, dropped, histo_key_count,
                 imported)
 
@@ -1879,6 +1983,7 @@ class AggregationEngine:
                 imports = (self._import_centroids, self._import_sets,
                            self._import_counter_acc,
                            self._import_gauge_acc)
+                self._count_landing(self._import_centroids)
                 self._import_centroids = []
                 self._import_centroid_total = 0
                 self._import_sets = []
@@ -1926,7 +2031,9 @@ class AggregationEngine:
         host = self._flush_device(snap, phases=phases, dirty=dirty,
                                   overflow=overflow)
         self._last_flush_info.update(import_batches=imported[0],
-                                     import_metrics=imported[1])
+                                     import_metrics=imported[1],
+                                     import_land_rows=imported[2],
+                                     import_land_bank=imported[3])
         t_device = time.monotonic_ns()
 
         # Delta export build (ISSUE 13): honor the request only when
@@ -2154,6 +2261,11 @@ class AggregationEngine:
             # them (veneur.import.batches_total / batch_metrics_total)
             "import_batches": imported[0],
             "import_metrics": imported[1],
+            # rows its clustered landings took through a work set, and
+            # landings that took the whole-bank passes
+            # (veneur.import.land_rows_total / land_bank_total)
+            "import_land_rows": imported[2],
+            "import_land_bank": imported[3],
             # what the export build actually shipped (delta requests
             # degrade to full when no bitmap exists — mesh, tracking
             # off — or the engine does not forward)

@@ -505,6 +505,28 @@ def cluster_rows(values, weights, compression: float = 100.0,
                          sorted_prefix=sorted_prefix)
 
 
+def _take_rows(bank: TDigestBank, rows) -> TDigestBank:
+    """The [R, .] part of `bank` at `rows` (ascending ids). An id of
+    num_slots or more is padding: it reads the last row here and
+    _put_rows drops it."""
+    take = jnp.minimum(rows, bank.num_slots - 1)
+    return jax.tree.map(lambda a: a[take], bank)
+
+
+def _put_rows(bank: TDigestBank, rows, part: TDigestBank) -> TDigestBank:
+    """Write back what a compress of `part` = _take_rows(bank, rows)
+    changed: the centroid and buffer leaves (the scalar leaves are not
+    a compress's to touch)."""
+    put = lambda leaf, new: leaf.at[rows].set(
+        new, mode="drop", indices_are_sorted=True)
+    return bank._replace(
+        mean=put(bank.mean, part.mean),
+        weight=put(bank.weight, part.weight),
+        buf_value=put(bank.buf_value, part.buf_value),
+        buf_weight=put(bank.buf_weight, part.buf_weight),
+        buf_n=put(bank.buf_n, part.buf_n))
+
+
 # Work-set sizes of the ingest's overflow compress, ascending: a batch
 # that leaves samples waiting on n rows compresses the smallest set
 # that holds n, and the whole bank when none does. On the v5e at the
@@ -514,6 +536,7 @@ def cluster_rows(values, weights, compression: float = 100.0,
 # rows in steady_10k, 40 in hot_1k, 1,000 in its first batches); a
 # second set of 128 saved 4% of a steady_10k tick's landing time and a
 # set of 4,096 cost 13% (builder's chip runs, PR 27; PERF.md 5b).
+# (The import landing's work sets are models/pipeline._IMPORT_LAND_ROWS.)
 _OVERFLOW_ROWS = (1024,)
 
 
@@ -600,17 +623,9 @@ def _add_batch_counted(bank: TDigestBank, slots, values, weights,
             # the front. Padding ids are K: gathered clamped, dropped
             # at the scatter.
             rows = jnp.sort(jnp.where(waiting, s, K))[:R]
-            take = jnp.minimum(rows, K - 1)
-            part = _compress_impl(jax.tree.map(lambda a: a[take], bank),
-                                  compression)
-            put = lambda leaf, new: leaf.at[rows].set(
-                new, mode="drop", indices_are_sorted=True)
-            return bank._replace(
-                mean=put(bank.mean, part.mean),
-                weight=put(bank.weight, part.weight),
-                buf_value=put(bank.buf_value, part.buf_value),
-                buf_weight=put(bank.buf_weight, part.buf_weight),
-                buf_n=put(bank.buf_n, part.buf_n))
+            return _put_rows(
+                bank, rows,
+                _compress_impl(_take_rows(bank, rows), compression))
         return arm
 
     # a work set as large as the bank saves nothing over the bank arm
@@ -710,6 +725,44 @@ def merge_scalars(bank: TDigestBank, slots, vmins, vmaxs, vsums, counts,
         vsum=vsum, count=count, recip=recip,
         vsum_lo=vsum_lo, count_lo=count_lo, recip_lo=recip_lo,
     )
+
+
+# The import landing's work set (models/pipeline._land_imports_clustered):
+# gather_rows -> compress -> fill_buffers -> compress -> scatter_rows
+# over the [R, .] rows one landing touches, instead of compress ->
+# merge_centroids -> compress over the bank. The compress passes are
+# this module's `compress`, on the part.
+
+@jax.jit
+def gather_rows(bank: TDigestBank, rows) -> TDigestBank:
+    """The [R, .] part at `rows` (ascending; ids of num_slots or more
+    are padding), for compress and fill_buffers to work on."""
+    return _take_rows(bank, rows)
+
+
+@partial(jax.jit, donate_argnames=("part",))
+def fill_buffers(part: TDigestBank, means, weights) -> TDigestBank:
+    """Write clustered centroid rows f32[R, W] (W <= buffer depth, row
+    i for part row i, weight 0 == empty lane) into the buffers of a
+    part that was JUST compressed, so that every buffer is empty: what
+    merge_centroids does with the same centroids flattened, without its
+    sort by slot. A cluster_rows row leads with its positive weights,
+    so they sit where merge_centroids would have ranked them; and were
+    an empty lane between them, the next compress tells it by its
+    weight and sorts the others in the order they have here."""
+    live = weights > 0
+    pad = ((0, 0), (0, part.buf_size - means.shape[1]))
+    return part._replace(
+        buf_value=jnp.pad(jnp.where(live, means, 0.0), pad),
+        buf_weight=jnp.pad(jnp.where(live, weights, 0.0), pad),
+        buf_n=jnp.sum(live, axis=1, dtype=jnp.int32))
+
+
+@partial(jax.jit, donate_argnames=("bank",))
+def scatter_rows(bank: TDigestBank, rows, part: TDigestBank) -> TDigestBank:
+    """Put the compressed part back at `rows`: ascending, ids of
+    num_slots or more dropped."""
+    return _put_rows(bank, rows, part)
 
 
 def merge_banks(a: TDigestBank, b: TDigestBank,

@@ -2073,6 +2073,7 @@ class Server:
         eng_stats = {"samples": 0, "dropped_no_slot": 0,
                      "overflow_rows": 0, "overflow_bank": 0,
                      "import_batches": 0, "import_metrics": 0,
+                     "import_land_rows": 0, "import_land_bank": 0,
                      "swap_ns": 0, "merge_ns": 0, "assembly_ns": 0}
         # Engines flush concurrently so their device programs and
         # device→host transfers overlap instead of queueing behind
@@ -2831,6 +2832,10 @@ class Server:
             tel.mark(S, "import.batches", eng_stats["import_batches"])
             tel.mark(S, "import.batch_metrics",
                      eng_stats["import_metrics"])
+            # the import's landings: rows that went through a work set,
+            # and landings that compressed the whole bank (the dear arm)
+            tel.mark(S, "import.land_rows", eng_stats["import_land_rows"])
+            tel.mark(S, "import.land_bank", eng_stats["import_land_bank"])
             tel.set_gauge(S, "flush.swap_duration_ns",
                           eng_stats["swap_ns"])
             tel.set_gauge(S, "flush.merge_duration_ns",

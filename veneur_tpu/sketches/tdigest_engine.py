@@ -104,6 +104,18 @@ class TDigestEngine:
         return tdigest.merge_scalars(bank, slots, vmins, vmaxs, vsums,
                                      counts, recips)
 
+    # the import landing's work set: gather -> compress -> fill ->
+    # compress -> scatter over the rows a landing touches
+
+    def gather_rows(self, bank, rows):
+        return tdigest.gather_rows(bank, rows)
+
+    def fill_buffers(self, part, means, weights):
+        return tdigest.fill_buffers(part, means, weights)
+
+    def scatter_rows(self, bank, rows, part):
+        return tdigest.scatter_rows(bank, rows, part)
+
     def cluster_rows(self, values, weights, num_centroids: int,
                      sorted_prefix: int = 0):
         return tdigest.cluster_rows(values, weights,
