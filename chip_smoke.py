@@ -579,14 +579,13 @@ def run_tiers(sizes: Sizes, seed: int, fused: str, backend: str,
                            touched_keys(sizes, seed, index))
                 lines = w.lines()
                 sent = (lines, datagrams(lines), reference(w))
-            # window 3 is window 2 again, sample for sample. Fresh
-            # values on the same keys could still compile: at
-            # compression 100 a digest holds 118-130 centroids, and the
-            # global's import landing pads piles to 128-lane widths, so
-            # other numbers can tip a chunk from 128 to 256 lanes and
-            # build a second cluster_rows (an inline compile under the
-            # flush that is on ROADMAP). The steady state the window
-            # stands for is: the same work compiles nothing.
+            # window 3 is window 2 again, sample for sample: the
+            # steady state the window stands for is that the same work
+            # compiles nothing. (Fresh values on the same keys no
+            # longer could either: the import landing's programs take
+            # their shapes from the configuration and are compiled in
+            # warmup(); a dirty set in another incremental bucket
+            # still would.)
             lines, dg, ref = sent
             ts = 1_000 + 10 * index
             snap = meter.snapshot()

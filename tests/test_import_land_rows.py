@@ -84,7 +84,7 @@ def _seeded_bank(eng, rng, landed, waiting):
     centroids of an earlier landing, `waiting` rows a half-full buffer
     of samples no compress has folded yet."""
     B = eng.histo_bank.buf_size
-    bank = eng._land_import_centroids(
+    bank, _did = eng._land_import_centroids(
         eng.histo_bank, [_item(rng, s, 150) for s in landed], None)
     n = B // 2
     slots = np.repeat(np.asarray(waiting, np.int32), n)
@@ -94,10 +94,11 @@ def _seeded_bank(eng, rng, landed, waiting):
         compression=eng.cfg.compression))
 
 
-def _reference(eng, before, items):
+def _reference(eng, before, items, scalars=None):
     """compress -> merge_centroids -> compress over the whole bank (in
     chunks of the buffer depth where it is under C), then the exact
-    scalars: what a landing was before it had a work set."""
+    scalars (`scalars`' where the piles in `items` are no longer the
+    staged digests): what a landing was before it had a work set."""
     comp = eng.cfg.compression
     bank = _device(before)
     C, B = bank.num_centroids, bank.buf_size
@@ -123,6 +124,7 @@ def _reference(eng, before, items):
             cm[:, c0:c0 + width].reshape(-1),
             cw[:, c0:c0 + width].reshape(-1))
     bank = tdigest.compress(bank, compression=comp)
+    items = scalars or items
     cols = [np.array([it[i] for it in items], np.float32)
             for i in range(3, 8)]
     return _host(tdigest.merge_scalars(
@@ -166,7 +168,8 @@ def test_landing_is_the_whole_bank_chain_on_the_rows_it_touches(
 
     del compress_rows[:]
     dirty = [np.zeros(K, bool)] + [np.zeros(8, bool)] * 3
-    got = _host(eng._land_import_centroids(_device(before), items, dirty))
+    bank, did = eng._land_import_centroids(_device(before), items, dirty)
+    got = _host(bank)
 
     chunks = -(-eng.histo_bank.num_centroids // B)
     assert compress_rows == [want or K] * (chunks + 1)
@@ -185,9 +188,8 @@ def test_landing_is_the_whole_bank_chain_on_the_rows_it_touches(
     for leaf in SCALARS:
         assert got[leaf].tobytes() == ref[leaf].tobytes(), leaf
     assert np.flatnonzero(dirty[0]).tolist() == touched
-    # and the tally, taken where the landing is decided
-    eng._count_landing(items)
-    assert (eng._import_land_rows, eng._import_land_bank) == \
+    # and the tally, the landing's own account of what it did
+    assert (did["import_land_rows"], did["import_land_bank"]) == \
         ((S, 0) if want is not None else (0, 1))
 
 
@@ -302,40 +304,261 @@ def test_the_ladder_a_bank_takes_follows_from_its_shape(slots, sizes):
         assert land(1, slots) == sizes[0]
 
 
-def test_warmup_compiles_every_work_set_program():
-    """After warmup() on a default EngineConfig (32,768 slots: both
-    sets serve it), landings of 1, 1,000, 1,808 and 8,192 rows compile
-    nothing of the work set's: gather, compress, fill and scatter have
-    shapes that follow from the configuration alone. What still
-    compiles at a landing is what draws its shape from the data, as
-    before: cluster_rows' [S, W] and merge_scalars' digest count."""
-    compiled = []
-    armed = [False]
+@pytest.fixture
+def compiled():
+    """Names of the programs JAX compiles while `armed[0]` is set."""
+    names, armed = [], [False]
 
     def listen(event, duration, **kw):
         if armed[0] and event == \
                 "/jax/core/compile/backend_compile_duration":
-            compiled.append(kw.get("fun_name", "?"))
+            names.append(kw.get("fun_name", "?"))
     jax.monitoring.register_event_duration_secs_listener(listen)
+    yield names, armed
+    armed[0] = False
 
+
+def _piles(rng, S, digests, centroids):
+    """Staged items for rows 0..S-1: row s gets digests[s % len]
+    digests of centroids[s % len] centroids each."""
+    return [_item(rng, s, centroids[s % len(centroids)])
+            for s in range(S)
+            for _ in range(digests[s % len(digests)])]
+
+
+# bank slots -> (rows a landing touches, digests a pile, centroids a
+# digest): widths on every step of the lane ladder, the stage's own
+# row counts (PERF.md 5c: 984, 1,000; steady_10k's tail of 1,808) and,
+# on the 512-slot bank, the whole-bank arm
+NO_COMPILE = {
+    1 << 15: [(1, (1,), (8,)), (984, (8, 32), (64, 4)),
+              (1000, (32, 12), (120, 4)), (1808, (1,), (130,)),
+              (8192, (1, 2), (118, 130)), (7, (3,), (1500,))],
+    512: [(1, (32,), (8,)), (100, (1, 16), (130, 64)),
+          (512, (2,), (300,))],
+}
+
+
+@pytest.mark.parametrize("slots", list(NO_COMPILE))
+def test_after_warmup_no_import_compiles(slots, compiled):
+    """After warmup() nothing an import dispatches compiles: histogram
+    landings of any row count and pile width up to the stage's bounds,
+    an oversized pile through both arms of the pre-cluster loop, set
+    tails, imported counters and gauges. Every shape follows from the
+    EngineConfig and the module's constants; on the default 32,768-slot
+    bank both work sets serve, a 512-slot bank takes the whole-bank
+    arm."""
+    names, armed = compiled
+    scalars = min(slots, 2048)
     eng = AggregationEngine(EngineConfig(
-        counter_slots=8, gauge_slots=8, set_slots=8, hll_precision=10,
+        histogram_slots=slots, counter_slots=scalars,
+        gauge_slots=scalars, set_slots=64, hll_precision=10,
         is_global=True))
-    assert eng.histo_bank.num_slots == 1 << 15
     eng.warmup()
     rng = np.random.default_rng(5)
+    C = eng.histo_bank.num_centroids
+    cap = eng._land_lanes(C)[-1]
     armed[0] = True
-    try:
-        for S in (1, 1000, 1808, 8192):
-            items = [_item(rng, s, 8) for s in range(S)]
-            with eng.lock:
-                eng._import_centroids = items
-                eng._flush_import_centroids()
-            jax.block_until_ready(eng.histo_bank)
-    finally:
-        armed[0] = False
-    assert eng._import_land_rows == 1 + 1000 + 1808 + 8192
-    assert eng._import_land_bank == 0
-    assert set(compiled) <= {"jit(cluster_rows)",
-                             "jit(merge_scalars)"}, compiled
-    assert compiled.count("jit(cluster_rows)") == 4
+    landings = NO_COMPILE[slots] + [
+        # 17 chunks of the cap cluster to 17 x C > cap lanes: a second,
+        # trusted pass (the sorted_prefix arm)
+        (2, (1,), (17 * cap,))]
+    for S, digests, centroids in landings:
+        with eng.lock:
+            eng._import_centroids = _piles(rng, S, digests, centroids)
+            eng._flush_import_centroids()
+        jax.block_until_ready(eng.histo_bank)
+    assert eng._import_land_prechunked == 2 + (7 if slots > 512 else 0)
+    for n in (1, 255, 256, 300):
+        for i in range(n):
+            eng.import_set(MetricKey(f"s{i % 50}", "set", ""),
+                           rng.integers(0, 9, 1 << 10).astype(np.uint8))
+        with eng.lock:
+            eng._flush_import_sets()
+    for n in (1, 300, scalars - 1, scalars):
+        for i in range(n):
+            eng.import_counter(MetricKey(f"c{i}", "counter", ""), 1.0)
+            eng.import_gauge(MetricKey(f"g{i}", "gauge", ""), float(i))
+        with eng.lock:
+            eng._flush_import_scalars()
+    jax.block_until_ready((eng.set_bank, eng.counter_bank,
+                           eng.gauge_bank))
+    armed[0] = False
+    assert names == []
+    # and it all landed: the flush after it compiles nothing either
+    by = {m.name: m.value for m in eng.flush(timestamp=1).metrics}
+    assert by[f"c{scalars - 1}"] == 1.0 and by["c0"] == 4.0
+    assert by[f"g{scalars - 1}"] == float(scalars - 1)
+
+
+def _parent_landing(eng, before, items):
+    """The landing as the parent commit computed it, every device
+    operand shaped by the data: oversized piles cut in [n_chunks, cap]
+    matrices (full sort, then the sorted_prefix arm on its own
+    outputs), the piles in [S, W] with W the widest rounded up to 128,
+    then `_reference`'s whole-bank chain and merge_scalars at the
+    digest count."""
+    comp = eng.cfg.compression
+    C = before["mean"].shape[1]
+    cap = max(pipeline._IMPORT_W_CAP, 2 * C)
+    by_slot = {}
+    for it in items:
+        by_slot.setdefault(it[0], []).append((it[1], it[2]))
+    trusted = set()
+    while True:
+        over = [s for s, piles in by_slot.items()
+                if sum(len(m) for m, _ in piles) > cap]
+        if not over:
+            break
+        for s in over:
+            if s in trusted:
+                per = cap // C
+                groups = [by_slot[s][i:i + per]
+                          for i in range(0, len(by_slot[s]), per)]
+                rows = [[np.concatenate(
+                    [np.pad(p[i], (0, C - len(p[i]))) for p in g])
+                    for i in (0, 1)] for g in groups]
+                prefix = C
+            else:
+                flat = [np.concatenate([p[i] for p in by_slot[s]])
+                        for i in (0, 1)]
+                rows = [[flat[0][i:i + cap], flat[1][i:i + cap]]
+                        for i in range(0, len(flat[0]), cap)]
+                prefix = 0
+            v, w = (np.stack([np.pad(r[i], (0, cap - len(r[i])))
+                              for r in rows]) for i in (0, 1))
+            cm, cw = (np.asarray(x) for x in tdigest.cluster_rows(
+                v, w, compression=comp, num_centroids=C,
+                sorted_prefix=prefix))
+            by_slot[s] = list(zip(cm, cw))
+        trusted.update(over)
+    flat = [(s, np.concatenate([m for m, _ in piles]),
+             np.concatenate([w for _, w in piles]))
+            for s, piles in by_slot.items()]
+    scalars = [(it[0], None, None) + tuple(it[3:]) for it in items]
+    return _reference(eng, before, flat, scalars)
+
+
+# name -> (bank slots, [(row, digests, centroids a digest)]): piles
+# whose width falls on, under and over steps of the lane ladder, on a
+# work set and over the whole bank
+EXACT = {
+    "one_digest_rows": (256, [(3, 1, 8), (200, 1, 130), (7, 1, 118)]),
+    "piles_8_to_32_wide": (256, [(s, 8 + s, 30) for s in range(0, 25, 4)]),
+    "a_step_exactly_and_one_over": (256, [(1, 4, 128), (2, 1, 513)]),
+    "the_widest_step_below_the_cap": (256, [(9, 16, 128), (4, 1, 2049)]),
+    "the_cap_exactly": (256, [(5, 32, 128), (6, 1, 40)]),
+    "oversized_one_pass": (256, [(5, 33, 128), (6, 1, 40)]),
+    "oversized_then_the_trusted_arm": (256, [(0, 1, 17 * 4096),
+                                             (255, 2, 100)]),
+    "whole_bank_arm": (32, [(s, 1 + s % 5, 90) for s in range(0, 32, 3)]),
+    "whole_bank_arm_oversized": (8, [(7, 1, 9000), (0, 1, 1)]),
+}
+
+
+@pytest.mark.parametrize("case", list(EXACT))
+def test_fixed_shape_landing_is_the_data_shaped_one_bit_for_bit(
+        case, ladder):
+    """Padding lanes weigh 0 and padding rows are empty, and the
+    clustering leaves both out: for the same staged piles the landing
+    at its configured [R, L] leaves every leaf of the bank as the
+    parent's landing at the data's [S, W] did."""
+    K, piles = EXACT[case]
+    rng = np.random.default_rng(33)
+    eng = _engine(K)
+    items = [_item(rng, s, n) for s, digests, n in piles
+             for _ in range(digests)]
+    rng.shuffle(items)
+    before = _seeded_bank(eng, rng, landed=[piles[0][0]],
+                          waiting=[piles[-1][0]])
+    ref = _parent_landing(eng, before, items)
+    got = _host(eng._land_import_centroids(_device(before), items, None)[0])
+    touched = sorted({s for s, _, _ in piles})
+    for leaf in LEAVES:
+        assert got[leaf][touched].tobytes() == \
+            ref[leaf][touched].tobytes(), leaf
+    for leaf in SCALARS:
+        assert got[leaf].tobytes() == ref[leaf].tobytes(), leaf
+
+
+@pytest.mark.parametrize("slots, C, sets", [
+    (64, 256, (64,)), (1024, 256, (1024,)), (2048, 256, (1024, 2048)),
+    (8192, 256, (1024, 8192)), (1 << 15, 256, (1024, 8192)),
+    (1 << 17, 256, (1024, 8192)), (1 << 17, 2560, (1024, 8192))])
+def test_the_cluster_shapes_follow_from_the_bank(slots, C, sets):
+    """Rows: the work sets that serve the bank, and the bank's own
+    count where a stage can outgrow them. Lanes: the module's steps
+    under the pre-cluster cap, then the cap. Sixteen programs or
+    fewer with the two pre-cluster arms."""
+    lanes = AggregationEngine._land_lanes(C)
+    cap = max(pipeline._IMPORT_W_CAP, 2 * C)
+    assert lanes[-1] == cap and list(lanes) == sorted(set(lanes))
+    assert lanes[:-1] == tuple(
+        n for n in pipeline._IMPORT_LAND_LANES if n < cap)
+    shapes = AggregationEngine._cluster_shapes(slots, C)
+    assert shapes == [(R, L) for R in sets for L in lanes]
+    assert len(shapes) + 2 <= 16
+    # every landing the stage can hold finds its shape among them
+    for S in {1, min(slots, 1000), min(slots, 1025),
+              min(slots, pipeline._IMPORT_STAGE_DIGESTS)}:
+        R = AggregationEngine._land_rows(S, slots) or slots
+        assert {(R, L) for L in lanes} <= set(shapes)
+
+
+@pytest.mark.parametrize("slots, want", [
+    (8, (8,)), (1024, (1024,)), (1025, (1024, 1025)),
+    (1 << 14, (1024, 1 << 14))])
+def test_the_scalar_rows_follow_from_the_bank(slots, want):
+    assert AggregationEngine._scalar_rows(slots) == want
+
+
+# name -> (piles as (digests, centroids a digest), then what the tally
+# must read: rows of the cluster dispatch x lanes, lanes filled, piles
+# pre-chunked) on a 256-slot bank under work sets of 8 and 32 rows
+TALLIES = {
+    "one_narrow_pile": ([(1, 8)], 8 * 128, 8, 0),
+    "nine_rows_take_the_next_set": ([(1, 100)] * 8 + [(2, 100)],
+                                    32 * 256, 1000, 0),
+    "a_pile_32_digests_wide": ([(32, 64), (1, 4)], 8 * 2048, 2052, 0),
+    "over_the_top_set": ([(1, 8)] * 33, 256 * 128, 264, 0),
+    "an_oversized_pile_is_cut_first": ([(1, 9000), (1, 130)],
+                                       8 * 1024, 3 * 256 + 130, 1),
+    "cut_twice": ([(1, 17 * 4096)], 8 * 512, 2 * 256, 1),
+}
+
+
+@pytest.mark.parametrize("case", list(TALLIES))
+def test_the_tally_counts_lanes_as_the_landing_pads_them(
+        case, ladder, monkeypatch):
+    """`import_land_lanes` is the [R, L] the cluster program was handed
+    (padding included), `import_land_lanes_filled` the lanes of it the
+    piles filled, `import_land_prechunked` the piles the pre-cluster
+    loop cut first; the landing returns them, its caller adds them to
+    the interval's tally, and they are checked here against what the
+    landing dispatched."""
+    piles, lanes, filled, cut = TALLIES[case]
+    rng = np.random.default_rng(2)
+    eng = _engine(256)
+    handed = []
+    orig = eng._heng.cluster_program
+
+    def spy(rows, n, C, sorted_prefix=0):
+        handed.append((rows, n))
+        return orig(rows, n, C, sorted_prefix=sorted_prefix)
+    monkeypatch.setattr(type(eng._heng), "cluster_program",
+                        lambda self, *a, **kw: spy(*a, **kw))
+    items = [_item(rng, s, n) for s, (digests, n) in enumerate(piles)
+             for _ in range(digests)]
+    with eng.lock:
+        eng._import_centroids = items
+        eng._flush_import_centroids()
+    assert (eng._import_land_lanes, eng._import_land_lanes_filled,
+            eng._import_land_prechunked) == (lanes, filled, cut)
+    assert handed[-1][0] * handed[-1][1] == lanes
+    assert all(shape == (pipeline._IMPORT_CHUNK_ROWS, 4096)
+               for shape in handed[:-1])
+    eng.flush(timestamp=1)
+    info = eng._last_flush_info
+    assert (info["import_land_lanes"], info["import_land_lanes_filled"],
+            info["import_land_prechunked"]) == (lanes, filled, cut)
+    assert eng._import_land_lanes == 0

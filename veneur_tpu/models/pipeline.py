@@ -71,12 +71,42 @@ _IMPORT_STAGE_DIGESTS = 8192
 # 5f has the sizes tried on the v5e.
 _IMPORT_LAND_ROWS = (1024, _IMPORT_STAGE_DIGESTS)
 
+# Lane widths of a clustered landing's [R, L] matrix, ascending: the
+# piles are padded (weight 0) to the narrowest step that holds the
+# widest, and to the pre-cluster cap above the last step (_land_lanes:
+# no pile is wider once the pre-cluster loop has cut it). With the row
+# sets above these are all the shapes the cluster program is ever
+# handed, so warmup() compiles every one. Powers of two: a digest of
+# compression 100 holds 118-130 centroids (128 or 256 lanes), a hot
+# key behind 32 senders 512 to 2,048.
+_IMPORT_LAND_LANES = (128, 256, 512, 1024, 2048)
+
+# Rows of one pre-cluster dispatch, [_IMPORT_CHUNK_ROWS, cap]: a full
+# centroid stage cut into chunks of the cap is this many.
+_IMPORT_CHUNK_ROWS = _IMPORT_STAGE_CENTROIDS // _IMPORT_W_CAP
+
+# Forwarded set rows staged before they land, and the rows of that
+# landing's one program (a flush-time tail is padded to it).
+_IMPORT_STAGE_SETS = 256
+
+# Imported counters and gauges land once a flush, padded to this many
+# entries or, past it, to the bank's own slot count (_scalar_rows).
+_IMPORT_SCALAR_ROWS = 1024
+
 # Flight-recorder phases one import landing stamps (into
 # engine.land_stamps, when the server armed it): the whole landing;
-# host piles + the [S, W] fill; the cluster_rows dispatch + fetch. The
-# gather/compress/fill/scatter dispatches (or the whole-bank compress
-# and merge) and merge_scalars are the rest of `import.land`.
+# host piles + the [R, L] fill; the cluster program's dispatch + fetch.
+# The gather/compress/fill/scatter dispatches (or the whole-bank
+# compress and merge) and merge_scalars are the rest of `import.land`.
 LAND_PHASES = ("import.land", "import.land.stage", "import.land.cluster")
+
+# The interval's import tally: engine attributes `_<name>`, added to
+# under the lock (a landing returns its own share, which the flush
+# adds for its retired stage), noted in _last_flush_info at the flush
+# and reset.
+_IMPORT_TALLY = ("import_batches", "import_metrics", "import_land_rows",
+                 "import_land_bank", "import_land_lanes",
+                 "import_land_lanes_filled", "import_land_prechunked")
 
 
 def _precluster_k1(v, w, n_points, keep_extremes=False):
@@ -418,6 +448,7 @@ def release_executables() -> None:
                     _flush_executable, _inc_flush_executable,
                     _flush_baseline_cached):
         factory.cache_clear()
+    sketches.release_executables()
 
 
 def _out_bank_kind(key: str) -> int:
@@ -772,11 +803,17 @@ class AggregationEngine:
         self._import_batches = 0
         self._import_metrics = 0
         # what the interval's clustered landings did: rows that went
-        # through a work set, and landings that took the whole-bank
-        # passes (_last_flush_info "import_land_rows" /
-        # "import_land_bank")
+        # through a work set, landings that took the whole-bank
+        # passes, lanes handed to the cluster program (padding
+        # included), lanes of them that carried a centroid, and piles
+        # the pre-cluster loop cut first (_last_flush_info
+        # "import_land_rows" / "_bank" / "_lanes" / "_lanes_filled" /
+        # "_prechunked")
         self._import_land_rows = 0
         self._import_land_bank = 0
+        self._import_land_lanes = 0
+        self._import_land_lanes_filled = 0
+        self._import_land_prechunked = 0
         # Overload defense (ingest/admission.py): attached by the
         # Server via attach_admission; None = every key mints freely
         # (direct engine construction, the pre-defense behavior).
@@ -1178,8 +1215,8 @@ class AggregationEngine:
             # the incremental path too: build the empty-flush baseline
             # and compile the smallest-bucket incremental program (one
             # dirty slot per bank — flush 0's common shape; bigger
-            # dirty sets compile their bucket inline, like the
-            # cluster_rows width ladder)
+            # dirty sets compile their bucket inline; the import's
+            # programs, below, are all compiled here)
             warm_dirty = [np.zeros_like(d) for d in self._dirty]
             for d in warm_dirty:
                 d[0] = True
@@ -1189,26 +1226,48 @@ class AggregationEngine:
         jax.block_until_ready(self.histo_bank)
 
     def _warm_import_landing(self):
-        """Precompile the clustered landing's work-set programs at
-        every size that serves this bank (_land_rows: the sets smaller
-        than it): their shapes follow from the engine's configuration
-        alone. All-padding landings:
-        every id lies past the bank, every centroid weighs 0, so live
-        state is untouched. (cluster_rows' [S, W] is the data's: it
-        compiles at the first landing of a new shape, as it did.)"""
-        if self._heng.import_strategy != "cluster":
-            return
+        """Precompile every device program an import dispatches: their
+        shapes follow from the engine's configuration and this
+        module's constants alone, never from what was staged. The
+        cluster program at every [rows, lanes] of _cluster_shapes and
+        both arms of the pre-cluster loop (compiled, not run); the
+        bank-side work at every row count that serves this bank
+        (_land_rows' sets, the whole-bank passes where a landing can
+        take them); merge_scalars at the stage's digest bound; the set
+        rows' merge at the stage's; counter_merge and gauge_set at
+        _scalar_rows. All-padding landings: every id is -1 or lies
+        past the bank, every centroid weighs 0, so live state is
+        untouched."""
         with self.lock:
-            bank = self.histo_bank
-            K = bank.num_slots
-            for R in _IMPORT_LAND_ROWS:
-                if R < K:
-                    zc = np.zeros((R, bank.num_centroids), np.float32)
-                    bank = self._land_work_set(
-                        bank, np.full(R, K, np.int32), zc, zc)
             # vlint: disable=DS01 reason=all-padding warm-up landings
-            # (every id past the bank, dropped at the scatter): no live
-            # data lands, nothing to mark
+            # (ids -1 or past the bank, dropped at the scatter): no
+            # live data lands, nothing to mark
+            self.set_bank = self._land_import_sets(
+                self.set_bank,
+                [(-1, np.zeros(self.set_bank.num_registers, np.uint8))],
+                None)
+            for n in self._scalar_rows(self.counter_bank.num_slots):
+                self.counter_bank = self._land_import_counters(
+                    self.counter_bank, np.full(n, -1, np.int32),
+                    np.zeros(n, np.float32), None)
+            for n in self._scalar_rows(self.gauge_bank.num_slots):
+                self.gauge_bank, _ = self._land_import_gauges(
+                    self.gauge_bank, np.full(n, -1, np.int32),
+                    np.zeros(n, np.float32), None, 0)
+            bank = self._merge_import_scalars(self.histo_bank, [])
+            if self._heng.import_strategy == "cluster":
+                K, C = bank.num_slots, bank.num_centroids
+                cap = self._land_lanes(C)[-1]
+                for rows, lanes in self._cluster_shapes(K, C):
+                    self._heng.cluster_program(rows, lanes, C)
+                for prefix in (0, C):
+                    self._heng.cluster_program(
+                        _IMPORT_CHUNK_ROWS, cap, C, sorted_prefix=prefix)
+                for rows in self._land_row_counts(K):
+                    zc = np.zeros((rows, C), np.float32)
+                    bank = self._land_clustered(
+                        bank, None if rows == K else rows,
+                        np.zeros(0, np.int32), zc, zc)
             self.histo_bank = jax.device_put(bank, self._device)
 
     def warm_ingest_kernels(self, b: int):
@@ -1295,7 +1354,7 @@ class AggregationEngine:
         if slot < 0:
             return
         self._import_sets.append((slot, regs))
-        if len(self._import_sets) >= 256:
+        if len(self._import_sets) >= _IMPORT_STAGE_SETS:
             self._flush_import_sets()
 
     def import_counter(self, key: MetricKey, value: float):
@@ -1376,13 +1435,23 @@ class AggregationEngine:
                                                self._dirty)
 
     def _land_import_sets(self, bank, items, dirty):
+        """Union staged register rows into `bank`, a stage's worth a
+        dispatch: a flush-time tail is padded to the same
+        [_IMPORT_STAGE_SETS, m] with slot -1, which merge_rows
+        drops."""
         if not items:
             return bank
-        slots = np.array([s for s, _ in items], np.int32)
-        if dirty is not None:
-            self._mark_dirty_into(dirty, 3, slots)
-        return jax.device_put(self._seng.merge_rows(
-            bank, slots, np.stack([r for _, r in items])), self._device)
+        n = _IMPORT_STAGE_SETS
+        for i in range(0, len(items), n):
+            part = items[i:i + n]
+            slots = np.full(n, -1, np.int32)
+            slots[:len(part)] = [s for s, _ in part]
+            regs = np.zeros((n, bank.num_registers), np.uint8)
+            regs[:len(part)] = [r for _, r in part]
+            if dirty is not None:
+                self._mark_dirty_into(dirty, 3, slots[:len(part)])
+            bank = self._seng.merge_rows(bank, slots, regs)
+        return jax.device_put(bank, self._device)
 
     def _flush_import_scalars(self):
         counters, self._import_counter_acc = self._import_counter_acc, {}
@@ -1392,35 +1461,58 @@ class AggregationEngine:
             self.counter_bank, self.gauge_bank, counters, gauges,
             self._dirty, self._gauge_seq)
 
+    @staticmethod
+    def _scalar_rows(K: int) -> tuple:
+        """Entry counts counter_merge and gauge_set are dispatched at
+        for a bank of K slots, ascending: an interval's distinct
+        imported keys are padded (slot -1, dropped) to the smallest
+        that holds them. No more keys than slots can be staged."""
+        return tuple(n for n in (_IMPORT_SCALAR_ROWS,) if n < K) + (K,)
+
+    def _pad_scalars(self, K: int, slots, *cols):
+        n = next(r for r in self._scalar_rows(K) if r >= len(slots))
+        more = (0, n - len(slots))
+        return (np.pad(slots, more, constant_values=-1),
+                *(np.pad(c, more) for c in cols))
+
+    def _land_import_counters(self, bank, slots, values, dirty):
+        if dirty is not None:
+            self._mark_dirty_into(dirty, 1, slots)
+        return jax.device_put(scalar.counter_merge(
+            bank, *self._pad_scalars(bank.num_slots, slots, values)),
+            self._device)
+
+    def _land_import_gauges(self, bank, slots, values, dirty, gauge_seq):
+        if dirty is not None:
+            self._mark_dirty_into(dirty, 2, slots)
+        seqs = np.arange(len(slots), dtype=np.int32) + gauge_seq + 1
+        return jax.device_put(scalar.gauge_set(
+            bank, *self._pad_scalars(bank.num_slots, slots, values, seqs)),
+            self._device), gauge_seq + len(slots)
+
     def _land_import_scalars(self, cbank, gbank, counters, gauges,
                              dirty, gauge_seq):
         if counters:
-            slots = np.fromiter(counters.keys(), np.int32, len(counters))
-            if dirty is not None:
-                self._mark_dirty_into(dirty, 1, slots)
-            cbank = jax.device_put(scalar.counter_merge(
-                cbank, slots,
+            cbank = self._land_import_counters(
+                cbank,
+                np.fromiter(counters.keys(), np.int32, len(counters)),
                 np.fromiter(counters.values(), np.float32,
-                            len(counters))), self._device)
+                            len(counters)), dirty)
         if gauges:
-            slots = np.fromiter(gauges.keys(), np.int32, len(gauges))
-            if dirty is not None:
-                self._mark_dirty_into(dirty, 2, slots)
-            seqs = np.arange(len(gauges), dtype=np.int32) + gauge_seq + 1
-            gauge_seq += len(gauges)
-            gbank = jax.device_put(scalar.gauge_set(
-                gbank, slots,
+            gbank, gauge_seq = self._land_import_gauges(
+                gbank, np.fromiter(gauges.keys(), np.int32, len(gauges)),
                 np.fromiter(gauges.values(), np.float32, len(gauges)),
-                seqs), self._device)
+                dirty, gauge_seq)
         return cbank, gbank, gauge_seq
 
     def _flush_import_centroids(self):
         items = self._import_centroids
         self._import_centroids = []
         self._import_centroid_total = 0
-        self._count_landing(items)
-        self.histo_bank = self._land_import_centroids(
+        self.histo_bank, did = self._land_import_centroids(
             self.histo_bank, items, self._dirty)
+        for name, n in did.items():
+            setattr(self, "_" + name, getattr(self, "_" + name) + n)
 
     @staticmethod
     def _land_rows(S: int, K: int):
@@ -1432,67 +1524,91 @@ class AggregationEngine:
                 return R
         return None
 
-    def _count_landing(self, items):
-        """Under the lock, where a landing of `items` is decided (a
-        full stage, the flush's swap): count what it will do into the
-        interval's tally. Not in the landing itself: the double-
-        buffered flush lands its retired stage outside the lock, beside
-        the next interval's landings."""
-        if not items or self._heng.import_strategy != "cluster":
-            return
-        S = len({it[0] for it in items})
-        if self._land_rows(S, self.histo_bank.num_slots) is None:
-            self._import_land_bank += 1
-        else:
-            self._import_land_rows += S
+    @staticmethod
+    def _land_lanes(C: int) -> tuple:
+        """The lane widths a clustered landing pads its piles to, for
+        a bank of C centroids a row: _IMPORT_LAND_LANES below the
+        pre-cluster cap, then the cap."""
+        cap = max(_IMPORT_W_CAP, 2 * C)
+        return tuple(n for n in _IMPORT_LAND_LANES if n < cap) + (cap,)
+
+    @staticmethod
+    def _land_row_counts(K: int) -> list:
+        """The row counts a landing into a bank of K rows can hand the
+        cluster program, ascending: the work sets that serve the bank
+        (_land_rows) and, where a stage can hold more rows than the
+        largest of them, the bank's own count (the whole-bank arm)."""
+        sets = [R for R in _IMPORT_LAND_ROWS if R < K]
+        if min(K, _IMPORT_STAGE_DIGESTS) > (sets[-1] if sets else 0):
+            sets.append(K)
+        return sets
+
+    @classmethod
+    def _cluster_shapes(cls, K: int, C: int) -> list:
+        """Every [rows, lanes] a landing can hand the cluster program:
+        _land_row_counts at every step of _land_lanes."""
+        return [(R, L) for R in cls._land_row_counts(K)
+                for L in cls._land_lanes(C)]
 
     def _land_import_centroids(self, bank, items, dirty):
         """Land staged foreign digests into `bank` under the engine's
         import strategy: "cluster" (t-digest — precluster each slot's
-        pile to <= C centroids with ONE batched cluster_rows program,
+        pile to <= C centroids with ONE batched cluster program,
         then compress, fill the emptied buffers and compress again,
         over the rows the landing touches) or "direct" (compactor
         engines —
         the items re-insert as weighted points in fixed-width batches;
         the engine's own compaction bounds memory, no preclustering).
         One `import.land` stamp per landing (LAND_PHASES), whichever
-        thread runs it: a worker mid-interval, the flusher at flush."""
+        thread runs it: a worker mid-interval, the flusher at flush.
+        Returns the bank and what the landing did, by the names of
+        _IMPORT_TALLY (a clustered landing's five `import_land_*`),
+        for the caller to add to its interval's tally: the double-
+        buffered flush lands its retired stage outside the lock,
+        beside the next interval's landings."""
         if not items:
-            return bank
+            return bank, {}
         stamps = self.land_stamps
         t0 = time.monotonic_ns()
         if self._heng.import_strategy == "direct":
-            bank = self._land_imports_direct(bank, items, dirty)
+            bank, did = self._land_imports_direct(bank, items, dirty), {}
         else:
-            bank = self._land_imports_clustered(bank, items, dirty,
-                                                stamps, t0)
+            bank, did = self._land_imports_clustered(bank, items, dirty,
+                                                     stamps, t0)
         if stamps is not None:
             stamps.add("import.land", t0, time.monotonic_ns())
-        return bank
+        return bank, did
 
     def _land_imports_clustered(self, bank, items, dirty, stamps, t0):
         """The "cluster" import strategy; `t0` is where the landing's
-        `import.land.stage` phase began."""
-        C = bank.num_centroids
+        `import.land.stage` phase began. Every device program it
+        dispatches has a shape that follows from the bank's and this
+        module's constants (_cluster_shapes, _IMPORT_CHUNK_ROWS,
+        _IMPORT_STAGE_DIGESTS), and warmup() has compiled it: what was
+        staged decides which of them runs, never a new one."""
+        K, C = bank.num_slots, bank.num_centroids
 
         by_slot: dict[int, list] = {}
         for s, means, weights, *_ in items:
             by_slot.setdefault(s, []).append((means, weights))
 
         # Forwarded payloads are untrusted: a digest with millions of
-        # centroids must not size the [S, W] device matrix (resource
-        # exhaustion + a fresh XLA compile per W bucket). Pre-cluster any
-        # oversized pile in fixed-width chunks — each pass reduces a chunk
-        # of `cap` raw centroids to C clustered ones, so with cap >= 2C
-        # the loop converges geometrically and every program shape stays
-        # bounded (cap must exceed C or re-chunking could never shrink a
-        # pile at high compression settings). Pass 1 full-sorts (foreign
-        # rows are unordered AND untrusted); later passes re-merge OUR OWN
-        # cluster_rows outputs — each pile a [C] cluster-ordered row — so
-        # chunks are built pile-aligned and take cluster_rows'
+        # centroids must not size the device matrix (resource
+        # exhaustion). Pre-cluster any oversized pile in fixed-width
+        # chunks — each pass reduces a chunk of `cap` raw centroids to C
+        # clustered ones, so with cap >= 2C the loop converges
+        # geometrically and every program shape stays bounded (cap must
+        # exceed C or re-chunking could never shrink a pile at high
+        # compression settings). Pass 1 full-sorts (foreign rows are
+        # unordered AND untrusted); later passes re-merge OUR OWN
+        # cluster outputs — each pile a [C] cluster-ordered row — so
+        # chunks are built pile-aligned and take the cluster program's
         # sorted_prefix=C fast arm (the importsrv re-merge case: the
         # leading run's order is proven, only the tail needs sorting).
-        cap = max(_IMPORT_W_CAP, 2 * C)
+        # The chunks go to the device _IMPORT_CHUNK_ROWS at a time,
+        # the last dispatch padded with empty rows.
+        lanes = self._land_lanes(C)
+        cap = lanes[-1]
         trusted: set = set()   # slots whose piles are all re-clustered
         while True:
             oversized = [
@@ -1500,48 +1616,45 @@ class AggregationEngine:
                 if sum(len(m) for m, _ in piles) > cap]
             if not oversized:
                 break
-            batches = {0: ([], [], []),        # sorted_prefix -> chunks
-                       C: ([], [], [])}
-            piles_per_chunk = cap // C
+            batches = {0: ([], []), C: ([], [])}   # sorted_prefix ->
+            piles_per_chunk = cap // C              # (owners, chunks)
             for s in oversized:
                 piles = by_slot[s]
                 if s in trusted:
-                    owners, chunks_v, chunks_w = batches[C]
+                    owners, chunks = batches[C]
                     for i in range(0, len(piles), piles_per_chunk):
                         group = piles[i:i + piles_per_chunk]
-                        cv = np.zeros(piles_per_chunk * C, np.float32)
-                        cw = np.zeros(piles_per_chunk * C, np.float32)
+                        chunk = np.zeros((2, cap), np.float32)
                         for g, (m, w) in enumerate(group):
-                            cv[g * C:g * C + len(m)] = m
-                            cw[g * C:g * C + len(m)] = w
+                            chunk[0, g * C:g * C + len(m)] = m
+                            chunk[1, g * C:g * C + len(m)] = w
                         owners.append(s)
-                        chunks_v.append(cv)
-                        chunks_w.append(cw)
+                        chunks.append(chunk)
                 else:
-                    owners, chunks_v, chunks_w = batches[0]
-                    m = np.concatenate([np.asarray(p[0], np.float32)
-                                        for p in piles])
-                    w = np.concatenate([np.asarray(p[1], np.float32)
-                                        for p in piles])
-                    for i in range(0, len(m), cap):
-                        cv = np.zeros(cap, np.float32)
-                        cw = np.zeros(cap, np.float32)
-                        seg = slice(i, min(len(m), i + cap))
-                        cv[:seg.stop - seg.start] = m[seg]
-                        cw[:seg.stop - seg.start] = w[seg]
+                    owners, chunks = batches[0]
+                    flat = np.stack([
+                        np.concatenate([np.asarray(p[i], np.float32)
+                                        for p in piles]) for i in (0, 1)])
+                    for i in range(0, flat.shape[1], cap):
+                        chunk = np.zeros((2, cap), np.float32)
+                        part = flat[:, i:i + cap]
+                        chunk[:, :part.shape[1]] = part
                         owners.append(s)
-                        chunks_v.append(cv)
-                        chunks_w.append(cw)
+                        chunks.append(chunk)
                 by_slot[s] = []
-            for prefix, (owners, chunks_v, chunks_w) in batches.items():
-                if not owners:
-                    continue
-                cm, cw = self._heng.cluster_rows(
-                    np.stack(chunks_v), np.stack(chunks_w),
-                    num_centroids=C, sorted_prefix=prefix)
-                cm, cw = np.asarray(cm), np.asarray(cw)
-                for row, s in enumerate(owners):
-                    by_slot[s].append((cm[row], cw[row]))
+            for prefix, (owners, chunks) in batches.items():
+                for i in range(0, len(owners), _IMPORT_CHUNK_ROWS):
+                    part = chunks[i:i + _IMPORT_CHUNK_ROWS]
+                    both = np.zeros((2, _IMPORT_CHUNK_ROWS, cap),
+                                    np.float32)
+                    both[:, :len(part)] = np.stack(part, axis=1)
+                    cm, cw = (np.asarray(a) for a in
+                              self._heng.cluster_rows(
+                                  *both, num_centroids=C,
+                                  sorted_prefix=prefix, lanes=lanes))
+                    for row, s in enumerate(
+                            owners[i:i + _IMPORT_CHUNK_ROWS]):
+                        by_slot[s].append((cm[row], cw[row]))
             trusted.update(oversized)
 
         # rows in ascending order: the work set's scatter is told so
@@ -1549,69 +1662,94 @@ class AggregationEngine:
         slot_ids = np.fromiter(by_slot.keys(), np.int32, len(by_slot))
         if dirty is not None:
             self._mark_dirty_into(dirty, 0, slot_ids)
-        widths = [sum(len(m) for m, _ in piles)
-                  for piles in by_slot.values()]
-        W = max(128, int(np.ceil(max(widths) / 128.0) * 128))
+        # the piles side by side in an [R, L] matrix: R the work set
+        # (the bank's own rows on the whole-bank arm), L the narrowest
+        # step that holds the widest pile. Padding lanes weigh 0 and
+        # padding rows are empty, which the clustering leaves out, so
+        # a pile's centroids do not depend on the shape it rode in
         S = len(slot_ids)
-        vals = np.zeros((S, W), np.float32)
-        wts = np.zeros((S, W), np.float32)
+        R = self._land_rows(S, K)
+        widest = max(sum(len(m) for m, _ in piles)
+                     for piles in by_slot.values())
+        L = next(n for n in lanes if n >= widest)
+        both = np.zeros((2, R or K, L), np.float32)
+        filled = 0
         for row, piles in enumerate(by_slot.values()):
             off = 0
             for m, w in piles:
                 n = len(m)
-                vals[row, off:off + n] = m
-                wts[row, off:off + n] = w
+                both[0, row, off:off + n] = m
+                both[1, row, off:off + n] = w
                 off += n
+            filled += off
         t1 = time.monotonic_ns()
-        cmeans, cwts = self._heng.cluster_rows(
-            vals, wts, num_centroids=C)
-        cmeans, cwts = np.asarray(cmeans), np.asarray(cwts)
+        cmeans, cwts = (np.asarray(a) for a in self._heng.cluster_rows(
+            *both, num_centroids=C, lanes=lanes))
         if stamps is not None:
             stamps.add("import.land.stage", t0, t1)
             stamps.add("import.land.cluster", t1, time.monotonic_ns())
-        # land the clustered centroids: a compress empties the buffers,
-        # the centroids go into them, a compress folds them. A buffer
-        # holds B lanes, so the C columns go in chunks of B with a
-        # compress before each (one chunk in the default config, where
-        # B >= C).
-        K = bank.num_slots
-        R = self._land_rows(S, K)
-        if R is None:
-            # over the whole bank; merge_centroids drops on buffer
-            # overflow, hence the chunks
-            B = bank.buf_size
-            for c0 in range(0, C, B):
-                chunk = slice(c0, min(C, c0 + B))
-                width = chunk.stop - chunk.start
-                bank = self._heng.compress(bank)
-                rows = np.repeat(slot_ids, width)
-                bank = self._heng.merge_centroids(
-                    bank, rows, cmeans[:, chunk].reshape(-1),
-                    cwts[:, chunk].reshape(-1))
-            bank = self._heng.compress(bank)
-        else:
-            # over the rows it touches, padded to the work set: the
-            # padding id K lies past the bank, reads its last row at
-            # the gather and is dropped at the scatter; padding
-            # centroids weigh 0
-            more = (0, R - S)
-            bank = self._land_work_set(
-                bank, np.pad(slot_ids, more, constant_values=K),
-                np.pad(cmeans, (more, (0, 0))),
-                np.pad(cwts, (more, (0, 0))))
-
-        sl = np.array([it[0] for it in items], np.int32)
-        bank = self._heng.merge_scalars(
-            bank, sl,
-            np.array([it[3] for it in items], np.float32),
-            np.array([it[4] for it in items], np.float32),
-            np.array([it[5] for it in items], np.float32),
-            np.array([it[6] for it in items], np.float32),
-            np.array([it[7] for it in items], np.float32))
+        bank = self._land_clustered(bank, R, slot_ids, cmeans, cwts)
+        bank = self._merge_import_scalars(bank, items)
         # the merge chain above ran through plain jits whose outputs are
         # uncommitted; recommit so the ingest kernels and the flush
         # program stay on their committed (fast) executables
-        return jax.device_put(bank, self._device)
+        return jax.device_put(bank, self._device), {
+            "import_land_rows": 0 if R is None else S,
+            "import_land_bank": int(R is None),
+            "import_land_lanes": (R or K) * L,
+            "import_land_lanes_filled": filled,
+            "import_land_prechunked": len(trusted)}
+
+    def _land_clustered(self, bank, R, slot_ids, cmeans, cwts):
+        """Land clustered centroids f32[R or K, C], row i for bank row
+        slot_ids[i] (ascending; the rows past them are padding): a
+        compress empties the buffers, the centroids go into them, a
+        compress folds them. A buffer holds B lanes, so the C columns
+        go in chunks of B with a compress before each (one chunk in
+        the default config, where B >= C). Over the work set of R
+        rows, or with R None over the whole bank."""
+        K, C = bank.num_slots, bank.num_centroids
+        more = (0, len(cmeans) - len(slot_ids))
+        if R is not None:
+            # the padding id K lies past the bank, reads its last row
+            # at the gather and is dropped at the scatter
+            return self._land_work_set(
+                bank, np.pad(slot_ids, more, constant_values=K),
+                cmeans, cwts)
+        # merge_centroids drops slot -1, and on buffer overflow, hence
+        # the chunks
+        ids = np.pad(slot_ids, more, constant_values=-1)
+        B = bank.buf_size
+        for c0 in range(0, C, B):
+            chunk = slice(c0, min(C, c0 + B))
+            bank = self._heng.compress(bank)
+            # vlint: disable=DS01 reason=the bank-side half of
+            # _land_imports_clustered, which marked slot_ids before
+            # its cluster dispatch (the warm-up's all-padding call
+            # lands nothing)
+            bank = self._heng.merge_centroids(
+                bank, np.repeat(ids, chunk.stop - chunk.start),
+                cmeans[:, chunk].reshape(-1), cwts[:, chunk].reshape(-1))
+        return self._heng.compress(bank)
+
+    def _merge_import_scalars(self, bank, items):
+        """merge_scalars of staged digests' exact stats, the stage's
+        digest bound a dispatch: slot -1 pads, which the program
+        masks. (No items: one all-padding dispatch, the warm-up's.)"""
+        n = _IMPORT_STAGE_DIGESTS
+        for i in range(0, max(len(items), 1), n):
+            part = items[i:i + n]
+            slots = np.full(n, -1, np.int32)
+            stats = np.zeros((5, n), np.float32)
+            if part:
+                slots[:len(part)] = [it[0] for it in part]
+                stats[:, :len(part)] = np.array(
+                    [it[3:8] for it in part], np.float32).T
+            # vlint: disable=DS01 reason=the exact-stats half of an
+            # import landing: both callers (_land_imports_clustered,
+            # _land_imports_direct) marked the items' rows first
+            bank = self._heng.merge_scalars(bank, slots, *stats)
+        return bank
 
     def _land_work_set(self, bank, rows, means, weights):
         """Gather the [R, .] part of `bank` at `rows`, fold the
@@ -1655,14 +1793,7 @@ class AggregationEngine:
             pm[:n] = means[seg]
             pw[:n] = wts[seg]
             bank = self._heng.merge_centroids(bank, ps, pm, pw)
-        sl = np.array([it[0] for it in items], np.int32)
-        bank = self._heng.merge_scalars(
-            bank, sl,
-            np.array([it[3] for it in items], np.float32),
-            np.array([it[4] for it in items], np.float32),
-            np.array([it[5] for it in items], np.float32),
-            np.array([it[6] for it in items], np.float32),
-            np.array([it[7] for it in items], np.float32))
+        bank = self._merge_import_scalars(bank, items)
         return jax.device_put(bank, self._device)
 
     # ---------------- flush ----------------
@@ -1897,10 +2028,10 @@ class AggregationEngine:
         for ki in (self.histo_keys, self.counter_keys,
                    self.gauge_keys, self.set_keys):
             ki.advance_interval()
-        imported = (self._import_batches, self._import_metrics,
-                    self._import_land_rows, self._import_land_bank)
-        self._import_batches = self._import_metrics = 0
-        self._import_land_rows = self._import_land_bank = 0
+        imported = {name: getattr(self, "_" + name)
+                    for name in _IMPORT_TALLY}
+        for name in imported:
+            setattr(self, "_" + name, 0)
         return (active, status, stats_samples, dropped, histo_key_count,
                 imported)
 
@@ -1935,11 +2066,11 @@ class AggregationEngine:
             sb = self._land_sets(sb, dirty, a["slots"], a["reg_idx"],
                                  a["rho"])
         centroids, sets, counters, gauges = imports
-        hb = self._land_import_centroids(hb, centroids, dirty)
+        hb, did = self._land_import_centroids(hb, centroids, dirty)
         sb = self._land_import_sets(sb, sets, dirty)
         cb, gb, _seq = self._land_import_scalars(
             cb, gb, counters, gauges, dirty, gauge_seq)
-        return (hb, cb, gb, sb), overflow
+        return (hb, cb, gb, sb), overflow, did
 
     def flush(self, timestamp: int | None = None,
               forward_kind: str = "full") -> FlushResult:
@@ -1983,7 +2114,6 @@ class AggregationEngine:
                 imports = (self._import_centroids, self._import_sets,
                            self._import_counter_acc,
                            self._import_gauge_acc)
-                self._count_landing(self._import_centroids)
                 self._import_centroids = []
                 self._import_centroid_total = 0
                 self._import_sets = []
@@ -2007,8 +2137,11 @@ class AggregationEngine:
             # shared monotonic_ns clock, returned in stats["phases"]
             # so the server grafts them into the tick's phase tree
             phases = [("swap", t_start, t_swap)]
-            snap, overflow = self._land_retired(
+            snap, overflow, did = self._land_retired(
                 snap, overflow, dirty, stages, imports, retired_seq)
+            # the retired stage's landing belongs to this flush
+            for name, n in did.items():
+                imported[name] += n
             t_drain = time.monotonic_ns()
             phases.append(("drain", t_swap, t_drain))
         else:
@@ -2030,10 +2163,7 @@ class AggregationEngine:
         fwd_out = self._fwd_out
         host = self._flush_device(snap, phases=phases, dirty=dirty,
                                   overflow=overflow)
-        self._last_flush_info.update(import_batches=imported[0],
-                                     import_metrics=imported[1],
-                                     import_land_rows=imported[2],
-                                     import_land_bank=imported[3])
+        self._last_flush_info.update(imported)
         t_device = time.monotonic_ns()
 
         # Delta export build (ISSUE 13): honor the request only when
@@ -2259,13 +2389,13 @@ class AggregationEngine:
             "overflow_bank": self._last_flush_info.get("overflow_bank", 0),
             # import batches applied this interval and the metrics in
             # them (veneur.import.batches_total / batch_metrics_total)
-            "import_batches": imported[0],
-            "import_metrics": imported[1],
+            "import_batches": imported["import_batches"],
+            "import_metrics": imported["import_metrics"],
             # rows its clustered landings took through a work set, and
             # landings that took the whole-bank passes
             # (veneur.import.land_rows_total / land_bank_total)
-            "import_land_rows": imported[2],
-            "import_land_bank": imported[3],
+            "import_land_rows": imported["import_land_rows"],
+            "import_land_bank": imported["import_land_bank"],
             # what the export build actually shipped (delta requests
             # degrade to full when no bitmap exists — mesh, tracking
             # off — or the engine does not forward)

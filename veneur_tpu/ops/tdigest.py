@@ -74,7 +74,7 @@ Semantics parity notes:
 from __future__ import annotations
 
 import math
-from functools import partial
+from functools import lru_cache, partial
 from typing import NamedTuple
 
 import jax
@@ -503,6 +503,23 @@ def cluster_rows(values, weights, compression: float = 100.0,
     cluster_rows output) — never pass it for untrusted payloads."""
     return _cluster_core(values, weights, compression, num_centroids,
                          sorted_prefix=sorted_prefix)
+
+
+@lru_cache(maxsize=None)
+def cluster_program(rows: int, lanes: int, compression: float,
+                    num_centroids: int, sorted_prefix: int = 0):
+    """cluster_rows compiled ahead of time for f32[rows, lanes]
+    operands: what the import landing runs. The landing pads its
+    piles to a shape that follows from the engine's configuration
+    (models/pipeline._IMPORT_LAND_ROWS x _IMPORT_LAND_LANES) and the
+    engine's warmup() asks for each of them here, so that no landing
+    compiles. Compiled, not run: the largest pair of operands is
+    268 MB, and a warm-up has no use for the answer. The program
+    keeps cluster_rows' name in a profile."""
+    spec = jax.ShapeDtypeStruct((rows, lanes), jnp.float32)
+    return cluster_rows.lower(
+        spec, spec, compression=compression, num_centroids=num_centroids,
+        sorted_prefix=sorted_prefix).compile()
 
 
 def _take_rows(bank: TDigestBank, rows) -> TDigestBank:

@@ -195,3 +195,11 @@ def describe(heng, seng) -> dict:
                            for k in seng.__dataclass_fields__},
                 "error_contract": seng.error_contract},
     }
+
+
+def release_executables() -> None:
+    """Drop the compiled programs the engines hold process-wide (the
+    t-digest landing's cluster programs, one a shape): the sketch
+    engines' share of models/pipeline.release_executables."""
+    from ..ops import tdigest
+    tdigest.cluster_program.cache_clear()

@@ -116,12 +116,33 @@ class TDigestEngine:
     def scatter_rows(self, bank, rows, part):
         return tdigest.scatter_rows(bank, rows, part)
 
-    def cluster_rows(self, values, weights, num_centroids: int,
-                     sorted_prefix: int = 0):
-        return tdigest.cluster_rows(values, weights,
-                                    compression=self.compression,
-                                    num_centroids=num_centroids,
-                                    sorted_prefix=sorted_prefix)
+    def cluster_program(self, rows: int, lanes: int, num_centroids: int,
+                        sorted_prefix: int = 0):
+        """The compiled clustering of f32[rows, lanes] (value, weight)
+        piles to <= num_centroids a row (ops/tdigest.cluster_program):
+        warmup() asks for it, cluster_rows calls it."""
+        return tdigest.cluster_program(rows, lanes, self.compression,
+                                       num_centroids, sorted_prefix)
+
+    def cluster_rows(self, values, weights, num_centroids: int, *,
+                     lanes: tuple, sorted_prefix: int = 0):
+        """The import landing's way onto the device: cluster piles
+        f32[R, W] through the program compiled for [R, L], L the
+        narrowest step of `lanes` (the landing's ladder,
+        models/pipeline._land_lanes) that holds W. The landing hands
+        over W == L. A width between two steps is padded up with
+        lanes of weight 0, which the clustering leaves out: only the
+        benchmark's perfbench/harness.LandingWatch sends one (it
+        replays each recorded landing at every multiple of 128), and
+        the branch goes when the watch does (PERF.md 7); a width past
+        the ladder is a caller's bug."""
+        rows, width = values.shape
+        L = next(n for n in lanes if n >= width)
+        if L != width:
+            pad = ((0, 0), (0, L - width))
+            values, weights = np.pad(values, pad), np.pad(weights, pad)
+        return self.cluster_program(rows, L, num_centroids,
+                                    sorted_prefix)(values, weights)
 
     # ---- donation (the fwd_out split the flush executable uses) ----
 
