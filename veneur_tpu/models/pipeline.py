@@ -366,8 +366,8 @@ def _inc_bucket(n: int, num_slots: int) -> int:
 
 def _pad_dirty_ids(ids, num_slots: int):
     """One bank's dirty-id vector padded to its _inc_bucket width with
-    index 0 (padding rows duplicate row 0's compute; consumers read
-    only the true-D prefix)."""
+    index 0 (padding rows duplicate row 0's compute; no row map points
+    at them, see _row_maps, so the flush's assembly never reads one)."""
     b = _inc_bucket(max(ids.size, 1), num_slots)
     pad = np.zeros(b, np.int32)
     pad[:ids.size] = ids
@@ -380,17 +380,19 @@ def _inc_flush_executable(device, heng, seng, fwd_out, agg_emit,
     """The INCREMENTAL interval-flush program (ISSUE 11 tentpole):
     gather only the dirty piles into a compact [D, ·] work set, run the
     SAME flush body (_flush_program_body) over that slice, and return
-    compact [D, ·] outputs the host scatters over the cached
-    empty-bank baseline (_flush_device). Cold piles are fresh-init by
-    construction (the swap re-zeroes every row; restore re-marks
-    restored rows dirty), and the flush body maps a fresh row to the
-    baseline row bit-for-bit, so skipping cold rows is exact — the
-    oracle suite pins incremental == full per engine backend.
+    compact [D, ·] outputs, which stay compact on the host: the
+    flush's assembly reads them through a row map that sends a dirty
+    slot to its row and every cold slot to the cached empty-bank
+    baseline row (_flush_device_incremental). Cold piles are
+    fresh-init by construction (the swap re-zeroes every row; restore
+    re-marks restored rows dirty), and the flush body maps a fresh row
+    to the baseline row bit-for-bit, so skipping cold rows is exact —
+    the oracle suite pins incremental == full per engine backend.
 
     `ih/ic/ig/is_` are per-bank dirty-slot index vectors, padded to
     their _inc_bucket width with index 0 (a padding row duplicates row
-    0's compute; the host scatter consumes only the true-D prefix, so
-    the duplicate work is dropped). One executable per (engine pair,
+    0's compute; no row map points past the true-D prefix, so the
+    duplicate work is dropped). One executable per (engine pair,
     bucket-shape) combination — jit retraces per input shape under the
     one cached wrapper.
 
@@ -417,8 +419,10 @@ def _flush_baseline_cached(device, heng, seng, fwd_out, agg_emit,
                            pallas_ok, qs, kernel_arm="xla"):
     """Empty-flush baseline rows (see _flush_baseline_rows), cached at
     module level so every engine with the same sketch pair + flush
-    config shares one K=1 compile. Treat the returned rows as
-    immutable. `kernel_arm` rides the key so the baseline is built by
+    config shares one K=1 compile. The rows are read-only: a full
+    resync's export hands out cold set rows as views of them, so a
+    writer must raise, not corrupt every engine's cold rows.
+    `kernel_arm` rides the key so the baseline is built by
     the same program arm that serves (bit-identical either way — the
     fresh row is a compress fixed point under both — but the arm
     accounting at /debug stays truthful)."""
@@ -432,8 +436,12 @@ def _flush_baseline_cached(device, heng, seng, fwd_out, agg_emit,
         jax.jit(body)(*fresh, np.asarray(qs, np.float32)))
     if "s_est" in host or "s_counts" in host:
         seng.estimate_finalize(host)
-    return {k: np.asarray(v)[0]
+    rows = {k: np.asarray(v)[0]
             for k, v in host.items() if np.asarray(v).ndim}
+    for row in rows.values():
+        if isinstance(row, np.ndarray):     # [1] leaves give scalars
+            row.setflags(write=False)
+    return rows
 
 
 def release_executables() -> None:
@@ -452,8 +460,8 @@ def release_executables() -> None:
 
 
 def _out_bank_kind(key: str) -> int:
-    """Which bank's dirty-index vector an incremental output key is
-    scattered under: 0=histogram, 1=counter, 2=gauge, 3=set. Keys are
+    """Which bank's row map an incremental output key is read
+    through: 0=histogram, 1=counter, 2=gauge, 3=set. Keys are
     grouped by prefix — h_*/q/agg* and the 2Sum lo_* terms ride the
     histogram bank, c_* the counter bank, g_* the gauge bank, s_* the
     set bank."""
@@ -464,6 +472,40 @@ def _out_bank_kind(key: str) -> int:
     if key.startswith("s_"):
         return 3
     return 0
+
+
+# The flush outputs whose rows are whole sketches (centroid rows,
+# register rows): flush()'s assembly reads them one row at a time, so
+# the incremental path never copies them (_ColdTail).
+_WIDE_LEAVES = frozenset(("h_mean", "h_weight", "s_regs"))
+
+
+class _ColdTail:
+    """A wide leaf of an incremental flush: the compact [D, ·] fetch as
+    it came, with the cached empty-flush row answering for index D,
+    where the row maps send every cold slot. Read a row at a time."""
+
+    __slots__ = ("rows", "cold")
+
+    def __init__(self, rows, cold):
+        self.rows = rows
+        self.cold = cold
+
+    def __getitem__(self, r):
+        return self.rows[r] if r < len(self.rows) else self.cold
+
+
+def _row_maps(ids, dirty, nrows) -> list:
+    """Per bank kind, slot -> row of the compact flush outputs: a dirty
+    slot's position in `ids`, and for every cold slot `nrows` (the
+    fetched row count), where the baseline row sits. K x 4 bytes a
+    kind: all that is left of the host scatter."""
+    maps = []
+    for i, d, n in zip(ids, dirty, nrows):
+        m = np.full(d.size, n, np.int32)
+        m[i] = np.arange(i.size, dtype=np.int32)
+        maps.append(m)
+    return maps
 
 
 class ImportFoldReroute(Exception):
@@ -513,9 +555,10 @@ class EngineConfig:
     # Incremental dirty-slot flush (ISSUE 11): the flush program
     # consumes the SAME dirty-slot bitmap the delta checkpoints mark at
     # every device-landing site, gathers only touched piles into a
-    # compact [D, ·] work set, and scatters results over the cached
-    # empty-bank baseline — cold piles keep their (fresh-init) state
-    # and materialized rows verbatim, bit-identical to the full
+    # compact [D, ·] work set, and hands the assembly the compact
+    # results with a row map that sends every cold slot to the cached
+    # empty-bank baseline row — cold piles read their (fresh-init)
+    # state and materialized rows verbatim, bit-identical to the full
     # program by construction. Above `flush_incremental_threshold`
     # dirty fraction on the histogram bank the full program runs
     # instead (a near-full gather costs more than it saves).
@@ -796,7 +839,7 @@ class AggregationEngine:
         # cold pile materializes to) — computed lazily on a 1-slot
         # fresh bank set (engine-pair-shaped, slot-count-independent)
         self._flush_baseline = None
-        self._last_flush_info = {"path": "full"}
+        self._last_flush_info = self._full_flush_info()
         self.last_import_op = 0
         # import batches applied since the last flush and the metrics
         # in them (_last_flush_info "import_batches" / "import_metrics")
@@ -1837,10 +1880,17 @@ class AggregationEngine:
         return retired
 
     def _flush_device(self, snap, phases=None, dirty=None,
-                      overflow=None) -> dict:
+                      overflow=None) -> tuple:
         """Run the flush program on the snapshot and fetch its outputs
         as host arrays: ONE program dispatch + ONE device_get.
         Overridden by the mesh engine.
+
+        Returns (host, row_of). `host` holds the fetched arrays as
+        they are; `row_of` says where a slot's row is in them: None
+        means the identity (the full program and the mesh engine:
+        index with the slot), else one int32[K] vector a bank kind
+        (_out_bank_kind) from slot to row of the compact incremental
+        outputs. flush()'s one assembly reads both through it.
 
         `dirty` is the retired interval's dirty-slot bitmap set: when
         given (and incremental flush is on), only the touched piles
@@ -1853,11 +1903,11 @@ class AggregationEngine:
         `phases` (flight-recorder stamp list, appended in place) splits
         the merge into dispatch / device exec / fetch."""
         if dirty is not None and self._use_incremental:
-            host = self._flush_device_incremental(snap, phases, dirty,
-                                                  overflow)
-            if host is not None:
-                return host
-        self._last_flush_info = {"path": "full"}
+            got = self._flush_device_incremental(snap, phases, dirty,
+                                                 overflow)
+            if got is not None:
+                return got
+        self._last_flush_info = self._full_flush_info()
         hb, cb, gb, sb = snap
         t0 = time.monotonic_ns()
         out = self._flush_exec(hb, cb, gb, sb, self._qs)
@@ -1865,8 +1915,17 @@ class AggregationEngine:
             out["overflow"] = overflow
         t1 = time.monotonic_ns()
         if phases is None:
-            return self._fetch_flush(out)
-        return self._timed_fetch(out, t0, t1, phases)
+            return self._fetch_flush(out), None
+        return self._timed_fetch(out, t0, t1, phases), None
+
+    def _full_flush_info(self) -> dict:
+        """_last_flush_info of a flush whose outputs cover every slot:
+        `host_rows`, the rows a bank kind hands the assembly, are the
+        banks' own."""
+        cfg = self.cfg
+        return {"path": "full",
+                "host_rows": [cfg.histogram_slots, cfg.counter_slots,
+                              cfg.gauge_slots, cfg.set_slots]}
 
     def _timed_fetch(self, out, t0, t1, phases):
         """Fetch flush outputs with the device.dispatch/exec/fetch
@@ -1887,10 +1946,11 @@ class AggregationEngine:
         flush config) on a 1-slot fresh bank set through the same
         program body + fetch post-processing as the serving path
         (slot-count-independent: fresh rows are identical), shared
-        process-wide via the module cache. The incremental flush
-        scatters dirty-row outputs over these rows; bit-identity to
-        the full program holds because the flush body maps a fresh
-        bank row to exactly this row (pinned by the oracle suite)."""
+        process-wide via the module cache and read-only. The
+        incremental flush reads every cold slot from these rows;
+        bit-identity to the full program holds because the flush body
+        maps a fresh bank row to exactly this row (pinned by the
+        oracle suite)."""
         if self._flush_baseline is None:
             self._flush_baseline = _flush_baseline_cached(
                 self._device, self._heng, self._seng, self._fwd_out,
@@ -1903,15 +1963,17 @@ class AggregationEngine:
     def _flush_device_incremental(self, snap, phases, dirty, overflow):
         """The incremental dirty-slot flush (ISSUE 11 tentpole):
         gather only touched piles into a compact [D, ·] work set, run
-        the shared flush body over that slice, and scatter the compact
-        outputs over the cached empty-bank baseline on host — cold
-        piles keep their prior (fresh-init) compressed state and
-        materialized rows verbatim. Returns None to fall back to the
-        full program when the histogram bank's dirty fraction exceeds
-        flush_incremental_threshold (a near-full gather costs more
-        than it saves). Phase stamps: `gather` (host dirty-index
-        extraction + padding), the usual device phases over the
-        compact program, `scatter` (host baseline overlay)."""
+        the shared flush body over that slice, and hand the assembly
+        the compact outputs with a row map a bank kind (_compact_host,
+        _row_maps) — cold piles read the cached empty-bank baseline
+        row, which is what a fresh-init pile materializes to. Nothing
+        here is sized by the bank but the four int32[K] maps. Returns
+        None to fall back to the full program when the histogram
+        bank's dirty fraction exceeds flush_incremental_threshold (a
+        near-full gather costs more than it saves). Phase stamps:
+        `gather` (host dirty-index extraction + padding), the usual
+        device phases over the compact program, `scatter` (the
+        baseline row's hand-over and the row maps)."""
         t0 = time.monotonic_ns()
         ids = [np.nonzero(d)[0].astype(np.int32) for d in dirty]
         if ids[0].size > (self.cfg.flush_incremental_threshold
@@ -1929,14 +1991,14 @@ class AggregationEngine:
             if overflow is not None:
                 self._last_flush_info.update(overflow_rows=0,
                                              overflow_bank=0)
-            host = self._scatter_host({}, ids, dirty, base)
+            got = self._compact_host({}, ids, dirty, base, [0, 0, 0, 0])
             t1 = time.monotonic_ns()
             if phases is not None:
                 phases.append(("gather", t0, t1))
-            return host
+            return got
         hb, cb, gb, sb = snap
         idx = [_pad_dirty_ids(i, d.size) for d, i in zip(dirty, ids)]
-        self._last_flush_info["buckets"] = [len(p) for p in idx]
+        buckets = self._last_flush_info["buckets"] = [len(p) for p in idx]
         exec_ = _inc_flush_executable(
             self._device, self._heng, self._seng, self._fwd_out,
             tuple(self._agg_emit),
@@ -1955,31 +2017,33 @@ class AggregationEngine:
         else:
             host_c = self._fetch_flush(out)
         t4 = time.monotonic_ns()
-        host = self._scatter_host(host_c, ids, dirty, base)
+        got = self._compact_host(host_c, ids, dirty, base, buckets)
         t5 = time.monotonic_ns()
         if phases is not None:
             phases.append(("scatter", t4, t5))
-        return host
+        return got
 
-    def _scatter_host(self, host_c, ids, dirty, base) -> dict:
-        """Rebuild the full-[K] flush-host contract from a compact
-        [D, ·] fetch: each per-slot output starts as its baseline row
-        broadcast over the bank and the dirty rows overlay it — the
-        assembly code downstream is one implementation for both
-        paths (every output of the flush body is per-slot, so every
-        key has a baseline row)."""
-        out = {}
+    def _compact_host(self, host_c, ids, dirty, base, nrows) -> tuple:
+        """(host, row_of) of an incremental flush: the compact fetch
+        `host_c` ([nrows[kind], ·] a key; empty on an idle interval)
+        with the baseline row behind its last row, and the row maps
+        that send every cold slot there. The column leaves take it as
+        one appended row (a few bytes a row, so the assembly's
+        vectorized gathers and f64 conversions run over D + 1 rows);
+        the wide leaves are not copied (_ColdTail). flush()'s assembly
+        stays one implementation for both paths: it indexes with
+        row_of[kind][slots] here and with the slots after the full
+        program."""
+        host = {}
         for k, row in base.items():
-            kind = _out_bank_kind(k)
-            K = dirty[kind].size
             v = host_c.get(k)
-            full = np.empty((K,) + row.shape, row.dtype)
-            full[...] = row
-            n = ids[kind].size
-            if v is not None and n:
-                full[ids[kind]] = np.asarray(v)[:n]
-            out[k] = full
-        return out
+            if k in _WIDE_LEAVES:
+                host[k] = _ColdTail(() if v is None else v, row)
+            else:
+                tail = np.asarray(row)[None]
+                host[k] = tail if v is None else np.concatenate([v, tail])
+        self._last_flush_info["host_rows"] = [n + 1 for n in nrows]
+        return host, _row_maps(ids, dirty, nrows)
 
     def _fetch_flush(self, out):
         """The flush's one device_get plus its host-side finishing
@@ -2161,10 +2225,20 @@ class AggregationEngine:
             phases = [("drain", t_start, t_swap)]
 
         fwd_out = self._fwd_out
-        host = self._flush_device(snap, phases=phases, dirty=dirty,
-                                  overflow=overflow)
+        host, row_of = self._flush_device(snap, phases=phases, dirty=dirty,
+                                          overflow=overflow)
         self._last_flush_info.update(imported)
         t_device = time.monotonic_ns()
+
+        def slot_rows(kind, infos):
+            """(slots, rows) of a key table of bank `kind`: where each
+            slot's row is in `host` — the slot itself after the full
+            program, its compact row (the baseline row for a cold
+            slot) after the incremental one."""
+            slots = np.fromiter((t[1] for t in infos), np.int64,
+                                len(infos))
+            return slots, (slots if row_of is None
+                           else row_of[kind][slots])
 
         # Delta export build (ISSUE 13): honor the request only when
         # the retired bitmap exists — it travels with exactly the bank
@@ -2198,9 +2272,9 @@ class AggregationEngine:
             live_cnt = (aggmat[:, ci] if ci is not None
                         else np.asarray(host["cnt"], np.float64))
             n = len(infos)
-            slots = np.fromiter((t[1] for t in infos), np.int64, n)
+            _slots, rows = slot_rows(0, infos)
             scopes = np.fromiter((t[2] for t in infos), np.int64, n)
-            live = live_cnt[slots] > 0
+            live = live_cnt[rows] > 0
             if fwd_out:
                 h_sum = (np.asarray(host["h_sum"], np.float64)
                          + np.asarray(host["h_sum_lo"], np.float64))
@@ -2212,16 +2286,16 @@ class AggregationEngine:
                 full_m = live & (scopes == LOCAL_ONLY)
                 aggonly_m = exp_m & (scopes != GLOBAL_ONLY)
                 for i in np.nonzero(exp_m)[0].tolist():
-                    key, slot = infos[i][0], infos[i][1]
-                    w = host["h_weight"][slot]
+                    key, row = infos[i][0], rows[i]
+                    w = host["h_weight"][row]
                     nz = w > 0
                     export.histograms.append((
-                        key, host["h_mean"][slot][nz], w[nz],
-                        float(host["h_min"][slot]),
-                        float(host["h_max"][slot]),
-                        float(h_sum[slot]),
-                        float(h_count[slot]),
-                        float(h_recip[slot])))
+                        key, host["h_mean"][row][nz], w[nz],
+                        float(host["h_min"][row]),
+                        float(host["h_max"][row]),
+                        float(h_sum[row]),
+                        float(h_count[row]),
+                        float(h_recip[row])))
             else:
                 full_m = live
                 aggonly_m = None
@@ -2232,7 +2306,7 @@ class AggregationEngine:
                 frame.add_block(
                     [p[0] for p in pres], [p[2] for p in pres],
                     np.concatenate(
-                        [qmat[slots[idx]], aggmat[slots[idx]]], axis=1),
+                        [qmat[rows[idx]], aggmat[rows[idx]]], axis=1),
                     self._histo_full_types)
             if aggonly_m is not None and self._agg_emit:
                 idx = np.nonzero(aggonly_m)[0].tolist()
@@ -2240,7 +2314,7 @@ class AggregationEngine:
                     pres = [self._histo_pres_of(infos[i]) for i in idx]
                     frame.add_block(
                         [p[1] for p in pres], [p[2] for p in pres],
-                        aggmat[slots[idx]], self._histo_agg_types)
+                        aggmat[rows[idx]], self._histo_agg_types)
 
         # ---- counters ----
         infos = active["counter"]
@@ -2251,8 +2325,8 @@ class AggregationEngine:
                      + np.asarray(host["c_lo"], np.float64))
         if infos:
             n = len(infos)
-            slots = np.fromiter((t[1] for t in infos), np.int64, n)
-            totals = c_tot[slots]
+            slots, rows = slot_rows(1, infos)
+            totals = c_tot[rows]
             keep = range(n)
             if fwd_out:
                 scopes = np.fromiter((t[2] for t in infos), np.int64, n)
@@ -2283,17 +2357,18 @@ class AggregationEngine:
             # idle zeros included — the receiver-liveness refresh a
             # steady-state delta deliberately skips. Wire only; the
             # local frame above stays touched-keys-only.
-            for key, slot, scope, _h in all_infos:
+            _slots, rows = slot_rows(1, all_infos)
+            for (key, _slot, scope, _h), row in zip(all_infos, rows):
                 if scope == GLOBAL_ONLY:
-                    export.counters.append((key, float(c_tot[slot])))
+                    export.counters.append((key, float(c_tot[row])))
 
         # ---- gauges ----
         infos = active["gauge"]
         if infos:
             n = len(infos)
-            slots = np.fromiter((t[1] for t in infos), np.int64, n)
-            live = np.asarray(host["g_seq"])[slots] >= 0
-            vals = np.asarray(host["g_value"], np.float64)[slots]
+            _slots, rows = slot_rows(2, infos)
+            live = np.asarray(host["g_seq"])[rows] >= 0
+            vals = np.asarray(host["g_value"], np.float64)[rows]
             if fwd_out:
                 scopes = np.fromiter((t[2] for t in infos), np.int64, n)
                 gm = live & (scopes == GLOBAL_ONLY)
@@ -2313,8 +2388,8 @@ class AggregationEngine:
         all_infos = active.get("set_all")
         if infos:
             n = len(infos)
-            slots = np.fromiter((t[1] for t in infos), np.int64, n)
-            ests = np.asarray(host["s_est"], np.float64)[slots]
+            slots, rows = slot_rows(3, infos)
+            ests = np.asarray(host["s_est"], np.float64)[rows]
             keep = range(n)
             if fwd_out:
                 scopes = np.fromiter((t[2] for t in infos), np.int64, n)
@@ -2332,7 +2407,7 @@ class AggregationEngine:
                 if em is not None:
                     for i in np.nonzero(em)[0].tolist():
                         export.sets.append(
-                            (infos[i][0], host["s_regs"][infos[i][1]]))
+                            (infos[i][0], host["s_regs"][rows[i]]))
                 keep = np.nonzero(~fm)[0].tolist()
             keep = list(keep)
             if keep:
@@ -2344,9 +2419,10 @@ class AggregationEngine:
             # FULL resync: every interned non-local set ships its
             # registers (idle = all-zero banks, a merge no-op that
             # keeps the key alive at the receiver)
-            for key, slot, scope, _h in all_infos:
+            _slots, rows = slot_rows(3, all_infos)
+            for (key, _slot, scope, _h), row in zip(all_infos, rows):
                 if scope != LOCAL_ONLY:
-                    export.sets.append((key, host["s_regs"][slot]))
+                    export.sets.append((key, host["s_regs"][row]))
 
         # ---- status checks (StatusCheck sampler flush shape) ----
         status_metrics = [

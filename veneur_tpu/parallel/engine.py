@@ -271,9 +271,11 @@ class MeshAggregationEngine(AggregationEngine):
         return snap
 
     def _flush_device(self, snap, phases=None, dirty=None,
-                      overflow=None) -> dict:
+                      overflow=None) -> tuple:
         """Collective merge over the mesh, mapped onto the host-dict
-        contract the shared assembly consumes. `phases` (the flight
+        contract the shared assembly consumes: (host, None), every
+        output dense over the slots, so the assembly's row map is the
+        identity. `phases` (the flight
         recorder's stamp list), `dirty` and `overflow` (always None
         here — the mesh engine carries no per-slot bitmaps and its
         sharded landing counts nothing) are accepted for
@@ -308,7 +310,8 @@ class MeshAggregationEngine(AggregationEngine):
             host["aggcols"] = np.stack(cols, axis=1)
         if "count" not in self._agg_emit:
             host["cnt"] = agg["count"]
-        return host
+        self._last_flush_info = self._full_flush_info()
+        return host, None
 
     def warmup(self):
         """Compile the SPMD ingest + merged flush (+ the global tier's
