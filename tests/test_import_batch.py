@@ -140,12 +140,12 @@ def _watch_landings(eng, kind):
     them, whichever thread or path lands."""
     seen = []
     if kind == "mesh":
-        orig = eng._land_staged_centroids
+        orig = eng._stage_landing
 
         def land():
             seen.append(_plain(eng._import_centroids))
             return orig()
-        eng._land_staged_centroids = land
+        eng._stage_landing = land
     else:
         orig = eng._land_import_centroids
 

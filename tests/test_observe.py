@@ -1570,9 +1570,15 @@ def test_mesh_engine_stamps_the_device_phases():
         (c0, c1, _p, _i), = _rows(t, "engine.device.fetch")
         assert f0 <= a0 and a1 == b0 and b1 == c0 and c1 <= f1
         (d0, d1, _p, drain), = _rows(t, "engine.drain")
-        (l0, l1, lpar, _i), = _rows(t, "import.land")
+        (l0, l1, lpar, land), = _rows(t, "import.land")
         assert lpar == drain and d0 <= l0 and l1 <= d1
-        assert not _rows(t, "import.land.stage")    # no cluster step
+        # the landing's two halves: the host's up to route_batch, then
+        # the routed programs' calls; no cluster step on the mesh
+        (s0, s1, spar, _i), = _rows(t, "import.land.stage")
+        (p0, p1, ppar, _i), = _rows(t, "import.land.dispatch")
+        assert spar == ppar == land
+        assert l0 == s0 and s1 == p0 and p1 == l1
+        assert not _rows(t, "import.land.cluster")
     finally:
         srv.stop()
 

@@ -98,7 +98,10 @@ _IMPORT_SCALAR_ROWS = 1024
 # host piles + the [R, L] fill; the cluster program's dispatch + fetch.
 # The gather/compress/fill/scatter dispatches (or the whole-bank
 # compress and merge) and merge_scalars are the rest of `import.land`.
-LAND_PHASES = ("import.land", "import.land.stage", "import.land.cluster")
+# The mesh engine's landing has the two children of its own: the host
+# half up to `route_batch`, and the calls of its routed SPMD programs.
+LAND_PHASES = ("import.land", "import.land.stage", "import.land.cluster",
+               "import.land.dispatch")
 
 # The interval's import tally: engine attributes `_<name>`, added to
 # under the lock (a landing returns its own share, which the flush
@@ -757,13 +760,10 @@ class AggregationEngine:
         self._seng = sketches.set_engine(cfg)
         self._setup_device()
 
-        self.histo_keys = KeyInterner(cfg.histogram_slots,
-                                      cfg.idle_ttl_intervals)
-        self.counter_keys = KeyInterner(cfg.counter_slots,
-                                        cfg.idle_ttl_intervals)
-        self.gauge_keys = KeyInterner(cfg.gauge_slots,
-                                      cfg.idle_ttl_intervals)
-        self.set_keys = KeyInterner(cfg.set_slots, cfg.idle_ttl_intervals)
+        self.histo_keys = self._key_table(cfg.histogram_slots)
+        self.counter_keys = self._key_table(cfg.counter_slots)
+        self.gauge_keys = self._key_table(cfg.gauge_slots)
+        self.set_keys = self._key_table(cfg.set_slots)
 
         b = cfg.batch_size
         f32, i32 = (np.float32, 0.0), (np.int32, 0)
@@ -880,6 +880,10 @@ class AggregationEngine:
         # status/message per (name, tags) per interval, flushed as
         # status-typed InterMetrics — NOT passed through raw.
         self._status: dict = {}
+
+    def _key_table(self, slots: int) -> KeyInterner:
+        """The key table of a bank of `slots` rows."""
+        return KeyInterner(slots, self.cfg.idle_ttl_intervals)
 
     # ---------------- ingest ----------------
 
@@ -2092,12 +2096,16 @@ class AggregationEngine:
         for ki in (self.histo_keys, self.counter_keys,
                    self.gauge_keys, self.set_keys):
             ki.advance_interval()
-        imported = {name: getattr(self, "_" + name)
-                    for name in _IMPORT_TALLY}
-        for name in imported:
-            setattr(self, "_" + name, 0)
         return (active, status, stats_samples, dropped, histo_key_count,
-                imported)
+                self._take_tally(_IMPORT_TALLY))
+
+    def _take_tally(self, names) -> dict:
+        """The interval's counts `_<name>`, read and reset (under the
+        lock, at the flush)."""
+        tally = {name: getattr(self, "_" + name) for name in names}
+        for name in names:
+            setattr(self, "_" + name, 0)
+        return tally
 
     def _land_retired(self, snap, overflow, dirty, stages, imports,
                       gauge_seq) -> tuple:

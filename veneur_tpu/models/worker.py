@@ -42,7 +42,7 @@ class KeyInterner:
         self.capacity = capacity
         self.idle_ttl = idle_ttl_intervals
         self._map: dict[MetricKey, SlotInfo] = {}
-        self._free = list(range(capacity - 1, -1, -1))
+        self._reset_free(())
         self._by_slot: list[MetricKey | None] = [None] * capacity
         self.interval = 0
         self.dropped_no_slot = 0
@@ -69,15 +69,32 @@ class KeyInterner:
         adm = self.admission
         if adm is not None and adm.admit_key(key) is None:
             return FOLD_SLOT
-        if not self._free:
+        slot = self._take_slot(key)
+        if slot < 0:
             if adm is not None:
                 adm.release_key(key)   # admitted, but no slot to mint
             self.dropped_no_slot += 1
             return -1
-        slot = self._free.pop()
         self._map[key] = SlotInfo(slot, self.interval, scope)
         self._by_slot[slot] = key
         return slot
+
+    # Where a new key's slot comes from and an evicted key's goes back
+    # to: one free list here, lowest slot first; the mesh engine's
+    # table (parallel/interner.py) keeps one a shard.
+
+    def _take_slot(self, key: MetricKey) -> int:
+        """A free slot for `key`, or -1 when none is left."""
+        return self._free.pop() if self._free else -1
+
+    def _release_slot(self, slot: int):
+        self._free.append(slot)
+
+    def _reset_free(self, used):
+        """The free list of a new or restored table: every slot not in
+        `used`, allocation resuming from the lowest."""
+        self._free = [s for s in range(self.capacity - 1, -1, -1)
+                      if s not in used]
 
     def key_of(self, slot: int) -> MetricKey | None:
         return self._by_slot[slot]
@@ -130,9 +147,7 @@ class KeyInterner:
             self._map[key] = SlotInfo(int(slot), int(last_interval),
                                       int(scope))
             self._by_slot[int(slot)] = key
-        used = {info.slot for info in self._map.values()}
-        self._free = [s for s in range(self.capacity - 1, -1, -1)
-                      if s not in used]
+        self._reset_free({info.slot for info in self._map.values()})
 
     def advance_interval(self):
         """Called at each flush boundary: ages entries and evicts those
@@ -149,6 +164,6 @@ class KeyInterner:
         for k in dead:
             info = self._map.pop(k)
             self._by_slot[info.slot] = None
-            self._free.append(info.slot)
+            self._release_slot(info.slot)
             if adm is not None:
                 adm.release_key(k)   # budget follows bank occupancy

@@ -307,7 +307,8 @@ class TickRecord:
         and the rows hang off it; its index is returned (-1 with no
         rows). A row whose name extends another row's by a dotted
         suffix and whose edges lie inside it (`import.land.stage` in
-        `import.land`) parents under that row. Rows stamped between
+        `import.land`; on the mesh engine `import.land.dispatch` too)
+        parents under that row. Rows stamped between
         ticks begin BEFORE this tick's mono_start.
 
         Grafts never overflow the tick: with fewer free slots than

@@ -2,8 +2,10 @@
 
 This is the `tpu_num_devices > 1` serving path (SURVEY §7 step 7): one
 engine whose banks are sharded over a ("dp", "shard") mesh, fed by the
-same staging/interning machinery as the single-device engine. The host
-keeps GLOBAL slot ids (slot g lives on shard g // slots_per_shard);
+same staging machinery as the single-device engine. The host keeps
+GLOBAL slot ids (slot g lives on shard g // slots_per_shard), and the
+key tables mint a new key's slot on shard digest(key) % S
+(parallel/interner.py), so each chip owns a slice of the key space;
 each staged batch is routed into the [D, S*N] segment layout in one
 vectorized pass and landed by the MeshEngine's SPMD scatter program;
 flush is the MeshEngine's collective merge (all_gather + psum/pmax over
@@ -37,7 +39,26 @@ from ..ingest.parser import GLOBAL_ONLY
 from ..models.pipeline import (AggregationEngine, EngineConfig,
                                _precluster_k1)
 from ..models.worker import FOLD_SLOT
+from .interner import ShardedKeyInterner
 from .mesh import MeshEngine, make_mesh
+
+# What an interval's import landings did, beside the base engine's
+# tally: engine attributes `_<name>`, added to under the lock, noted in
+# _last_flush_info at the flush and reset. Points staged (the two
+# extremes riders of each digest among them), programs dispatched for
+# imports (routed ingest, scalar and set-row merges), scatter rounds,
+# and slots _stage_histos pre-clustered on the host (the landing's
+# schedule keeps it 0).
+_MESH_TALLY = ("mesh_import_points", "mesh_import_dispatches",
+               "mesh_import_rounds", "mesh_import_preclustered")
+
+# The server's self-metric (veneur.<name>_total) for each count of an
+# interval that the flush notes in _last_flush_info.
+MESH_TELEMETRY = {"mesh_import_points": "import.mesh.points",
+                  "mesh_import_dispatches": "import.mesh.dispatches",
+                  "mesh_import_rounds": "import.mesh.rounds",
+                  "mesh_import_preclustered": "import.mesh.preclustered",
+                  "mesh_interner_spills": "import.mesh.interner_spills"}
 
 
 class MeshAggregationEngine(AggregationEngine):
@@ -67,6 +88,8 @@ class MeshAggregationEngine(AggregationEngine):
         self._import_h_points = 0
         self._import_h_deltas: dict = {}
         self._set_rows_chunk = 64
+        for name in _MESH_TALLY:
+            setattr(self, "_" + name, 0)
         super().__init__(config)
 
     # ---------------- device setup ----------------
@@ -109,6 +132,13 @@ class MeshAggregationEngine(AggregationEngine):
         self._flush_exec = None
     # _fetch_flush is inherited from AggregationEngine.
 
+    def _key_table(self, slots: int) -> ShardedKeyInterner:
+        """Each chip owns a slice of the key space: a new key's row is
+        minted on shard digest(key) % S (parallel/interner.py), over the
+        bank as the MeshEngine padded it."""
+        return ShardedKeyInterner(-(-slots // self.S) * self.S, self.S,
+                                  self.cfg.idle_ttl_intervals)
+
     # ---------------- ingest ----------------
     # Staged batches carry GLOBAL slot ids straight from the interners;
     # each dispatch routes one bank's batch into the segment layout and
@@ -150,7 +180,9 @@ class MeshAggregationEngine(AggregationEngine):
                         self._pad(np.uint8)]
         return out
 
-    def _add_histos(self, slots, values, weights):
+    def _stage_histos(self, slots, values, weights) -> tuple:
+        """The host half of a histogram batch: (slots, values, weights)
+        routed into the segment layout."""
         # Hot-slot sidestep, mesh flavor: a batch overfilling one slot's
         # buffer would loop full-shard sorts inside the SPMD ingest
         # program. Pre-cluster hot slots on host to <= B weighted points
@@ -168,6 +200,7 @@ class MeshAggregationEngine(AggregationEngine):
             values = np.asarray(values, np.float32)
             weights = np.asarray(weights, np.float32)
             hot = uniq[cnt > B]
+            self._mesh_import_preclustered += len(hot)
             # compact the cold rows first: cold + (<= B points per hot
             # slot, each of which had > B raw samples) always fits the
             # original batch width, so nothing can truncate below
@@ -195,10 +228,12 @@ class MeshAggregationEngine(AggregationEngine):
             slots[:len(fs)] = fs[:n]
             values[:len(fs)] = fv[:n]
             weights[:len(fs)] = fw[:n]
-        hs, hv, hw = self._route(
+        return self._route(
             self.me.histogram_slots // self.S, slots, values, weights)
-        self.me.ingest(hs, hv, hw, *self._pads_for("counter", "gauge",
-                                                   "set"))
+
+    def _add_histos(self, slots, values, weights):
+        self.me.ingest(*self._stage_histos(slots, values, weights),
+                       *self._pads_for("counter", "gauge", "set"))
 
     def _dispatch_histos(self):
         a = self._histo_stage.drain()
@@ -265,6 +300,25 @@ class MeshAggregationEngine(AggregationEngine):
 
     # ---------------- flush ----------------
 
+    def _take_tally(self, names) -> dict:
+        """The base engine's tally of the interval with this engine's
+        own, and where the keys sit: live histogram rows a shard
+        (`mesh_shard_rows`) and keys a full shard spilled onto another
+        (`mesh_interner_spills`, all four tables), read after the
+        interval's evictions."""
+        tally = super()._take_tally(names + _MESH_TALLY)
+        # a native bridge's views (Server._setup_native_ingest) place
+        # keys themselves and count neither
+        sharded = [ki for ki in (self.histo_keys, self.counter_keys,
+                                 self.gauge_keys, self.set_keys)
+                   if isinstance(ki, ShardedKeyInterner)]
+        if isinstance(self.histo_keys, ShardedKeyInterner):
+            tally["mesh_shard_rows"] = self.histo_keys.shard_rows()
+        tally["mesh_interner_spills"] = sum(ki.spills for ki in sharded)
+        for ki in sharded:
+            ki.spills = 0
+        return tally
+
     def _swap_banks(self):
         snap = self.me.banks
         self.me.banks = self.me._fresh_fn()
@@ -278,7 +332,7 @@ class MeshAggregationEngine(AggregationEngine):
         identity. `phases` (the flight
         recorder's stamp list), `dirty` and `overflow` (always None
         here — the mesh engine carries no per-slot bitmaps and its
-        sharded landing counts nothing) are accepted for
+        in-program overflow compress counts nothing) are accepted for
         signature parity with the single-device engine. With `phases`
         the one collective program is stamped device.dispatch /
         device.exec (bounded by block_until_ready) / device.fetch, as
@@ -418,17 +472,34 @@ class MeshAggregationEngine(AggregationEngine):
         self._flush_import_centroids_locked()
 
     def _flush_import_centroids_locked(self):
+        """One landing of the staged digests, stamped `import.land`
+        with its two halves under it: `import.land.stage`, the host's
+        (rounds by slot, concatenation, padding to the batch width, the
+        hot-slot sidestep, route_batch), then `import.land.dispatch`,
+        the calls of the routed ingest and of merge_histo_scalars."""
         if not self._import_centroids:
             return
         t0 = time.monotonic_ns()
-        self._land_staged_centroids()
+        batches, deltas = self._stage_landing()
+        t1 = time.monotonic_ns()
+        pads = self._pads_for("counter", "gauge", "set")
+        for routed in batches:
+            self.me.ingest(*routed, *pads)
+        for routed in deltas:
+            self.me.merge_histo_scalars(*routed)
+        self._mesh_import_dispatches += len(batches) + len(deltas)
         if self.land_stamps is not None:
-            # the routed SPMD ingest is this engine's whole landing:
-            # no stage / cluster children
-            self.land_stamps.add("import.land", t0, time.monotonic_ns())
+            t2 = time.monotonic_ns()
+            self.land_stamps.add("import.land", t0, t2)
+            self.land_stamps.add("import.land.stage", t0, t1)
+            self.land_stamps.add("import.land.dispatch", t1, t2)
 
-    def _land_staged_centroids(self):
+    def _stage_landing(self) -> tuple:
+        """Take the staged digests and their exact-stats deltas and
+        route them: (ingest batches, merge_histo_scalars batches), each
+        the operands of one program, in dispatch order."""
         items, self._import_centroids = self._import_centroids, []
+        self._mesh_import_points += self._import_h_points
         self._import_h_points = 0
         # schedule landing so each slot contributes at most one item
         # (<= buffer_depth points) per scatter round: the recip scatter
@@ -437,7 +508,9 @@ class MeshAggregationEngine(AggregationEngine):
         by_slot: dict = {}
         for item in items:
             by_slot.setdefault(item[0], []).append(item)
+        batches = []
         while by_slot:
+            self._mesh_import_rounds += 1
             round_items = []
             for slot in list(by_slot):
                 round_items.append(by_slot[slot].pop(0))
@@ -457,9 +530,10 @@ class MeshAggregationEngine(AggregationEngine):
             fv = np.concatenate(vals)
             fw = np.concatenate(wts)
             for cs, (cv, cw) in self._batched(fs, fv, fw):
-                self._add_histos(cs, cv, cw)
+                batches.append(self._stage_histos(cs, cv, cw))
         # exact-stats correction deltas (see import_histogram)
         deltas, self._import_h_deltas = self._import_h_deltas, {}
+        routed_deltas = []
         if deltas:
             dslots = np.fromiter(deltas.keys(), np.int32, len(deltas))
             arr = np.array(list(deltas.values()), np.float64)
@@ -471,9 +545,10 @@ class MeshAggregationEngine(AggregationEngine):
                     arr[:, 2].astype(np.float32)):
                 rs, rsum, rcnt, rrcp = self._route(
                     per_shard, cs, dsum, dcnt, drcp)
-                self.me.merge_histo_scalars(
-                    rs, np.full_like(rsum, inf),
-                    np.full_like(rsum, -inf), rsum, rcnt, rrcp)
+                routed_deltas.append(
+                    (rs, np.full_like(rsum, inf),
+                     np.full_like(rsum, -inf), rsum, rcnt, rrcp))
+        return batches, routed_deltas
 
     def _flush_import_sets(self):
         self._flush_import_sets_locked()
@@ -499,6 +574,7 @@ class MeshAggregationEngine(AggregationEngine):
             out_s[0, dest] = slots[order] % per_shard
             out_r[0, dest] = regs[order]
             self.me.merge_set_rows(out_s, out_r)
+            self._mesh_import_dispatches += 1
 
     def _batched(self, flat_slots, *flat_cols):
         """Yield (slots, cols) batch_size-padded chunks of flat
@@ -528,6 +604,7 @@ class MeshAggregationEngine(AggregationEngine):
                     np.ones(len(cs), np.float32))
                 self.me.ingest(*self._pads_for("histo"), rs, rv, rw,
                                *self._pads_for("gauge", "set"))
+                self._mesh_import_dispatches += 1
         if self._import_gauge_acc:
             acc, self._import_gauge_acc = self._import_gauge_acc, {}
             slots = np.fromiter(acc.keys(), np.int32, len(acc))
@@ -541,3 +618,4 @@ class MeshAggregationEngine(AggregationEngine):
                     self.me.gauge_slots // self.S, cs, cv, seqs)
                 self.me.ingest(*self._pads_for("histo", "counter"),
                                gs, gv, gq, *self._pads_for("set"))
+                self._mesh_import_dispatches += 1

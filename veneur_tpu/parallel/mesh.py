@@ -175,7 +175,10 @@ class MeshEngine:
         comp = self.compression
         batch_spec = P("dp", "shard")  # [D, S*N] -> per-instance [1, N]
 
-        def local(banks, hs, hv, hw, cs, cv, cw, gs, gv, gq, ss, si, sr):
+        # each SPMD program is named for what it does: a profile lists
+        # it as jit_<name>
+        def mesh_ingest(banks, hs, hv, hw, cs, cv, cw, gs, gv, gq, ss, si,
+                        sr):
             sq = lambda a: a[0]
             histo = jax.tree.map(sq, banks.histo)
             histo = tdigest._add_batch_impl(histo, sq(hs), sq(hv), sq(hw),
@@ -193,7 +196,7 @@ class MeshEngine:
                              jax.tree.map(ex, sets))
 
         shmapped = jax.shard_map(
-            local, mesh=self.mesh,
+            mesh_ingest, mesh=self.mesh,
             in_specs=(self._specs,) + (batch_spec,) * 12,
             out_specs=self._specs)
         return jax.jit(shmapped, donate_argnums=(0,))
@@ -225,7 +228,7 @@ class MeshEngine:
             out_sh = jax.tree.map(lambda _: sds, self.banks)
             return jax.jit(step, donate_argnums=(0,), out_shardings=out_sh)
 
-        def local(banks, slots, regs):
+        def mesh_merge_set_rows(banks, slots, regs):
             sq = lambda a: a[0]
             sets = hll.merge_rows(jax.tree.map(sq, banks.sets),
                                   slots[0], regs[0])
@@ -233,7 +236,7 @@ class MeshEngine:
                 sets=jax.tree.map(lambda a: a[None], sets))
 
         shmapped = jax.shard_map(
-            local, mesh=self.mesh,
+            mesh_merge_set_rows, mesh=self.mesh,
             in_specs=(self._specs, P("dp", "shard"),
                       P("dp", "shard", None)),
             out_specs=self._specs)
@@ -250,7 +253,8 @@ class MeshEngine:
         """Routed fold of exact per-slot scalar deltas into the t-digest
         bank's 2Sum pairs (the global tier's exact-stats correction; the
         min/max args accept +/-inf sentinels to no-op)."""
-        def local_fn(banks, slots, dmin, dmax, dsum, dcnt, drcp):
+        def mesh_merge_histo_scalars(banks, slots, dmin, dmax, dsum, dcnt,
+                                     drcp):
             sq = lambda a: a[0]
             histo = tdigest.merge_scalars.__wrapped__(
                 jax.tree.map(sq, banks.histo), slots[0], dmin[0],
@@ -262,10 +266,10 @@ class MeshEngine:
             dev = self.mesh.devices.reshape(-1)[0]
             sds = jax.sharding.SingleDeviceSharding(dev)
             out_sh = jax.tree.map(lambda _: sds, self.banks)
-            return jax.jit(local_fn, donate_argnums=(0,),
+            return jax.jit(mesh_merge_histo_scalars, donate_argnums=(0,),
                            out_shardings=out_sh)
         shmapped = jax.shard_map(
-            local_fn, mesh=self.mesh,
+            mesh_merge_histo_scalars, mesh=self.mesh,
             in_specs=(self._specs,) + (P("dp", "shard"),) * 6,
             out_specs=self._specs)
         return jax.jit(shmapped, donate_argnums=(0,))
@@ -358,7 +362,7 @@ class MeshEngine:
         # docstring). CPU meshes therefore keep the epilogue path.
         pallas_ok = self.pallas_estimate
 
-        def merge(histo, counter, gauge, sets):
+        def mesh_flush_merge(histo, counter, gauge, sets):
             sq = lambda a: a[0]
             hb = jax.tree.map(sq, histo)
             cb = jax.tree.map(sq, counter)
@@ -422,12 +426,12 @@ class MeshEngine:
         # all_gather/psum/pmax over "dp"), but the varying-axes inference
         # can't prove it for all_gather-derived values.
         merge_fn = jax.jit(jax.shard_map(
-            merge, mesh=self.mesh,
+            mesh_flush_merge, mesh=self.mesh,
             in_specs=tuple(self._specs), out_specs=out_specs,
             check_vma=False))
 
         @jax.jit
-        def epilogue(merged, est_or_regs, qs):
+        def mesh_flush_epilogue(merged, est_or_regs, qs):
             q = tdigest.quantile(merged, qs)
             agg = tdigest.aggregates(merged)
             if pallas_ok:
@@ -441,7 +445,7 @@ class MeshEngine:
 
         def flush(banks):
             merged, c_hi, c_lo, g_seq, g_val, eor = merge_fn(*banks)
-            q, agg, est, pairs = epilogue(merged, eor, self.qs)
+            q, agg, est, pairs = mesh_flush_epilogue(merged, eor, self.qs)
             return q, agg, c_hi, c_lo, g_seq, g_val, est, pairs
 
         return flush

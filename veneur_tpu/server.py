@@ -145,11 +145,14 @@ class Server:
             # over a device mesh; slot routing replaces worker sharding
             # (SURVEY §7 step 7). Forward/import stay on the cluster
             # tier — the engine constructor enforces it.
-            from .parallel.engine import MeshAggregationEngine
+            from .parallel.engine import (MESH_TELEMETRY,
+                                          MeshAggregationEngine)
             self.engines = [MeshAggregationEngine(
                 EngineConfig(**ecfg_kw),
                 n_devices=cfg.tpu_num_devices)]
+            self._mesh_telemetry = MESH_TELEMETRY
         else:
+            self._mesh_telemetry = {}
             self.engines = [AggregationEngine(EngineConfig(**ecfg_kw))
                             for _ in range(n_workers)]
         if cfg.flight_recorder:
@@ -2126,6 +2129,10 @@ class Server:
                 raise RuntimeError("engine flush failed")
             for k in eng_stats:
                 eng_stats[k] += res.stats.get(k, 0)
+            # the mesh engine's own tally of the interval's imports,
+            # from its note on the flush
+            for k in self._mesh_telemetry:
+                eng_stats[k] = res.stats["flush_path"].get(k, 0)
             if tick is not None:
                 # graft the engine's own stamps (drain / device
                 # dispatch / device exec / fetch / materialize) under
@@ -2836,6 +2843,12 @@ class Server:
             # and landings that compressed the whole bank (the dear arm)
             tel.mark(S, "import.land_rows", eng_stats["import_land_rows"])
             tel.mark(S, "import.land_bank", eng_stats["import_land_bank"])
+            # the mesh engine's landings (veneur.import.mesh.*):
+            # points staged, programs dispatched, scatter rounds, hot
+            # slots pre-clustered on the host, keys a full shard
+            # spilled onto another
+            for k, name in self._mesh_telemetry.items():
+                tel.mark(S, name, eng_stats.get(k, 0))
             tel.set_gauge(S, "flush.swap_duration_ns",
                           eng_stats["swap_ns"])
             tel.set_gauge(S, "flush.merge_duration_ns",
