@@ -135,6 +135,20 @@ def _plain(items):
     return [tuple(_bytes(f) for f in it) for it in items]
 
 
+def _mesh_stage(eng):
+    """The mesh engine's columnar stage as plain values: the staged
+    points, the slot and point count of each staged digest, and the
+    interval's exact-stats deltas of every slot that has one."""
+    n, nd = eng._h_n, eng._h_nd
+    touched = np.flatnonzero(eng._h_deltas.any(axis=0))
+    return {"points": [c[:n].tobytes() for c in
+                       (eng._h_slots, eng._h_vals, eng._h_wts)],
+            "digests": [eng._h_dslots[:nd].tolist(),
+                        eng._h_dpoints[:nd].tolist()],
+            "deltas": [touched.tolist(),
+                       eng._h_deltas[:, touched].tobytes()]}
+
+
 def _watch_landings(eng, kind):
     """Every histogram landing's rows, in the order it was handed
     them, whichever thread or path lands."""
@@ -143,7 +157,11 @@ def _watch_landings(eng, kind):
         orig = eng._stage_landing
 
         def land():
-            seen.append(_plain(eng._import_centroids))
+            if eng._h_n:
+                # the deltas wait for the flush: a landing inside a
+                # batch finds the whole batch's there already
+                stage = _mesh_stage(eng)
+                seen.append((stage["points"], stage["digests"]))
             return orig()
         eng._stage_landing = land
     else:
@@ -163,8 +181,7 @@ def _staged(eng, kind):
              "counters": list(eng._import_counter_acc.items()),
              "gauges": list(eng._import_gauge_acc.items())}
     if kind == "mesh":
-        state["points"] = eng._import_h_points
-        state["deltas"] = sorted(eng._import_h_deltas.items())
+        state["stage"] = _mesh_stage(eng)
     else:
         state["centroid_total"] = eng._import_centroid_total
     return state

@@ -3,7 +3,10 @@ devices: eight senders' requests from the benchmark's generator
 (`forward_payloads`, rehearsal size) through `import_list`, against the
 generator's plain numpy reference and, in the exact fields, against the
 one-chip engine; a key that takes 32 digests in one landing; the
-landing's phases and the interval's counters.
+landing's phases and the interval's counters. And the columnar stage
+(ISSUE 37): a request staged by one vectorised pass against the per-key
+arithmetic it replaced, kept below as the reference; what a flush
+dispatches for an interval of two landings.
 """
 
 import os
@@ -18,10 +21,12 @@ if REPO not in sys.path:
 
 from perfbench import harness, reference  # noqa: E402
 from veneur_tpu import observe  # noqa: E402
-from veneur_tpu.cluster.protos import forward_pb2  # noqa: E402
-from veneur_tpu.ingest.parser import MetricKey  # noqa: E402
+from veneur_tpu.cluster import wire  # noqa: E402
+from veneur_tpu.cluster.protos import forward_pb2, metric_pb2  # noqa: E402
+from veneur_tpu.ingest.parser import GLOBAL_ONLY, MetricKey  # noqa: E402
 from veneur_tpu.models.pipeline import (LAND_PHASES,  # noqa: E402
-                                        AggregationEngine, EngineConfig)
+                                        AggregationEngine, EngineConfig,
+                                        _precluster_k1)
 
 SEED = 2**31 + 36
 EXACT = (".count", ".min", ".max")
@@ -232,3 +237,304 @@ def test_a_mesh_server_drains_the_tally_as_self_metrics(fleet):
     assert set(second) == set(first) and set(second.values()) == {0}
     assert names[1] == [{}, {}]
     assert "mesh_import_points" not in info        # the one-chip engine's
+
+
+# ---------------- the columnar stage (ISSUE 37) ----------------
+
+STAGE = dict(buffer_depth=64, batch_size=512)
+
+
+def _digest(ml, name, means, weights=None, lo=None, hi=None):
+    """One forwarded timer. `lo` / `hi` stand in for the exact extremes
+    where a case wants a centroid mean outside them."""
+    means = np.asarray(means, np.float64)
+    weights = np.ones(len(means)) if weights is None \
+        else np.asarray(weights, np.float64)
+    m = ml.metrics.add(name=name, type=metric_pb2.Timer, tags=["env:prod"])
+    td = m.histogram.t_digest
+    for mean, w in zip(means, weights):
+        td.centroids.add(mean=float(mean), weight=float(w))
+    td.min = float(means.min() if lo is None else lo)
+    td.max = float(means.max() if hi is None else hi)
+    td.sum = float((means * weights).sum())
+    td.count = float(weights.sum())
+    nz = means != 0
+    td.reciprocal_sum = float((weights[nz] / means[nz]).sum())
+
+
+def _requests(case):
+    """The case's traffic: a list of steps, each a request's metrics
+    (through import_list) or one digest's fields (through
+    import_histogram)."""
+    rng = np.random.default_rng([SEED, 37])
+
+    def draw(n):
+        return np.sort(rng.lognormal(np.log(100.0), 0.3, n))
+
+    ml = forward_pb2.MetricList()
+    if case == "cold_4_centroids":
+        for k in range(40):
+            x = draw(4)
+            # a mean a few ulp outside the exact extremes is clipped
+            _digest(ml, f"c.k{k}", x, lo=np.nextafter(x[0], np.inf),
+                    hi=np.nextafter(x[-1], -np.inf))
+    elif case == "hot_64_centroids":
+        for k in range(6):
+            _digest(ml, f"c.hot{k}", draw(62), rng.integers(1, 40, 62))
+    elif case == "wider_than_the_buffer":
+        _digest(ml, "c.k0", draw(4))
+        _digest(ml, "c.wide", draw(150), rng.integers(1, 9, 150))
+        _digest(ml, "c.k1", draw(4))
+        _digest(ml, "c.wide2", draw(63))
+    elif case == "zero_mean":
+        _digest(ml, "c.zero", [0.0, 0.0, 1.5, 4.0], [3.0, 1.0, 2.0, 1.0])
+        _digest(ml, "c.neg", [-2.0, 0.0, 2.0])
+    elif case == "one_slot_from_several_senders":
+        steps = []
+        for _sender in range(3):
+            ml = forward_pb2.MetricList()
+            _digest(ml, "c.shared", draw(20), rng.integers(1, 9, 20))
+            _digest(ml, "c.shared", draw(4))
+            for k in range(5):
+                _digest(ml, f"c.k{k}", draw(4))
+            steps.append(list(ml.metrics))
+        return steps
+    elif case == "stage_fills_inside_a_request":
+        # 100 x 6 = 600 points against a stage of 512: the 86th digest
+        # would overfill it (510 staged), so the landing holds 85
+        for k in range(100):
+            _digest(ml, f"c.k{k}", draw(4))
+    elif case == "corrupt_metric_mid_batch":
+        _digest(ml, "c.k0", draw(4))
+        bad = ml.metrics.add(name="c.evil", type=metric_pb2.Timer)
+        bad.histogram.t_digest.packed_centroids = b"\xff\x00garbage"
+        _digest(ml, "c.k1", draw(9), rng.integers(1, 9, 9))
+        alien = ml.metrics.add(name="c.alien", type=metric_pb2.Set)
+        alien.set.hyper_log_log = wire.encode_set_payload(
+            "ull", np.zeros(1 << 14, np.uint8))
+        _digest(ml, "c.k2", draw(4))
+    elif case == "batch_of_one_through_import_histogram":
+        x = draw(30)
+        w = rng.integers(1, 9, 30).astype(np.float64)
+        return [(MetricKey("c.one", "timer", "env:prod"), x, w,
+                 float(np.nextafter(x[0], np.inf)), float(x[-1]),
+                 float((x * w).sum()), float(w.sum()),
+                 float((w / x).sum()))]
+    else:
+        raise AssertionError(case)
+    return [list(ml.metrics)]
+
+
+# case -> (requests' rejects, digests the columnar pass staged, digests
+# that took the per-key fallback, calls of the routed ingest)
+STAGE_CASES = {
+    "cold_4_centroids": (0, 40, 0, 1),
+    "hot_64_centroids": (0, 6, 0, 1),
+    "wider_than_the_buffer": (0, 4, 2, 1),
+    "zero_mean": (0, 2, 0, 1),
+    "one_slot_from_several_senders": (0, 21, 0, 6),
+    "stage_fills_inside_a_request": (0, 100, 0, 2),
+    "corrupt_metric_mid_batch": (2, 3, 0, 1),
+    "batch_of_one_through_import_histogram": (0, 1, 0, 1),
+}
+
+
+def _per_key_reference(eng, digests):
+    """PR 36's arithmetic, a key at a time
+    (`MeshAggregationEngine._import_histogram_locked` and
+    `_stage_landing` as they stood): each digest's staged points and
+    what it adds to its slot's exact-minus-staged deltas. `digests` are
+    (key, means, weights, min, max, sum, count, reciprocal sum) in
+    staging order -> ([(slot, values f32, weights f32)], {slot: [dsum,
+    dcount, drecip]}, {slot: the sums' magnitudes})."""
+    B = eng.cfg.buffer_depth - 2
+    points, deltas, scale = [], {}, {}
+    for key, means, weights, vmin, vmax, vsum, count, recip in digests:
+        slot = eng.histo_keys.lookup(key, GLOBAL_ONLY)
+        means = np.asarray(means, np.float64)
+        weights = np.asarray(weights, np.float64)
+        if len(means) > B:
+            means, weights = _precluster_k1(means, weights, B)
+        means = np.clip(means, vmin, vmax)
+        m32 = means.astype(np.float32)
+        w32 = weights.astype(np.float32)
+        staged_sum = float((m32 * w32).astype(np.float64).sum())
+        staged_cnt = float(w32.astype(np.float64).sum())
+        nz = m32 != 0
+        staged_rcp = float((w32[nz] / m32[nz]).astype(np.float64).sum())
+        d = deltas.setdefault(slot, [0.0, 0.0, 0.0])
+        d[0] += float(vsum) - staged_sum
+        d[1] += float(count) - staged_cnt
+        d[2] += float(recip) - staged_rcp
+        sc = scale.setdefault(slot, [0.0, 0.0, 0.0])
+        sc[0] += float(np.abs(m32.astype(np.float64) * w32).sum())
+        sc[1] += staged_cnt
+        sc[2] += float(np.abs(w32[nz] / m32[nz]).astype(np.float64).sum())
+        points.append((
+            slot,
+            np.concatenate([means, [vmin, vmax]]).astype(np.float32),
+            np.concatenate([weights, [0.0, 0.0]]).astype(np.float32)))
+    return points, deltas, scale
+
+
+def _reference_calls(eng, points):
+    """The routed ingest's operands for `points` under the stage's
+    schedule: a landing is cut before the digest that would overfill
+    the batch, and holds a slot's digests one a scatter round."""
+    n = eng.cfg.batch_size
+    landings, used = [[]], 0
+    for item in points:
+        if used + len(item[1]) > n:
+            landings.append([])
+            used = 0
+        landings[-1].append(item)
+        used += len(item[1])
+    calls = []
+    for items in landings:
+        rounds, seen = [], {}
+        for item in items:
+            r = seen[item[0]] = seen.get(item[0], -1) + 1
+            if r == len(rounds):
+                rounds.append([])
+            rounds[r].append(item)
+        for round_items in rounds:
+            slots = np.full(n, -1, np.int32)
+            vals = np.zeros(n, np.float32)
+            wts = np.zeros(n, np.float32)
+            at = 0
+            for slot, v, w in round_items:
+                slots[at:at + len(v)] = slot
+                vals[at:at + len(v)] = v
+                wts[at:at + len(v)] = w
+                at += len(v)
+            calls.append(eng._route(
+                eng.me.histogram_slots // eng.S, slots, vals, wts))
+    return calls
+
+
+def _bits(operands):
+    return [np.asarray(a).tobytes() for a in operands]
+
+
+@pytest.fixture(scope="module")
+def stage_engine():
+    return _engine("mesh", **STAGE)
+
+
+@pytest.mark.parametrize("case", STAGE_CASES)
+def test_the_columnar_stage_is_the_per_key_arithmetic(stage_engine, case):
+    """The routed ingest's operands bit for bit, the exact-stats deltas
+    to the order of an f64 summation, and the two counts of the pass."""
+    eng = stage_engine
+    n_rejected, n_staged, n_fallback, n_ingests = STAGE_CASES[case]
+    ingests, folds = [], []
+
+    def spy(name, into, keep):
+        inner = getattr(eng.me, name)
+
+        def call(*a):
+            into.append(a[:keep])
+            return inner(*a)
+        setattr(eng.me, name, call)
+        return inner
+
+    restore = {"ingest": spy("ingest", ingests, 3),
+               "merge_histo_scalars": spy("merge_histo_scalars", folds, 6)}
+    digests, rejected = [], []
+    try:
+        before = (eng._mesh_import_staged, eng._mesh_import_staged_fallback)
+        for step in _requests(case):
+            if isinstance(step, tuple):
+                eng.import_histogram(*step)
+                digests.append(step)
+                continue
+            op = eng.last_import_op + 1
+            rerouted, bad = eng.import_list(op, step)
+            assert rerouted == []
+            assert eng.last_import_op == op
+            rejected += [pb.name for pb, _e in bad]
+            records, means, weights, _bad = wire.decode_metric_batch(step)
+            digests += [(rec[1], means[rec[3]:rec[4]],
+                         weights[rec[3]:rec[4]], *rec[5:])
+                        for rec in records
+                        if rec[0] == wire.IMPORT_HISTOGRAM]
+        assert len(rejected) == n_rejected
+        assert (eng._mesh_import_staged - before[0],
+                eng._mesh_import_staged_fallback - before[1]) \
+            == (n_staged, n_fallback)
+        assert folds == []           # the deltas wait for the flush
+        mid_interval = len(ingests)
+        staged_deltas = eng._h_deltas.copy()
+        with eng.lock:
+            eng._flush_import_centroids()
+    finally:
+        for name, inner in restore.items():
+            setattr(eng.me, name, inner)
+
+    points, deltas, scale = _per_key_reference(eng, digests)
+    want = _reference_calls(eng, points)
+    assert len(ingests) == len(want) == n_ingests
+    for got, ref in zip(ingests, want):
+        assert _bits(got) == _bits(ref)
+    if case == "stage_fills_inside_a_request":
+        # one dispatch inside the request, cut on the 85th digest's edge
+        assert mid_interval == 1
+        assert int((ingests[0][0] >= 0).sum()) == 85 * 6
+        assert int((ingests[1][0] >= 0).sum()) == 15 * 6
+    else:
+        assert mid_interval == 0
+
+    # the deltas: host f64 a slot until the flush, there once
+    touched = np.flatnonzero(staged_deltas.any(axis=0))
+    assert set(touched.tolist()) <= set(deltas)
+    for slot, want_d in deltas.items():
+        for row in range(3):
+            assert abs(staged_deltas[row, slot] - want_d[row]) \
+                <= 1e-12 * scale[slot][row], (case, slot, row)
+    assert not eng._h_deltas.any()
+    assert len(folds) == 1
+    per_shard = eng.me.histogram_slots // eng.S
+    rs, rmin, rmax, rsum, rcnt, rrcp = (np.asarray(a)[0] for a in folds[0])
+    at = np.flatnonzero(rs >= 0)
+    n = eng.cfg.batch_size
+    assert (at // n * per_shard + rs[at]).tolist() == touched.tolist()
+    for row, operand in enumerate((rsum, rcnt, rrcp)):
+        assert operand[at].tobytes() \
+            == staged_deltas[row, touched].astype(np.float32).tobytes()
+    assert np.all(rmin == np.inf) and np.all(rmax == -np.inf)
+
+
+def test_a_flush_of_two_landings_dispatches_them_one_fold_and_the_sets():
+    """An interval whose digests fill the stage once: the landing
+    inside the request, the landing at the flush, one fold of the
+    exact-stats deltas and one set-row merge; before ISSUE 37 every
+    landing was two calls of the routed ingest and one fold."""
+    eng = _engine("mesh", **STAGE)
+    calls = []
+    for name in ("ingest", "merge_histo_scalars", "merge_set_rows"):
+        def counting(*a, _inner=getattr(eng.me, name), _n=name):
+            calls.append(_n)
+            return _inner(*a)
+        setattr(eng.me, name, counting)
+    ml = forward_pb2.MetricList()
+    for m in _requests("stage_fills_inside_a_request")[0]:
+        ml.metrics.add().CopyFrom(m)
+    rng = np.random.default_rng([SEED, 38])
+    for k in range(3):
+        m = ml.metrics.add(name=f"c.users{k}", type=metric_pb2.Set)
+        m.set.hyper_log_log = wire.encode_set_payload(
+            "hll", rng.integers(0, 6, 1 << 14).astype(np.uint8))
+    assert eng.import_list(1, list(ml.metrics)) == ([], [])
+    assert calls == ["ingest"]
+    res = eng.flush(timestamp=37)
+    info = eng._last_flush_info
+    assert calls == ["ingest", "ingest", "merge_histo_scalars",
+                     "merge_set_rows"]
+    assert info["mesh_import_dispatches"] == 2 + 1 + 1
+    assert info["mesh_import_rounds"] == 2
+    assert info["mesh_import_points"] == 600
+    assert (info["mesh_import_staged"],
+            info["mesh_import_staged_fallback"]) == (100, 0)
+    got = {m.name: m.value for m in res.metrics}
+    assert sum(v for name, v in got.items()
+               if name.endswith(".count")) == 400.0

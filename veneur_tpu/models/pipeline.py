@@ -1439,42 +1439,53 @@ class AggregationEngine:
         lock hold in which the applied-op watermark also advances, so
         a concurrent checkpoint_state() sees either none of the op or
         all of it — the exactness the watermark's replay filter
-        depends on. Staging is the per-metric `_import_*_locked` calls,
-        so a landing still fires at the digest, centroid or set that
-        fills its stage, in the middle of a batch where that is where
-        it falls. Returns (rerouted, rejected): fold keys homed on
-        other engines as (ImportFoldReroute, pb) pairs the worker loop
-        re-routes, and per-metric poison pills as (pb, exception)
-        pairs it counts — one corrupt metric must reject itself, not
-        the op."""
+        depends on. Staging is the engine's own
+        (_stage_import_records). Returns (rerouted, rejected): fold
+        keys homed on other engines as (ImportFoldReroute, pb) pairs
+        the worker loop re-routes, and per-metric poison pills as
+        (pb, exception) pairs it counts — one corrupt metric must
+        reject itself, not the op."""
         from ..cluster import wire
         records, means, weights, rejected = wire.decode_metric_batch(pbs)
         rerouted = []
         with self.lock:
-            for rec in records:
-                kind = rec[0]
-                try:
-                    if kind == wire.IMPORT_HISTOGRAM:
-                        (_, key, _, start, stop, vmin, vmax, vsum, count,
-                         recip) = rec
-                        self._import_histogram_locked(
-                            key, means[start:stop], weights[start:stop],
-                            vmin, vmax, vsum, count, recip)
-                    elif kind == wire.IMPORT_SET:
-                        self._import_set_locked(rec[1], rec[3], rec[4])
-                    elif kind == wire.IMPORT_COUNTER:
-                        self._import_counter_locked(rec[1], rec[3])
-                    else:
-                        self._import_gauge_locked(rec[1], rec[3])
-                except ImportFoldReroute as fr:
-                    rerouted.append((fr, rec[2]))
-                except Exception as e:
-                    rejected.append((rec[2], e))
+            self._stage_import_records(records, means, weights, rerouted,
+                                       rejected)
             self._import_batches += 1
             self._import_metrics += len(pbs)
             if op_id > self.last_import_op:
                 self.last_import_op = op_id
         return rerouted, rejected
+
+    def _stage_import_records(self, records, means, weights, rerouted,
+                              rejected):
+        """Stage a decoded request (wire.decode_metric_batch) under the
+        lock, appending to `rerouted` and `rejected`. Here: the
+        per-metric `_import_*_locked` calls in wire order, so a landing
+        still fires at the digest, centroid or set that fills its
+        stage, in the middle of a batch where that is where it falls.
+        The mesh engine, whose landing is another device program,
+        stages a request's digests its own way."""
+        from ..cluster import wire
+        for rec in records:
+            kind = rec[0]
+            try:
+                if kind == wire.IMPORT_HISTOGRAM:
+                    (_, key, _, start, stop, vmin, vmax, vsum, count,
+                     recip) = rec
+                    self._import_histogram_locked(
+                        key, means[start:stop], weights[start:stop],
+                        vmin, vmax, vsum, count, recip)
+                elif kind == wire.IMPORT_SET:
+                    self._import_set_locked(rec[1], rec[3], rec[4])
+                elif kind == wire.IMPORT_COUNTER:
+                    self._import_counter_locked(rec[1], rec[3])
+                else:
+                    self._import_gauge_locked(rec[1], rec[3])
+            except ImportFoldReroute as fr:
+                rerouted.append((fr, rec[2]))
+            except Exception as e:
+                rejected.append((rec[2], e))
 
     def _flush_import_sets(self):
         items, self._import_sets = self._import_sets, []

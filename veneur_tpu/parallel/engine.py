@@ -25,6 +25,20 @@ row-merge program; counters/gauges accumulate on host and land through
 the scalar scatter kernels. Only upstream forwarding from a mesh engine
 is rejected (a multi-chip pod is a root of the aggregation tree; pods
 chain via the cluster tier's importsrv).
+
+The import stage is columnar: three point columns (slot, value,
+weight) of the routed ingest's batch width, and beside them the slot
+and point count of every staged digest. A request's digests are staged
+by one vectorised pass over the decoded batch's flat centroid columns
+(_stage_digests; a single forwarded digest is a batch of one), cut on
+a digest's edge where the next one would not fit: a landing is one
+full batch, one call of the routed ingest a scatter round. What the
+staged f32 points will add to a row's sum, count and reciprocal sum is
+worked out in the same pass, and the exact-minus-staged difference
+accumulates a slot in host f64 until the flush, which folds it in with
+merge_histo_scalars once. A dispatch of the routed ingest feeds one
+bank; the all-padding operands of the other three are put on the
+devices once (_pad) and handed over again every call.
 """
 
 from __future__ import annotations
@@ -37,7 +51,7 @@ import numpy as np
 
 from ..ingest.parser import GLOBAL_ONLY
 from ..models.pipeline import (AggregationEngine, EngineConfig,
-                               _precluster_k1)
+                               ImportFoldReroute, _precluster_k1)
 from ..models.worker import FOLD_SLOT
 from .interner import ShardedKeyInterner
 from .mesh import MeshEngine, make_mesh
@@ -47,10 +61,13 @@ from .mesh import MeshEngine, make_mesh
 # _last_flush_info at the flush and reset. Points staged (the two
 # extremes riders of each digest among them), programs dispatched for
 # imports (routed ingest, scalar and set-row merges), scatter rounds,
-# and slots _stage_histos pre-clustered on the host (the landing's
-# schedule keeps it 0).
+# slots _stage_histos pre-clustered on the host (the landing's
+# schedule keeps it 0), digests the columnar pass staged and, of those,
+# the ones too wide for a row's buffer that it first pre-clustered by
+# themselves.
 _MESH_TALLY = ("mesh_import_points", "mesh_import_dispatches",
-               "mesh_import_rounds", "mesh_import_preclustered")
+               "mesh_import_rounds", "mesh_import_preclustered",
+               "mesh_import_staged", "mesh_import_staged_fallback")
 
 # The server's self-metric (veneur.<name>_total) for each count of an
 # interval that the flush notes in _last_flush_info.
@@ -58,6 +75,9 @@ MESH_TELEMETRY = {"mesh_import_points": "import.mesh.points",
                   "mesh_import_dispatches": "import.mesh.dispatches",
                   "mesh_import_rounds": "import.mesh.rounds",
                   "mesh_import_preclustered": "import.mesh.preclustered",
+                  "mesh_import_staged": "import.mesh.staged",
+                  "mesh_import_staged_fallback":
+                      "import.mesh.staged_fallback",
                   "mesh_interner_spills": "import.mesh.interner_spills"}
 
 
@@ -85,8 +105,6 @@ class MeshAggregationEngine(AggregationEngine):
                 "the t-digest/HLL ops)")
         self._mesh_cfg = (mesh, n_devices, n_dp)
         self._pad_cache: dict = {}
-        self._import_h_points = 0
-        self._import_h_deltas: dict = {}
         self._set_rows_chunk = 64
         for name in _MESH_TALLY:
             setattr(self, "_" + name, 0)
@@ -118,6 +136,17 @@ class MeshAggregationEngine(AggregationEngine):
             hll_precision=cfg.hll_precision,
             percentiles=tuple(cfg.percentiles))
         self.S = self.me.S
+        # the import stage (module docstring): point columns of one
+        # batch, the staged digests' slots and point counts (a digest
+        # is two points at least), and the exact-stats deltas a slot
+        n = cfg.batch_size
+        self._h_slots = np.full(n, -1, np.int32)
+        self._h_vals = np.zeros(n, np.float32)
+        self._h_wts = np.zeros(n, np.float32)
+        self._h_dslots = np.zeros(n // 2, np.int32)
+        self._h_dpoints = np.zeros(n // 2, np.int64)
+        self._h_n = self._h_nd = 0
+        self._h_deltas = np.zeros((3, self.me.histogram_slots), np.float64)
         # the mesh flush is its own sharded program: XLA compress and
         # insert whatever the knob says, and the estimate reduction
         # through the Pallas kernel exactly where the MeshEngine
@@ -145,22 +174,26 @@ class MeshAggregationEngine(AggregationEngine):
     # runs the SPMD scatter with all-padding batches for the other
     # banks (fixed shapes, so there is exactly one ingest executable).
 
-    def _route(self, per_shard, slots, *arrays, fill=0.0):
+    def _route(self, per_shard, slots, *arrays, fill=0.0, segment=None):
+        """`slots` and their columns in the segment layout, a shard's
+        segment `segment` wide: as wide as the batch unless the caller
+        has seen to it that no shard takes more."""
         out = self.me.route_batch(
             slots, *arrays, slots_per_shard=per_shard,
-            n_per_segment=len(np.asarray(slots)), fill=fill)
-        assert out[-1] == 0  # segments are batch-sized: cannot overflow
+            n_per_segment=segment or len(np.asarray(slots)), fill=fill)
+        assert out[-1] == 0  # no segment can overflow
         return out[:-1]
 
     def _pad(self, dtype=np.float32, fill=0.0):
-        # all-padding batches are constant; build each once and share
-        # (JAX never mutates jit inputs, and neither do we)
+        # all-padding batches are constant: each is built once, put on
+        # the devices under the ingest program's input sharding and
+        # shared (the program donates its banks, never a batch)
         key = (np.dtype(dtype).name, fill)
         cached = self._pad_cache.get(key)
         if cached is None:
             shape = (self.me.D, self.S * self.cfg.batch_size)
-            cached = np.full(shape, fill, dtype)
-            cached.setflags(write=False)
+            cached = jax.device_put(np.full(shape, fill, dtype),
+                                    self.me.batch_sharding)
             # vlint: disable=TH01 reason=every caller (dispatch paths,
             # warmup, import landing) already holds the engine lock —
             # taking self.lock here would self-deadlock
@@ -397,54 +430,139 @@ class MeshAggregationEngine(AggregationEngine):
     # are the `_locked` halves: the base class's import_histogram /
     # import_set take the lock and import_list holds it across a batch.
 
-    def _import_histogram_locked(self, key, means, weights, vmin, vmax,
-                                 vsum, count, recip=0.0):
-        slot = self.histo_keys.lookup(key, GLOBAL_ONLY)
+    def _import_slot(self, interner, key) -> int:
+        """The row a forwarded key lands in, or < 0 for none."""
+        slot = interner.lookup(key, GLOBAL_ONLY)
         if slot == FOLD_SLOT:
             # overload defense: over-budget forwarded keys fold
             # into `<prefix>.__other__` here too (the mesh server
             # is a single engine, so the fold is always local)
-            slot = self._fold_import_slot(self.histo_keys, key)
-        if slot < 0:
-            return
-        means = np.asarray(means, np.float64)
-        weights = np.asarray(weights, np.float64)
-        # cap at B-2 so item + extreme riders never exceeds B — the
-        # landing batches are scheduled so one slot never overflows
-        # its buffer in a single scatter, keeping the hot-slot
-        # pre-cluster (whose recip is approximate) OFF this path
-        B = self.cfg.buffer_depth - 2
-        if len(means) > B:
-            means, weights = _precluster_k1(means, weights, B)
+            slot = self._fold_import_slot(interner, key)
+        return slot
+
+    def _import_histogram_locked(self, key, means, weights, vmin, vmax,
+                                 vsum, count, recip=0.0):
+        slot = self._import_slot(self.histo_keys, key)
+        if slot >= 0:
+            self._stage_digests(
+                [slot], [(0, len(means), vmin, vmax, vsum, count, recip)],
+                means, weights)
+
+    def _stage_import_records(self, records, means, weights, rerouted,
+                              rejected):
+        """A request's digests through one pass of _stage_digests, a
+        row looked up a key; then its other metrics as the base engine
+        stages them. A key that rejects or re-routes does so by itself
+        and the rest of the request stages."""
+        from ..cluster import wire
+        slots, table, others = [], [], []
+        for rec in records:
+            if rec[0] != wire.IMPORT_HISTOGRAM:
+                others.append(rec)
+                continue
+            try:
+                slot = self._import_slot(self.histo_keys, rec[1])
+            except ImportFoldReroute as fr:
+                rerouted.append((fr, rec[2]))
+                continue
+            except Exception as e:
+                rejected.append((rec[2], e))
+                continue
+            if slot >= 0:
+                slots.append(slot)
+                table.append(rec[3:])
+        if slots:
+            self._stage_digests(slots, table, means, weights)
+        super()._stage_import_records(others, means, weights, rerouted,
+                                      rejected)
+
+    def _stage_digests(self, slots, table, means, weights):
+        """Stage digests into the point columns, landing a batch
+        wherever the next digest would not fit. `table` has a row a
+        digest: (start, stop, min, max, sum, count, reciprocal sum),
+        its centroids `means[start:stop]`, `weights[start:stop]`; and
+        `slots` its row in the bank."""
+        slots = np.asarray(slots, np.int32)
+        table = np.asarray(table, np.float64).reshape(len(slots), 7)
+        starts = table[:, 0].astype(np.int64)
+        lens = table[:, 1].astype(np.int64) - starts
+        vmin, vmax = table[:, 2], table[:, 3]
+        # a row's buffer takes one digest a scatter round, its two
+        # extremes riders with it: a wider digest is pre-clustered to
+        # that width first, a key at a time. The hot-slot sidestep of
+        # _stage_histos (whose recip is approximate) then never runs.
+        # (Nor can the stage take a digest wider than a batch.)
+        cap = min(self.cfg.buffer_depth, self.cfg.batch_size) - 2
+        wide = np.flatnonzero(lens > cap)
+        self._mesh_import_staged += len(slots)
+        self._mesh_import_staged_fallback += len(wide)
+        if len(wide):
+            cols_m = [np.asarray(means, np.float64)]
+            cols_w = [np.asarray(weights, np.float64)]
+            end = len(cols_m[0])
+            for i in wide.tolist():
+                seg = slice(starts[i], starts[i] + lens[i])
+                cm, cw = _precluster_k1(cols_m[0][seg], cols_w[0][seg], cap)
+                cols_m.append(cm)
+                cols_w.append(cw)
+                starts[i], lens[i] = end, len(cm)
+                end += len(cm)
+            means, weights = np.concatenate(cols_m), np.concatenate(cols_w)
+        n, total = len(slots), int(lens.sum())
+        # the digests' centroids side by side, `of` the digest of each
+        of = np.repeat(np.arange(n), lens)
+        first = np.cumsum(lens) - lens
+        take = np.arange(total) + np.repeat(starts - first, lens)
+        m64 = np.asarray(means)[take].astype(np.float64)
         # a centroid mean comes out of a cumsum difference and can
         # sit a few ulp outside the digest's exact [vmin, vmax];
         # staged as a sample it would then move this slot's
         # extremes off the forwarded exact ones
-        means = np.clip(means, vmin, vmax)
-        self._import_centroids.append(
-            (slot, means, weights, float(vmin), float(vmax)))
-        self._import_h_points += len(means) + 2
+        m32 = np.clip(m64, vmin[of], vmax[of]).astype(np.float32)
+        w32 = np.asarray(weights)[take].astype(np.float32)
         # The staged centroids flow through the ingest scatter, so
         # they CONTRIBUTE approximate vsum/count/recip; accumulate
-        # the exact-minus-staged delta per slot (f64 host math) and
-        # fold it in via merge_histo_scalars — making the flushed
-        # sum/count/hmean match the forwarded exact values, like
-        # the single-device merge_scalars path.
-        # replicate the device's f32 per-term arithmetic so the
-        # delta cancels the staged contribution to rounding level
-        m32 = means.astype(np.float32)
-        w32 = weights.astype(np.float32)
-        staged_sum = float((m32 * w32).astype(np.float64).sum())
-        staged_cnt = float(w32.astype(np.float64).sum())
-        nz = m32 != 0
-        staged_rcp = float((w32[nz] / m32[nz])
-                           .astype(np.float64).sum())
-        d = self._import_h_deltas.setdefault(slot, [0.0, 0.0, 0.0])
-        d[0] += float(vsum) - staged_sum
-        d[1] += float(count) - staged_cnt
-        d[2] += float(recip) - staged_rcp
-        if self._import_h_points >= self.cfg.batch_size:
-            self._flush_import_centroids_locked()
+        # the exact-minus-staged delta per slot (f64 host math) for
+        # the flush to fold in via merge_histo_scalars — making the
+        # flushed sum/count/hmean match the forwarded exact values,
+        # like the single-device merge_scalars path. Replicate the
+        # device's f32 per-term arithmetic so the delta cancels the
+        # staged contribution to rounding level
+        rcp = np.zeros(total, np.float32)
+        np.divide(w32, m32, out=rcp, where=m32 != 0)
+        for row, col, terms in ((0, 4, m32 * w32), (1, 5, w32), (2, 6, rcp)):
+            np.add.at(self._h_deltas[row], slots,
+                      table[:, col] - np.bincount(of, terms, n))
+        # the points: a digest's centroids, then its exact extremes as
+        # zero-weight samples: they update the min/max scatter, add
+        # nothing to sum/count/recip
+        points = lens + 2
+        last = np.cumsum(points)
+        p_vals = np.empty(total + 2 * n, np.float32)
+        p_wts = np.zeros(total + 2 * n, np.float32)
+        at = np.arange(total) + 2 * of
+        p_vals[at], p_wts[at] = m32, w32
+        p_vals[last - 2], p_vals[last - 1] = vmin, vmax
+        p_slots = np.repeat(slots, points)
+        # into the stage, a batch at a time, cut on a digest's edge
+        i = done = 0
+        while i < n:
+            room = self.cfg.batch_size - self._h_n
+            j = int(np.searchsorted(last, done + room, side="right"))
+            if j > i:
+                upto = int(last[j - 1])
+                dst = slice(self._h_n, self._h_n + upto - done)
+                self._h_slots[dst] = p_slots[done:upto]
+                self._h_vals[dst] = p_vals[done:upto]
+                self._h_wts[dst] = p_wts[done:upto]
+                dst = slice(self._h_nd, self._h_nd + j - i)
+                self._h_dslots[dst] = slots[i:j]
+                self._h_dpoints[dst] = points[i:j]
+                self._h_n += upto - done
+                self._h_nd += j - i
+                i, done = j, upto
+            if i < n:
+                self._land_stage_locked()
 
     def _import_set_locked(self, key, registers, engine_id=None):
         # the mesh engine is hll-only (constructor guard): a wire row
@@ -454,9 +572,7 @@ class MeshAggregationEngine(AggregationEngine):
             raise ValueError(
                 f"set sketch engine mismatch: payload {engine_id!r}, "
                 "mesh banks run 'hll'")
-        slot = self.set_keys.lookup(key, GLOBAL_ONLY)
-        if slot == FOLD_SLOT:
-            slot = self._fold_import_slot(self.set_keys, key)
+        slot = self._import_slot(self.set_keys, key)
         if slot < 0:
             return
         self._import_sets.append(
@@ -469,18 +585,21 @@ class MeshAggregationEngine(AggregationEngine):
     # onto the routed scalar kernels.
 
     def _flush_import_centroids(self):
-        self._flush_import_centroids_locked()
+        """The flush's drain of the stage: what is left lands, and the
+        interval's exact-stats deltas are folded in."""
+        self._land_stage_locked(fold=True)
 
-    def _flush_import_centroids_locked(self):
-        """One landing of the staged digests, stamped `import.land`
+    def _land_stage_locked(self, fold=False):
+        """One landing of the staged points, stamped `import.land`
         with its two halves under it: `import.land.stage`, the host's
-        (rounds by slot, concatenation, padding to the batch width, the
-        hot-slot sidestep, route_batch), then `import.land.dispatch`,
-        the calls of the routed ingest and of merge_histo_scalars."""
-        if not self._import_centroids:
+        (rounds by slot, the hot-slot sidestep, route_batch), then
+        `import.land.dispatch`, the calls of the routed ingest and,
+        with `fold`, of merge_histo_scalars."""
+        if not self._h_n and not (fold and self._h_deltas.any()):
             return
         t0 = time.monotonic_ns()
-        batches, deltas = self._stage_landing()
+        batches = self._stage_landing()
+        deltas = self._stage_deltas() if fold else []
         t1 = time.monotonic_ns()
         pads = self._pads_for("counter", "gauge", "set")
         for routed in batches:
@@ -494,61 +613,61 @@ class MeshAggregationEngine(AggregationEngine):
             self.land_stamps.add("import.land.stage", t0, t1)
             self.land_stamps.add("import.land.dispatch", t1, t2)
 
-    def _stage_landing(self) -> tuple:
-        """Take the staged digests and their exact-stats deltas and
-        route them: (ingest batches, merge_histo_scalars batches), each
-        the operands of one program, in dispatch order."""
-        items, self._import_centroids = self._import_centroids, []
-        self._mesh_import_points += self._import_h_points
-        self._import_h_points = 0
-        # schedule landing so each slot contributes at most one item
-        # (<= buffer_depth points) per scatter round: the recip scatter
-        # then sees the staged points verbatim and the exact-stats
-        # deltas cancel to rounding level
-        by_slot: dict = {}
-        for item in items:
-            by_slot.setdefault(item[0], []).append(item)
-        batches = []
-        while by_slot:
-            self._mesh_import_rounds += 1
-            round_items = []
-            for slot in list(by_slot):
-                round_items.append(by_slot[slot].pop(0))
-                if not by_slot[slot]:
-                    del by_slot[slot]
-            slots, vals, wts = [], [], []
-            for slot, means, weights, vmin, vmax in round_items:
-                n = len(means) + 2
-                slots.append(np.full(n, slot, np.int32))
-                vals.append(np.concatenate(
-                    [means, [vmin, vmax]]).astype(np.float32))
-                # exact extremes as zero-weight samples: they update
-                # the min/max scatter, add nothing to sum/count/recip
-                wts.append(np.concatenate(
-                    [weights, [0.0, 0.0]]).astype(np.float32))
-            fs = np.concatenate(slots)
-            fv = np.concatenate(vals)
-            fw = np.concatenate(wts)
-            for cs, (cv, cw) in self._batched(fs, fv, fw):
-                batches.append(self._stage_histos(cs, cv, cw))
-        # exact-stats correction deltas (see import_histogram)
-        deltas, self._import_h_deltas = self._import_h_deltas, {}
-        routed_deltas = []
-        if deltas:
-            dslots = np.fromiter(deltas.keys(), np.int32, len(deltas))
-            arr = np.array(list(deltas.values()), np.float64)
-            per_shard = self.me.histogram_slots // self.S
-            inf = np.float32(np.inf)
-            for cs, (dsum, dcnt, drcp) in self._batched(
-                    dslots, arr[:, 0].astype(np.float32),
-                    arr[:, 1].astype(np.float32),
-                    arr[:, 2].astype(np.float32)):
-                rs, rsum, rcnt, rrcp = self._route(
-                    per_shard, cs, dsum, dcnt, drcp)
-                routed_deltas.append(
-                    (rs, np.full_like(rsum, inf),
-                     np.full_like(rsum, -inf), rsum, rcnt, rrcp))
-        return batches, routed_deltas
+    def _stage_landing(self) -> list:
+        """Take the staged points and route them: the operands of each
+        call of the routed ingest, in dispatch order."""
+        n, nd = self._h_n, self._h_nd
+        if not n:
+            return []
+        self._mesh_import_points += n
+        # schedule the landing so each slot contributes at most one
+        # digest (<= buffer_depth points) per scatter round: the recip
+        # scatter then sees the staged points verbatim and the
+        # exact-stats deltas cancel to rounding level. A digest's round
+        # is its rank among the stage's digests of its slot
+        dslots = self._h_dslots[:nd]
+        order = np.argsort(dslots, kind="stable")
+        run = np.flatnonzero(np.diff(dslots[order], prepend=-1))
+        rank = np.empty(nd, np.int64)
+        rank[order] = np.arange(nd) - np.repeat(
+            run, np.diff(run, append=nd))
+        rounds = int(rank.max()) + 1
+        self._mesh_import_rounds += rounds
+        # a round is the stage with the other rounds' points masked
+        # out: route_batch packs the valid ones, in their order
+        p_rank = np.full(len(self._h_slots), -1, np.int64)
+        p_rank[:n] = np.repeat(rank, self._h_dpoints[:nd])
+        batches = [
+            self._stage_histos(np.where(p_rank == r, self._h_slots, -1),
+                               self._h_vals, self._h_wts)
+            for r in range(rounds)]
+        self._h_slots[:n] = -1
+        self._h_n = self._h_nd = 0
+        return batches
+
+    def _stage_deltas(self) -> list:
+        """Take the interval's exact-stats deltas and route them: the
+        operands of each call of merge_histo_scalars, a shard's segment
+        filled before a second call is made."""
+        slots = np.flatnonzero(self._h_deltas.any(axis=0)).astype(np.int32)
+        if not len(slots):
+            return []
+        dsum, dcnt, drcp = self._h_deltas[:, slots].astype(np.float32)
+        self._h_deltas[:, slots] = 0.0
+        n = self.cfg.batch_size
+        per_shard = self.me.histogram_slots // self.S
+        shard = slots // per_shard
+        call = (np.arange(len(slots)) - np.searchsorted(shard, shard)) // n
+        inf = np.float32(np.inf)
+        routed = []
+        for c in range(int(call.max()) + 1):
+            take = np.flatnonzero(call == c)
+            rs, rsum, rcnt, rrcp = self._route(
+                per_shard, slots[take], dsum[take], dcnt[take], drcp[take],
+                segment=n)
+            routed.append((rs, np.full_like(rsum, inf),
+                           np.full_like(rsum, -inf), rsum, rcnt, rrcp))
+        return routed
 
     def _flush_import_sets(self):
         self._flush_import_sets_locked()

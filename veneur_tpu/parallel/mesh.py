@@ -91,6 +91,7 @@ class MeshEngine:
         # kernel inside the shard_map, or jnp in the epilogue
         self.pallas_estimate = hll.will_use_pallas(1 << hll_precision)
         self._specs = None
+        self.batch_sharding = NamedSharding(mesh, P("dp", "shard"))
         self.banks = self._init_banks()
         if self._single:
             self._ingest_fn = self._build_ingest_single()
@@ -206,9 +207,13 @@ class MeshEngine:
         """Sample arrays are [D, S*N]: row d feeds dp replica d; columns
         are S per-shard segments of N, each holding LOCAL slot ids
         (-1 padding)."""
-        self.banks = self._ingest_fn(
-            self.banks, h_slots, h_vals, h_wts, c_slots, c_vals, c_wts,
-            g_slots, g_vals, g_seqs, s_slots, s_idx, s_rho)
+        # every operand committed under the program's input sharding
+        # (one executable whoever built them), so an operand that never
+        # changes can be put once by its owner and handed over again
+        batches = jax.device_put(
+            (h_slots, h_vals, h_wts, c_slots, c_vals, c_wts, g_slots,
+             g_vals, g_seqs, s_slots, s_idx, s_rho), self.batch_sharding)
+        self.banks = self._ingest_fn(self.banks, *batches)
 
     def _build_merge_set_rows(self):
         """SPMD union of forwarded HLL register rows into the sharded
