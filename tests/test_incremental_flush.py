@@ -225,8 +225,8 @@ def test_idle_interval_skips_the_device_program():
 
 def test_double_buffer_phases_and_lock_window():
     """The tick's phase stamps carry the new engine.swap/gather/scatter
-    names, and the lock-held window (swap_ns) excludes the retired
-    drain + device + materialize work."""
+    names, and the lock-held window (the `swap` row) excludes the
+    retired drain + device + materialize work."""
     eng = _mk_engine(True)
     rng = np.random.default_rng(0)
     _touch(eng, rng, list(range(0, K_H, 10)))
@@ -235,10 +235,13 @@ def test_double_buffer_phases_and_lock_window():
     assert names[:2] == ["swap", "drain"]
     assert "gather" in names and "scatter" in names
     total_ns = sum(p[2] - p[1] for p in res.stats["phases"])
-    assert res.stats["swap_ns"] < total_ns  # lock window is a slice,
-    # not the tick: drain/device/materialize happen outside it
-    assert res.stats["swap_ns"] + res.stats["merge_ns"] \
-        + res.stats["assembly_ns"] > 0
+    (_n, s0, s1), drain = res.stats["phases"][:2]
+    assert 0 < s1 - s0 < total_ns  # lock window is a slice, not the
+    # tick: drain/device/materialize happen outside it, after it
+    assert drain[1] == s1
+    assert res.stats["phases"][-1][0] == "materialize"
+    # the same edges are not served a second way
+    assert not {"swap_ns", "merge_ns", "assembly_ns"} & set(res.stats)
 
 
 def test_server_defaults_run_the_incremental_path():

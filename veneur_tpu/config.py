@@ -245,7 +245,7 @@ class Config:
     # the tick).
     flight_recorder: bool = True
     flight_recorder_ticks: int = 32        # ring: last N ticks kept
-    flight_recorder_max_phases: int = 192  # per-tick phase slot budget
+    flight_recorder_max_phases: int = 256  # per-tick phase slot budget
     # Dogfood loop: re-ingest each tick's top-level phase durations as
     # LOCAL-ONLY `veneur.flush.phase.*` timers, so the engine serves
     # percentiles of its own flush phases like any tenant metric.

@@ -102,14 +102,44 @@ _IMPORT_SCALAR_ROWS = 1024
 # half up to `route_batch`, and the calls of its routed SPMD programs.
 LAND_PHASES = ("import.land", "import.land.stage", "import.land.cluster",
                "import.land.dispatch")
+# ... and the three one import request stamps into the same log, inside
+# the worker's `import.apply` run that holds it (import_list): the
+# request's protobuf walk outside the lock; the wait for the engine's
+# lock; the lock hold, staging and tally, the landings that fall in
+# the batch inside it. IMPORT_PHASES is every name the log holds.
+APPLY_PHASES = ("import.apply.decode", "import.apply.lock_wait",
+                "import.apply.stage")
+IMPORT_PHASES = LAND_PHASES + APPLY_PHASES
 
 # The interval's import tally: engine attributes `_<name>`, added to
 # under the lock (a landing returns its own share, which the flush
 # adds for its retired stage), noted in _last_flush_info at the flush
-# and reset.
+# and reset. APPLY_CPU_TALLY is the applying thread's CPU nanoseconds
+# (time.thread_time_ns) over the stretches `import.apply.decode` and
+# `import.apply.stage` time on the wall clock; 0 with no stamp log.
+APPLY_CPU_TALLY = ("import_decode_cpu_ns", "import_stage_cpu_ns")
 _IMPORT_TALLY = ("import_batches", "import_metrics", "import_land_rows",
                  "import_land_bank", "import_land_lanes",
-                 "import_land_lanes_filled", "import_land_prechunked")
+                 "import_land_lanes_filled", "import_land_prechunked",
+                 ) + APPLY_CPU_TALLY
+
+
+def _open_clocks() -> tuple:
+    """(monotonic_ns, the calling thread's CPU ns) where a stretch
+    begins: the wall clock first, and last in _close_clocks, so the
+    CPU reading's window lies inside the wall reading's and a thread
+    that never left the CPU reads no more CPU than wall time."""
+    t = time.monotonic_ns()
+    return t, time.thread_time_ns()
+
+
+def _close_clocks() -> tuple:
+    c = time.thread_time_ns()
+    return time.monotonic_ns(), c
+
+
+def _no_clock() -> tuple:
+    return 0, 0
 
 
 def _precluster_k1(v, w, n_points, keep_extremes=False):
@@ -857,6 +887,11 @@ class AggregationEngine:
         self._import_land_lanes = 0
         self._import_land_lanes_filled = 0
         self._import_land_prechunked = 0
+        # the applying threads' CPU time in import_list, outside and
+        # under the lock (_last_flush_info "import_decode_cpu_ns" /
+        # "import_stage_cpu_ns"; counted only with land_stamps armed)
+        self._import_decode_cpu_ns = 0
+        self._import_stage_cpu_ns = 0
         # Overload defense (ingest/admission.py): attached by the
         # Server via attach_admission; None = every key mints freely
         # (direct engine construction, the pre-defense behavior).
@@ -869,7 +904,7 @@ class AggregationEngine:
         # not one per key.
         self._import_centroids: list = []
         self._import_centroid_total = 0
-        # observe.StampLog for LAND_PHASES, set by a Server whose
+        # observe.StampLog for IMPORT_PHASES, set by a Server whose
         # flight recorder is on; flush() hands its rows to the tick
         self.land_stamps = None
         self._import_sets: list = []          # (slot, registers u8[m])
@@ -1446,15 +1481,33 @@ class AggregationEngine:
         (pb, exception) pairs it counts — one corrupt metric must
         reject itself, not the op."""
         from ..cluster import wire
+        # with the stamp log armed: the three APPLY_PHASES and the
+        # thread's CPU time beside two of them, four readings of each
+        # clock a request; without it no clock is read
+        stamps = self.land_stamps
+        opened, closed = ((_no_clock, _no_clock) if stamps is None
+                          else (_open_clocks, _close_clocks))
+        t0, c0 = opened()
         records, means, weights, rejected = wire.decode_metric_batch(pbs)
+        t1, c1 = closed()
         rerouted = []
         with self.lock:
+            t2, c2 = opened()
             self._stage_import_records(records, means, weights, rerouted,
                                        rejected)
             self._import_batches += 1
             self._import_metrics += len(pbs)
             if op_id > self.last_import_op:
                 self.last_import_op = op_id
+            t3, c3 = closed()
+            self._import_decode_cpu_ns += c1 - c0
+            self._import_stage_cpu_ns += c3 - c2
+        if stamps is not None:
+            # no merge gap: requests interleave, and a decode row
+            # merged with the next would swallow the stage between
+            stamps.add("import.apply.decode", t0, t1)
+            stamps.add("import.apply.lock_wait", t1, t2)
+            stamps.add("import.apply.stage", t2, t3)
         return rerouted, rejected
 
     def _stage_import_records(self, records, means, weights, rerouted,
@@ -2455,23 +2508,19 @@ class AggregationEngine:
                 hostname=sc.hostname or cfg.hostname)
             for sc in status.values()]
 
-        t_end = time.monotonic_ns()
-        phases.append(("materialize", t_device, t_end))
+        phases.append(("materialize", t_device, time.monotonic_ns()))
         stats = {
             "samples": stats_samples,
             "histo_keys": histo_key_count,
             "dropped_no_slot": dropped,
-            # Flush phase durations (veneur's flush.*_duration_ns
-            # self-metrics; flusher.go sym: Server.Flush spans).
-            # swap_ns is the LOCK-HELD window: under double buffering
-            # that is the retire-and-swap only; merge_ns then includes
-            # the out-of-lock retired drain + the device program.
-            "swap_ns": t_swap - t_start,
-            "merge_ns": t_device - t_swap,
-            "assembly_ns": t_end - t_device,
+            # where the flush's time went, as flight-recorder rows:
+            # `swap` is the LOCK-HELD window (under double buffering
+            # the retire-and-swap only), then the out-of-lock `drain`,
+            # the device program's and `materialize`
             "phases": phases,
-            # import landings since the previous flush (LAND_PHASES
-            # rows, this flush's own among them)
+            # import requests applied and landings run since the
+            # previous flush (IMPORT_PHASES rows, this flush's own
+            # landing among them)
             "import_phases": ([] if self.land_stamps is None
                               else self.land_stamps.take()),
             # which device path ran (full vs incremental + dirty/pile

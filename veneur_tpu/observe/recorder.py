@@ -300,12 +300,12 @@ class TickRecord:
         return i
 
     def graft(self, rows, root: str | None = None,
-              parent: int = -1) -> int:
+              parent: int = -1, **meta) -> int:
         """Add phases stamped elsewhere — [(name, t0_ns, t1_ns)], a
         StampLog's take — with their real edges. With `root`, one root
-        phase of that name spanning them all is added under `parent`
-        and the rows hang off it; its index is returned (-1 with no
-        rows). A row whose name extends another row's by a dotted
+        phase of that name spanning them all is added under `parent`,
+        carrying `meta`, and the rows hang off it; its index is
+        returned (-1 with no rows). A row whose name extends another row's by a dotted
         suffix and whose edges lie inside it (`import.land.stage` in
         `import.land`; on the mesh engine `import.land.dispatch` too)
         parents under that row. Rows stamped between
@@ -322,7 +322,7 @@ class TickRecord:
             rows = _fit(rows, room)
         if root is not None:
             parent = self.add(root, min(r[1] for r in rows),
-                              max(r[2] for r in rows), parent)
+                              max(r[2] for r in rows), parent, **meta)
         held = []       # (name + ".", t1, idx) of rows that may hold others
         for name, t0, t1 in sorted(rows, key=lambda r: (r[1], -r[2])):
             held = [h for h in held if h[1] > t0]
@@ -395,7 +395,7 @@ class FlightRecorder:
     about to be recycled — stale but never unsafe (slot objects are
     never freed, and the snapshot tolerates in-flight phases)."""
 
-    def __init__(self, capacity: int = 32, max_phases: int = 192):
+    def __init__(self, capacity: int = 32, max_phases: int = 256):
         self.capacity = max(1, capacity)
         self.max_phases = max(8, max_phases)
         self._ring = [TickRecord(self.max_phases)

@@ -32,8 +32,8 @@ from . import observe, resilience
 from .config import Config, _parse_interval
 from .ingest import parser
 from .metrics import FrameSet, InterMetric, MetricType
-from .models.pipeline import (LAND_PHASES, AggregationEngine, EngineConfig,
-                              ForwardExport)
+from .models.pipeline import (APPLY_CPU_TALLY, APPLY_PHASES, LAND_PHASES,
+                              AggregationEngine, EngineConfig, ForwardExport)
 from .sinks import MetricSink
 from .sinks.basic import (BlackholeMetricSink, DebugMetricSink,
                           LocalFilePlugin)
@@ -92,11 +92,15 @@ class Server:
     # done between ticks (observe.StampLog budgets: past them a kind's
     # rows coalesce and its seconds stay exact). The import kinds are
     # sized for 100k keys a tick (~16 requests x 3 phases, ~13 landings
-    # x 3) next to the tick's own ~35 phases in the default
-    # flight_recorder_max_phases (192); the pump's for a local, whose
+    # x 3) next to the tick's own 16-35 phases, `import.apply.request`
+    # (the three children an engine stamps a request, APPLY_PHASES) for
+    # a fleet's 32 requests a tick: 209 rows behind 32 senders of
+    # 10,000 keys, inside the default flight_recorder_max_phases
+    # (256); the pump's for a local, whose
     # tick has the forward's 3 phases a chunk besides — TickRecord.graft
     # folds what the tick has no slots left for, so none is dropped.
     GRAFT_BUDGET = {"import.request": 16, "import.apply": 12,
+                    "import.apply.request": 32,
                     "import.land": 16, "ingest.pump.batch": 256}
     # a worker's busy runs closer than this are one `import.apply` run
     APPLY_MERGE_GAP_NS = 1_000_000
@@ -156,11 +160,16 @@ class Server:
             self.engines = [AggregationEngine(EngineConfig(**ecfg_kw))
                             for _ in range(n_workers)]
         if cfg.flight_recorder:
-            # import landings stamp `import.land` (+ children) here;
-            # the engine's flush hands them to the tick
+            # import landings stamp `import.land` (+ children) here,
+            # and import_list a request's three `import.apply.*`; the
+            # engine's flush hands them to the tick
             for eng in self.engines:
-                eng.land_stamps = observe.StampLog(dict.fromkeys(
-                    LAND_PHASES, self.GRAFT_BUDGET["import.land"]))
+                eng.land_stamps = observe.StampLog({
+                    **dict.fromkeys(LAND_PHASES,
+                                    self.GRAFT_BUDGET["import.land"]),
+                    **dict.fromkeys(
+                        APPLY_PHASES,
+                        self.GRAFT_BUDGET["import.apply.request"])})
         self.worker_queues: list[queue.Queue] = [
             _WorkerQueue(maxsize=65536) for _ in range(n_workers)]
         # per queue: until when a full queue sheds imports without
@@ -2002,14 +2011,15 @@ class Server:
         if self.flight is not None:
             tick = self.flight.begin_tick(ts)
             # the cut: import and pump work stamped up to here is this
-            # tick's (taken now, grafted when the tick ends); the
-            # engines' flushes add the landings they hold
+            # tick's (taken now, grafted when the tick ends, each kind
+            # under a root with the meta beside its rows); the
+            # engines' flushes add the stamps they hold
             grafts = {
                 "import": ([] if self._import_stamps is None
-                           else self._import_stamps.take()),
+                           else self._import_stamps.take(), {}),
                 "ingest": ([] if self.native_pump is None
                            or self.native_pump.stamps is None
-                           else self.native_pump.stamps.take())}
+                           else self.native_pump.stamps.take(), {})}
             if timestamp is not None:
                 # scripted/explicit timestamps stay scripted all the
                 # way through the e2e accounting: the interval-close
@@ -2044,8 +2054,8 @@ class Server:
             if tick is not None:
                 # grafted last, so a tick short of slots drops these
                 # rows and never its own phases
-                for root, rows in grafts.items():
-                    tick.graft(rows, root=root)
+                for root, (rows, meta) in grafts.items():
+                    tick.graft(rows, root=root, **meta)
                 self.flight.end_tick(tick)
                 if self.trace_client is not None:
                     self.flight.emit_spans(tick, self.trace_client)
@@ -2068,7 +2078,8 @@ class Server:
     def _flush_tick(self, ts: int, t0: float, tick, grafts: dict):
         """The tick body (split from flush_once so recorder lifecycle
         wraps it exactly once). `tick` is the TickRecord or None;
-        `grafts` the rows flush_once grafts when the tick ends."""
+        `grafts` the (rows, root's meta) of each kind that flush_once
+        grafts when the tick ends."""
         frames = []
         merged_export = ForwardExport()
         events, checks = [], []
@@ -2076,8 +2087,7 @@ class Server:
         eng_stats = {"samples": 0, "dropped_no_slot": 0,
                      "overflow_rows": 0, "overflow_bank": 0,
                      "import_batches": 0, "import_metrics": 0,
-                     "import_land_rows": 0, "import_land_bank": 0,
-                     "swap_ns": 0, "merge_ns": 0, "assembly_ns": 0}
+                     "import_land_rows": 0, "import_land_bank": 0}
         # Engines flush concurrently so their device programs and
         # device→host transfers overlap instead of queueing behind
         # one another's host assembly. Single engine = no thread.
@@ -2143,14 +2153,20 @@ class Server:
                                    parent=eng_ph[i])
                     if nm == "drain":
                         drain = (idx, p0)
-                # import landings since the previous flush: the
-                # flush-time one ran inside engine.drain and nests
-                # there; mid-interval ones join the `import` root
-                land = res.stats.get("import_phases", ())
-                tick.graft([r for r in land if r[1] >= drain[1]],
-                           parent=drain[0])
-                grafts["import"].extend(
-                    r for r in land if r[1] < drain[1])
+                # the engine's import stamps since the previous flush:
+                # the flush-time landing ran inside engine.drain and
+                # nests there; mid-interval landings and the requests'
+                # `import.apply.*` join the `import` root, where the
+                # worker's CPU time over the latter is the root's meta
+                own, (between, meta) = [], grafts["import"]
+                for r in res.stats.get("import_phases", ()):
+                    (own if r[1] >= drain[1] and r[0] in LAND_PHASES
+                     else between).append(r)
+                tick.graft(own, parent=drain[0])
+                for k in APPLY_CPU_TALLY:
+                    ns = res.stats.get("flush_path", {}).get(k, 0)
+                    if ns:
+                        meta[k] = meta.get(k, 0) + ns
             frames.append(res.frame)
             status_metrics.extend(res.status_metrics)
             merged_export.histograms.extend(res.export.histograms)
@@ -2849,12 +2865,6 @@ class Server:
             # spilled onto another
             for k, name in self._mesh_telemetry.items():
                 tel.mark(S, name, eng_stats.get(k, 0))
-            tel.set_gauge(S, "flush.swap_duration_ns",
-                          eng_stats["swap_ns"])
-            tel.set_gauge(S, "flush.merge_duration_ns",
-                          eng_stats["merge_ns"])
-            tel.set_gauge(S, "flush.assembly_duration_ns",
-                          eng_stats["assembly_ns"])
         # ---- drop classes ----
         # Losses are counted exactly once, at the layer that owns them:
         #   veneur.worker.dropped_total          ingest backpressure —
