@@ -14,9 +14,17 @@ builds every payload and its reference from the seed, and runs the
 cell's own tick untimed until a whole cycle of payloads compiles
 nothing. The window then runs whole ticks until `--seconds` of ticks
 have passed: a tick that starts inside the window is finished and timed.
-Every tick, timed or not, is checked against the reference between
-ticks, outside every timed interval and off the window's clock. The
-last line of standard output is the result.
+A traced window (`--trace 1`) is shorter: at most `TRACED_TICKS` timed
+ticks, so that what a traced run costs (the ticks, the comparisons
+between them under the profiler, the trace's events and their
+reduction) is fixed by the benchmark and not by how fast the tree under
+test ticks; `breakdown`'s seconds are sums over that window. A trace
+whose device lines end before the last timed tick starts is refused
+(`tracered.refuse_cut_short`), and a traced run's last log line says
+where its seconds went. Every tick, timed or not, traced or not, is
+checked against the reference between ticks, outside every timed
+interval and off the window's clock. The last line of standard output
+is the result.
 
 Off the chip the run fails and prints no result. `--rehearsal` is the
 explicit CPU run for tests: tiny sizes, virtual devices, the result
@@ -40,6 +48,14 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 MAX_WARMUP_TICKS = 8
+# The per-layer metrics are medians over the timed ticks, and sixteen are
+# ample for a median (the cells with the longest ticks have had 4-7 all
+# along). Without the cap the number of traced ticks is `--seconds` over
+# the tick's length, so a tree that ticks twice as fast pays for twice
+# the comparisons, events and reduction, and ran into the driver's time
+# limit for being faster (PR 40); and the profiler's device line holds
+# some 25 ticks of the cell with the most device events a tick.
+TRACED_TICKS = 16
 
 
 def load_manifest(root: str = ROOT) -> dict:
@@ -59,6 +75,17 @@ def cell_metrics(manifest: dict, cell: str, group: str) -> list:
         return reports(e2e[m["moves"]]) if "moves" in m else True
 
     return [m for m in manifest[group] if reports(m)]
+
+
+def window_open(k: int, measured_s: float, seconds: float,
+                traced: bool) -> bool:
+    """Whether the window takes one more tick after `k` timed ticks of
+    `measured_s` seconds together: always a first; then until `seconds`
+    of ticks have passed, and under the profiler until `TRACED_TICKS`
+    ticks have, whichever comes first."""
+    if traced and k >= TRACED_TICKS:
+        return False
+    return k == 0 or measured_s < seconds
 
 
 def reexec_with_process_env(want: dict):
@@ -164,6 +191,7 @@ def main(argv=None) -> int:
     tol = cfg["guarantees"]["tolerances"]
     t = driver.Driver(cfg, args.rehearsal)
     verdicts, records = [], []
+    took_s = {"comparisons": 0.0}   # a traced run's stages, for its log
     try:
         want_devices = int(cfg["global"].get("tpu_num_devices", 1))
         got_devices = t.mesh_devices()
@@ -177,7 +205,10 @@ def main(argv=None) -> int:
                 raise RuntimeError(
                     f"the driver {cfg['driver']!r} gave a tick record "
                     f"without {layers.missing_keys(rec)}")
+            c0 = time.monotonic()
             v = t.check(p, rec, tol)
+            if timed:
+                took_s["comparisons"] += time.monotonic() - c0
             rec.update(index=i, timed=timed, payload=i % len(payloads) + 1,
                        compared={k: val for k, (val, _lim)
                                  in v["numbers"].items()})
@@ -236,12 +267,14 @@ def main(argv=None) -> int:
         w0 = time.monotonic()
         k, measured_s = 0, 0.0
         try:
-            while k == 0 or measured_s < args.seconds:
+            while window_open(k, measured_s, args.seconds, bool(args.trace)):
                 measured_s += one_tick(n + k, True)["wall_s"]
                 k += 1
         finally:
+            w1 = time.monotonic()
             if args.trace:
                 jax.profiler.stop_trace()
+                took_s["stop_trace"] = time.monotonic() - w1
         counters = t.drop_counters()
         peak = max(((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                     for d in devs[:max(chips, 1)]), default=0)
@@ -251,7 +284,7 @@ def main(argv=None) -> int:
 
     timed = [r for r in records if r["timed"]]
     log(f"window: {len(timed)} timed ticks, {measured_s:.1f}s of ticks in "
-        f"{time.monotonic() - w0:.1f}s with the comparisons between them")
+        f"{w1 - w0:.1f}s with the comparisons between them")
     log(f"counters: {json.dumps(counters)}")
 
     # ---- correct: every tick of the run, warm-up included
@@ -302,12 +335,21 @@ def main(argv=None) -> int:
     group = "per_layer" if args.trace else "end_to_end"
     if args.trace and not args.rehearsal:
         from perfbench import tracered
-        tr = tracered.reduce_trace(
-            tracered.load_xplane(tracered.find_xplane(trace_dir)),
-            spans.rows, [row for r in timed for row in r["phase_rows"]],
-            [(r["t_first_ns"], r["t_end_ns"]) for r in timed])
+        r0 = time.monotonic()
+        trace = tracered.load_xplane(tracered.find_xplane(trace_dir))
         if not args.keep_trace:
             shutil.rmtree(trace_dir, ignore_errors=True)
+        r1 = time.monotonic()
+        windows = [(r["t_first_ns"], r["t_end_ns"]) for r in timed]
+        ends, (tick0, tick1) = tracered.refuse_cut_short(
+            trace, spans.rows, windows)
+        log(f"trace: the last timed tick ran {tick0:.3f}s-{tick1:.3f}s on "
+            f"the trace's clock; its device lines end at {ends}")
+        tr = tracered.reduce_trace(
+            trace, spans.rows,
+            [row for r in timed for row in r["phase_rows"]], windows)
+        took_s.update(load_xplane=r1 - r0,
+                      reduce_trace=time.monotonic() - r1)
         ctx["trace"] = tr
         device["busy_s"] = tr["busy_s"]
         device["window_s"] = tr["window_s"]
@@ -327,6 +369,13 @@ def main(argv=None) -> int:
     result["compared"] = {
         name: {"value": val if math.isfinite(val) else repr(val),
                "limit": lim} for name, (val, lim) in numbers.items()}
+    if args.trace:
+        took_s = {"set-up": w0 - t_start, "ticks": measured_s,
+                  "between ticks": w1 - w0 - measured_s, **took_s}
+        log(f"traced run, {len(timed)} timed ticks: "
+            + "  ".join(f"{k} {v:.1f}s" for k, v in took_s.items())
+            + f"  (the comparisons are inside `between ticks`);  "
+              f"{time.monotonic() - t_start:.1f}s since the process started")
     print("\n".join(compared), file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0

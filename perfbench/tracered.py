@@ -26,7 +26,9 @@ inside the trace, gives the offset between the two.
 
 from __future__ import annotations
 
+import bisect
 import glob
+import heapq
 import os
 import re
 
@@ -114,24 +116,60 @@ class Busy:
 def _partition(spans):
     """Cut time at every span edge; label each piece by the covering
     spans from the outermost to the deepest. `spans`: (name, t0, t1,
-    depth). Returns [(t0, t1, label)] for pieces some span covers."""
+    depth). Returns [(t0, t1, label)] for pieces some span covers.
+    One sweep over the sorted edges: a heap of the benchmark's spans
+    (depth 0) and one of the phases, each with the shortest span that
+    covers the piece on top; a span leaves when the sweep has passed its
+    end."""
     edges = sorted({t for _n, a, b, _d in spans for t in (a, b)})
-    out = []
+    begun = sorted(range(len(spans)), key=lambda i: spans[i][1])
+    heaps = ([], [])            # (length, tie, end, name): depth 0, deeper
+    out, nxt = [], 0
     for a, b in zip(edges[:-1], edges[1:]):
-        mid = (a + b) / 2
-        cover = [(d, t1 - t0, n) for n, t0, t1, d in spans if t0 <= mid < t1]
-        if not cover:
+        while nxt < len(begun) and spans[begun[nxt]][1] <= a:
+            i = begun[nxt]
+            n, t0, t1, d = spans[i]
+            # ties as a scan in the spans' order broke them: the
+            # benchmark's spans by name, the phases by the first listed
+            heapq.heappush(heaps[d > 0], (t1 - t0, i if d else n, t1, n))
+            nxt += 1
+        for heap in heaps:
+            while heap and heap[0][2] <= a:
+                heapq.heappop(heap)
+        top, deep = heaps
+        if not top and not deep:
             continue
-        top = min(c for c in cover if c[0] == 0) if any(
-            c[0] == 0 for c in cover) else None
-        deep = [c for c in cover if c[0] > 0]
-        label = top[2] if top else ""
+        label = top[0][3] if top else ""
         if deep:
             # the deepest phase is the shortest one that covers the piece
-            label = (label + "/" if label else "") + min(
-                deep, key=lambda c: c[1])[2]
+            label = (label + "/" if label else "") + deep[0][3]
         out.append((a, b, label))
     return out
+
+
+class Windows:
+    """The timed ticks on the trace's clock, sorted and apart from each
+    other, so that what overlaps an interval is found by bisection."""
+
+    def __init__(self, wins):
+        self.wins = sorted(wins)
+        self.w0 = np.array([a for a, _b in self.wins], float)
+        self.w1 = np.array([b for _a, b in self.wins], float)
+        if (self.w1 <= self.w0).any() or (self.w0[1:] < self.w1[:-1]).any():
+            raise ValueError("a window is empty, or two windows overlap")
+
+    def clip(self, a, b):
+        """The parts of [a, b) inside the windows."""
+        i = bisect.bisect_right(self.w1, a)       # windows that end after a
+        j = bisect.bisect_left(self.w0, b)        # and start before b
+        return [(max(a, w0), min(b, w1))
+                for w0, w1 in (self.wins[i:j] if b > a else [])]
+
+    def touched(self, starts, ends):
+        """For each [start, end) whether any of it is inside a window."""
+        i = np.searchsorted(self.w1, starts, side="right")
+        j = np.searchsorted(self.w0, ends, side="left")
+        return (j > i) & (ends > starts)
 
 
 def clock_offset(trace: dict, bench_rows: list) -> float:
@@ -143,6 +181,31 @@ def clock_offset(trace: dict, bench_rows: list) -> float:
     return mono[0][1] - ev[0][1]
 
 
+def refuse_cut_short(trace: dict, bench_rows: list, windows: list):
+    """The profiler keeps so many events a device line and drops what
+    comes after. A line whose last event ends before the last timed tick
+    starts was cut short: its busy seconds, idle share and gaps are the
+    missing tail, not readings. Every tick of every cell dispatches
+    device work, so a whole trace cannot trip it. Raises ValueError
+    with both times; returns `({"<device>/<line>": end_s}, (start_s,
+    end_s))`, every line that has events and the last timed tick, in
+    seconds on the trace's clock."""
+    off = clock_offset(trace, bench_rows)
+    t0, t1 = ((t - off) / 1e9 for t in max(windows))
+    ends = {f"{d}/{line}": max(r[1] + r[2] for r in rows) / 1e9
+            for line in ("device", "modules")
+            for d, rows in sorted(trace.get(line, {}).items()) if rows}
+    if not any(k.endswith("/device") for k in ends):
+        raise ValueError("the trace has no device event")
+    short = {k: e for k, e in ends.items() if e < t0}
+    if short:
+        raise ValueError(
+            f"the trace was cut short: the last timed tick runs from "
+            f"{t0:.3f}s to {t1:.3f}s on the trace's clock, and these "
+            f"device lines end before it starts: {short}")
+    return ends, (t0, t1)
+
+
 def reduce_trace(trace: dict, bench_rows: list, phase_rows: list,
                  windows: list, top: int = 10) -> dict:
     """`bench_rows` / `phase_rows`: (name, t0_ns, t1_ns) on the
@@ -151,48 +214,65 @@ def reduce_trace(trace: dict, bench_rows: list, phase_rows: list,
     What the benchmark does between ticks (checking against the
     reference) is outside every window, so it is nobody's idle time."""
     off = clock_offset(trace, bench_rows)
-    wins = sorted((a - off, b - off) for a, b in windows)
     devices = sorted(trace["device"])
-    if not devices or not wins:
+    if not devices or not windows:
         raise ValueError("the trace has no device plane, or no window")
+    wins = Windows((a - off, b - off) for a, b in windows)
     busy = {d: Busy(trace["device"][d]) for d in devices}
-    busy_ns = {d: sum(busy[d].within(a, b) for a, b in wins)
+    busy_ns = {d: float(np.sum(busy[d].upto(wins.w1) - busy[d].upto(wins.w0)))
                for d in devices}
-    window_ns = sum(b - a for a, b in wins)
+    window_ns = float(np.sum(wins.w1 - wins.w0))
     first = busy[devices[0]]
 
-    def clip(a, b):
-        return [(max(a, w0), min(b, w1)) for w0, w1 in wins
-                if min(b, w1) > max(a, w0)]
+    def idle_and_busy(pieces):
+        """Of [(a, b)] inside the windows: each piece's idle and busy
+        nanoseconds on the first device."""
+        a = np.array([p[0] for p in pieces], float)
+        b = np.array([p[1] for p in pieces], float)
+        ran = first.upto(b) - first.upto(a)
+        return (b - a) - ran, ran
 
     spans = [(n, a - off, b - off, 0) for n, a, b in bench_rows if n != SYNC]
     spans += [(n, a - off, b - off, 1) for n, a, b in phase_rows]
-    spans = [s for s in spans if clip(s[1], s[2])]
+    inside = wins.touched(np.array([s[1] for s in spans], float),
+                          np.array([s[2] for s in spans], float))
+    spans = [s for s, keep in zip(spans, inside) if keep]
+    pieces = [(a1, b1, label) for a, b, label in _partition(spans)
+              for a1, b1 in wins.clip(a, b)]
     gaps: dict = {}
     covered = 0.0
-    for a, b, label in _partition(spans):
-        for a1, b1 in clip(a, b):
-            idle = (b1 - a1) - first.within(a1, b1)
-            gaps[label] = gaps.get(label, 0.0) + idle
-            covered += idle
+    for (_a, _b, label), idle in zip(pieces,
+                                     idle_and_busy(pieces)[0].tolist()):
+        gaps[label] = gaps.get(label, 0.0) + idle
+        covered += idle
     gaps["(no span)"] = max(0.0, window_ns - busy_ns[devices[0]] - covered)
     in_span: dict = {}
     for name in {s[0] for s in spans if s[3] == 0}:
         rows = [r for n, a, b, d in spans if n == name and d == 0
-                for r in clip(a, b)]
+                for r in wins.clip(a, b)]
         total = sum(b - a for a, b in rows)
-        in_span[name] = (sum(first.within(a, b) for a, b in rows) / total
+        in_span[name] = (float(np.sum(idle_and_busy(rows)[1])) / total
                          if total > 0 else None)
+
     def totals(table):
+        """Seconds, calls and the first full text of every operation
+        that ran inside a window, by stable name, in the order met."""
+        rows = [r for d in devices for r in table.get(d, [])]
+        start = np.array([r[1] for r in rows], float)
+        dur = np.array([r[2] for r in rows], float)
+        met = np.nonzero(wins.touched(start, start + dur))[0]
+        texts: dict = {}                # full text -> column, as met
+        col = np.array([texts.setdefault(rows[i][0], len(texts))
+                        for i in met], int)
+        text_s = np.bincount(col, weights=dur[met] / 1e9,
+                             minlength=len(texts))
+        text_n = np.bincount(col, minlength=len(texts))
         secs, calls, text = {}, {}, {}
-        for d in devices:
-            for name, start, dur in table.get(d, []):
-                if not clip(start, start + dur):
-                    continue
-                key = stable_name(name)
-                secs[key] = secs.get(key, 0.0) + dur / 1e9
-                calls[key] = calls.get(key, 0) + 1
-                text.setdefault(key, name)
+        for name, n in texts.items():
+            key = stable_name(name)
+            secs[key] = secs.get(key, 0.0) + float(text_s[n])
+            calls[key] = calls.get(key, 0) + int(text_n[n])
+            text.setdefault(key, name)
         return secs, calls, text
 
     ops, calls, shapes = totals(trace["device"])
