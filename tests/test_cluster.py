@@ -158,7 +158,7 @@ def test_grpc_forward_chunks_fit_the_message_limit():
     got = []
     server, port = start_import_server(
         "127.0.0.1:0",
-        lambda metrics, _env: got.extend(metrics) or len(metrics))
+        lambda metrics, _env, _raw=None: got.extend(metrics) or len(metrics))
     try:
         fw = GrpcForwarder(f"127.0.0.1:{port}")
         fw(exp)          # default limits on both ends

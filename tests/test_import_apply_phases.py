@@ -8,24 +8,31 @@ and from `_last_flush_info`. All on the CPU backend; the mesh engine on
 four of the virtual devices tests/conftest.py pins.
 """
 
+import logging
 import threading
 import time
 
 import numpy as np
 import pytest
 
+from veneur_tpu import sketches
 from veneur_tpu.cluster import wire
-from veneur_tpu.cluster.forward import GrpcForwarder
+from veneur_tpu.cluster.forward import (SEND_METRICS, GrpcForwarder,
+                                        HttpJsonForwarder)
 from veneur_tpu.cluster.protos import forward_pb2, metric_pb2
 from veneur_tpu.config import read_config
+from veneur_tpu.ingest import native
+from veneur_tpu.ingest.parser import MetricKey
 from veneur_tpu.models import pipeline
 from veneur_tpu.models.pipeline import (APPLY_CPU_TALLY, APPLY_PHASES,
-                                        IMPORT_PHASES, LAND_PHASES,
-                                        AggregationEngine, EngineConfig)
+                                        DECODE_TALLY, IMPORT_PHASES,
+                                        LAND_PHASES, AggregationEngine,
+                                        EngineConfig, ForwardExport)
 from veneur_tpu.observe import FlightRecorder, StampLog, TelemetryRegistry
 from veneur_tpu.resilience import ResilientForwarder
 from veneur_tpu.server import Server
 from veneur_tpu.sinks.basic import CaptureMetricSink
+from veneur_tpu.utils.faults import kill_journal_lock
 
 DECODE, LOCK_WAIT, STAGE = APPLY_PHASES
 CPU_KEYS = APPLY_CPU_TALLY
@@ -174,11 +181,15 @@ def test_a_forwarded_interval_opens_import_apply_in_the_globals_tick(
                 STAGE: sum(s[1] - s[0] for s in stages)}
         for key, name in zip(CPU_KEYS, (DECODE, STAGE)):
             assert 0 < info[key] <= wall[name] + len(decs) * SLACK_NS
+        # the requests came over gRPC with their bytes: every sketch
+        # was read from them, its key minted once (ISSUE 42)
+        assert [info[k] for k in DECODE_TALLY] == [20, 0, 0, 20]
+        assert info["import_metrics"] == 20
         # the next interval's tick carries nothing over
         glob.flush_once(timestamp=2010)
         assert not _rows(glob.flight.last_tick(), "import")
-        assert [glob.engines[0]._last_flush_info[k] for k in CPU_KEYS] \
-            == [0, 0]
+        assert [glob.engines[0]._last_flush_info[k]
+                for k in CPU_KEYS + DECODE_TALLY] == [0] * 6
     finally:
         local.stop()
         glob.stop()
@@ -351,3 +362,151 @@ def test_the_engines_log_holds_the_landings_names_and_the_requests():
     assert all(n.startswith("import.apply.") for n in APPLY_PHASES)
     assert Server.GRAFT_BUDGET["import.apply.request"] >= 32
     assert CPU_KEYS == ("import_decode_cpu_ns", "import_stage_cpu_ns")
+
+
+# ---- a request's bytes reach the worker, or they do not (ISSUE 42) ----
+
+N_SKETCHES = 33
+
+
+def _fleet_request():
+    """(serialized MetricList, the export it was made from): timers of
+    two tags, a set, counters and a gauge, every value one f32 holds, so
+    the JSON contract carries what the protobuf one does."""
+    rng = np.random.default_rng(42)
+    ex = ForwardExport()
+    for i in range(24):
+        x = np.sort(rng.lognormal(4.6, 0.3, 4 if i < 20 else 40)).astype(
+            np.float32).astype(np.float64)
+        ex.histograms.append(
+            (MetricKey(f"t42.lat.k{i}", "timer", "env:prod,svc:api"), x,
+             np.ones(x.size), x[0], x[-1], float(x.sum()), float(x.size),
+             float((1.0 / x).sum())))
+    ex.sets = [(MetricKey("t42.users", "set", "env:prod"),
+                rng.integers(0, 7, 1 << 14).astype(np.uint8))]
+    ex.counters = [(MetricKey(f"t42.hits.c{i}", "counter", "env:prod"),
+                    float(3 * i - 5)) for i in range(7)]
+    ex.gauges = [(MetricKey("t42.level", "gauge", ""), 2.5)]
+    raw = forward_pb2.MetricList(
+        metrics=wire.export_to_metrics(ex),
+        sketch_engines=sketches.DEFAULT_STAMP).SerializeToString()
+    assert len(forward_pb2.MetricList.FromString(raw).metrics) \
+        == N_SKETCHES
+    return raw, ex
+
+
+def _send_bytes(glob, raw):
+    """The request as a sender's channel puts it on the wire."""
+    import grpc
+    with grpc.insecure_channel(f"127.0.0.1:{glob.grpc_port}") as ch:
+        ch.unary_unary(SEND_METRICS, request_serializer=lambda b: b,
+                       response_deserializer=lambda b: b)(raw, timeout=10)
+
+
+def _flushed(glob, ts=4200):
+    """(the flush's own rows, its self-metrics by name, the engine's
+    note of the interval)."""
+    assert glob.drain(30.0)
+    out = glob.flush_once(timestamp=ts)
+    rows = sorted((m.name, tuple(m.tags), repr(m.value)) for m in out
+                  if not m.name.startswith("veneur."))
+    own = {m.name: m.value for m in out if m.name.startswith("veneur.")}
+    return rows, own, glob.engines[0]._last_flush_info
+
+
+def _decoded(info):
+    return [info[k] for k in DECODE_TALLY]
+
+
+@pytest.mark.parametrize("engine", ["single", "mesh"])
+def test_a_request_flushes_the_same_whichever_way_it_was_decoded(
+        monkeypatch, caplog, tmp_path, engine):
+    """One request into four globals: by gRPC (the worker reads the
+    sketches from its bytes), by HTTP /import and by recovery's replay
+    of the journaled op (no bytes: Python), and by gRPC into a process
+    that cannot build the library (Python, said once). The same rows
+    every time; the phases stamped as before; the counts say which
+    path ran."""
+    over = {"tpu_num_devices": 4} if engine == "mesh" else {}
+    raw, export = _fleet_request()
+    # over gRPC: native
+    glob = _global(monkeypatch, durability_enabled=True,
+                   durability_dir=str(tmp_path), durability_fsync="never",
+                   **over)
+    try:
+        assert (type(glob.engines[0]).__name__
+                == "MeshAggregationEngine") == (engine == "mesh")
+        _send_bytes(glob, raw)
+        want, own, info = _flushed(glob)
+        assert len(want) > N_SKETCHES
+        assert _decoded(info) == [N_SKETCHES, 0, 0, N_SKETCHES]
+        assert info["import_metrics"] == N_SKETCHES
+        decs, _waits, _stages = _check_children(glob.flight.last_tick())
+        assert len(decs) == 1
+        assert all(info[k] > 0 for k in CPU_KEYS)
+        # a second tick of the same keys finds every one
+        _send_bytes(glob, raw)
+        _rows2, own, info = _flushed(glob, 4210)
+        assert _decoded(info) == [N_SKETCHES, 0, N_SKETCHES, 0]
+        assert [own[f"veneur.import.decode_{k}_total"] for k in (
+            "native", "fallback", "key_hits", "key_misses")] \
+            == [N_SKETCHES, 0, N_SKETCHES, 0]
+        # ... and a third, which no flush follows, for the replay
+        _send_bytes(glob, raw)
+        assert glob.drain(30.0)
+    finally:
+        journaled = glob._engine_journal is not None
+        glob._stop.set()
+        for j in (glob._engine_journal, glob._dedupe_journal):
+            if j is not None:
+                kill_journal_lock(j)
+        glob.stop()
+    # recovery's replay of that third request: Python, the same rows
+    # (the mesh engine keeps no engine journal: nothing to replay)
+    assert journaled == (engine == "single")
+    if journaled:
+        glob = _global(monkeypatch, durability_enabled=True,
+                       durability_dir=str(tmp_path),
+                       durability_fsync="never")
+        try:
+            assert glob._recovery["ops_replayed"] == 1
+            rows, _own, info = _flushed(glob)
+            assert rows == want
+            assert _decoded(info) == [0, N_SKETCHES, 0, 0]
+        finally:
+            glob.stop()
+    # over HTTP /import: Python
+    glob = _global(monkeypatch, http_address="127.0.0.1:0", is_global=True,
+                   **over)
+    try:
+        HttpJsonForwarder(f"http://127.0.0.1:{glob.http_api.port}",
+                          engine_stamp=glob.engine_stamp)(export)
+        rows, own, info = _flushed(glob)
+        assert rows == want
+        assert _decoded(info) == [0, N_SKETCHES, 0, 0]
+        assert own["veneur.import.decode_fallback_total"] == N_SKETCHES
+        _check_children(glob.flight.last_tick())
+    finally:
+        glob.stop()
+
+    # a process where the library cannot be had: it starts, says so
+    # once, and imports in Python
+    def no_compiler(**_kw):
+        raise native.NativeUnavailable("no compiler in this test")
+
+    monkeypatch.setattr(wire, "_native_fn", None)
+    monkeypatch.setattr(native, "build", no_compiler)
+    with caplog.at_level(logging.WARNING, logger="veneur_tpu.cluster.wire"):
+        glob = _global(monkeypatch, **over)
+        try:
+            _send_bytes(glob, raw)
+            rows, _own, info = _flushed(glob)
+            assert rows == want
+            assert _decoded(info) == [0, N_SKETCHES, 0, 0]
+            _send_bytes(glob, raw)
+            assert _decoded(_flushed(glob, 4210)[2]) \
+                == [0, N_SKETCHES, 0, 0]
+        finally:
+            glob.stop()
+    said = [r for r in caplog.records if "libvtpu_wire" in r.getMessage()]
+    assert len(said) == 1

@@ -199,9 +199,10 @@ def _shape(landing):
 
 @functools.lru_cache(maxsize=None)
 def _both_ways(kind, case):
-    """The case's request applied metric by metric (`one`) and as one
-    batch (`batch`) into two fresh engines -> what each staged, landed,
-    rejected and flushed."""
+    """The case's request applied metric by metric (`one`), as one
+    batch of parsed messages (`batch`) and as one batch with the
+    request's bytes (`bytes`, ISSUE 42) into three fresh engines ->
+    what each staged, landed, rejected and flushed."""
     args, stage_digests, budget = CASES[case]
     request = _request(*args)
     out = {}
@@ -209,7 +210,7 @@ def _both_ways(kind, case):
     if stage_digests is not None:
         pipeline._IMPORT_STAGE_DIGESTS = stage_digests
     try:
-        for way in ("one", "batch"):
+        for way in ("one", "batch", "bytes"):
             eng = _engine(kind, budget)
             landings = _watch_landings(eng, kind)
             if way == "one":
@@ -220,17 +221,21 @@ def _both_ways(kind, case):
                     except Exception:
                         rejected += 1
             else:
-                rerouted, bad = eng.import_list(7, request.metrics)
+                raw = request.SerializeToString() if way == "bytes" \
+                    else None
+                rerouted, bad = eng.import_list(7, request.metrics, raw)
                 assert rerouted == []
                 rejected = len(bad)
                 assert [pb.name for pb, _e in bad] == ["svc.evil"]
                 assert eng.last_import_op == 7
             staged = _staged(eng, kind)
+            decoded = [getattr(eng, "_" + k) for k in pipeline.DECODE_TALLY]
             mid = len(landings)
             rows = sorted(
                 (m.name, tuple(m.tags), repr(m.value), m.type)
                 for m in eng.flush(timestamp=30).metrics)
             out[way] = {"staged": staged, "landings": landings,
+                        "decoded": decoded,
                         "mid_interval_landings": mid,
                         "rejected": rejected, "rows": rows,
                         "folded": (None if budget is None else
@@ -304,6 +309,27 @@ def test_batch_flushes_bit_identically(kind, case):
                 "svc.q16.50percentile", "svc.users.s3", "svc.hits",
                 "svc.level"} <= names
     assert "svc.evil" not in names
+
+
+@each_case
+def test_batch_from_its_bytes_is_the_batch_from_its_messages(kind, case):
+    """The worker reads a gRPC request's sketches from its bytes in one
+    native pass (ISSUE 42): everything a flush, a checkpoint or a
+    landing can see is what the parsed messages gave, the q16 digest
+    (which the pass leaves to Python) in its place among the others
+    and the malformed set rejecting itself alone."""
+    got = _both_ways(kind, case)
+    native, parsed = got["bytes"], got["batch"]
+    for what in ("staged", "landings", "mid_interval_landings",
+                 "rejected", "rows", "folded"):
+        assert native[what] == parsed[what], what
+    n = len(_request(*CASES[case][0]).metrics)
+    n_native, n_fallback, hits, misses = native["decoded"]
+    assert (n_native, n_fallback) == (n - 1, 1)
+    # a key of either tag order is minted once and found afterwards
+    assert hits + misses == n - 1 and misses <= CASES[case][0][1] * 2 + 20
+    assert parsed["decoded"] == [0, n, 0, 0]
+    assert got["one"]["decoded"] == [0, 0, 0, 0]
 
 
 def test_decode_rolls_back_a_digest_that_fails_half_way():
@@ -396,7 +422,8 @@ def test_one_request_is_one_queue_item_an_engine(transport, workers):
     else:
         handler = ForwardHandler(srv._submit_import_batch)
         if transport == "grpc":
-            handler._send_metrics(request, _Ctx())
+            raw = request.SerializeToString()
+            handler._send_metrics(request, _Ctx(), raw=raw)
         else:
             handler._send_metrics_v2(iter(request.metrics), _Ctx())
     queued = _queued(srv)
@@ -410,6 +437,15 @@ def test_one_request_is_one_queue_item_an_engine(transport, workers):
     for share in shares:        # wire order kept inside a share
         it = iter(want)
         assert all(name in it for name in share)
+    # SendMetrics alone has the request's bytes: they ride in every
+    # share, with the share's positions in the request (ISSUE 42)
+    for b, share in zip(batches, shares):
+        if transport != "grpc":
+            assert b.raw is None
+        else:
+            assert b.raw is raw
+            assert (b.at is None if workers == 1
+                    else [want[i] for i in b.at] == share)
     if workers == 1:
         assert shares == [want]
     if workers == 2:
@@ -588,10 +624,10 @@ def test_drain_waits_until_the_batch_is_staged():
         gate, entered = threading.Event(), threading.Event()
         orig = eng.import_list
 
-        def slow(op_id, pbs):
+        def slow(*batch):
             entered.set()
             assert gate.wait(30)
-            return orig(op_id, pbs)
+            return orig(*batch)
         eng.import_list = slow
         request = _request(20, 10)
         srv._submit_import_batch(request.metrics)

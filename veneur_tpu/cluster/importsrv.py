@@ -37,13 +37,16 @@ log = logging.getLogger("veneur_tpu.cluster.importsrv")
 
 
 def _decode_metric_list(data: bytes):
-    """SendMetrics' request_deserializer: the parsed MetricList with
+    """SendMetrics' request_deserializer: the parsed MetricList, the
+    bytes it was parsed from (the import worker reads the sketches
+    from them, wire.BatchDecoder; the parse is what the envelope, the
+    journal, a reroute, a reject and that decoder's fallback read) and
     the parse's edges on the monotonic clock. gRPC deserializes on its
     own polling thread before any handler runs, so this is the only
     place the import's `decode` phase can be stamped."""
     t0 = time.monotonic_ns()
     request = forward_pb2.MetricList.FromString(data)
-    return request, t0, time.monotonic_ns()
+    return request, data, t0, time.monotonic_ns()
 
 
 class ImportedBatch:
@@ -54,13 +57,18 @@ class ImportedBatch:
     (engine.import_list: one decode pass, one lock hold) and the op id
     advances that engine's applied-op watermark in the same critical
     section — the consistent cut the engine checkpoint's replay filter
-    depends on (durability/ ISSUE 9)."""
+    depends on (durability/ ISSUE 9). `raw` is the serialized
+    MetricList the metrics were parsed from, where the request came
+    with one (gRPC SendMetrics), and `at` the positions of `pbs` in
+    it, None for the whole request."""
 
-    __slots__ = ("op_id", "pbs")
+    __slots__ = ("op_id", "pbs", "raw", "at")
 
-    def __init__(self, op_id, pbs):
+    def __init__(self, op_id, pbs, raw=None, at=None):
         self.op_id = op_id
         self.pbs = pbs
+        self.raw = raw
+        self.at = at
 
 
 class _SenderState:
@@ -291,8 +299,10 @@ class ForwardHandler(grpc.GenericRpcHandler):
                  observer=None,
                  engine_stamp: str | None = None, note_stamp=None,
                  merge_sketches=None):
-        """`submit_batch(metrics, envelope) -> routed count` routes one
-        request's metrics as a unit: the Server's implementation puts
+        """`submit_batch(metrics, envelope, raw=None) -> routed count`
+        routes one request's metrics as a unit (`raw` the serialized
+        MetricList they were parsed from, which SendMetrics alone has):
+        the Server's implementation puts
         ONE ImportedBatch an engine on the worker queues, after
         write-aheading the request to the engine journal where that is
         armed, so an admitted-and-acked interval survives a receiver
@@ -326,7 +336,8 @@ class ForwardHandler(grpc.GenericRpcHandler):
         if details.method == SEND_METRICS:
             return grpc.unary_unary_rpc_method_handler(
                 lambda decoded, context: self._send_metrics(
-                    decoded[0], context, decode_ns=decoded[1:]),
+                    decoded[0], context, decode_ns=decoded[2:],
+                    raw=decoded[1]),
                 request_deserializer=_decode_metric_list,
                 response_serializer=forward_pb2.Empty.SerializeToString)
         if details.method == SEND_METRICS_V2:
@@ -336,7 +347,7 @@ class ForwardHandler(grpc.GenericRpcHandler):
                 response_serializer=forward_pb2.Empty.SerializeToString)
         return None
 
-    def _route_all(self, metrics, env=None) -> int:
+    def _route_all(self, metrics, env=None, raw=None) -> int:
         """Route one request's metrics in ONE submit_batch call: the
         request travels to the engines as a unit, grouped by target
         engine there, and the write-ahead journal sees it as ONE op
@@ -344,7 +355,9 @@ class ForwardHandler(grpc.GenericRpcHandler):
         routed count."""
         if not hasattr(metrics, "__len__"):
             metrics = list(metrics)     # an unmaterialized V2 stream
-        return self._submit_batch(metrics, env)
+        if raw is None:
+            return self._submit_batch(metrics, env)
+        return self._submit_batch(metrics, env, raw)
 
     def _check_stamp(self, remote, env) -> bool:
         """Engine-stamp verdict for one request; on False the verdict
@@ -386,7 +399,7 @@ class ForwardHandler(grpc.GenericRpcHandler):
             return False
         return not self._ledger.check_delta(env[0], env[1])
 
-    def _apply(self, scope, env, metrics) -> None:
+    def _apply(self, scope, env, metrics, raw=None) -> None:
         """The shared admit-then-route tail, phase-attributed: `route`
         is grouping by engine (a key digest a metric only where there
         is more than one) + enqueue; the Combine itself runs on a
@@ -399,11 +412,11 @@ class ForwardHandler(grpc.GenericRpcHandler):
         if not ok:
             return
         ph = scope.start("route")
-        n = self._route_all(metrics, env)
+        n = self._route_all(metrics, env, raw)
         scope.finish(ph, n_metrics=n)
         scope.n_metrics = n
 
-    def _send_metrics(self, request, context, decode_ns=None):
+    def _send_metrics(self, request, context, decode_ns=None, raw=None):
         env = wire.envelope_from_metric_list(request)
         trace = wire.trace_from_metric_list(request)
         remote = wire.sketch_stamp_from_metric_list(request)
@@ -422,13 +435,13 @@ class ForwardHandler(grpc.GenericRpcHandler):
         obs = self._observer
         if obs is None:
             if self._admit(env):
-                self._route_all(request.metrics, env)
+                self._route_all(request.metrics, env, raw)
             return forward_pb2.Empty()
         kw = {} if self._engine_stamp is None else {"stamp": remote}
         with obs.request(env, trace, "grpc", **kw) as scope:
             if decode_ns is not None:
                 scope.add("decode", *decode_ns)
-            self._apply(scope, env, request.metrics)
+            self._apply(scope, env, request.metrics, raw)
         return forward_pb2.Empty()
 
     def _send_metrics_v2(self, request_iterator, context):

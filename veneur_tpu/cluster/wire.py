@@ -8,8 +8,11 @@ samplers/samplers.go, worker.go (sym: Worker.ImportMetricGRPC).
 from __future__ import annotations
 
 import base64
+import ctypes
 import json
+import logging
 import struct
+import threading
 
 import numpy as np
 
@@ -19,6 +22,8 @@ from ..ingest.parser import (GLOBAL_ONLY, LOCAL_ONLY, MIXED_SCOPE,
 from ..models.pipeline import ForwardExport
 from ..utils.hashing import metric_digest
 from .protos import forward_pb2, metric_pb2
+
+log = logging.getLogger("veneur_tpu.cluster.wire")
 
 HLL_VERSION = 1
 
@@ -650,7 +655,9 @@ IMPORT_HISTOGRAM, IMPORT_SET, IMPORT_COUNTER, IMPORT_GAUGE = range(4)
 def decode_metric_batch(pbs) -> tuple:
     """One request's metricpb.Metrics, decoded in one pass for
     AggregationEngine.import_list -> (records, means, weights,
-    rejected). A record is `(kind, key, pb, ...)` in wire order:
+    rejected). A record is `(kind, key, at, ...)` in wire order, `at`
+    the metric's position in `pbs` (whoever re-routes or rejects a
+    record reads the object there):
 
       IMPORT_HISTOGRAM  (.., start, stop, min, max, sum, count, recip)
                         its centroids are means[start:stop] /
@@ -664,11 +671,15 @@ def decode_metric_batch(pbs) -> tuple:
     Decoding is identical to apply_metric_to_engine's, metric by
     metric: a payload that one rejects the other rejects, as
     `(pb, exception)` in `rejected`, and the rest of the batch
-    decodes. A metric with no value set yields no record."""
+    decodes. A metric with no value set yields no record.
+
+    This is the reference: BatchDecoder reads the same records from a
+    request's bytes in one native pass, and comes here for every
+    metric and every batch that pass does not take."""
     records, rejected = [], []
     fm: list = []
     fw: list = []
-    for m in pbs:
+    for at, m in enumerate(pbs):
         start = len(fm)
         try:
             key = metric_key_of(m)
@@ -684,22 +695,186 @@ def decode_metric_batch(pbs) -> tuple:
                     for c in td.centroids:
                         fm.append(c.mean)
                         fw.append(c.weight)
-                records.append((IMPORT_HISTOGRAM, key, m, start, len(fm),
+                records.append((IMPORT_HISTOGRAM, key, at, start, len(fm),
                                 td.min, td.max, td.sum, td.count,
                                 td.reciprocal_sum))
             elif which == "set":
                 eng_id, regs = decode_set_payload(m.set.hyper_log_log)
-                records.append((IMPORT_SET, key, m, regs, eng_id))
+                records.append((IMPORT_SET, key, at, regs, eng_id))
             elif which == "counter":
-                records.append((IMPORT_COUNTER, key, m,
+                records.append((IMPORT_COUNTER, key, at,
                                 float(m.counter.value)))
             elif which == "gauge":
-                records.append((IMPORT_GAUGE, key, m, m.gauge.value))
+                records.append((IMPORT_GAUGE, key, at, m.gauge.value))
         except Exception as e:
             del fm[start:], fw[start:]
             rejected.append((m, e))
     return (records, np.array(fm, np.float32), np.array(fw, np.float32),
             rejected)
+
+
+# ---- the native pass over a request's bytes (native/vtpu_wire.cpp) ----
+
+# a row's kind beyond the four of a record: no member of the `value`
+# oneof set (no record), and a metric the pass is not sure of (read
+# from the parsed message by decode_metric_batch)
+_ROW_NONE, _ROW_FALLBACK = 4, 5
+# the least a Centroid with a weight takes on the wire (tag, length,
+# tag, fixed64), which sizes the columns the pass fills: a request
+# whose centroids all have one fits, and a metric that would overrun
+# them falls back like any other the pass is not sure of
+_CENTROID_WIRE_BYTES = 11
+
+_native_lock = threading.Lock()
+_native_fn = None       # vtpu_wire_decode; False where it cannot be had
+
+
+def native_decode_fn():
+    """libvtpu_wire's entry point, built (at most once a checkout, like
+    the ingest bridge and through the same `make`) and loaded at most
+    once a process; None where the library cannot be built or loaded,
+    said once in the log: every batch is then decoded in Python. The
+    call keeps the interpreter's lock (ctypes.PyDLL): the walk is a
+    tenth of a request's decode, 0.1 ms of a 1,110-sketch request,
+    and a worker that let the lock go for it would wait behind the
+    handler threads, a switch interval at a time, to have it back."""
+    global _native_fn
+    if _native_fn is None:
+        with _native_lock:
+            if _native_fn is None:
+                _native_fn = _load_native() or False
+    return _native_fn or None
+
+
+def _load_native():
+    from ..ingest import native
+    try:
+        lib = ctypes.PyDLL(native.build(name="vtpu_wire"))
+        fn = lib.vtpu_wire_decode
+    except (native.NativeUnavailable, OSError, AttributeError) as e:
+        log.warning("libvtpu_wire unavailable, imports decode in "
+                    "Python: %s", e)
+        return None
+    fn.restype = ctypes.c_int64
+    fn.argtypes = [ctypes.c_char_p, ctypes.c_int64, ctypes.c_void_p,
+                   ctypes.c_int64] + [ctypes.c_void_p] * 4 + [
+                       ctypes.c_int64]
+    return fn
+
+
+class BatchDecoder:
+    """decode_metric_batch for an engine's import worker: the same
+    records, columns and rejects, value for value and in wire order,
+    read from the request's serialized bytes in one native pass where
+    the batch still has them (gRPC SendMetrics) and the library loaded.
+    A metric's MetricKey is then found by the raw bytes of its name,
+    tags and type in a dictionary, filled on a miss by metric_key_of of
+    the parsed metric (sorting, joining and UTF-8 stay the parser's),
+    emptied whole when it passes `max_keys` entries. A metric the pass
+    is not sure of is decoded by decode_metric_batch from its parsed
+    message and spliced in where it lay; a batch without bytes, or a
+    list the pass cannot walk, goes there whole."""
+
+    def __init__(self, max_keys: int):
+        self.max_keys = max(1, int(max_keys))
+        self._keys: dict = {}
+
+    def decode(self, pbs, raw=None, at=None) -> tuple:
+        """-> (records, means, weights, rejected, counts). `raw` is the
+        serialized forwardrpc.MetricList that `pbs` was parsed from and
+        `at` the positions of `pbs` in its `metrics` (ascending; None
+        for all of them); `counts` is (native, fallback, key hits, key
+        misses), sketches by the path that decoded them."""
+        if isinstance(raw, bytes) and len(pbs):
+            fn = native_decode_fn()
+            out = fn and self._decode_native(fn, pbs, raw, at)
+            if out:
+                return out
+        return decode_metric_batch(pbs) + ((0, len(pbs), 0, 0),)
+
+    def _decode_native(self, fn, pbs, raw, at):
+        n = len(pbs)
+        cap = len(raw) // _CENTROID_WIRE_BYTES + 1
+        # a column each (C order): a column is one flat list below,
+        # and no list is built a sketch
+        ints = np.empty((5, n), np.int64)
+        floats = np.empty((5, n), np.float64)
+        means = np.empty(cap, np.float32)
+        weights = np.empty(cap, np.float32)
+        if at is not None:
+            at = np.ascontiguousarray(at, np.int64)
+            if at.shape != (n,):
+                return None
+        total = fn(raw, len(raw), None if at is None else at.ctypes.data,
+                   n, ints.ctypes.data, floats.ctypes.data,
+                   means.ctypes.data, weights.ctypes.data, cap)
+        if total < 0:
+            return None
+        records, rejected = [], []
+        keys, add = self._keys, records.append
+        misses = fallback = 0
+        # where a fallback digest's centroids go into the native
+        # columns, and how far that has moved the digests behind it
+        splices, moved, cursor = [], 0, 0
+        for i, kind, ko, kl, a, b, f0, f1, f2, f3, f4 in zip(
+                range(n), *ints.tolist(), *floats.tolist()):
+            if kind == _ROW_FALLBACK:
+                fallback += 1
+                recs, fm, fw, rej = decode_metric_batch((pbs[i],))
+                rejected += rej
+                for rec in recs:
+                    if rec[0] == IMPORT_HISTOGRAM:
+                        start = cursor + moved
+                        splices.append((cursor, fm, fw))
+                        moved += len(fm)
+                        rec = rec[:2] + (i, start, start + len(fm)) \
+                            + rec[5:]
+                    else:
+                        rec = rec[:2] + (i,) + rec[3:]
+                    add(rec)
+                continue
+            if kind == _ROW_NONE:
+                continue
+            kb = raw[ko:ko + kl]
+            key = keys.get(kb)
+            if key is None:
+                misses += 1
+                if len(keys) >= self.max_keys:
+                    keys.clear()
+                key = keys[kb] = metric_key_of(pbs[i])
+            if kind == IMPORT_HISTOGRAM:
+                cursor = b
+                add((IMPORT_HISTOGRAM, key, i, a + moved, b + moved,
+                     f0, f1, f2, f3, f4))
+            elif kind == IMPORT_COUNTER:
+                add((IMPORT_COUNTER, key, i, float(a)))
+            elif kind == IMPORT_GAUGE:
+                add((IMPORT_GAUGE, key, i, f0))
+            else:
+                try:
+                    eng_id, regs = decode_set_payload(raw[a:a + b])
+                except Exception as e:
+                    rejected.append((pbs[i], e))
+                else:
+                    add((IMPORT_SET, key, i, regs, eng_id))
+        # the columns at their own size: the stage keeps slices of them
+        means, weights = means[:total].copy(), weights[:total].copy()
+        if splices:
+            means = _spliced(means, [(c, m) for c, m, _w in splices])
+            weights = _spliced(weights, [(c, w) for c, _m, w in splices])
+        looked_up = int((ints[0] < _ROW_NONE).sum())
+        return (records, means, weights, rejected,
+                (n - fallback, fallback, looked_up - misses, misses))
+
+
+def _spliced(col, pieces):
+    """`col` with each of `pieces` = (at, values) put in before
+    col[at], in the order given (ascending `at`)."""
+    out, done = [], 0
+    for at, values in pieces:
+        out += [col[done:at], values]
+        done = at
+    return np.concatenate(out + [col[done:]])
 
 
 def _split_tags(joined: str) -> list[str]:

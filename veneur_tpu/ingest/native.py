@@ -40,8 +40,6 @@ P_METRIC, P_ERROR, P_OTHER = 0, 1, 2
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "build", "libvtpu_ingest.so")
-
 _lib = None
 _lib_lock = threading.Lock()
 
@@ -50,23 +48,27 @@ class NativeUnavailable(RuntimeError):
     pass
 
 
-def build(force: bool = False) -> str:
-    """Compile the shared library if missing or older than its source;
-    `force` rebuilds it regardless (make -B) — a copied tree's mtimes
-    say nothing about which source a library on disk was built from.
+def build(force: bool = False, name: str = "vtpu_ingest") -> str:
+    """Compile native/<name>.cpp into native/build/lib<name>.so if the
+    library is missing or older than its source (the bridge by default;
+    cluster/wire.py builds `vtpu_wire` through here too); `force`
+    rebuilds it regardless (make -B) — a copied tree's mtimes say
+    nothing about which source a library on disk was built from.
     Returns its path."""
-    src = os.path.join(_NATIVE_DIR, "vtpu_ingest.cpp")
+    src = os.path.join(_NATIVE_DIR, name + ".cpp")
+    lib = os.path.join(_NATIVE_DIR, "build", f"lib{name}.so")
     if not os.path.exists(src):
         raise NativeUnavailable(f"source missing: {src}")
-    if force or not os.path.exists(_LIB_PATH) or (
-            os.path.getmtime(_LIB_PATH) < os.path.getmtime(src)):
+    if force or not os.path.exists(lib) or (
+            os.path.getmtime(lib) < os.path.getmtime(src)):
         proc = subprocess.run(
-            ["make", "-C", _NATIVE_DIR] + (["-B"] if force else []),
+            ["make", "-C", _NATIVE_DIR, f"build/lib{name}.so"]
+            + (["-B"] if force else []),
             capture_output=True, text=True)
         if proc.returncode != 0:
             raise NativeUnavailable(
                 f"native build failed:\n{proc.stdout}\n{proc.stderr}")
-    return _LIB_PATH
+    return lib
 
 
 def load() -> ctypes.CDLL:

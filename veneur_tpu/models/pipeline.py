@@ -118,10 +118,17 @@ IMPORT_PHASES = LAND_PHASES + APPLY_PHASES
 # (time.thread_time_ns) over the stretches `import.apply.decode` and
 # `import.apply.stage` time on the wall clock; 0 with no stamp log.
 APPLY_CPU_TALLY = ("import_decode_cpu_ns", "import_stage_cpu_ns")
+# DECODE_TALLY is what wire.BatchDecoder.decode counts a request, in
+# its order: sketches read from the request's bytes by the native
+# pass, sketches read from parsed messages in Python (the two add up
+# to import_metrics), and the hits and misses of the dictionary that
+# finds a natively read sketch's key by its bytes.
+DECODE_TALLY = ("import_decode_native", "import_decode_fallback",
+                "import_decode_key_hits", "import_decode_key_misses")
 _IMPORT_TALLY = ("import_batches", "import_metrics", "import_land_rows",
                  "import_land_bank", "import_land_lanes",
                  "import_land_lanes_filled", "import_land_prechunked",
-                 ) + APPLY_CPU_TALLY
+                 ) + APPLY_CPU_TALLY + DECODE_TALLY
 
 
 def _open_clocks() -> tuple:
@@ -892,6 +899,15 @@ class AggregationEngine:
         # "import_stage_cpu_ns"; counted only with land_stamps armed)
         self._import_decode_cpu_ns = 0
         self._import_stage_cpu_ns = 0
+        # how the interval's sketches were decoded (DECODE_TALLY), and
+        # the decoder, whose key dictionary holds as many entries as
+        # the banks hold keys
+        for name in DECODE_TALLY:
+            setattr(self, "_" + name, 0)
+        from ..cluster import wire
+        self._import_decoder = wire.BatchDecoder(
+            cfg.histogram_slots + cfg.counter_slots + cfg.gauge_slots
+            + cfg.set_slots)
         # Overload defense (ingest/admission.py): attached by the
         # Server via attach_admission; None = every key mints freely
         # (direct engine construction, the pre-defense behavior).
@@ -1465,22 +1481,24 @@ class AggregationEngine:
             return
         self._import_gauge_acc[slot] = float(value)  # last write wins
 
-    def import_list(self, op_id: int, pbs) -> tuple:
+    def import_list(self, op_id: int, pbs, raw=None, at=None) -> tuple:
         """Apply one import request's metrics for this engine as a
         unit — the one way from a request to the banks' staging (the
         worker loop, recovery's replay and the history tier all call
         it). The batch is decoded in one pass outside the lock
-        (wire.decode_metric_batch), then staged in wire order under ONE
-        lock hold in which the applied-op watermark also advances, so
-        a concurrent checkpoint_state() sees either none of the op or
-        all of it — the exactness the watermark's replay filter
-        depends on. Staging is the engine's own
+        (wire.BatchDecoder: from `raw`, the request's serialized bytes,
+        where the batch came with them, `at` the positions of `pbs` in
+        the request or None for all of it; else from the parsed
+        messages, wire.decode_metric_batch), then staged in wire order
+        under ONE lock hold in which the applied-op watermark also
+        advances, so a concurrent checkpoint_state() sees either none
+        of the op or all of it — the exactness the watermark's replay
+        filter depends on. Staging is the engine's own
         (_stage_import_records). Returns (rerouted, rejected): fold
         keys homed on other engines as (ImportFoldReroute, pb) pairs
         the worker loop re-routes, and per-metric poison pills as
         (pb, exception) pairs it counts — one corrupt metric must
         reject itself, not the op."""
-        from ..cluster import wire
         # with the stamp log armed: the three APPLY_PHASES and the
         # thread's CPU time beside two of them, four readings of each
         # clock a request; without it no clock is read
@@ -1488,13 +1506,15 @@ class AggregationEngine:
         opened, closed = ((_no_clock, _no_clock) if stamps is None
                           else (_open_clocks, _close_clocks))
         t0, c0 = opened()
-        records, means, weights, rejected = wire.decode_metric_batch(pbs)
+        records, means, weights, rejected, decoded = \
+            self._import_decoder.decode(pbs, raw, at)
         t1, c1 = closed()
-        rerouted = []
+        # staging names a metric by its position in `pbs`
+        rerouted_at, rejected_at = [], []
         with self.lock:
             t2, c2 = opened()
-            self._stage_import_records(records, means, weights, rerouted,
-                                       rejected)
+            self._stage_import_records(records, means, weights,
+                                       rerouted_at, rejected_at)
             self._import_batches += 1
             self._import_metrics += len(pbs)
             if op_id > self.last_import_op:
@@ -1502,18 +1522,22 @@ class AggregationEngine:
             t3, c3 = closed()
             self._import_decode_cpu_ns += c1 - c0
             self._import_stage_cpu_ns += c3 - c2
+            for name, n in zip(DECODE_TALLY, decoded):
+                setattr(self, "_" + name, getattr(self, "_" + name) + n)
         if stamps is not None:
             # no merge gap: requests interleave, and a decode row
             # merged with the next would swallow the stage between
             stamps.add("import.apply.decode", t0, t1)
             stamps.add("import.apply.lock_wait", t1, t2)
             stamps.add("import.apply.stage", t2, t3)
-        return rerouted, rejected
+        rejected += [(pbs[i], e) for i, e in rejected_at]
+        return [(fr, pbs[i]) for fr, i in rerouted_at], rejected
 
     def _stage_import_records(self, records, means, weights, rerouted,
                               rejected):
         """Stage a decoded request (wire.decode_metric_batch) under the
-        lock, appending to `rerouted` and `rejected`. Here: the
+        lock, appending to `rerouted` and `rejected`, which name a
+        metric by its position in the batch as its record does. Here: the
         per-metric `_import_*_locked` calls in wire order, so a landing
         still fires at the digest, centroid or set that fills its
         stage, in the middle of a batch where that is where it falls.
@@ -2540,6 +2564,9 @@ class AggregationEngine:
             # (veneur.import.land_rows_total / land_bank_total)
             "import_land_rows": imported["import_land_rows"],
             "import_land_bank": imported["import_land_bank"],
+            # its sketches by the path that decoded them, and the key
+            # dictionary's hits and misses (veneur.import.decode_*)
+            **{name: imported[name] for name in DECODE_TALLY},
             # what the export build actually shipped (delta requests
             # degrade to full when no bitmap exists — mesh, tracking
             # off — or the engine does not forward)

@@ -32,8 +32,9 @@ from . import observe, resilience
 from .config import Config, _parse_interval
 from .ingest import parser
 from .metrics import FrameSet, InterMetric, MetricType
-from .models.pipeline import (APPLY_CPU_TALLY, APPLY_PHASES, LAND_PHASES,
-                              AggregationEngine, EngineConfig, ForwardExport)
+from .models.pipeline import (APPLY_CPU_TALLY, APPLY_PHASES, DECODE_TALLY,
+                              LAND_PHASES, AggregationEngine, EngineConfig,
+                              ForwardExport)
 from .sinks import MetricSink
 from .sinks.basic import (BlackholeMetricSink, DebugMetricSink,
                           LocalFilePlugin)
@@ -1340,27 +1341,31 @@ class Server:
 
     def _group_imports(self, pbs) -> dict:
         """One import request's metrics by target engine (= worker
-        queue), wire order kept inside each share: the worker-sharding
-        digest (FNV-1a over name, type and tags, as the packet path's)
-        where there is more than one engine to choose between, and no
-        work a metric where there is one. An unroutable metric (bad
-        key bytes) rejects itself, counted and logged."""
+        queue), wire order kept inside each share, as (share, the
+        share's positions in the request or None for all of it): the
+        worker-sharding digest (FNV-1a over name, type and tags, as
+        the packet path's) where there is more than one engine to
+        choose between, and no work a metric where there is one. An
+        unroutable metric (bad key bytes) rejects itself, counted and
+        logged."""
         from .cluster import wire
         n = len(self.engines)
         if n == 1:
-            return {0: pbs} if len(pbs) else {}
-        groups: dict[int, list] = {}
-        for pb in pbs:
+            return {0: (pbs, None)} if len(pbs) else {}
+        groups: dict[int, tuple] = {}
+        for at, pb in enumerate(pbs):
             try:
                 digest = wire.metric_digest_of(pb)
             except Exception as e:
                 self._count("import.rejected")
                 log.warning("rejected unroutable imported metric: %s", e)
                 continue
-            groups.setdefault(digest % n, []).append(pb)
+            share, ats = groups.setdefault(digest % n, ([], []))
+            share.append(pb)
+            ats.append(at)
         return groups
 
-    def _submit_import_batch(self, pbs, envelope=None) -> int:
+    def _submit_import_batch(self, pbs, envelope=None, raw=None) -> int:
         """The import submit path (importsrv and the HTTP /import
         handler): one admitted request = one op, grouped per target
         engine so each engine's share travels as ONE ImportedBatch and
@@ -1373,7 +1378,10 @@ class Server:
         (the request's already-admitted idempotency envelope) rides in
         the op record so recovery can re-seed the dedupe ledger —
         recovered state plus a forgotten envelope would double-count
-        the sender's replay. Returns the count routed."""
+        the sender's replay. `raw` (the serialized MetricList `pbs` was
+        parsed from, where the request came as one: gRPC SendMetrics)
+        rides in each ImportedBatch, for the worker to read the
+        sketches from. Returns the count routed."""
         from .cluster.importsrv import ImportedBatch
         from .durability import records as drecords
         groups = self._group_imports(pbs)
@@ -1396,12 +1404,12 @@ class Server:
                         self._import_ops_evicted = True
                 except Exception:
                     self._engine_journal_failed("import write-ahead")
-            for qi, share in groups.items():
+            for qi, (share, at) in groups.items():
                 # a shed batch is journaled all the same: recovery
                 # replays it, only live processing loses it
-                self._enqueue_import(qi, ImportedBatch(op_id, share),
-                                     len(share))
-        return sum(len(share) for share in groups.values())
+                self._enqueue_import(
+                    qi, ImportedBatch(op_id, share, raw, at), len(share))
+        return sum(len(share) for share, _at in groups.values())
 
     def _recover_engine_state(self):
         """Recovery-before-listen: rebuild the engines from the engine
@@ -1502,7 +1510,7 @@ class Server:
                 self.dedupe_ledger.admit(*env)
             applied = False
             reroutes: list = []
-            for ei, epbs in self._group_imports(pbs).items():
+            for ei, (epbs, _at) in self._group_imports(pbs).items():
                 eng = self.engines[ei]
                 if op_id <= eng.last_import_op:
                     continue   # inside the restored checkpoint already
@@ -1790,8 +1798,13 @@ class Server:
     def _start_import_listener(self, addr: str):
         """Global-mode gRPC receive path (importsrv): forwarded metrics
         are re-hashed onto the worker queues and merged via Combine."""
+        from .cluster import wire
         from .cluster.importsrv import start_import_server
 
+        # its requests come with their bytes: have the library that
+        # reads them built and loaded before the first one (a process
+        # that cannot says so once and decodes in Python)
+        wire.native_decode_fn()
         server, port = start_import_server(
             addr, self._submit_import_batch, ledger=self.dedupe_ledger,
             observer=self.import_observer,
@@ -1913,8 +1926,8 @@ class Server:
                     # one import request's share for this engine,
                     # applied as a unit so the engine's applied-op
                     # watermark is an exact replay cut
-                    rerouted, rejected = eng.import_list(item.op_id,
-                                                         item.pbs)
+                    rerouted, rejected = eng.import_list(
+                        item.op_id, item.pbs, item.raw, item.at)
                     for fr, pb in rerouted:
                         # overload defense: the fold key is homed on
                         # another engine — rewrite the aggregate onto
@@ -2087,7 +2100,8 @@ class Server:
         eng_stats = {"samples": 0, "dropped_no_slot": 0,
                      "overflow_rows": 0, "overflow_bank": 0,
                      "import_batches": 0, "import_metrics": 0,
-                     "import_land_rows": 0, "import_land_bank": 0}
+                     "import_land_rows": 0, "import_land_bank": 0,
+                     **dict.fromkeys(DECODE_TALLY, 0)}
         # Engines flush concurrently so their device programs and
         # device→host transfers overlap instead of queueing behind
         # one another's host assembly. Single engine = no thread.
@@ -2859,6 +2873,14 @@ class Server:
             # and landings that compressed the whole bank (the dear arm)
             tel.mark(S, "import.land_rows", eng_stats["import_land_rows"])
             tel.mark(S, "import.land_bank", eng_stats["import_land_bank"])
+            # the import's decode: sketches the native pass read from a
+            # request's bytes, sketches read from parsed messages in
+            # Python, and the hits and misses of the dictionary that
+            # finds a natively read sketch's key
+            # (veneur.import.decode_native_total / _fallback_total /
+            # _key_hits_total / _key_misses_total)
+            for k in DECODE_TALLY:
+                tel.mark(S, k.replace("_", ".", 1), eng_stats[k])
             # the mesh engine's landings (veneur.import.mesh.*):
             # points staged, programs dispatched, scatter rounds, hot
             # slots pre-clustered on the host, keys a full shard
