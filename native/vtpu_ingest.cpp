@@ -28,6 +28,8 @@
 #include <algorithm>
 #include <atomic>
 #include <cerrno>
+#include <chrono>
+#include <condition_variable>
 #include <cmath>
 #include <cstdint>
 #include <cstdlib>
@@ -512,6 +514,18 @@ struct Bridge {
   std::atomic<uint64_t> ssf_errors{0};
   int ssf_bound_port = 0;
   int ssf_max_dgram = 16384;
+
+  // framed SSF streams (unix://, tcp://): what every stream reader
+  // tallies, added once a read. ssf_stream_wait_ns stays 0: a reader
+  // that finds a sub-ring full drops and counts (ring_drops) as every
+  // transport of the bridge does; it never waits for room
+  std::atomic<uint64_t> ssf_stream_frames{0}, ssf_stream_conns{0},
+      ssf_stream_conn_errors{0}, ssf_stream_read_ns{0},
+      ssf_stream_wait_ns{0};
+  // connection readers are detached; stop waits until the last has left
+  std::mutex stream_mu;
+  std::condition_variable stream_cv;
+  int stream_active = 0;
 
   std::atomic<uint64_t> packets{0}, lines{0}, samples{0}, parse_errors{0},
       slow_routed{0};
@@ -1156,6 +1170,138 @@ void ssf_reader_loop(Bridge* br, int sock) {
   }
 }
 
+// The frame layout of an SSF stream as ssf/framing.py defines it: one
+// version byte, the payload's length, the SSFSpan protobuf. Each
+// constant MUST stay equal to its Python twin (VERSION_BYTE,
+// LENGTH_BYTES, LENGTH_LITTLE_ENDIAN, MAX_FRAME_LENGTH; vlint NA03).
+constexpr int kSsfFrameVersion = 0;
+constexpr int kSsfFrameLengthBytes = 4;
+constexpr int kSsfFrameLengthLittleEndian = 1;
+constexpr int kSsfMaxFrameLength = 16 * 1024 * 1024;
+constexpr size_t kSsfFrameHeader = 1 + kSsfFrameLengthBytes;
+
+inline size_t ssf_frame_length(const uint8_t* p) {
+  size_t v = 0;
+  for (int i = 0; i < kSsfFrameLengthBytes; i++) {
+    int at = kSsfFrameLengthLittleEndian ? kSsfFrameLengthBytes - 1 - i : i;
+    v = (v << 8) | p[at];
+  }
+  return v;
+}
+
+inline uint64_t mono_ns() {
+  return static_cast<uint64_t>(
+      std::chrono::duration_cast<std::chrono::nanoseconds>(
+          std::chrono::steady_clock::now().time_since_epoch())
+          .count());
+}
+
+// One accepted connection of a framed SSF stream (Server.ReadSSFStream
+// Socket's C++ twin, server.py:_read_ssf_stream's): whatever the socket
+// has is read into one buffer and cut into frames, every whole frame
+// goes through handle_ssf with ONE LocalStage, flushed into the rings
+// once a read. A bad version byte, an oversize length, a malformed
+// protobuf or a close inside a frame counts one ssf error and closes
+// this connection only.
+void ssf_stream_conn_loop(Bridge* br, int fd) {
+  LocalStage st;
+  std::vector<uint8_t> buf(1 << 16);
+  size_t have = 0;
+  bool bad = false;
+  pollfd pfd{fd, POLLIN, 0};
+  while (!bad && !br->stop.load(std::memory_order_relaxed)) {
+    int pr = poll(&pfd, 1, 100);
+    if (pr <= 0) continue;
+    ssize_t n = recv(fd, buf.data() + have, buf.size() - have, 0);
+    if (n < 0) {
+      if (errno == EINTR || errno == EAGAIN) continue;
+      bad = true;
+      break;
+    }
+    if (n == 0) {
+      bad = have > 0;  // closed inside a frame
+      break;
+    }
+    have += static_cast<size_t>(n);
+    uint64_t t0 = mono_ns();
+    size_t off = 0;
+    uint64_t frames = 0;
+    while (off < have) {
+      if (buf[off] != kSsfFrameVersion) {
+        bad = true;
+        break;
+      }
+      if (have - off < kSsfFrameHeader) break;
+      size_t len = ssf_frame_length(&buf[off + 1]);
+      if (len > static_cast<size_t>(kSsfMaxFrameLength)) {
+        bad = true;
+        break;
+      }
+      size_t whole = kSsfFrameHeader + len;
+      if (have - off < whole) {
+        // the frame's tail is still on its way: room for all of it
+        if (buf.size() < whole) buf.resize(whole);
+        break;
+      }
+      const uint8_t* body = &buf[off + kSsfFrameHeader];
+      int rc = handle_ssf(br, &st, body, len);
+      frames++;
+      off += whole;
+      if (rc == 0) {
+        route_ssf_other(br, body, len);
+      } else if (rc < 0) {
+        bad = true;
+        break;
+      }
+    }
+    st.flush(br);
+    if (off > 0) {
+      memmove(buf.data(), buf.data() + off, have - off);
+      have -= off;
+    }
+    br->ssf_stream_frames.fetch_add(frames, std::memory_order_relaxed);
+    br->ssf_stream_read_ns.fetch_add(mono_ns() - t0,
+                                     std::memory_order_relaxed);
+  }
+  if (bad) {
+    br->ssf_errors.fetch_add(1, std::memory_order_relaxed);
+    br->ssf_stream_conn_errors.fetch_add(1, std::memory_order_relaxed);
+  }
+  close(fd);
+  std::lock_guard<std::mutex> g(br->stream_mu);
+  br->stream_active--;
+  br->stream_cv.notify_all();
+}
+
+// Accepts on a listening stream socket the server bound (unix:// or
+// tcp://; `lsock` is the bridge's own dup of it) and gives every
+// connection a reader thread of its own.
+void ssf_stream_accept_loop(Bridge* br, int lsock) {
+  pollfd pfd{lsock, POLLIN, 0};
+  while (!br->stop.load(std::memory_order_relaxed)) {
+    int pr = poll(&pfd, 1, 100);
+    if (pr <= 0) continue;
+    int fd = accept(lsock, nullptr, nullptr);
+    if (fd < 0) continue;
+    br->ssf_stream_conns.fetch_add(1, std::memory_order_relaxed);
+    {
+      std::lock_guard<std::mutex> g(br->stream_mu);
+      br->stream_active++;
+    }
+    std::thread(ssf_stream_conn_loop, br, fd).detach();
+  }
+}
+
+// Readers and acceptors joined, every accepted connection closed.
+void stop_threads(Bridge* br) {
+  br->stop.store(true);
+  for (auto& t : br->readers)
+    if (t.joinable()) t.join();
+  br->readers.clear();
+  std::unique_lock<std::mutex> g(br->stream_mu);
+  br->stream_cv.wait(g, [br] { return br->stream_active == 0; });
+}
+
 }  // namespace
 
 // ================================================================ C ABI
@@ -1185,9 +1331,7 @@ void* vtpu_create(int32_t histo_slots, int32_t counter_slots,
 
 void vtpu_destroy(void* h) {
   Bridge* br = static_cast<Bridge*>(h);
-  br->stop.store(true);
-  for (auto& t : br->readers)
-    if (t.joinable()) t.join();
+  stop_threads(br);
   for (int s : br->socks) close(s);
   delete br;
 }
@@ -1301,6 +1445,20 @@ int32_t vtpu_start_ssf_udp(void* h, const char* host, int32_t port,
   return bound;
 }
 
+// Start the native reader of framed SSF streams on a listening stream
+// socket the caller bound and listens on (unix:// or tcp://). The bridge
+// accepts on a dup of `listen_fd`, so the caller may close its own; every
+// accepted connection is the bridge's, closed by vtpu_stop. Returns 0 or
+// -errno.
+int32_t vtpu_start_ssf_stream(void* h, int32_t listen_fd) {
+  Bridge* br = static_cast<Bridge*>(h);
+  int fd = dup(listen_fd);
+  if (fd < 0) return -errno;
+  br->socks.push_back(fd);
+  br->readers.emplace_back(ssf_stream_accept_loop, br, fd);
+  return 0;
+}
+
 // Drain fallback SSF datagrams (STATUS-carrying spans) as u32le
 // length-prefixed records for the Python span pipeline.
 int32_t vtpu_drain_ssf_other(void* h, uint8_t* buf, int32_t buf_len) {
@@ -1323,10 +1481,7 @@ int32_t vtpu_drain_ssf_other(void* h, uint8_t* buf, int32_t buf_len) {
 
 void vtpu_stop(void* h) {
   Bridge* br = static_cast<Bridge*>(h);
-  br->stop.store(true);
-  for (auto& t : br->readers)
-    if (t.joinable()) t.join();
-  br->readers.clear();
+  stop_threads(br);
   for (int s : br->socks) close(s);
   br->socks.clear();
 }
@@ -1489,7 +1644,11 @@ int64_t vtpu_key_count(void* h, int32_t bank) {
 
 // stats[0..8] = packets, lines, samples, parse_errors, slow_routed,
 //               drops_no_slot(sum), ring_drops(sum), other_drops,
-//               pending_other
+//               pending_other;  [9..13] = ssf_spans, ssf_fallbacks,
+//               ssf_errors, ssf_other_drops, pending_ssf_other;
+//               [14..18] = the framed-stream readers' frames, connections
+//               accepted, connections closed on error, ns inside
+//               handle_ssf + staging, ns waited for ring room (always 0)
 void vtpu_stats(void* h, uint64_t* out) {
   Bridge* br = static_cast<Bridge*>(h);
   out[0] = br->packets.load();
@@ -1515,6 +1674,11 @@ void vtpu_stats(void* h, uint64_t* out) {
     out[12] = br->ssf_other_drops;
     out[13] = br->ssf_other.size();
   }
+  out[14] = br->ssf_stream_frames.load();
+  out[15] = br->ssf_stream_conns.load();
+  out[16] = br->ssf_stream_conn_errors.load();
+  out[17] = br->ssf_stream_read_ns.load();
+  out[18] = br->ssf_stream_wait_ns.load();
   std::lock_guard<std::mutex> g(br->other_mu);
   out[7] = br->other_drops;
   out[8] = br->other.size();

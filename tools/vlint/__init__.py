@@ -8,6 +8,7 @@ Checks (see tools/vlint/README.md for the full contract):
   CF01  config-plumbing parity across sibling listener-start calls
   NA01  nullptr-reachable string::assign in the native bridge
   NA02  native/Python decoder recursion-cap divergence
+  NA03  native/Python SSF frame-layout divergence
   VL00  suppression without a reason
   VL01  file failed to parse
 

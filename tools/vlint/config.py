@@ -28,6 +28,14 @@ DEFAULT_CONFIG = {
     # NA02: the Python-side parity constant for the native decoder's
     # recursion cap.
     "na02_py_constant": "PB_SKIP_MAX_DEPTH",
+    # NA03: the SSF stream's frame layout, native constant -> its twin
+    # in ssf/framing.py.
+    "na03_pairs": {
+        "kSsfFrameVersion": "VERSION_BYTE",
+        "kSsfFrameLengthBytes": "LENGTH_BYTES",
+        "kSsfFrameLengthLittleEndian": "LENGTH_LITTLE_ENDIAN",
+        "kSsfMaxFrameLength": "MAX_FRAME_LENGTH",
+    },
     # RS01: modules allowed to make raw urlopen / grpc-channel calls —
     # the resilience layer itself owns the one raw transport.
     "rs01_allow": (

@@ -231,6 +231,21 @@ def param_names(fn: ast.FunctionDef | ast.AsyncFunctionDef
             + [p.arg for p in a.kwonlyargs])
 
 
+def int_expr(node: ast.AST) -> int | None:
+    """A module-level whole number written as literals joined by `*`,
+    `+` or `<<` (16 * 1024 * 1024), or None."""
+    if isinstance(node, ast.Constant) and isinstance(node.value, int):
+        return int(node.value)
+    if isinstance(node, ast.BinOp) and isinstance(
+            node.op, (ast.Mult, ast.Add, ast.LShift)):
+        a, b = int_expr(node.left), int_expr(node.right)
+        if a is None or b is None:
+            return None
+        return (a * b if isinstance(node.op, ast.Mult)
+                else a + b if isinstance(node.op, ast.Add) else a << b)
+    return None
+
+
 # ---------------------------------------------------------------- runner
 
 def run_project(proj: Project, config: dict) -> list[Violation]:

@@ -1,4 +1,4 @@
-"""Line-based C++ passes for native/vtpu_ingest.cpp: NA01, NA02.
+"""Line-based C++ passes for native/vtpu_ingest.cpp: NA01, NA02, NA03.
 
 These are deliberately regex-level — the native bridge is one file of
 C-with-classes and the two defect classes it has actually shipped
@@ -9,9 +9,10 @@ tier-1 gate that must run in milliseconds with no extra deps.
 
 from __future__ import annotations
 
+import ast
 import re
 
-from .core import NativeFile, Violation
+from .core import NativeFile, Violation, int_expr
 
 # const uint8_t *k = nullptr, *v = nullptr;   (captures each name)
 _NULLPTR_DECL_RE = re.compile(r"\*\s*(\w+)\s*=\s*nullptr\b")
@@ -135,5 +136,56 @@ def check_na02(nf: NativeFile, ctx, config: dict) -> list[Violation]:
     return out
 
 
+# constexpr <type> kName = <whole numbers, *, <<, +, 0x..>;
+_CONST_EXPR_RE = re.compile(
+    r"\bconstexpr\s+[\w:]+\s+(\w+)\s*=\s*([0-9a-fA-Fx\s*+<()]+);")
+
+
+def check_na03(nf: NativeFile, ctx, config: dict) -> list[Violation]:
+    """Frame-layout parity of an SSF stream. The bridge cuts framed
+    streams itself, so each constant of the layout exists twice: here
+    and in ssf/framing.py, which the Python loop, the clients and the
+    tests use. Each native constant named in `na03_pairs` must equal
+    its Python twin, and a file that defines one of them defines all."""
+    pairs = config["na03_pairs"]
+    found = {}
+    for i, text in enumerate(nf.lines):
+        for m in _CONST_EXPR_RE.finditer(text.split("//", 1)[0]):
+            if m.group(1) in pairs:
+                value = int_expr(ast.parse(m.group(2).strip(),
+                                           mode="eval").body)
+                if value is not None:
+                    found[m.group(1)] = (value, i + 1)
+    if not found:
+        return []
+    out = []
+    first = min(line for _v, line in found.values())
+    for cpp_name, py_name in pairs.items():
+        if cpp_name not in found:
+            out.append(Violation(
+                nf.path, first, "NA03",
+                f"the frame layout is defined here without {cpp_name} "
+                f"(the twin of {py_name}): all of "
+                f"{', '.join(pairs)} belong together"))
+            continue
+        value, lineno = found[cpp_name]
+        twin = ctx.na03_values.get(py_name)
+        if twin is None:
+            out.append(Violation(
+                nf.path, lineno, "NA03",
+                f"{cpp_name}={value} has no Python-side {py_name} in the "
+                "scanned tree: the native stream reader and "
+                "ssf/framing.py must cut frames by one layout"))
+        elif twin[0] != value:
+            out.append(Violation(
+                nf.path, lineno, "NA03",
+                f"{cpp_name}={value} diverges from {py_name}={twin[0]} "
+                f"({twin[1]}): the native stream reader and the Python "
+                "frame loop would cut the same bytes into different "
+                "frames"))
+    return out
+
+
 def check_file(nf: NativeFile, ctx, config: dict) -> list[Violation]:
-    return check_na01(nf) + check_na02(nf, ctx, config)
+    return (check_na01(nf) + check_na02(nf, ctx, config)
+            + check_na03(nf, ctx, config))

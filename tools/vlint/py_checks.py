@@ -15,7 +15,8 @@ import ast
 import os
 from dataclasses import dataclass, field
 
-from .core import (PyModule, Project, Violation, dotted, is_jit_expr,
+from .core import (PyModule, Project, Violation, dotted, int_expr,
+                   is_jit_expr,
                    jit_call_keywords, literal_ints, literal_strs,
                    param_names)
 
@@ -43,6 +44,8 @@ class Context:
     # NA02: value of the Python-side recursion-cap parity constant
     na02_value: int | None = None
     na02_path: str | None = None
+    # NA03: Python-side frame-layout constants, name -> (value, path)
+    na03_values: dict = field(default_factory=dict)
 
 
 def _module_basename(path: str) -> str:
@@ -52,6 +55,7 @@ def _module_basename(path: str) -> str:
 def build_context(proj: Project, config: dict) -> Context:
     ctx = Context()
     const_name = config["na02_py_constant"]
+    na03_names = set(config["na03_pairs"].values())
     for mod in proj.py_modules:
         for node in ast.walk(mod.tree):
             if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
@@ -70,6 +74,13 @@ def build_context(proj: Project, config: dict) -> Context:
                     and isinstance(node.value.value, int)):
                 ctx.na02_value = node.value.value
                 ctx.na02_path = mod.path
+            if (isinstance(node, ast.Assign)
+                    and len(node.targets) == 1
+                    and isinstance(node.targets[0], ast.Name)
+                    and node.targets[0].id in na03_names):
+                value = int_expr(node.value)
+                if value is not None:
+                    ctx.na03_values[node.targets[0].id] = (value, mod.path)
     return ctx
 
 

@@ -108,6 +108,9 @@ def load() -> ctypes.CDLL:
                                            ctypes.c_char_p,
                                            ctypes.c_int32, ctypes.c_int32,
                                            ctypes.c_int32, ctypes.c_int32]
+        lib.vtpu_start_ssf_stream.restype = ctypes.c_int32
+        lib.vtpu_start_ssf_stream.argtypes = [ctypes.c_void_p,
+                                              ctypes.c_int32]
         lib.vtpu_drain_ssf_other.restype = ctypes.c_int32
         lib.vtpu_drain_ssf_other.argtypes = [ctypes.c_void_p, u8p,
                                              ctypes.c_int32]
@@ -274,6 +277,17 @@ class NativeBridge:
             raise OSError(-rc, os.strerror(-rc))
         return rc
 
+    def start_ssf_stream(self, listen_fd: int) -> None:
+        """Start the native reader of framed SSF streams on a listening
+        stream socket the caller bound (unix:// or tcp://): accept, read,
+        frame cutting (ssf/framing.py's layout), decode and ring staging
+        in C++, one thread a connection; fallback frames queue for
+        drain_ssf_other. The bridge accepts on its own dup of the
+        descriptor and closes what it accepted in stop()."""
+        rc = self._lib.vtpu_start_ssf_stream(self._h, listen_fd)
+        if rc < 0:
+            raise OSError(-rc, os.strerror(-rc))
+
     def drain_ssf_other(self) -> list:
         """Fallback SSF datagrams (STATUS-carrying spans) for the
         Python span pipeline, as raw protobuf bytes."""
@@ -378,14 +392,16 @@ class NativeBridge:
             _u8(ta), len(tb))
 
     def stats(self) -> dict:
-        out = np.zeros(14, np.uint64)
-        self._lib.vtpu_stats(
-            self._h, out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)))
         keys = ("packets", "lines", "samples", "parse_errors",
                 "slow_routed", "drops_no_slot", "ring_drops",
                 "other_drops", "pending_other", "ssf_spans",
                 "ssf_fallbacks", "ssf_errors", "ssf_other_drops",
-                "pending_ssf_other")
+                "pending_ssf_other", "ssf_stream_frames",
+                "ssf_stream_conns", "ssf_stream_conn_errors",
+                "ssf_stream_read_ns", "ssf_stream_wait_ns")
+        out = np.zeros(len(keys), np.uint64)
+        self._lib.vtpu_stats(
+            self._h, out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)))
         return dict(zip(keys, out.tolist()))
 
 
@@ -509,6 +525,7 @@ class NativePump:
         # its `ingest` root. Preallocated; past its budget a tick's
         # later dispatches lengthen the last row, so seconds stay exact
         self.stamps = stamps
+        self._ssf_read_ns = 0   # the stream readers' tally at the last take
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         # pump_once may be called by both the pump thread and
@@ -520,6 +537,23 @@ class NativePump:
                 np.zeros(batch, np.float32), np.zeros(batch, np.int32))
             for b in _BANKS
         }
+
+    def take_stamps(self) -> list:
+        """The interval's flight recorder rows, for the flush tick that
+        grafts them under its `ingest` root: the pump's batches and,
+        where framed SSF streams were read, one `ingest.ssf.read` row
+        of the stream readers' seconds inside handle_ssf + staging
+        since the last take. The readers keep a tally and no edges, so
+        the row is laid to end where the pump's last batch did."""
+        if self.stamps is None:
+            return []
+        rows = self.stamps.take()
+        read_ns = int(self.bridge.stats()["ssf_stream_read_ns"])
+        took, self._ssf_read_ns = read_ns - self._ssf_read_ns, read_ns
+        if took > 0:
+            end = max((r[2] for r in rows), default=time.monotonic_ns())
+            rows.append(("ingest.ssf.read", end - took, end))
+        return rows
 
     def start(self):
         self._thread = threading.Thread(target=self._run, name="native-pump",

@@ -863,7 +863,13 @@ def quantile(bank: TDigestBank, qs) -> jax.Array:
     knot_v = jnp.concatenate([vmin, jnp.where(w > 0, means, vmax), vmax],
                              axis=1)
 
-    out = _interp_knots(knot_q, knot_v, qs)
+    # The clamp is not a formality: a cluster's mean is the difference of
+    # two f32 running sums over its row (_cluster_tail), so it is off by
+    # up to an ulp of the ROW's sum, not of the value. With some dozens
+    # of samples of ~100 a row (a sum in the thousands) the top
+    # singleton landed 3e-6 of its value above the row's exact max, and
+    # a p99 read between it and vmax came out above the max (PR 43).
+    out = jnp.clip(_interp_knots(knot_q, knot_v, qs), vmin, vmax)
     # Empty digests -> 0 (host layer skips unallocated slots anyway).
     return jnp.where(total > 0, out, 0.0)
 

@@ -88,6 +88,18 @@ class _WorkerQueue(queue.Queue):
         return len(getattr(item, "pbs", ())) or 1
 
 
+# veneur.ssf.* self-metrics a native local drains from `bridge.stats()`,
+# beside ssf.received / ssf.error: name -> the bridge's counter
+SSF_BRIDGE_TELEMETRY = {
+    "ssf.fallback": "ssf_fallbacks",
+    "ssf.stream.frames": "ssf_stream_frames",
+    "ssf.stream.connections": "ssf_stream_conns",
+    "ssf.stream.connection_errors": "ssf_stream_conn_errors",
+    "ssf.stream.read_ns": "ssf_stream_read_ns",
+    "ssf.stream.ring_wait_ns": "ssf_stream_wait_ns",
+}
+
+
 class Server:
     # Flight-recorder rows ONE flush tick keeps of each kind of work
     # done between ticks (observe.StampLog budgets: past them a kind's
@@ -1167,6 +1179,17 @@ class Server:
                 lsock.bind(rest)
             lsock.listen(128)
             self._listen_socks.append(lsock)
+            if self._native_ssf:
+                # C++ stream readers: the bridge accepts on this socket
+                # and reads, cuts and decodes the frames itself, one
+                # thread a connection and no Python thread on the path;
+                # fallback frames come back through the pump's
+                # ssf_slow_path, and stop() closes what it accepted.
+                # Every other case (a span sink besides the ssfmetrics
+                # bridge, no bridge) keeps the Python loop below.
+                self.native_bridge.start_ssf_stream(lsock.fileno())
+                log.info("native SSF stream listener on %s", addr)
+                return
             t = threading.Thread(target=self._accept_ssf_streams,
                                  args=(lsock,), name=f"ssf-{scheme}-accept",
                                  daemon=True)
@@ -1218,26 +1241,18 @@ class Server:
 
     def _read_ssf_stream(self, conn: socket.socket):
         """Server.HandleTracePacket over a framed stream; a corrupt
-        frame poisons only its own connection."""
+        frame poisons only its own connection. The loop of a listener
+        the bridge does not read itself (`_start_ssf_listener`): every
+        span goes through the span pipeline."""
         from .ssf import framing
 
-        native_ssf = self._native_ssf
         try:
             with conn:
                 while not self._stop.is_set():
                     try:
-                        payload = framing.read_ssf_frame(conn)
-                        if payload is None:
+                        span = framing.read_ssf(conn)
+                        if span is None:
                             return
-                        if native_ssf:
-                            rc = self.native_bridge.handle_ssf(payload)
-                            if rc == 1:
-                                # counted via the bridge's ssf_spans
-                                continue
-                            if rc < 0:
-                                self._count("ssf.error")
-                                return
-                        span = framing.parse_ssf_datagram(payload)
                     except (framing.FramingError, EOFError, OSError):
                         self._count("ssf.error")
                         return
@@ -2031,8 +2046,7 @@ class Server:
                 "import": ([] if self._import_stamps is None
                            else self._import_stamps.take(), {}),
                 "ingest": ([] if self.native_pump is None
-                           or self.native_pump.stamps is None
-                           else self.native_pump.stamps.take(), {})}
+                           else self.native_pump.take_stamps(), {})}
             if timestamp is not None:
                 # scripted/explicit timestamps stay scripted all the
                 # way through the e2e accounting: the interval-close
@@ -2800,6 +2814,12 @@ class Server:
             tel.incr(S, "ssf.error",
                      int(st["ssf_errors"])
                      - int(last.get("ssf_errors", 0)))
+            # spans the fast path handed back whole, and what the
+            # framed-stream readers tallied (veneur.ssf.fallback_total,
+            # veneur.ssf.stream.*_total; ring_wait_ns is 0 while a full
+            # ring drops and counts instead of making the reader wait)
+            for name, key in SSF_BRIDGE_TELEMETRY.items():
+                tel.incr(S, name, int(st[key]) - int(last.get(key, 0)))
             if eng_stats is not None:
                 eng_stats["dropped_no_slot"] = (
                     int(st["drops_no_slot"])

@@ -19,8 +19,15 @@ import struct
 
 from .protos import ssf_pb2
 
+# The frame layout, written once: native/vtpu_ingest.cpp reads framed
+# streams itself and holds the same four (kSsfFrameVersion,
+# kSsfFrameLengthBytes, kSsfFrameLengthLittleEndian, kSsfMaxFrameLength;
+# vlint NA03 keeps each pair equal).
 VERSION_BYTE = 0x00
-_LEN = struct.Struct("<I")
+LENGTH_BYTES = 4
+LENGTH_LITTLE_ENDIAN = 1
+_LEN = struct.Struct(("<" if LENGTH_LITTLE_ENDIAN else ">")
+                     + {2: "H", 4: "I", 8: "Q"}[LENGTH_BYTES])
 
 # Defensive bound mirroring the reference's refusal to allocate
 # attacker-controlled buffer sizes.
@@ -69,7 +76,7 @@ def read_ssf_frame(stream) -> bytes | None:
         return None
     if first[0] != VERSION_BYTE:
         raise FramingError(f"unknown SSF frame version {first[0]:#x}")
-    (length,) = _LEN.unpack(_read_exact(read, 4))
+    (length,) = _LEN.unpack(_read_exact(read, LENGTH_BYTES))
     if length > MAX_FRAME_LENGTH:
         raise FramingError(f"frame length {length} exceeds max "
                            f"{MAX_FRAME_LENGTH}")
