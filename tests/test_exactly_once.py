@@ -521,17 +521,18 @@ tpu_set_slots: 128
             bad.histogram.t_digest.centroids.add(mean=float("nan"),
                                                  weight=-1.0)
             # monkeypatch the engine to make centroid import explode the
-            # way a malformed payload does deeper in the stack
+            # way a malformed payload does deeper in the stack: at the
+            # digest's own step of the request's block, its row's lookup
             eng = srv.engines[0]
-            orig = eng._import_histogram_locked
-            eng._import_histogram_locked = lambda *a, **kw: (
+            orig = eng.histo_keys.lookup
+            eng.histo_keys.lookup = lambda *a, **kw: (
                 _ for _ in ()).throw(ValueError("malformed centroid"))
             try:
                 srv._submit_import_batch([bad])
                 srv._submit_import_batch([_metric("fine", 1)])
                 assert srv.drain(5.0)
             finally:
-                eng._import_histogram_locked = orig
+                eng.histo_keys.lookup = orig
             out = {m.name: m.value
                    for m in srv.flush_once(timestamp=10)}
             assert out.get("fine") == 1.0

@@ -167,23 +167,23 @@ def _watch_landings(eng, kind):
     else:
         orig = eng._land_import_centroids
 
-        def land(bank, items, dirty):
-            if items:
-                seen.append(_plain(items))
-            return orig(bank, items, dirty)
+        def land(bank, stage, dirty):
+            if stage.digests:
+                seen.append(_plain(stage.items()))
+            return orig(bank, stage, dirty)
         eng._land_import_centroids = land
     return seen
 
 
 def _staged(eng, kind):
-    state = {"centroids": _plain(eng._import_centroids),
+    state = {"centroids": _plain(eng._import_centroids.items()),
              "sets": _plain(eng._import_sets),
              "counters": list(eng._import_counter_acc.items()),
              "gauges": list(eng._import_gauge_acc.items())}
     if kind == "mesh":
         state["stage"] = _mesh_stage(eng)
     else:
-        state["centroid_total"] = eng._import_centroid_total
+        state["centroid_total"] = eng._import_centroids.centroids
     return state
 
 

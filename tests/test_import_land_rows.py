@@ -21,7 +21,8 @@ import jax
 
 from veneur_tpu.ingest.parser import MetricKey
 from veneur_tpu.models import pipeline
-from veneur_tpu.models.pipeline import AggregationEngine, EngineConfig
+from veneur_tpu.models.pipeline import (AggregationEngine, DigestStage,
+                                        EngineConfig)
 from veneur_tpu.ops import tdigest
 
 LADDER = (8, 32)
@@ -85,7 +86,8 @@ def _seeded_bank(eng, rng, landed, waiting):
     of samples no compress has folded yet."""
     B = eng.histo_bank.buf_size
     bank, _did = eng._land_import_centroids(
-        eng.histo_bank, [_item(rng, s, 150) for s in landed], None)
+        eng.histo_bank,
+        DigestStage.of_items([_item(rng, s, 150) for s in landed]), None)
     n = B // 2
     slots = np.repeat(np.asarray(waiting, np.int32), n)
     vals = rng.lognormal(4.6, 0.3, slots.size).astype(np.float32)
@@ -168,7 +170,8 @@ def test_landing_is_the_whole_bank_chain_on_the_rows_it_touches(
 
     del compress_rows[:]
     dirty = [np.zeros(K, bool)] + [np.zeros(8, bool)] * 3
-    bank, did = eng._land_import_centroids(_device(before), items, dirty)
+    bank, did = eng._land_import_centroids(
+        _device(before), DigestStage.of_items(items), dirty)
     got = _host(bank)
 
     chunks = -(-eng.histo_bank.num_centroids // B)
@@ -365,7 +368,8 @@ def test_after_warmup_no_import_compiles(slots, compiled):
         (2, (1,), (17 * cap,))]
     for S, digests, centroids in landings:
         with eng.lock:
-            eng._import_centroids = _piles(rng, S, digests, centroids)
+            eng._import_centroids = DigestStage.of_items(
+                _piles(rng, S, digests, centroids))
             eng._flush_import_centroids()
         jax.block_until_ready(eng.histo_bank)
     assert eng._import_land_prechunked == 2 + (7 if slots > 512 else 0)
@@ -472,7 +476,8 @@ def test_fixed_shape_landing_is_the_data_shaped_one_bit_for_bit(
     before = _seeded_bank(eng, rng, landed=[piles[0][0]],
                           waiting=[piles[-1][0]])
     ref = _parent_landing(eng, before, items)
-    got = _host(eng._land_import_centroids(_device(before), items, None)[0])
+    got = _host(eng._land_import_centroids(
+        _device(before), DigestStage.of_items(items), None)[0])
     touched = sorted({s for s, _, _ in piles})
     for leaf in LEAVES:
         assert got[leaf][touched].tobytes() == \
@@ -550,7 +555,7 @@ def test_the_tally_counts_lanes_as_the_landing_pads_them(
     items = [_item(rng, s, n) for s, (digests, n) in enumerate(piles)
              for _ in range(digests)]
     with eng.lock:
-        eng._import_centroids = items
+        eng._import_centroids = DigestStage.of_items(items)
         eng._flush_import_centroids()
     assert (eng._import_land_lanes, eng._import_land_lanes_filled,
             eng._import_land_prechunked) == (lanes, filled, cut)

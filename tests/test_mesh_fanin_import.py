@@ -124,6 +124,59 @@ def test_the_exact_fields_are_the_one_chip_engines(flushed):
         == {n: repr(single[n]) for n in exact}
 
 
+def _import_as_the_parent_did(eng, pbs):
+    """One request into the mesh engine the way the parent commit
+    staged it (ISSUE 44 took the loop out): a tuple a sketch from
+    `decode_metric_batch`, a row looked up a record, the digests' table
+    rebuilt from the tuples (`table.append(rec[3:])`,
+    `np.asarray(table, np.float64)`), then the other metrics one by
+    one."""
+    records, means, weights, bad = wire.decode_metric_batch(pbs)
+    assert bad == []
+    with eng.lock:
+        slots, table = [], []
+        for rec in records:
+            if rec[0] == wire.IMPORT_HISTOGRAM:
+                slot = eng._import_slot(eng.histo_keys, rec[1])
+                if slot >= 0:
+                    slots.append(slot)
+                    table.append(rec[3:])
+        table = np.asarray(table, np.float64).reshape(len(slots), 7)
+        starts = table[:, 0].astype(np.int64)
+        eng._stage_digests(
+            np.asarray(slots, np.int32), starts,
+            table[:, 1].astype(np.int64) - starts, table[:, 2:].T,
+            means, weights)
+        for rec in records:
+            if rec[0] == wire.IMPORT_SET:
+                eng._import_set_locked(rec[1], rec[3], rec[4])
+            elif rec[0] == wire.IMPORT_COUNTER:
+                eng._import_counter_locked(rec[1], rec[3])
+            elif rec[0] == wire.IMPORT_GAUGE:
+                eng._import_gauge_locked(rec[1], rec[3])
+
+
+def test_the_flush_is_the_parents_record_loop_value_for_value(fleet,
+                                                              flushed):
+    """The fleet's requests staged as blocks of columns flush what the
+    parent's loop over a tuple a sketch flushed: every name, every
+    value, bit for bit."""
+    cfg, payload = fleet
+    eng = _engine("mesh", cfg)
+    for body in payload["requests"]:
+        _import_as_the_parent_did(
+            eng, forward_pb2.MetricList.FromString(body).metrics)
+    want = reference.sink_values(eng.flush(timestamp=36).metrics)
+    got, info = flushed["mesh"][:2]
+    assert len(want) > 40 * 6
+    assert {n: repr(v) for n, v in got.items()} \
+        == {n: repr(v) for n, v in want.items()}
+    n_digests = 8 * 40
+    assert (info["import_digests_block"], info["import_digests_single"],
+            info["mesh_import_staged"]) == (n_digests, 0, n_digests)
+    assert eng._last_flush_info["mesh_import_staged"] == n_digests
+
+
 def test_the_counters_are_what_the_payload_holds(fleet, flushed):
     _cfg, payload = fleet
     _answers, info, _rows, calls = flushed["mesh"]

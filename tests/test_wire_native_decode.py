@@ -1,7 +1,11 @@
 """The import worker's native pass over a request's bytes (ISSUE 42):
 `wire.BatchDecoder.decode(pbs, raw)` must be `wire.decode_metric_batch(
 FromString(raw).metrics)` in everything `import_list` stages from:
-the records in wire order, the two f32 columns bit for bit, the rejects.
+the metrics in wire order, every digest's centroids and exact
+statistics bit for bit, the rejects. The decoder hands the histograms
+over as columns (a `wire.DigestBlock`, ISSUE 44) and the reference as
+records, which `wire.digest_block`, the one helper between the two
+forms, turns into the same block.
 The Python function is the reference; `native/vtpu_wire.cpp` reads the
 plain shape `export_to_metrics` writes and marks every other metric
 for the reference to decode, so the two agree on any bytes the protobuf
@@ -245,11 +249,27 @@ def _bits(v):
 
 
 def _canon(out, pbs):
-    records, means, weights, rejected = out[:4]
-    assert means.dtype == weights.dtype == np.float32
-    assert means.ndim == weights.ndim == 1
-    return ([tuple(_bits(v) for v in rec) for rec in records],
-            means.tobytes(), weights.tobytes(),
+    """The decoder's (block, records, rejected, ...) or the
+    reference's (records, means, weights, rejected), value for value:
+    a digest as its position, key, centroids and five statistics,
+    whichever stretch of the columns it names."""
+    if isinstance(out[0], wire.DigestBlock):
+        block, records, rejected = out[:3]
+    else:
+        (block, records), rejected = wire.digest_block(*out[:3]), out[3]
+    assert block.means.dtype == block.weights.dtype == np.float32
+    assert block.means.ndim == block.weights.ndim == 1
+    assert block.at.dtype == block.start.dtype == block.stop.dtype \
+        == np.int64
+    assert block.stats.dtype == np.float64
+    assert block.stats.shape == (5, len(block.keys))
+    assert all(rec[0] != wire.IMPORT_HISTOGRAM for rec in records)
+    digests = [(at, key, block.means[a:b].tobytes(),
+                block.weights[a:b].tobytes(), five.tobytes())
+               for at, key, a, b, five in zip(
+                   block.at.tolist(), block.keys, block.start.tolist(),
+                   block.stop.tolist(), block.stats.T)]
+    return (digests, [tuple(_bits(v) for v in rec) for rec in records],
             [(pb.SerializeToString(), type(e), str(e))
              for pb, e in rejected])
 
@@ -264,10 +284,13 @@ def _agree(raw, decoder=None, at=None):
     got = decoder.decode(pbs, raw, at)
     want = wire.decode_metric_batch(pbs)
     assert _canon(got, pbs) == _canon(want, pbs)
-    # a record names its metric by position, in wire order
-    ats = [rec[2] for rec in got[0]]
-    assert ats == sorted(ats) and all(0 <= i < len(pbs) for i in ats)
-    counts = got[4]
+    # a digest and a record name their metric by position, in wire
+    # order, and no position twice
+    ats = got[0].at.tolist(), [rec[2] for rec in got[1]]
+    for kind in ats:
+        assert kind == sorted(kind) and all(0 <= i < len(pbs) for i in kind)
+    assert len(set(ats[0] + ats[1])) == len(ats[0]) + len(ats[1])
+    counts = got[3]
     assert counts[NATIVE] + counts[FALLBACK] == len(pbs)
     return counts
 
@@ -398,7 +421,7 @@ def test_a_share_of_the_request_by_its_positions():
     for at in ([n], [3, 2], [-1]):
         pbs = list(forward_pb2.MetricList.FromString(raw).metrics)[:len(at)]
         out = wire.BatchDecoder(64).decode(pbs, raw, at)
-        assert out[4] == (0, len(at), 0, 0)
+        assert out[3] == (0, len(at), 0, 0)
         assert _canon(out, pbs) == _canon(wire.decode_metric_batch(pbs),
                                           pbs)
 
@@ -419,9 +442,9 @@ def test_a_key_is_found_by_its_bytes_and_the_dictionary_is_bounded():
                      for k, *rest in ex.histograms[:3]]
     other = _request(ex)
     pbs = forward_pb2.MetricList.FromString(other).metrics
-    records, *_rest, counts = roomy.decode(pbs, other)
+    block, *_rest, counts = roomy.decode(pbs, other)
     assert counts == (3, 0, 0, 3)
-    assert [r[1] for r in records] == [
+    assert block.keys == [
         MetricKey(f"k{i}", "timer", "a:1,b:2") for i in range(3)]
     # past its bound the dictionary is emptied whole, never grown
     assert len(roomy._keys) == 3
@@ -441,11 +464,11 @@ def test_without_bytes_or_without_the_library_python_decodes(
     decoder = wire.BatchDecoder(256)
     for no_bytes in (None, bytearray(raw), memoryview(raw)):
         out = decoder.decode(pbs, no_bytes)
-        assert out[4] == (0, 85, 0, 0) and _canon(out, pbs) == want
-    assert decoder.decode([], raw)[4] == (0, 0, 0, 0)
+        assert out[3] == (0, 85, 0, 0) and _canon(out, pbs) == want
+    assert decoder.decode([], raw)[3] == (0, 0, 0, 0)
     # bytes that are another request's: the list's count differs
     out = decoder.decode(pbs, _every_width())
-    assert out[4] == (0, 85, 0, 0) and _canon(out, pbs) == want
+    assert out[3] == (0, 85, 0, 0) and _canon(out, pbs) == want
 
     def no_compiler(**_kw):
         raise native.NativeUnavailable("no compiler in this test")
@@ -455,7 +478,7 @@ def test_without_bytes_or_without_the_library_python_decodes(
     with caplog.at_level(logging.WARNING, logger="veneur_tpu.cluster.wire"):
         for _ in range(3):
             out = decoder.decode(pbs, raw)
-            assert out[4] == (0, 85, 0, 0) and _canon(out, pbs) == want
+            assert out[3] == (0, 85, 0, 0) and _canon(out, pbs) == want
     said = [r for r in caplog.records if "libvtpu_wire" in r.getMessage()]
     assert len(said) == 1 and "no compiler" in said[0].getMessage()
     assert wire.native_decode_fn() is None

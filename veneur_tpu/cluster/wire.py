@@ -13,6 +13,8 @@ import json
 import logging
 import struct
 import threading
+from operator import itemgetter
+from typing import NamedTuple
 
 import numpy as np
 
@@ -713,6 +715,38 @@ def decode_metric_batch(pbs) -> tuple:
             rejected)
 
 
+class DigestBlock(NamedTuple):
+    """A request's histograms as columns, a row a digest in wire order:
+    what the import stage takes (AggregationEngine._stage_import_records),
+    so that no object is built a sketch between the request's bytes and
+    the landing's device matrix. Digest `j` is the metric `pbs[at[j]]`
+    under `keys[j]`, its centroids `means[start[j]:stop[j]]` and
+    `weights[start[j]:stop[j]]` of the two flat f32 columns (which may
+    hold stretches no digest names), its exact statistics the column
+    `stats[:, j]`: min, max, sum, count, reciprocal sum."""
+    at: np.ndarray          # int64[n]
+    keys: list              # MetricKey a digest
+    start: np.ndarray       # int64[n]
+    stop: np.ndarray        # int64[n]
+    stats: np.ndarray       # float64[5, n]
+    means: np.ndarray       # float32[...]
+    weights: np.ndarray     # float32[...]
+
+
+def digest_block(records, means, weights) -> tuple:
+    """decode_metric_batch's records as the stage takes them ->
+    (DigestBlock of the histograms, the other records in their order):
+    the one way from the reference's records into the stage."""
+    digests = [r for r in records if r[0] == IMPORT_HISTOGRAM]
+    others = [r for r in records if r[0] != IMPORT_HISTOGRAM]
+    n = len(digests)
+    table = np.array([r[2:] for r in digests], np.float64).reshape(n, 8)
+    spans = table[:, :3].astype(np.int64)
+    return DigestBlock(spans[:, 0], [r[1] for r in digests], spans[:, 1],
+                       spans[:, 2], np.ascontiguousarray(table[:, 3:].T),
+                       means, weights), others
+
+
 # ---- the native pass over a request's bytes (native/vtpu_wire.cpp) ----
 
 # a row's kind beyond the four of a record: no member of the `value`
@@ -764,23 +798,29 @@ def _load_native():
 
 class BatchDecoder:
     """decode_metric_batch for an engine's import worker: the same
-    records, columns and rejects, value for value and in wire order,
+    digests, records and rejects, value for value and in wire order,
     read from the request's serialized bytes in one native pass where
     the batch still has them (gRPC SendMetrics) and the library loaded.
-    A metric's MetricKey is then found by the raw bytes of its name,
+    The request's histograms leave as the pass's own columns (a
+    DigestBlock), never as an object a sketch; its sets, counters and
+    gauges as decode_metric_batch's records.
+    A metric's MetricKey is found by the raw bytes of its name,
     tags and type in a dictionary, filled on a miss by metric_key_of of
     the parsed metric (sorting, joining and UTF-8 stay the parser's),
-    emptied whole when it passes `max_keys` entries. A metric the pass
+    emptied whole when it passes `max_keys` entries: the one loop over
+    a request's sketches that is left. A metric the pass
     is not sure of is decoded by decode_metric_batch from its parsed
-    message and spliced in where it lay; a batch without bytes, or a
-    list the pass cannot walk, goes there whole."""
+    message and takes its place by its position; a batch without bytes,
+    or a list the pass cannot walk, goes there whole (digest_block)."""
 
     def __init__(self, max_keys: int):
         self.max_keys = max(1, int(max_keys))
         self._keys: dict = {}
 
     def decode(self, pbs, raw=None, at=None) -> tuple:
-        """-> (records, means, weights, rejected, counts). `raw` is the
+        """-> (block, records, rejected, counts): the histograms as a
+        DigestBlock, the other metrics as decode_metric_batch's
+        records. `raw` is the
         serialized forwardrpc.MetricList that `pbs` was parsed from and
         `at` the positions of `pbs` in its `metrics` (ascending; None
         for all of them); `counts` is (native, fallback, key hits, key
@@ -790,13 +830,14 @@ class BatchDecoder:
             out = fn and self._decode_native(fn, pbs, raw, at)
             if out:
                 return out
-        return decode_metric_batch(pbs) + ((0, len(pbs), 0, 0),)
+        records, means, weights, rejected = decode_metric_batch(pbs)
+        return (*digest_block(records, means, weights), rejected,
+                (0, len(pbs), 0, 0))
 
     def _decode_native(self, fn, pbs, raw, at):
         n = len(pbs)
         cap = len(raw) // _CENTROID_WIRE_BYTES + 1
-        # a column each (C order): a column is one flat list below,
-        # and no list is built a sketch
+        # a column each (C order): rows are picked out of them by kind
         ints = np.empty((5, n), np.int64)
         floats = np.empty((5, n), np.float64)
         means = np.empty(cap, np.float32)
@@ -810,31 +851,13 @@ class BatchDecoder:
                    means.ctypes.data, weights.ctypes.data, cap)
         if total < 0:
             return None
-        records, rejected = [], []
-        keys, add = self._keys, records.append
-        misses = fallback = 0
-        # where a fallback digest's centroids go into the native
-        # columns, and how far that has moved the digests behind it
-        splices, moved, cursor = [], 0, 0
-        for i, kind, ko, kl, a, b, f0, f1, f2, f3, f4 in zip(
-                range(n), *ints.tolist(), *floats.tolist()):
-            if kind == _ROW_FALLBACK:
-                fallback += 1
-                recs, fm, fw, rej = decode_metric_batch((pbs[i],))
-                rejected += rej
-                for rec in recs:
-                    if rec[0] == IMPORT_HISTOGRAM:
-                        start = cursor + moved
-                        splices.append((cursor, fm, fw))
-                        moved += len(fm)
-                        rec = rec[:2] + (i, start, start + len(fm)) \
-                            + rec[5:]
-                    else:
-                        rec = rec[:2] + (i,) + rec[3:]
-                    add(rec)
-                continue
-            if kind == _ROW_NONE:
-                continue
+        kinds = ints[0]
+        # a key a sketch the pass read, in wire order
+        keyed = np.flatnonzero(kinds < _ROW_NONE)
+        keys, found = self._keys, []
+        misses = 0
+        for i, ko, kl in zip(keyed.tolist(), ints[1, keyed].tolist(),
+                             ints[2, keyed].tolist()):
             kb = raw[ko:ko + kl]
             key = keys.get(kb)
             if key is None:
@@ -842,39 +865,72 @@ class BatchDecoder:
                 if len(keys) >= self.max_keys:
                     keys.clear()
                 key = keys[kb] = metric_key_of(pbs[i])
-            if kind == IMPORT_HISTOGRAM:
-                cursor = b
-                add((IMPORT_HISTOGRAM, key, i, a + moved, b + moved,
-                     f0, f1, f2, f3, f4))
-            elif kind == IMPORT_COUNTER:
-                add((IMPORT_COUNTER, key, i, float(a)))
+            found.append(key)
+        # the histograms: rows of the pass's columns, the columns at
+        # their own size (the stage keeps views of them)
+        digests = kinds[keyed] == IMPORT_HISTOGRAM
+        which = np.flatnonzero(digests)
+        rows = keyed[which]
+        block = DigestBlock(
+            rows, list(map(found.__getitem__, which.tolist())),
+            ints[3, rows], ints[4, rows], floats[:, rows],
+            means[:total].copy(), weights[:total].copy())
+        # the others: a record each
+        records, rejected = [], []
+        which = np.flatnonzero(~digests)
+        rows = keyed[which]
+        for j, i, kind, a, b, f0 in zip(
+                which.tolist(), rows.tolist(), kinds[rows].tolist(),
+                ints[3, rows].tolist(), ints[4, rows].tolist(),
+                floats[0, rows].tolist()):
+            if kind == IMPORT_COUNTER:
+                records.append((IMPORT_COUNTER, found[j], i, float(a)))
             elif kind == IMPORT_GAUGE:
-                add((IMPORT_GAUGE, key, i, f0))
+                records.append((IMPORT_GAUGE, found[j], i, f0))
             else:
                 try:
                     eng_id, regs = decode_set_payload(raw[a:a + b])
                 except Exception as e:
-                    rejected.append((pbs[i], e))
+                    rejected.append((i, pbs[i], e))
                 else:
-                    add((IMPORT_SET, key, i, regs, eng_id))
-        # the columns at their own size: the stage keeps slices of them
-        means, weights = means[:total].copy(), weights[:total].copy()
-        if splices:
-            means = _spliced(means, [(c, m) for c, m, _w in splices])
-            weights = _spliced(weights, [(c, w) for c, _m, w in splices])
-        looked_up = int((ints[0] < _ROW_NONE).sum())
-        return (records, means, weights, rejected,
-                (n - fallback, fallback, looked_up - misses, misses))
+                    records.append((IMPORT_SET, found[j], i, regs, eng_id))
+        fallback = np.flatnonzero(kinds == _ROW_FALLBACK).tolist()
+        if fallback:
+            block = self._with_fallback(pbs, fallback, block, records,
+                                        rejected)
+        return (block, records, [(pb, e) for _i, pb, e in rejected],
+                (n - len(fallback), len(fallback), len(keyed) - misses,
+                 misses))
 
-
-def _spliced(col, pieces):
-    """`col` with each of `pieces` = (at, values) put in before
-    col[at], in the order given (ascending `at`)."""
-    out, done = [], 0
-    for at, values in pieces:
-        out += [col[done:at], values]
-        done = at
-    return np.concatenate(out + [col[done:]])
+    @staticmethod
+    def _with_fallback(pbs, fallback, block, records, rejected):
+        """The metrics at positions `fallback`, which the pass left to
+        decode_metric_batch, put where they lay: their records and
+        rejects (by position) into the two lists, their digests into
+        `block`'s rows, the centroids behind the pass's own. Returns
+        the block."""
+        recs, means, weights, rej = decode_metric_batch(
+            [pbs[i] for i in fallback])
+        where = {id(pbs[i]): i for i in fallback}
+        rejected += [(where[id(pb)], pb, e) for pb, e in rej]
+        rejected.sort(key=itemgetter(0))
+        more, others = digest_block(
+            [r[:2] + (fallback[r[2]],) + r[3:] for r in recs], means, weights)
+        records += others
+        records.sort(key=itemgetter(2))
+        if not more.keys:
+            return block
+        at = np.concatenate([block.at, more.at])
+        order = np.argsort(at, kind="stable")
+        keys, behind = block.keys + more.keys, len(block.means)
+        return DigestBlock(
+            at[order],
+            list(map(keys.__getitem__, order.tolist())),
+            np.concatenate([block.start, more.start + behind])[order],
+            np.concatenate([block.stop, more.stop + behind])[order],
+            np.concatenate([block.stats, more.stats], axis=1)[:, order],
+            np.concatenate([block.means, means]),
+            np.concatenate([block.weights, weights]))
 
 
 def _split_tags(joined: str) -> list[str]:

@@ -125,10 +125,14 @@ APPLY_CPU_TALLY = ("import_decode_cpu_ns", "import_stage_cpu_ns")
 # finds a natively read sketch's key by its bytes.
 DECODE_TALLY = ("import_decode_native", "import_decode_fallback",
                 "import_decode_key_hits", "import_decode_key_misses")
+# STAGE_TALLY is how the interval's digests reached the stage: in a
+# request's block of columns (import_list), or as a block of one
+# through a per-metric entry point (import_histogram).
+STAGE_TALLY = ("import_digests_block", "import_digests_single")
 _IMPORT_TALLY = ("import_batches", "import_metrics", "import_land_rows",
                  "import_land_bank", "import_land_lanes",
                  "import_land_lanes_filled", "import_land_prechunked",
-                 ) + APPLY_CPU_TALLY + DECODE_TALLY
+                 ) + APPLY_CPU_TALLY + DECODE_TALLY + STAGE_TALLY
 
 
 def _open_clocks() -> tuple:
@@ -712,6 +716,78 @@ class _Stage:
         return out
 
 
+def _spans(starts, lens):
+    """Indices of the stretches [start, start + len) laid end to end,
+    in the order given."""
+    first = np.cumsum(lens) - lens
+    return np.arange(int(lens.sum())) + np.repeat(starts - first, lens)
+
+
+class DigestStage:
+    """Forwarded digests waiting for a landing, as columns and in
+    arrival order: a block a request (or the part of one a landing's
+    edge cut off), `(slots i32[n], starts i64[n], lens i64[n], stats
+    f64[5, n], means f32[], weights f32[])`, digest `j` on bank row
+    `slots[j]` with the centroids `means[starts[j]:starts[j] + lens[j]]`
+    and the exact min, max, sum, count and reciprocal sum
+    `stats[:, j]`. `digests` and `centroids` are what the stage's two
+    bounds count."""
+
+    __slots__ = ("blocks", "digests", "centroids")
+
+    def __init__(self):
+        self.blocks: list = []
+        self.digests = 0
+        self.centroids = 0
+
+    def add(self, slots, starts, lens, stats, means, weights):
+        """Take digests (one at least) of the columns `means` /
+        `weights`: views of the stretch they lie in, no copy."""
+        lo, hi = int(starts.min()), int((starts + lens).max())
+        self.blocks.append((slots, starts - lo, lens, stats,
+                            means[lo:hi], weights[lo:hi]))
+        self.digests += len(slots)
+        self.centroids += int(lens.sum())
+
+    def columns(self) -> tuple:
+        """The stage (not empty) as one block."""
+        if len(self.blocks) == 1:
+            return self.blocks[0]
+        slots, starts, lens, stats, means, weights = zip(*self.blocks)
+        at = np.cumsum([0] + [len(m) for m in means[:-1]])
+        return (np.concatenate(slots),
+                np.concatenate([s + o for s, o in zip(starts, at)]),
+                np.concatenate(lens), np.concatenate(stats, axis=1),
+                np.concatenate(means), np.concatenate(weights))
+
+    def items(self) -> list:
+        """A tuple a digest, `(slot, means, weights, min, max, sum,
+        count, reciprocal sum)`: the form a checkpoint saves
+        (durability/records.py), for the edges that run once a
+        checkpoint and not once a sketch."""
+        return [(slot, means[a:a + n], weights[a:a + n], *five)
+                for slots, starts, lens, stats, means, weights
+                in self.blocks
+                for slot, a, n, five in zip(
+                    slots.tolist(), starts.tolist(), lens.tolist(),
+                    stats.T.tolist())]
+
+    @classmethod
+    def of_items(cls, items) -> "DigestStage":
+        """The stage holding `items` (the form `items()` gives) as one
+        block."""
+        stage = cls()
+        if items:
+            slots, means, weights, *five = zip(*items)
+            lens = np.array([len(m) for m in means], np.int64)
+            stage.add(np.array(slots, np.int32), np.cumsum(lens) - lens,
+                      lens, np.array(five, np.float64),
+                      *(np.concatenate([np.asarray(c, np.float32)
+                                        for c in col])
+                        for col in (means, weights)))
+        return stage
+
+
 class AggregationEngine:
     # Subclass gates for the ISSUE 11 flush paths: the mesh engine owns
     # sharded banks (no per-slot bitmaps, landing paths write banks in
@@ -899,10 +975,10 @@ class AggregationEngine:
         # "import_stage_cpu_ns"; counted only with land_stamps armed)
         self._import_decode_cpu_ns = 0
         self._import_stage_cpu_ns = 0
-        # how the interval's sketches were decoded (DECODE_TALLY), and
-        # the decoder, whose key dictionary holds as many entries as
-        # the banks hold keys
-        for name in DECODE_TALLY:
+        # how the interval's sketches were decoded (DECODE_TALLY) and
+        # its digests staged (STAGE_TALLY), and the decoder, whose key
+        # dictionary holds as many entries as the banks hold keys
+        for name in DECODE_TALLY + STAGE_TALLY:
             setattr(self, "_" + name, 0)
         from ..cluster import wire
         self._import_decoder = wire.BatchDecoder(
@@ -918,8 +994,7 @@ class AggregationEngine:
         # Imported (Combine) staging for the global tier — everything is
         # batched so a 32-shard import costs a handful of device calls,
         # not one per key.
-        self._import_centroids: list = []
-        self._import_centroid_total = 0
+        self._import_centroids = DigestStage()
         # observe.StampLog for IMPORT_PHASES, set by a Server whose
         # flight recorder is on; flush() hands its rows to the tick
         self.land_stamps = None
@@ -1352,7 +1427,8 @@ class AggregationEngine:
                 self.gauge_bank, _ = self._land_import_gauges(
                     self.gauge_bank, np.full(n, -1, np.int32),
                     np.zeros(n, np.float32), None, 0)
-            bank = self._merge_import_scalars(self.histo_bank, [])
+            bank = self._merge_import_scalars(
+                self.histo_bank, np.zeros(0, np.int32), np.zeros((5, 0)))
             if self._heng.import_strategy == "cluster":
                 K, C = bank.num_slots, bank.num_centroids
                 cap = self._land_lanes(C)[-1]
@@ -1410,23 +1486,54 @@ class AggregationEngine:
             self._import_histogram_locked(key, means, weights, vmin,
                                           vmax, vsum, count, recip)
 
+    def _import_slot(self, interner, key) -> int:
+        """The row a forwarded key lands in, or < 0 for none; an
+        over-budget key's is its prefix's fold key's (overload
+        defense), which raises ImportFoldReroute where that key is
+        homed on another engine."""
+        slot = interner.lookup(key, GLOBAL_ONLY)
+        if slot == FOLD_SLOT:
+            slot = self._fold_import_slot(interner, key)
+        return slot
+
     def _import_histogram_locked(self, key, means, weights, vmin, vmax,
                                  vsum, count, recip=0.0):
-        slot = self.histo_keys.lookup(key, GLOBAL_ONLY)
-        if slot == FOLD_SLOT:
-            slot = self._fold_import_slot(self.histo_keys, key)
+        """One digest, staged as a block of one."""
+        slot = self._import_slot(self.histo_keys, key)
         if slot < 0:
             return
+        self._import_digests_single += 1
+        self._stage_digests(
+            np.array([slot], np.int32), np.zeros(1, np.int64),
+            np.array([len(means)], np.int64),
+            np.array([[vmin], [vmax], [vsum], [count], [recip]],
+                     np.float64), means, weights)
+
+    def _stage_digests(self, slots, starts, lens, stats, means, weights):
+        """Stage digests given as columns (DigestStage has the form), in
+        their order. A landing fires at the digest that brings the
+        stage to either of its bounds, in the middle of the columns
+        where that is where it falls: they are cut there, and every
+        landing holds the digests it would hold had they come one by
+        one. The mesh engine, whose landing is another device program,
+        stages them its own way."""
         means = np.asarray(means, np.float32)
-        self._import_centroids.append(
-            (slot, means, np.asarray(weights, np.float32),
-             float(vmin), float(vmax), float(vsum), float(count),
-             float(recip)))
-        self._import_centroid_total += len(means)
-        if (len(self._import_centroids) >= _IMPORT_STAGE_DIGESTS
-                or self._import_centroid_total
-                >= _IMPORT_STAGE_CENTROIDS):
-            self._flush_import_centroids()
+        weights = np.asarray(weights, np.float32)
+        ends = np.cumsum(lens)
+        i, n = 0, len(slots)
+        while i < n:
+            stage = self._import_centroids
+            room = _IMPORT_STAGE_CENTROIDS - stage.centroids \
+                + (int(ends[i - 1]) if i else 0)
+            j = max(i + 1, min(
+                n, i + _IMPORT_STAGE_DIGESTS - stage.digests,
+                int(np.searchsorted(ends, room, side="left")) + 1))
+            stage.add(slots[i:j], starts[i:j], lens[i:j], stats[:, i:j],
+                      means, weights)
+            if (stage.digests >= _IMPORT_STAGE_DIGESTS
+                    or stage.centroids >= _IMPORT_STAGE_CENTROIDS):
+                self._flush_import_centroids()
+            i = j
 
     def import_set(self, key: MetricKey, registers, engine_id=None):
         with self.lock:
@@ -1446,9 +1553,7 @@ class AggregationEngine:
             raise ValueError(
                 f"set register width {regs.shape[-1]} != bank width "
                 f"{self.set_bank.num_registers}")
-        slot = self.set_keys.lookup(key, GLOBAL_ONLY)
-        if slot == FOLD_SLOT:
-            slot = self._fold_import_slot(self.set_keys, key)
+        slot = self._import_slot(self.set_keys, key)
         if slot < 0:
             return
         self._import_sets.append((slot, regs))
@@ -1460,9 +1565,7 @@ class AggregationEngine:
             self._import_counter_locked(key, value)
 
     def _import_counter_locked(self, key, value):
-        slot = self.counter_keys.lookup(key, GLOBAL_ONLY)
-        if slot == FOLD_SLOT:
-            slot = self._fold_import_slot(self.counter_keys, key)
+        slot = self._import_slot(self.counter_keys, key)
         if slot < 0:
             return
         # Host-side f64 accumulation — exact, one device call per flush.
@@ -1474,9 +1577,7 @@ class AggregationEngine:
             self._import_gauge_locked(key, value)
 
     def _import_gauge_locked(self, key, value):
-        slot = self.gauge_keys.lookup(key, GLOBAL_ONLY)
-        if slot == FOLD_SLOT:
-            slot = self._fold_import_slot(self.gauge_keys, key)
+        slot = self._import_slot(self.gauge_keys, key)
         if slot < 0:
             return
         self._import_gauge_acc[slot] = float(value)  # last write wins
@@ -1489,7 +1590,8 @@ class AggregationEngine:
         (wire.BatchDecoder: from `raw`, the request's serialized bytes,
         where the batch came with them, `at` the positions of `pbs` in
         the request or None for all of it; else from the parsed
-        messages, wire.decode_metric_batch), then staged in wire order
+        messages, wire.decode_metric_batch), its histograms into a
+        block of columns (wire.DigestBlock), then staged in wire order
         under ONE lock hold in which the applied-op watermark also
         advances, so a concurrent checkpoint_state() sees either none
         of the op or all of it — the exactness the watermark's replay
@@ -1506,15 +1608,15 @@ class AggregationEngine:
         opened, closed = ((_no_clock, _no_clock) if stamps is None
                           else (_open_clocks, _close_clocks))
         t0, c0 = opened()
-        records, means, weights, rejected, decoded = \
+        block, records, rejected, decoded = \
             self._import_decoder.decode(pbs, raw, at)
         t1, c1 = closed()
         # staging names a metric by its position in `pbs`
         rerouted_at, rejected_at = [], []
         with self.lock:
             t2, c2 = opened()
-            self._stage_import_records(records, means, weights,
-                                       rerouted_at, rejected_at)
+            self._stage_import_records(block, records, rerouted_at,
+                                       rejected_at)
             self._import_batches += 1
             self._import_metrics += len(pbs)
             if op_id > self.last_import_op:
@@ -1533,27 +1635,27 @@ class AggregationEngine:
         rejected += [(pbs[i], e) for i, e in rejected_at]
         return [(fr, pbs[i]) for fr, i in rerouted_at], rejected
 
-    def _stage_import_records(self, records, means, weights, rerouted,
-                              rejected):
-        """Stage a decoded request (wire.decode_metric_batch) under the
-        lock, appending to `rerouted` and `rejected`, which name a
-        metric by its position in the batch as its record does. Here: the
-        per-metric `_import_*_locked` calls in wire order, so a landing
-        still fires at the digest, centroid or set that fills its
-        stage, in the middle of a batch where that is where it falls.
-        The mesh engine, whose landing is another device program,
-        stages a request's digests its own way."""
+    def _stage_import_records(self, block, records, rerouted, rejected):
+        """Stage a decoded request under the lock: `block`, its
+        histograms as columns (wire.DigestBlock), and `records`, its
+        other metrics (wire.decode_metric_batch's); appending to
+        `rerouted` and `rejected`, which name a metric by its position
+        in the batch. A row is looked up a key, every kind's in wire
+        order (an admission budget is spent in the order the metrics
+        came): the one loop over the block's digests, each lookup under
+        its own `try`, so a key that re-routes or rejects does so by
+        itself and the rest of the request stages. The digests that
+        found a row are then staged as columns (_stage_digests), the
+        others by the per-metric `_import_*_locked` calls."""
         from ..cluster import wire
-        for rec in records:
+        slots: list = []
+        # digests ahead of each record, by position
+        ahead = np.searchsorted(block.at, [rec[2] for rec in records])
+        for rec, upto in zip(records, ahead.tolist()):
+            self._block_slots(block, upto, slots, rerouted, rejected)
             kind = rec[0]
             try:
-                if kind == wire.IMPORT_HISTOGRAM:
-                    (_, key, _, start, stop, vmin, vmax, vsum, count,
-                     recip) = rec
-                    self._import_histogram_locked(
-                        key, means[start:stop], weights[start:stop],
-                        vmin, vmax, vsum, count, recip)
-                elif kind == wire.IMPORT_SET:
+                if kind == wire.IMPORT_SET:
                     self._import_set_locked(rec[1], rec[3], rec[4])
                 elif kind == wire.IMPORT_COUNTER:
                     self._import_counter_locked(rec[1], rec[3])
@@ -1563,6 +1665,30 @@ class AggregationEngine:
                 rerouted.append((fr, rec[2]))
             except Exception as e:
                 rejected.append((rec[2], e))
+        self._block_slots(block, len(block.keys), slots, rerouted, rejected)
+        slots = np.array(slots, np.int32)
+        keep = np.flatnonzero(slots >= 0)
+        self._import_digests_block += len(keep)
+        if len(keep):
+            self._stage_digests(
+                slots[keep], block.start[keep],
+                (block.stop - block.start)[keep], block.stats[:, keep],
+                block.means, block.weights)
+
+    def _block_slots(self, block, upto, slots, rerouted, rejected):
+        """Extend `slots` to the first `upto` digests of `block`: each
+        one's bank row, or -1 for a digest that is not to be staged (no
+        row left, re-routed, or rejected: the two lists say which)."""
+        lookup, table, j = self._import_slot, self.histo_keys, len(slots)
+        for j, key in enumerate(block.keys[j:upto], j):
+            try:
+                slots.append(lookup(table, key))
+            except ImportFoldReroute as fr:
+                rerouted.append((fr, int(block.at[j])))
+                slots.append(-1)
+            except Exception as e:
+                rejected.append((int(block.at[j]), e))
+                slots.append(-1)
 
     def _flush_import_sets(self):
         items, self._import_sets = self._import_sets, []
@@ -1641,11 +1767,9 @@ class AggregationEngine:
         return cbank, gbank, gauge_seq
 
     def _flush_import_centroids(self):
-        items = self._import_centroids
-        self._import_centroids = []
-        self._import_centroid_total = 0
+        stage, self._import_centroids = self._import_centroids, DigestStage()
         self.histo_bank, did = self._land_import_centroids(
-            self.histo_bank, items, self._dirty)
+            self.histo_bank, stage, self._dirty)
         for name, n in did.items():
             setattr(self, "_" + name, getattr(self, "_" + name) + n)
 
@@ -1685,14 +1809,14 @@ class AggregationEngine:
         return [(R, L) for R in cls._land_row_counts(K)
                 for L in cls._land_lanes(C)]
 
-    def _land_import_centroids(self, bank, items, dirty):
-        """Land staged foreign digests into `bank` under the engine's
-        import strategy: "cluster" (t-digest — precluster each slot's
-        pile to <= C centroids with ONE batched cluster program,
-        then compress, fill the emptied buffers and compress again,
-        over the rows the landing touches) or "direct" (compactor
-        engines —
-        the items re-insert as weighted points in fixed-width batches;
+    def _land_import_centroids(self, bank, stage, dirty):
+        """Land staged foreign digests (a DigestStage) into `bank`
+        under the engine's import strategy: "cluster" (t-digest —
+        precluster each slot's pile to <= C centroids with ONE batched
+        cluster program, then compress, fill the emptied buffers and
+        compress again, over the rows the landing touches) or "direct"
+        (compactor engines —
+        the digests re-insert as weighted points in fixed-width batches;
         the engine's own compaction bounds memory, no preclustering).
         One `import.land` stamp per landing (LAND_PHASES), whichever
         thread runs it: a worker mid-interval, the flusher at flush.
@@ -1701,122 +1825,75 @@ class AggregationEngine:
         for the caller to add to its interval's tally: the double-
         buffered flush lands its retired stage outside the lock,
         beside the next interval's landings."""
-        if not items:
+        if not stage.digests:
             return bank, {}
         stamps = self.land_stamps
         t0 = time.monotonic_ns()
+        cols = stage.columns()
         if self._heng.import_strategy == "direct":
-            bank, did = self._land_imports_direct(bank, items, dirty), {}
+            bank, did = self._land_imports_direct(bank, cols, dirty), {}
         else:
-            bank, did = self._land_imports_clustered(bank, items, dirty,
+            bank, did = self._land_imports_clustered(bank, cols, dirty,
                                                      stamps, t0)
         if stamps is not None:
             stamps.add("import.land", t0, time.monotonic_ns())
         return bank, did
 
-    def _land_imports_clustered(self, bank, items, dirty, stamps, t0):
-        """The "cluster" import strategy; `t0` is where the landing's
-        `import.land.stage` phase began. Every device program it
-        dispatches has a shape that follows from the bank's and this
-        module's constants (_cluster_shapes, _IMPORT_CHUNK_ROWS,
-        _IMPORT_STAGE_DIGESTS), and warmup() has compiled it: what was
-        staged decides which of them runs, never a new one."""
+    def _land_imports_clustered(self, bank, cols, dirty, stamps, t0):
+        """The "cluster" import strategy over a stage's columns; `t0`
+        is where the landing's `import.land.stage` phase began. Every
+        device program it dispatches has a shape that follows from the
+        bank's and this module's constants (_cluster_shapes,
+        _IMPORT_CHUNK_ROWS, _IMPORT_STAGE_DIGESTS), and warmup() has
+        compiled it: what was staged decides which of them runs, never
+        a new one. A slot's pile is its digests' centroids side by side
+        in arrival order; the piles are found, measured and laid into
+        the device matrix by array operations over the columns, no
+        loop over the digests."""
         K, C = bank.num_slots, bank.num_centroids
-
-        by_slot: dict[int, list] = {}
-        for s, means, weights, *_ in items:
-            by_slot.setdefault(s, []).append((means, weights))
-
-        # Forwarded payloads are untrusted: a digest with millions of
-        # centroids must not size the device matrix (resource
-        # exhaustion). Pre-cluster any oversized pile in fixed-width
-        # chunks — each pass reduces a chunk of `cap` raw centroids to C
-        # clustered ones, so with cap >= 2C the loop converges
-        # geometrically and every program shape stays bounded (cap must
-        # exceed C or re-chunking could never shrink a pile at high
-        # compression settings). Pass 1 full-sorts (foreign rows are
-        # unordered AND untrusted); later passes re-merge OUR OWN
-        # cluster outputs — each pile a [C] cluster-ordered row — so
-        # chunks are built pile-aligned and take the cluster program's
-        # sorted_prefix=C fast arm (the importsrv re-merge case: the
-        # leading run's order is proven, only the tail needs sorting).
-        # The chunks go to the device _IMPORT_CHUNK_ROWS at a time,
-        # the last dispatch padded with empty rows.
+        slots, starts, lens, stats, means, weights = cols
         lanes = self._land_lanes(C)
         cap = lanes[-1]
         trusted: set = set()   # slots whose piles are all re-clustered
+        # the digests whose centroids make the piles: the stage's own
+        # until the pre-cluster loop has cut a pile
+        d_slots, d_starts, d_lens = slots, starts, lens
         while True:
-            oversized = [
-                s for s, piles in by_slot.items()
-                if sum(len(m) for m, _ in piles) > cap]
-            if not oversized:
+            # rows in ascending order (the work set's scatter is told
+            # so), a row's digests in arrival order
+            order = np.argsort(d_slots, kind="stable")
+            run = np.flatnonzero(np.diff(d_slots[order], prepend=-1))
+            slot_ids = d_slots[order[run]]
+            widths = np.add.reduceat(d_lens[order], run)
+            over = np.flatnonzero(widths > cap)
+            if not len(over):
                 break
-            batches = {0: ([], []), C: ([], [])}   # sorted_prefix ->
-            piles_per_chunk = cap // C              # (owners, chunks)
-            for s in oversized:
-                piles = by_slot[s]
-                if s in trusted:
-                    owners, chunks = batches[C]
-                    for i in range(0, len(piles), piles_per_chunk):
-                        group = piles[i:i + piles_per_chunk]
-                        chunk = np.zeros((2, cap), np.float32)
-                        for g, (m, w) in enumerate(group):
-                            chunk[0, g * C:g * C + len(m)] = m
-                            chunk[1, g * C:g * C + len(m)] = w
-                        owners.append(s)
-                        chunks.append(chunk)
-                else:
-                    owners, chunks = batches[0]
-                    flat = np.stack([
-                        np.concatenate([np.asarray(p[i], np.float32)
-                                        for p in piles]) for i in (0, 1)])
-                    for i in range(0, flat.shape[1], cap):
-                        chunk = np.zeros((2, cap), np.float32)
-                        part = flat[:, i:i + cap]
-                        chunk[:, :part.shape[1]] = part
-                        owners.append(s)
-                        chunks.append(chunk)
-                by_slot[s] = []
-            for prefix, (owners, chunks) in batches.items():
-                for i in range(0, len(owners), _IMPORT_CHUNK_ROWS):
-                    part = chunks[i:i + _IMPORT_CHUNK_ROWS]
-                    both = np.zeros((2, _IMPORT_CHUNK_ROWS, cap),
-                                    np.float32)
-                    both[:, :len(part)] = np.stack(part, axis=1)
-                    cm, cw = (np.asarray(a) for a in
-                              self._heng.cluster_rows(
-                                  *both, num_centroids=C,
-                                  sorted_prefix=prefix, lanes=lanes))
-                    for row, s in enumerate(
-                            owners[i:i + _IMPORT_CHUNK_ROWS]):
-                        by_slot[s].append((cm[row], cw[row]))
-            trusted.update(oversized)
-
-        # rows in ascending order: the work set's scatter is told so
-        by_slot = dict(sorted(by_slot.items()))
-        slot_ids = np.fromiter(by_slot.keys(), np.int32, len(by_slot))
+            ends = np.append(run[1:], len(order))
+            d_slots, d_starts, d_lens, means, weights = self._precluster(
+                C, lanes, trusted,
+                (d_slots, d_starts, d_lens, means, weights),
+                [(int(slot_ids[k]), order[run[k]:ends[k]])
+                 for k in over.tolist()])
         if dirty is not None:
             self._mark_dirty_into(dirty, 0, slot_ids)
         # the piles side by side in an [R, L] matrix: R the work set
         # (the bank's own rows on the whole-bank arm), L the narrowest
         # step that holds the widest pile. Padding lanes weigh 0 and
         # padding rows are empty, which the clustering leaves out, so
-        # a pile's centroids do not depend on the shape it rode in
+        # a pile's centroids do not depend on the shape it rode in.
+        # One indexed write a plane: centroid `i` of the piles laid end
+        # to end goes to its pile's row, at its offset in the pile
         S = len(slot_ids)
         R = self._land_rows(S, K)
-        widest = max(sum(len(m) for m, _ in piles)
-                     for piles in by_slot.values())
-        L = next(n for n in lanes if n >= widest)
+        L = next(n for n in lanes if n >= widths.max())
+        filled = int(widths.sum())
+        take = _spans(d_starts[order], d_lens[order])
+        row = np.repeat(np.arange(S), widths)
+        lane = np.arange(filled) - np.repeat(np.cumsum(widths) - widths,
+                                             widths)
         both = np.zeros((2, R or K, L), np.float32)
-        filled = 0
-        for row, piles in enumerate(by_slot.values()):
-            off = 0
-            for m, w in piles:
-                n = len(m)
-                both[0, row, off:off + n] = m
-                both[1, row, off:off + n] = w
-                off += n
-            filled += off
+        both[0, row, lane] = means[take]
+        both[1, row, lane] = weights[take]
         t1 = time.monotonic_ns()
         cmeans, cwts = (np.asarray(a) for a in self._heng.cluster_rows(
             *both, num_centroids=C, lanes=lanes))
@@ -1824,7 +1901,7 @@ class AggregationEngine:
             stamps.add("import.land.stage", t0, t1)
             stamps.add("import.land.cluster", t1, time.monotonic_ns())
         bank = self._land_clustered(bank, R, slot_ids, cmeans, cwts)
-        bank = self._merge_import_scalars(bank, items)
+        bank = self._merge_import_scalars(bank, slots, stats)
         # the merge chain above ran through plain jits whose outputs are
         # uncommitted; recommit so the ingest kernels and the flush
         # program stay on their committed (fast) executables
@@ -1834,6 +1911,68 @@ class AggregationEngine:
             "import_land_lanes": (R or K) * L,
             "import_land_lanes_filled": filled,
             "import_land_prechunked": len(trusted)}
+
+    def _precluster(self, C, lanes, trusted, cols, piles):
+        """One pass of the pre-cluster loop over the oversized `piles`,
+        each (slot, its digests' places in `cols` in arrival order);
+        `cols` are (slots, starts, lens) a digest and the two centroid
+        columns. Returns `cols` with those piles' digests replaced by
+        the pass's outputs, a row of C lanes each, put behind the
+        columns; the piles' slots join `trusted`.
+
+        Forwarded payloads are untrusted: a digest with millions of
+        centroids must not size the device matrix (resource
+        exhaustion). Pre-cluster any oversized pile in fixed-width
+        chunks — each pass reduces a chunk of `cap` raw centroids to C
+        clustered ones, so with cap >= 2C the loop converges
+        geometrically and every program shape stays bounded (cap must
+        exceed C or re-chunking could never shrink a pile at high
+        compression settings). Pass 1 full-sorts (foreign rows are
+        unordered AND untrusted); later passes re-merge OUR OWN
+        cluster outputs — each a [C] cluster-ordered row — so
+        chunks hold whole rows and take the cluster program's
+        sorted_prefix=C fast arm (the importsrv re-merge case: the
+        leading run's order is proven, only the tail needs sorting).
+        The chunks go to the device _IMPORT_CHUNK_ROWS at a time,
+        the last dispatch padded with empty rows."""
+        d_slots, d_starts, d_lens, means, weights = cols
+        cap = lanes[-1]
+        batches = {0: ([], []), C: ([], [])}   # sorted_prefix ->
+        for s, digests in piles:                # (owners, chunks)
+            prefix = C if s in trusted else 0
+            owners, chunks = batches[prefix]
+            take = _spans(d_starts[digests], d_lens[digests])
+            step = cap // C * C if prefix else cap
+            for i in range(0, len(take), step):
+                part = take[i:i + step]
+                chunk = np.zeros((2, cap), np.float32)
+                chunk[0, :len(part)] = means[part]
+                chunk[1, :len(part)] = weights[part]
+                owners.append(s)
+                chunks.append(chunk)
+        out_slots, out_means, out_weights = [], [means], [weights]
+        for prefix, (owners, chunks) in batches.items():
+            for i in range(0, len(owners), _IMPORT_CHUNK_ROWS):
+                part = chunks[i:i + _IMPORT_CHUNK_ROWS]
+                both = np.zeros((2, _IMPORT_CHUNK_ROWS, cap), np.float32)
+                both[:, :len(part)] = np.stack(part, axis=1)
+                cm, cw = (np.asarray(a) for a in
+                          self._heng.cluster_rows(
+                              *both, num_centroids=C,
+                              sorted_prefix=prefix, lanes=lanes))
+                out_slots += owners[i:i + _IMPORT_CHUNK_ROWS]
+                out_means.append(cm[:len(part)].reshape(-1))
+                out_weights.append(cw[:len(part)].reshape(-1))
+        cut = [s for s, _digests in piles]
+        trusted.update(cut)
+        keep = ~np.isin(d_slots, cut)
+        rows = len(out_slots)
+        return (np.concatenate([d_slots[keep],
+                                np.array(out_slots, d_slots.dtype)]),
+                np.concatenate([d_starts[keep],
+                                len(means) + C * np.arange(rows)]),
+                np.concatenate([d_lens[keep], np.full(rows, C)]),
+                np.concatenate(out_means), np.concatenate(out_weights))
 
     def _land_clustered(self, bank, R, slot_ids, cmeans, cwts):
         """Land clustered centroids f32[R or K, C], row i for bank row
@@ -1867,23 +2006,22 @@ class AggregationEngine:
                 cmeans[:, chunk].reshape(-1), cwts[:, chunk].reshape(-1))
         return self._heng.compress(bank)
 
-    def _merge_import_scalars(self, bank, items):
-        """merge_scalars of staged digests' exact stats, the stage's
-        digest bound a dispatch: slot -1 pads, which the program
-        masks. (No items: one all-padding dispatch, the warm-up's.)"""
+    def _merge_import_scalars(self, bank, slots, stats):
+        """merge_scalars of staged digests' exact stats (`stats[:, j]`
+        digest j's, on row `slots[j]`), the stage's digest bound a
+        dispatch: slot -1 pads, which the program masks. (No digests:
+        one all-padding dispatch, the warm-up's.)"""
         n = _IMPORT_STAGE_DIGESTS
-        for i in range(0, max(len(items), 1), n):
-            part = items[i:i + n]
-            slots = np.full(n, -1, np.int32)
-            stats = np.zeros((5, n), np.float32)
-            if part:
-                slots[:len(part)] = [it[0] for it in part]
-                stats[:, :len(part)] = np.array(
-                    [it[3:8] for it in part], np.float32).T
+        for i in range(0, max(len(slots), 1), n):
+            part = slots[i:i + n]
+            ps = np.full(n, -1, np.int32)
+            pstats = np.zeros((5, n), np.float32)
+            ps[:len(part)] = part
+            pstats[:, :len(part)] = stats[:, i:i + n]
             # vlint: disable=DS01 reason=the exact-stats half of an
             # import landing: both callers (_land_imports_clustered,
-            # _land_imports_direct) marked the items' rows first
-            bank = self._heng.merge_scalars(bank, slots, *stats)
+            # _land_imports_direct) marked the digests' rows first
+            bank = self._heng.merge_scalars(bank, ps, *pstats)
         return bank
 
     def _land_work_set(self, bank, rows, means, weights):
@@ -1903,21 +2041,18 @@ class AggregationEngine:
     # shape however many centroids an interval staged
     _DIRECT_LAND_WIDTH = 4096
 
-    def _land_imports_direct(self, bank, items, dirty):
+    def _land_imports_direct(self, bank, cols, dirty):
         """The "direct" import strategy (compactor engines): re-insert
         every forwarded weighted point through the engine's own
         merge_centroids — its internal compaction bounds memory, so no
         host-side preclustering pass is needed. Batches are fixed-width
         (padded, slot -1 dropped) so the program shape never varies."""
         W = self._DIRECT_LAND_WIDTH
-        slots = np.concatenate([
-            np.full(len(it[1]), it[0], np.int32) for it in items])
-        means = np.concatenate([
-            np.asarray(it[1], np.float32) for it in items])
-        wts = np.concatenate([
-            np.asarray(it[2], np.float32) for it in items])
+        d_slots, starts, lens, stats, means, wts = cols
+        take = _spans(starts, lens)
+        slots, means, wts = np.repeat(d_slots, lens), means[take], wts[take]
         if dirty is not None:
-            self._mark_dirty_into(dirty, 0, np.unique(slots))
+            self._mark_dirty_into(dirty, 0, np.unique(d_slots))
         for i in range(0, len(slots), W):
             seg = slice(i, min(len(slots), i + W))
             n = seg.stop - seg.start
@@ -1928,7 +2063,7 @@ class AggregationEngine:
             pm[:n] = means[seg]
             pw[:n] = wts[seg]
             bank = self._heng.merge_centroids(bank, ps, pm, pw)
-        bank = self._merge_import_scalars(bank, items)
+        bank = self._merge_import_scalars(bank, d_slots, stats)
         return jax.device_put(bank, self._device)
 
     # ---------------- flush ----------------
@@ -2274,8 +2409,7 @@ class AggregationEngine:
                 imports = (self._import_centroids, self._import_sets,
                            self._import_counter_acc,
                            self._import_gauge_acc)
-                self._import_centroids = []
-                self._import_centroid_total = 0
+                self._import_centroids = DigestStage()
                 self._import_sets = []
                 self._import_counter_acc = {}
                 self._import_gauge_acc = {}
@@ -2733,7 +2867,7 @@ class AggregationEngine:
                 kind: (ki.interval, ki.snapshot_entries())
                 for kind, _attr, ki in self._bank_table()}
             staged = {
-                "centroids": list(self._import_centroids),
+                "centroids": self._import_centroids.items(),
                 "sets": list(self._import_sets),
                 "counters": list(self._import_counter_acc.items()),
                 "gauges": list(self._import_gauge_acc.items()),
@@ -2803,13 +2937,8 @@ class AggregationEngine:
                                          (np.zeros(0, np.int32), {}))
                 if self._dirty is not None and len(ids):
                     self._dirty[kind][ids] = True
-            self._import_centroids = [
-                (int(s), np.asarray(m, np.float32),
-                 np.asarray(w, np.float32), float(a), float(b),
-                 float(c), float(d), float(e))
-                for s, m, w, a, b, c, d, e in staged.get("centroids", [])]
-            self._import_centroid_total = sum(
-                len(m) for _s, m, *_rest in self._import_centroids)
+            self._import_centroids = DigestStage.of_items(
+                staged.get("centroids", []))
             self._import_sets = [(int(s), np.asarray(r, np.uint8))
                                  for s, r in staged.get("sets", [])]
             self._import_counter_acc = {

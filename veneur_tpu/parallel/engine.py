@@ -49,10 +49,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ingest.parser import GLOBAL_ONLY
 from ..models.pipeline import (AggregationEngine, EngineConfig,
-                               ImportFoldReroute, _precluster_k1)
-from ..models.worker import FOLD_SLOT
+                               _precluster_k1, _spans)
 from .interner import ShardedKeyInterner
 from .mesh import MeshEngine, make_mesh
 
@@ -427,66 +425,17 @@ class MeshAggregationEngine(AggregationEngine):
     # Overrides: the single-device engine merges imports with dedicated
     # cluster/merge programs; on the mesh everything lands through the
     # routed SPMD ingest instead (see module docstring). The overrides
-    # are the `_locked` halves: the base class's import_histogram /
-    # import_set take the lock and import_list holds it across a batch.
+    # run under the lock: the stage of a block of digests (the base
+    # class looks the rows up, a request's block or import_histogram's
+    # block of one) and the `_locked` half of a set.
 
-    def _import_slot(self, interner, key) -> int:
-        """The row a forwarded key lands in, or < 0 for none."""
-        slot = interner.lookup(key, GLOBAL_ONLY)
-        if slot == FOLD_SLOT:
-            # overload defense: over-budget forwarded keys fold
-            # into `<prefix>.__other__` here too (the mesh server
-            # is a single engine, so the fold is always local)
-            slot = self._fold_import_slot(interner, key)
-        return slot
-
-    def _import_histogram_locked(self, key, means, weights, vmin, vmax,
-                                 vsum, count, recip=0.0):
-        slot = self._import_slot(self.histo_keys, key)
-        if slot >= 0:
-            self._stage_digests(
-                [slot], [(0, len(means), vmin, vmax, vsum, count, recip)],
-                means, weights)
-
-    def _stage_import_records(self, records, means, weights, rerouted,
-                              rejected):
-        """A request's digests through one pass of _stage_digests, a
-        row looked up a key; then its other metrics as the base engine
-        stages them. A key that rejects or re-routes does so by itself
-        and the rest of the request stages."""
-        from ..cluster import wire
-        slots, table, others = [], [], []
-        for rec in records:
-            if rec[0] != wire.IMPORT_HISTOGRAM:
-                others.append(rec)
-                continue
-            try:
-                slot = self._import_slot(self.histo_keys, rec[1])
-            except ImportFoldReroute as fr:
-                rerouted.append((fr, rec[2]))
-                continue
-            except Exception as e:
-                rejected.append((rec[2], e))
-                continue
-            if slot >= 0:
-                slots.append(slot)
-                table.append(rec[3:])
-        if slots:
-            self._stage_digests(slots, table, means, weights)
-        super()._stage_import_records(others, means, weights, rerouted,
-                                      rejected)
-
-    def _stage_digests(self, slots, table, means, weights):
+    def _stage_digests(self, slots, starts, lens, stats, means, weights):
         """Stage digests into the point columns, landing a batch
-        wherever the next digest would not fit. `table` has a row a
-        digest: (start, stop, min, max, sum, count, reciprocal sum),
-        its centroids `means[start:stop]`, `weights[start:stop]`; and
-        `slots` its row in the bank."""
-        slots = np.asarray(slots, np.int32)
-        table = np.asarray(table, np.float64).reshape(len(slots), 7)
-        starts = table[:, 0].astype(np.int64)
-        lens = table[:, 1].astype(np.int64) - starts
-        vmin, vmax = table[:, 2], table[:, 3]
+        wherever the next digest would not fit. A digest an entry of
+        the columns: `slots` its row in the bank, its centroids
+        `means[start:start + len]`, `weights[start:start + len]`, and
+        `stats[:, j]` its min, max, sum, count and reciprocal sum."""
+        vmin, vmax = stats[0], stats[1]
         # a row's buffer takes one digest a scatter round, its two
         # extremes riders with it: a wider digest is pre-clustered to
         # that width first, a key at a time. The hot-slot sidestep of
@@ -497,6 +446,7 @@ class MeshAggregationEngine(AggregationEngine):
         self._mesh_import_staged += len(slots)
         self._mesh_import_staged_fallback += len(wide)
         if len(wide):
+            starts, lens = starts.copy(), lens.copy()
             cols_m = [np.asarray(means, np.float64)]
             cols_w = [np.asarray(weights, np.float64)]
             end = len(cols_m[0])
@@ -511,8 +461,7 @@ class MeshAggregationEngine(AggregationEngine):
         n, total = len(slots), int(lens.sum())
         # the digests' centroids side by side, `of` the digest of each
         of = np.repeat(np.arange(n), lens)
-        first = np.cumsum(lens) - lens
-        take = np.arange(total) + np.repeat(starts - first, lens)
+        take = _spans(starts, lens)
         m64 = np.asarray(means)[take].astype(np.float64)
         # a centroid mean comes out of a cumsum difference and can
         # sit a few ulp outside the digest's exact [vmin, vmax];
@@ -530,9 +479,9 @@ class MeshAggregationEngine(AggregationEngine):
         # staged contribution to rounding level
         rcp = np.zeros(total, np.float32)
         np.divide(w32, m32, out=rcp, where=m32 != 0)
-        for row, col, terms in ((0, 4, m32 * w32), (1, 5, w32), (2, 6, rcp)):
+        for row, terms in enumerate((m32 * w32, w32, rcp)):
             np.add.at(self._h_deltas[row], slots,
-                      table[:, col] - np.bincount(of, terms, n))
+                      stats[2 + row] - np.bincount(of, terms, n))
         # the points: a digest's centroids, then its exact extremes as
         # zero-weight samples: they update the min/max scatter, add
         # nothing to sum/count/recip
