@@ -434,6 +434,13 @@ struct Ring {
 
 // ---------------------------------------------------------------- interner
 
+inline uint64_t mono_ns() {
+  return static_cast<uint64_t>(
+      std::chrono::duration_cast<std::chrono::nanoseconds>(
+          std::chrono::steady_clock::now().time_since_epoch())
+          .count());
+}
+
 struct NewKey {
   uint8_t bank, mtype, scope;
   int32_t slot;
@@ -455,6 +462,8 @@ struct BankMeta {
   std::atomic<uint32_t> interval{0};
   std::atomic<uint64_t> drops_no_slot{0};
   std::atomic<int64_t> key_count{0};
+  // running totals: keys minted into a slot, keys the idle TTL evicted
+  std::atomic<uint64_t> keys_interned{0}, keys_evicted{0};
 
   void init(int32_t cap) {
     capacity = cap;
@@ -529,6 +538,9 @@ struct Bridge {
 
   std::atomic<uint64_t> packets{0}, lines{0}, samples{0}, parse_errors{0},
       slow_routed{0};
+  // ns inside intern_key's slow path (a key that holds no slot: the free
+  // list, the shard map's insert, the new-key record), all callers summed
+  std::atomic<uint64_t> intern_ns{0};
 
   std::vector<int> socks;
   std::vector<std::thread> readers;
@@ -598,6 +610,7 @@ int32_t intern_key(Bridge* br, const ParsedMetric& m,
     if (it != sh.map[bk].end()) {
       slot = it->second;
     } else {
+      uint64_t t0 = mono_ns();
       {
         std::lock_guard<std::mutex> fg(bank.free_mu);
         if (bank.free_slots.empty()) {
@@ -616,8 +629,12 @@ int32_t intern_key(Bridge* br, const ParsedMetric& m,
       nk.slot = slot;
       nk.name = m.name;
       nk.tags = m.joined_tags;
-      std::lock_guard<std::mutex> ng(br->newkeys_mu);
-      br->newkeys.push_back(std::move(nk));
+      {
+        std::lock_guard<std::mutex> ng(br->newkeys_mu);
+        br->newkeys.push_back(std::move(nk));
+      }
+      bank.keys_interned.fetch_add(1, std::memory_order_relaxed);
+      br->intern_ns.fetch_add(mono_ns() - t0, std::memory_order_relaxed);
     }
   }
   touch_meta(bank, slot, m.scope);
@@ -1189,13 +1206,6 @@ inline size_t ssf_frame_length(const uint8_t* p) {
   return v;
 }
 
-inline uint64_t mono_ns() {
-  return static_cast<uint64_t>(
-      std::chrono::duration_cast<std::chrono::nanoseconds>(
-          std::chrono::steady_clock::now().time_since_epoch())
-          .count());
-}
-
 // One accepted connection of a framed SSF stream (Server.ReadSSFStream
 // Socket's C++ twin, server.py:_read_ssf_stream's): whatever the socket
 // has is read into one buffer and cut into frames, every whole frame
@@ -1593,6 +1603,8 @@ int32_t vtpu_advance_interval(void* h, int32_t bank) {
       }
     }
   }
+  bm.keys_evicted.fetch_add(static_cast<uint64_t>(evicted),
+                            std::memory_order_relaxed);
   return evicted;
 }
 
@@ -1648,7 +1660,13 @@ int64_t vtpu_key_count(void* h, int32_t bank) {
 //               ssf_errors, ssf_other_drops, pending_ssf_other;
 //               [14..18] = the framed-stream readers' frames, connections
 //               accepted, connections closed on error, ns inside
-//               handle_ssf + staging, ns waited for ring room (always 0)
+//               handle_ssf + staging, ns waited for ring room (always 0);
+//               [19] = ns inside intern_key's slow path; then per bank
+//               (histo, counter, gauge, set) [20..23] = keys holding a
+//               slot, [24..27] = keys minted, [28..31] = keys the idle
+//               TTL evicted (running totals)
+constexpr int kStatsFields = 32;  // ingest/native.py:STATS_FIELDS (NA04)
+
 void vtpu_stats(void* h, uint64_t* out) {
   Bridge* br = static_cast<Bridge*>(h);
   out[0] = br->packets.load();
@@ -1679,6 +1697,12 @@ void vtpu_stats(void* h, uint64_t* out) {
   out[16] = br->ssf_stream_conn_errors.load();
   out[17] = br->ssf_stream_read_ns.load();
   out[18] = br->ssf_stream_wait_ns.load();
+  out[19] = br->intern_ns.load();
+  for (int i = 0; i < NUM_BANKS; i++) {
+    out[20 + i] = static_cast<uint64_t>(br->banks[i].key_count.load());
+    out[24 + i] = br->banks[i].keys_interned.load();
+    out[28 + i] = br->banks[i].keys_evicted.load();
+  }
   std::lock_guard<std::mutex> g(br->other_mu);
   out[7] = br->other_drops;
   out[8] = br->other.size();

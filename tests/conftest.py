@@ -78,6 +78,36 @@ _PR36_LISTS = {"mesh.ack_last_s": _PR36_MESH[1:2],
                                 "mesh.device_busy_least"), _PR36_MESH)}
 
 
+# PR 45 appends one cell and five per-layer entries behind PR 43's: two
+# tests of `test_perfbench_ssf_cells.py` pin PR 43's two cells to the end
+# of `workloads` and its three `ssf.*` entries to the end of `per_layer`.
+# While outgrown they are expected failures too, and
+# `tests/perfbench/test_perfbench_zipf_cell.py` holds what they held, by
+# name.
+_SSF_CELLS = "perfbench/test_perfbench_ssf_cells.py::"
+_PR43_CELLS = ["ssf_two_tier_1chip.spans_10k",
+               "fanin32_mesh_global_4chip.fleet_10k"]
+_PR43_ENTRIES = ["ssf.span_us", "ssf.fallback_share", "ssf.ring_wait_ms"]
+# It also appends its cell to four lists that
+# `test_perfbench_fixed_landing.py` pins to PR 33's cells (the global's
+# flush and import landing run in it as in `steady_10k`): the test of
+# each, by its parameter, is an expected failure while its list has
+# grown, and the new file holds the lists as appended to.
+_FIXED_LANDING = "perfbench/test_perfbench_fixed_landing.py::"
+_TWO_TIER = ["two_tier_1chip.steady_10k", "two_tier_1chip.wide_100k",
+             "two_tier_1chip.hot_1k"]
+_FANIN = ["fanin32_global_1chip.fleet_1k", "fanin32_global_1chip.fleet_10k"]
+_PR33_LISTS = {
+    "test_entry_is_what_the_issue_named[import.land_pad_share]":
+        _TWO_TIER + _FANIN,
+    "test_entry_is_what_the_issue_named[import.cluster_roofline]":
+        _FANIN + _TWO_TIER[1:2],
+    "test_an_accepted_metric_of_a_layer_the_cells_run_lists_them"
+    "[global.flush_device_ms]": _TWO_TIER + _FANIN,
+    "test_an_accepted_metric_of_a_layer_the_cells_run_lists_them"
+    "[import.compress_device_ms]": _TWO_TIER + _FANIN}
+
+
 def pytest_collection_modifyitems(items):
     import json
     with open(os.path.join(os.path.dirname(os.path.dirname(
@@ -89,8 +119,16 @@ def pytest_collection_modifyitems(items):
                 if lists.get(name) != pinned}
     if [w["name"] for w in manifest["workloads"]][-4:] != _PR36_TAIL:
         outgrown.add("test_pr33s_cells_found_by_name")
+    if [w["name"] for w in manifest["workloads"]][-2:] != _PR43_CELLS:
+        outgrown.add(_SSF_CELLS + "test_pr33s_and_pr36s_cells_found_by_name")
+    if [m["name"] for m in manifest["per_layer"]][-3:] != _PR43_ENTRIES:
+        outgrown.add(_SSF_CELLS + "test_the_ssf_cell_reports_steady_10ks_"
+                                  "metrics_and_its_own")
+    outgrown |= {_FIXED_LANDING + test for test, pinned in _PR33_LISTS.items()
+                 if lists.get(test[test.index("[") + 1:-1]) != pinned}
     for item in items:
-        if any(item.nodeid.endswith(_MESH_READERS + name)
+        if any(item.nodeid.endswith(name if "::" in name
+                                    else _MESH_READERS + name)
                for name in outgrown):
             item.add_marker(pytest.mark.xfail(
                 raises=AssertionError, strict=False,

@@ -65,11 +65,36 @@ def test_na03_frame_layout_without_a_python_side():
 def test_na03_holds_the_real_tree():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     paths = [os.path.join(repo, "native", "vtpu_ingest.cpp"),
-             os.path.join(repo, "veneur_tpu", "ssf", "framing.py")]
+             os.path.join(repo, "veneur_tpu", "ssf", "framing.py"),
+             os.path.join(repo, "veneur_tpu", "ingest", "native.py")]
     assert [v for v in run_paths(paths) if v.rule.startswith("NA")] == []
     # and it is looking: the bridge's file does define the layout
     with open(paths[0]) as f:
         assert "constexpr int kSsfMaxFrameLength" in f.read()
+
+
+def test_na04_stats_array_diverges_from_python_and_from_its_writes():
+    # the length against its Python twin, and against the highest index
+    # written (out[2 + i] over four banks is out[5]: six fields)
+    assert lint("na04_diverge.cpp", "na04_parity.py") == [("NA04", 4),
+                                                          ("NA04", 4)]
+
+
+def test_na04_stats_array_without_a_python_side():
+    assert [r for r, _l in lint("na04_diverge.cpp")] == ["NA04"] * 2
+
+
+def test_na04_holds_the_real_tree():
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    paths = [os.path.join(repo, "native", "vtpu_ingest.cpp"),
+             os.path.join(repo, "veneur_tpu", "ingest", "native.py"),
+             os.path.join(repo, "veneur_tpu", "ssf", "framing.py")]
+    assert [v for v in run_paths(paths) if v.rule.startswith("NA")] == []
+    # and it is looking: the per-bank key counts are among the fields
+    with open(paths[0]) as f:
+        text = f.read()
+    assert "constexpr int kStatsFields" in text
+    assert "out[28 + i] = br->banks[i].keys_evicted" in text
 
 
 def test_rs01_raw_egress_bypasses_resilience():

@@ -36,6 +36,11 @@ DEFAULT_CONFIG = {
         "kSsfFrameLengthLittleEndian": "LENGTH_LITTLE_ENDIAN",
         "kSsfMaxFrameLength": "MAX_FRAME_LENGTH",
     },
+    # NA04: the layout of the bridge's stats array, native constant ->
+    # its twin in ingest/native.py.
+    "na04_pairs": {
+        "kStatsFields": "STATS_FIELDS",
+    },
     # RS01: modules allowed to make raw urlopen / grpc-channel calls —
     # the resilience layer itself owns the one raw transport.
     "rs01_allow": (

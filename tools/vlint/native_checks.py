@@ -1,4 +1,4 @@
-"""Line-based C++ passes for native/vtpu_ingest.cpp: NA01, NA02, NA03.
+"""Line-based C++ passes for native/vtpu_ingest.cpp: NA01 to NA04.
 
 These are deliberately regex-level — the native bridge is one file of
 C-with-classes and the two defect classes it has actually shipped
@@ -141,6 +141,20 @@ _CONST_EXPR_RE = re.compile(
     r"\bconstexpr\s+[\w:]+\s+(\w+)\s*=\s*([0-9a-fA-Fx\s*+<()]+);")
 
 
+def _constexprs(nf: NativeFile, names) -> dict:
+    """name -> (value, line) of the file's whole-number constexprs
+    among `names`."""
+    found = {}
+    for i, text in enumerate(nf.lines):
+        for m in _CONST_EXPR_RE.finditer(text.split("//", 1)[0]):
+            if m.group(1) in names:
+                value = int_expr(ast.parse(m.group(2).strip(),
+                                           mode="eval").body)
+                if value is not None:
+                    found[m.group(1)] = (value, i + 1)
+    return found
+
+
 def check_na03(nf: NativeFile, ctx, config: dict) -> list[Violation]:
     """Frame-layout parity of an SSF stream. The bridge cuts framed
     streams itself, so each constant of the layout exists twice: here
@@ -148,14 +162,7 @@ def check_na03(nf: NativeFile, ctx, config: dict) -> list[Violation]:
     tests use. Each native constant named in `na03_pairs` must equal
     its Python twin, and a file that defines one of them defines all."""
     pairs = config["na03_pairs"]
-    found = {}
-    for i, text in enumerate(nf.lines):
-        for m in _CONST_EXPR_RE.finditer(text.split("//", 1)[0]):
-            if m.group(1) in pairs:
-                value = int_expr(ast.parse(m.group(2).strip(),
-                                           mode="eval").body)
-                if value is not None:
-                    found[m.group(1)] = (value, i + 1)
+    found = _constexprs(nf, pairs)
     if not found:
         return []
     out = []
@@ -186,6 +193,57 @@ def check_na03(nf: NativeFile, ctx, config: dict) -> list[Violation]:
     return out
 
 
+_STATS_FN_RE = re.compile(r"^void\s+vtpu_stats\s*\(")
+# out[7] = ...;   out[20 + i] = ...;   (a loop over the banks)
+_STATS_OUT_RE = re.compile(r"\bout\[\s*(\d+)\s*(\+\s*i\s*)?\]\s*=")
+
+
+def check_na04(nf: NativeFile, ctx, config: dict) -> list[Violation]:
+    """Layout parity of the bridge's stats array. `vtpu_stats` fills a
+    caller's array by index and `NativeBridge.stats()` names the fields
+    by position, so a field added on one side alone shifts or drops
+    silently. The native constant of `na04_pairs` (the array's length)
+    must equal its Python twin, which sizes the array and is held to
+    the names' count where they are zipped, and must be one more than
+    the highest index `vtpu_stats` writes (`out[n + i]` counting a loop
+    over NUM_BANKS)."""
+    out = []
+    consts = _constexprs(nf, set(config["na04_pairs"]) | {"NUM_BANKS"})
+    for cpp_name, py_name in config["na04_pairs"].items():
+        if cpp_name not in consts:
+            continue
+        value, lineno = consts[cpp_name]
+        twin = ctx.na03_values.get(py_name)
+        if twin is None:
+            out.append(Violation(
+                nf.path, lineno, "NA04",
+                f"{cpp_name}={value} has no Python-side {py_name} in the "
+                "scanned tree: the stats array is filled by index here "
+                "and named by position there"))
+        elif twin[0] != value:
+            out.append(Violation(
+                nf.path, lineno, "NA04",
+                f"{cpp_name}={value} diverges from {py_name}={twin[0]} "
+                f"({twin[1]}): a stats field exists on one side only"))
+        banks = consts.get("NUM_BANKS", (1, 0))[0]
+        written, inside = [], False
+        for text in nf.lines:
+            if _STATS_FN_RE.match(text):
+                inside = True
+            elif inside and text.startswith("}"):
+                break
+            if inside:
+                written += [int(n) + (banks - 1 if loop else 0)
+                            for n, loop in _STATS_OUT_RE.findall(
+                                text.split("//", 1)[0])]
+        if written and max(written) + 1 != value:
+            out.append(Violation(
+                nf.path, lineno, "NA04",
+                f"vtpu_stats writes up to out[{max(written)}] and "
+                f"{cpp_name} is {value}"))
+    return out
+
+
 def check_file(nf: NativeFile, ctx, config: dict) -> list[Violation]:
     return (check_na01(nf) + check_na02(nf, ctx, config)
-            + check_na03(nf, ctx, config))
+            + check_na03(nf, ctx, config) + check_na04(nf, ctx, config))

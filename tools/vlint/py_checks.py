@@ -44,7 +44,8 @@ class Context:
     # NA02: value of the Python-side recursion-cap parity constant
     na02_value: int | None = None
     na02_path: str | None = None
-    # NA03: Python-side frame-layout constants, name -> (value, path)
+    # NA03, NA04: Python-side twins of native constants (the frame
+    # layout, the stats array's length), name -> (value, path)
     na03_values: dict = field(default_factory=dict)
 
 
@@ -55,7 +56,8 @@ def _module_basename(path: str) -> str:
 def build_context(proj: Project, config: dict) -> Context:
     ctx = Context()
     const_name = config["na02_py_constant"]
-    na03_names = set(config["na03_pairs"].values())
+    na03_names = (set(config["na03_pairs"].values())
+                  | set(config["na04_pairs"].values()))
     for mod in proj.py_modules:
         for node in ast.walk(mod.tree):
             if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):

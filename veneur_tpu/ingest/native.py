@@ -34,6 +34,24 @@ from ..ingest.parser import MetricKey
 from ..models.worker import SlotInfo
 
 _BANKS = {"histo": 0, "counter": 1, "gauge": 2, "set": 3}
+# `stats()`'s per-bank key counts, each as `<name>_<bank>`: keys holding
+# a slot now, and running totals of keys minted into a slot and of keys
+# the idle TTL evicted
+KEY_STATS = ("keys_live", "keys_interned", "keys_evicted")
+# the length of the array `vtpu_stats` fills (native/vtpu_ingest.cpp:
+# kStatsFields; vlint NA04 holds the two equal) and its fields' names,
+# by position. A tuple built once: senders pace on `stats()`
+STATS_FIELDS = 32
+_STATS_KEYS = ("packets", "lines", "samples", "parse_errors",
+               "slow_routed", "drops_no_slot", "ring_drops",
+               "other_drops", "pending_other", "ssf_spans",
+               "ssf_fallbacks", "ssf_errors", "ssf_other_drops",
+               "pending_ssf_other", "ssf_stream_frames",
+               "ssf_stream_conns", "ssf_stream_conn_errors",
+               "ssf_stream_read_ns", "ssf_stream_wait_ns", "intern_ns",
+               *(f"{name}_{bank}" for name in KEY_STATS
+                 for bank in _BANKS))
+assert len(_STATS_KEYS) == STATS_FIELDS
 _MTYPE_NAMES = ["counter", "gauge", "timer", "histogram", "set"]
 
 P_METRIC, P_ERROR, P_OTHER = 0, 1, 2
@@ -392,17 +410,10 @@ class NativeBridge:
             _u8(ta), len(tb))
 
     def stats(self) -> dict:
-        keys = ("packets", "lines", "samples", "parse_errors",
-                "slow_routed", "drops_no_slot", "ring_drops",
-                "other_drops", "pending_other", "ssf_spans",
-                "ssf_fallbacks", "ssf_errors", "ssf_other_drops",
-                "pending_ssf_other", "ssf_stream_frames",
-                "ssf_stream_conns", "ssf_stream_conn_errors",
-                "ssf_stream_read_ns", "ssf_stream_wait_ns")
-        out = np.zeros(len(keys), np.uint64)
+        out = np.zeros(STATS_FIELDS, np.uint64)
         self._lib.vtpu_stats(
             self._h, out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)))
-        return dict(zip(keys, out.tolist()))
+        return dict(zip(_STATS_KEYS, out.tolist()))
 
 
 class BridgeKeyView:
@@ -429,9 +440,16 @@ class BridgeKeyView:
         # reassigns a slot to a new key (register()).
         self._holders: dict[int, SlotInfo] = {}
         self.dropped_no_slot = 0
+        # keys the idle TTL evicted at the newest advance_interval
+        self.evicted = 0
 
     def __len__(self):
         return self.bridge.key_count(self.bank)
+
+    @property
+    def interned(self) -> int:
+        """Running total of keys the C++ table minted into a slot."""
+        return int(self.bridge.stats()["keys_interned_" + self.bank])
 
     def lookup(self, key: MetricKey, scope: int) -> int:
         """KeyInterner.lookup parity for the engine's Python entry points
@@ -493,7 +511,7 @@ class BridgeKeyView:
 
     def advance_interval(self):
         self.touched[:] = False
-        self.bridge.advance_interval(self.bank)
+        self.evicted = self.bridge.advance_interval(self.bank)
 
 
 class NativePump:
@@ -525,7 +543,9 @@ class NativePump:
         # its `ingest` root. Preallocated; past its budget a tick's
         # later dispatches lengthen the last row, so seconds stay exact
         self.stamps = stamps
-        self._ssf_read_ns = 0   # the stream readers' tally at the last take
+        # the bridge's tallies at the last take: the stream readers' ns
+        # and the ns inside intern_key's slow path
+        self._taken_ns = {"ssf_stream_read_ns": 0, "intern_ns": 0}
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         # pump_once may be called by both the pump thread and
@@ -543,16 +563,26 @@ class NativePump:
         grafts them under its `ingest` root: the pump's batches and,
         where framed SSF streams were read, one `ingest.ssf.read` row
         of the stream readers' seconds inside handle_ssf + staging
-        since the last take. The readers keep a tally and no edges, so
-        the row is laid to end where the pump's last batch did."""
+        since the last take; where keys were minted, one
+        `ingest.intern` row of the seconds inside intern_key's slow
+        path. The readers keep a tally and no edges, so each row is
+        laid to end where the pump's last batch did."""
         if self.stamps is None:
             return []
         rows = self.stamps.take()
-        read_ns = int(self.bridge.stats()["ssf_stream_read_ns"])
-        took, self._ssf_read_ns = read_ns - self._ssf_read_ns, read_ns
-        if took > 0:
-            end = max((r[2] for r in rows), default=time.monotonic_ns())
-            rows.append(("ingest.ssf.read", end - took, end))
+        st = self.bridge.stats()
+        end = max((r[2] for r in rows), default=time.monotonic_ns())
+        # keys minted while no batch was pumped (the server's own
+        # timers, through the Python path) wait for an interval that
+        # pumped: an idle interval grafts no `ingest` root
+        tallies = [("ingest.ssf.read", "ssf_stream_read_ns")]
+        if rows:
+            tallies.append(("ingest.intern", "intern_ns"))
+        for name, key in tallies:
+            total = int(st[key])
+            took, self._taken_ns[key] = total - self._taken_ns[key], total
+            if took > 0:
+                rows.append((name, end - took, end))
         return rows
 
     def start(self):

@@ -788,6 +788,15 @@ class DigestStage:
         return stage
 
 
+def _f32_pair(totals) -> tuple:
+    """f64 totals as two f32 columns, the f32 nearest each and the f32
+    of what that left: a forwarded counter total past 2^24 is not an
+    f32, and folded into the bank's 2Sum pair one column after the
+    other it lands exact to 48 bits."""
+    hi = totals.astype(np.float32)
+    return hi, (totals - hi).astype(np.float32)
+
+
 class AggregationEngine:
     # Subclass gates for the ISSUE 11 flush paths: the mesh engine owns
     # sharded banks (no per-slot bitmaps, landing paths write banks in
@@ -877,6 +886,9 @@ class AggregationEngine:
         self.counter_keys = self._key_table(cfg.counter_slots)
         self.gauge_keys = self._key_table(cfg.gauge_slots)
         self.set_keys = self._key_table(cfg.set_slots)
+        # each table's running `interned` at the last flush (a flush
+        # notes what the interval minted)
+        self._keys_interned_seen = [0, 0, 0, 0]
 
         b = cfg.batch_size
         f32, i32 = (np.float32, 0.0), (np.int32, 0)
@@ -1754,11 +1766,12 @@ class AggregationEngine:
     def _land_import_scalars(self, cbank, gbank, counters, gauges,
                              dirty, gauge_seq):
         if counters:
-            cbank = self._land_import_counters(
-                cbank,
-                np.fromiter(counters.keys(), np.int32, len(counters)),
-                np.fromiter(counters.values(), np.float32,
-                            len(counters)), dirty)
+            slots = np.fromiter(counters.keys(), np.int32, len(counters))
+            hi, lo = _f32_pair(np.fromiter(counters.values(), np.float64,
+                                           len(counters)))
+            cbank = self._land_import_counters(cbank, slots, hi, dirty)
+            if lo.any():
+                cbank = self._land_import_counters(cbank, slots, lo, None)
         if gauges:
             gbank, gauge_seq = self._land_import_gauges(
                 gbank, np.fromiter(gauges.keys(), np.int32, len(gauges)),
@@ -2311,16 +2324,29 @@ class AggregationEngine:
         stats_samples = self.samples_processed
         self.samples_processed = 0
         dropped = 0
-        for ki in (self.histo_keys, self.counter_keys,
-                   self.gauge_keys, self.set_keys):
+        tables = (self.histo_keys, self.counter_keys,
+                  self.gauge_keys, self.set_keys)
+        for ki in tables:
             dropped += ki.dropped_no_slot
             ki.dropped_no_slot = 0  # per-interval, like `samples`
         histo_key_count = len(self.histo_keys)
-        for ki in (self.histo_keys, self.counter_keys,
-                   self.gauge_keys, self.set_keys):
+        # the advance of all four key tables: keys idle past the TTL
+        # give their slots back. What each table minted since the last
+        # flush, evicted now and holds after it, by bank (histo,
+        # counter, gauge, set), with the advance's edges: the flush
+        # notes the counts in `_last_flush_info` and stamps the phase
+        t_adv = time.monotonic_ns()
+        minted = [ki.interned for ki in tables]
+        for ki in tables:
             ki.advance_interval()
+        keys = {"keys_interned": [n - n0 for n, n0 in
+                                  zip(minted, self._keys_interned_seen)],
+                "keys_evicted": [ki.evicted for ki in tables],
+                "keys_live": [len(ki) for ki in tables]}
+        self._keys_interned_seen = minted
         return (active, status, stats_samples, dropped, histo_key_count,
-                self._take_tally(_IMPORT_TALLY))
+                self._take_tally(_IMPORT_TALLY), keys,
+                ("advance", t_adv, time.monotonic_ns()))
 
     def _take_tally(self, names) -> dict:
         """The interval's counts `_<name>`, read and reset (under the
@@ -2425,11 +2451,13 @@ class AggregationEngine:
                 # history tier records (ISSUE 14)
                 retired_wm = self.last_import_op
                 (active, status, stats_samples, dropped, histo_key_count,
-                 imported) = self._flush_bookkeeping(full_export)
+                 imported, keys,
+                 advance) = self._flush_bookkeeping(full_export)
             t_swap = time.monotonic_ns()
             # flight-recorder stamps: (name, t0_ns, t1_ns) on the
             # shared monotonic_ns clock, returned in stats["phases"]
-            # so the server grafts them into the tick's phase tree
+            # so the server grafts them into the tick's phase tree; a
+            # fourth field names the earlier stamp it nests under
             phases = [("swap", t_start, t_swap)]
             snap, overflow, did = self._land_retired(
                 snap, overflow, dirty, stages, imports, retired_seq)
@@ -2438,6 +2466,8 @@ class AggregationEngine:
                 imported[name] += n
             t_drain = time.monotonic_ns()
             phases.append(("drain", t_swap, t_drain))
+            # the advance ran under the lock: a child of `swap`
+            phases.append((*advance, "swap"))
         else:
             with self.lock:
                 self.drain_all()
@@ -2450,14 +2480,16 @@ class AggregationEngine:
                 self._gauge_seq = 0
                 retired_wm = self.last_import_op
                 (active, status, stats_samples, dropped, histo_key_count,
-                 imported) = self._flush_bookkeeping(full_export)
+                 imported, keys,
+                 advance) = self._flush_bookkeeping(full_export)
             t_swap = time.monotonic_ns()
-            phases = [("drain", t_start, t_swap)]
+            phases = [("drain", t_start, t_swap), (*advance, "drain")]
 
         fwd_out = self._fwd_out
         host, row_of = self._flush_device(snap, phases=phases, dirty=dirty,
                                           overflow=overflow)
         self._last_flush_info.update(imported)
+        self._last_flush_info.update(keys)
         t_device = time.monotonic_ns()
 
         def slot_rows(kind, infos):
@@ -2698,6 +2730,10 @@ class AggregationEngine:
             # (veneur.import.land_rows_total / land_bank_total)
             "import_land_rows": imported["import_land_rows"],
             "import_land_bank": imported["import_land_bank"],
+            # keys the four tables minted in the interval, evicted at
+            # this flush and hold after it (veneur.keys.interned_total
+            # / .evicted_total / .live; by bank in `flush_path`)
+            **{name: sum(by_bank) for name, by_bank in keys.items()},
             # its sketches by the path that decoded them, and the key
             # dictionary's hits and misses (veneur.import.decode_*)
             **{name: imported[name] for name in DECODE_TALLY},

@@ -46,6 +46,10 @@ class KeyInterner:
         self._by_slot: list[MetricKey | None] = [None] * capacity
         self.interval = 0
         self.dropped_no_slot = 0
+        # running total of keys minted into a slot, and the keys the
+        # newest advance_interval evicted (the engine's flush reads both)
+        self.interned = 0
+        self.evicted = 0
         # Overload defense (ingest/admission.py), attached by
         # AggregationEngine.attach_admission: consulted ONLY on the
         # allocation path — a key already holding a slot pays zero
@@ -77,6 +81,7 @@ class KeyInterner:
             return -1
         self._map[key] = SlotInfo(slot, self.interval, scope)
         self._by_slot[slot] = key
+        self.interned += 1
         return slot
 
     # Where a new key's slot comes from and an evicted key's goes back
@@ -153,6 +158,7 @@ class KeyInterner:
         """Called at each flush boundary: ages entries and evicts those
         idle longer than the TTL, returning their slots to the free list."""
         self.interval += 1
+        self.evicted = 0
         if self.idle_ttl <= 0:
             return
         horizon = self.interval - self.idle_ttl
@@ -160,6 +166,7 @@ class KeyInterner:
             return
         dead = [k for k, info in self._map.items()
                 if info.last_interval < horizon]
+        self.evicted = len(dead)
         adm = self.admission
         for k in dead:
             info = self._map.pop(k)

@@ -50,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.pipeline import (AggregationEngine, EngineConfig,
-                               _precluster_k1, _spans)
+                               _f32_pair, _precluster_k1, _spans)
 from .interner import ShardedKeyInterner
 from .mesh import MeshEngine, make_mesh
 
@@ -665,14 +665,18 @@ class MeshAggregationEngine(AggregationEngine):
         if self._import_counter_acc:
             acc, self._import_counter_acc = self._import_counter_acc, {}
             slots = np.fromiter(acc.keys(), np.int32, len(acc))
-            vals = np.fromiter(acc.values(), np.float32, len(acc))
-            for cs, (cv,) in self._batched(slots, vals):
-                rs, rv, rw = self._route(
-                    self.me.counter_slots // self.S, cs, cv,
-                    np.ones(len(cs), np.float32))
-                self.me.ingest(*self._pads_for("histo"), rs, rv, rw,
-                               *self._pads_for("gauge", "set"))
-                self._mesh_import_dispatches += 1
+            hi, lo = _f32_pair(np.fromiter(acc.values(), np.float64,
+                                           len(acc)))
+            # a total past 2^24 in two batches, never both halves of a
+            # key in one: a batch's delta is one f32 sum a slot
+            for vals in (hi, lo) if lo.any() else (hi,):
+                for cs, (cv,) in self._batched(slots, vals):
+                    rs, rv, rw = self._route(
+                        self.me.counter_slots // self.S, cs, cv,
+                        np.ones(len(cs), np.float32))
+                    self.me.ingest(*self._pads_for("histo"), rs, rv, rw,
+                                   *self._pads_for("gauge", "set"))
+                    self._mesh_import_dispatches += 1
         if self._import_gauge_acc:
             acc, self._import_gauge_acc = self._import_gauge_acc, {}
             slots = np.fromiter(acc.keys(), np.int32, len(acc))

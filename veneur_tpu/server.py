@@ -2115,6 +2115,7 @@ class Server:
                      "overflow_rows": 0, "overflow_bank": 0,
                      "import_batches": 0, "import_metrics": 0,
                      "import_land_rows": 0, "import_land_bank": 0,
+                     "keys_interned": 0, "keys_evicted": 0, "keys_live": 0,
                      **dict.fromkeys(DECODE_TALLY, 0)}
         # Engines flush concurrently so their device programs and
         # device→host transfers overlap instead of queueing behind
@@ -2176,9 +2177,12 @@ class Server:
                 # dispatch / device exec / fetch / materialize) under
                 # its engine.flush phase, with their real edges
                 drain = (eng_ph[i], tick.mono_start)
-                for nm, p0, p1 in res.stats.get("phases", ()):
-                    idx = tick.add("engine." + nm, p0, p1,
-                                   parent=eng_ph[i])
+                added = {}
+                for nm, p0, p1, *under in res.stats.get("phases", ()):
+                    # a stamp that names one before it nests there
+                    idx = added[nm] = tick.add(
+                        "engine." + nm, p0, p1,
+                        parent=added[under[0]] if under else eng_ph[i])
                     if nm == "drain":
                         drain = (idx, p0)
                 # the engine's import stamps since the previous flush:
@@ -2883,6 +2887,13 @@ class Server:
             # one, and passes over a whole histogram bank (the dear arm)
             tel.mark(S, "ingest.overflow_rows", eng_stats["overflow_rows"])
             tel.mark(S, "ingest.overflow_bank", eng_stats["overflow_bank"])
+            # the key tables, all banks and engines summed: keys minted
+            # into a slot in the interval, keys the idle TTL evicted at
+            # this flush, keys holding a slot after it
+            # (veneur.keys.interned_total / .evicted_total / .live)
+            tel.mark(S, "keys.interned", eng_stats["keys_interned"])
+            tel.mark(S, "keys.evicted", eng_stats["keys_evicted"])
+            tel.set_gauge(S, "keys.live", eng_stats["keys_live"])
             # the import's hand-over: batches the engines applied and
             # the forwarded metrics in them (one batch a request and
             # engine; a ratio near 1 means requests of one metric)
