@@ -49,6 +49,22 @@ def _release_compiled_executables():
     gc.collect()
 
 
+@pytest.fixture
+def compiled():
+    """(names, armed): the names of the programs JAX compiles while
+    `armed[0]` is set."""
+    import jax
+    names, armed = [], [False]
+
+    def listen(event, duration, **kw):
+        if armed[0] and event == \
+                "/jax/core/compile/backend_compile_duration":
+            names.append(kw.get("fun_name", "?"))
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    yield names, armed
+    armed[0] = False
+
+
 # Tests of the accepted benchmark (`tests/perfbench/`, which a PR that
 # adds cells may not edit) that pin a list the contract has later PRs
 # append to, as `tests/perfbench/conftest.py` does for the one test that

@@ -3,6 +3,7 @@ plus the in-process two-tier (local Servers -> global Server) merge test —
 the reference's "multi-node without a cluster" strategy (server_test.go,
 flusher_test.go)."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -354,6 +355,230 @@ def test_hot_slots_in_a_batch_wider_than_batch_size():
         assert by[f"hot{i}.max"] == float(mine.max())
         exp = float(np.quantile(mine.astype(np.float64), 0.5))
         assert abs(by[f"hot{i}.50percentile"] - exp) / exp < 0.02
+
+
+# ---- the sidestep lands on the rows it touches (ISSUE 46) -------------
+
+def _sidestep_engine(**kw):
+    """A 64-slot bank with buffers 16 deep: a 256-wide batch has a work
+    set of 16 rows (the row arm), a 2,048-wide one of 128 (no smaller
+    than the bank: the whole-bank arm)."""
+    cfg = dict(histogram_slots=64, counter_slots=8, gauge_slots=8,
+               set_slots=8, batch_size=256, buffer_depth=16,
+               percentiles=(0.5,), aggregates=("min", "max", "count"))
+    cfg.update(kw)
+    return AggregationEngine(EngineConfig(**cfg))
+
+
+def _timer_slots(eng, n):
+    from veneur_tpu.ingest.parser import MetricKey
+    return [eng.histo_keys.lookup(MetricKey(f"t{i}", "timer", ""), 0)
+            for i in range(n)]
+
+
+def _hot_batch(rng, width, per_slot, weighted=False):
+    """A `width`-wide batch: slot s brings per_slot[s] samples, the
+    rest is padding, shuffled."""
+    slots = np.full(width, -1, np.int32)
+    ids = np.concatenate([np.full(n, s, np.int32)
+                          for s, n in per_slot.items()])
+    slots[:ids.size] = ids
+    rng.shuffle(slots)
+    values = rng.gamma(2.0, 20.0, width).astype(np.float32)
+    weights = (rng.choice([1.0, 2.0, 10.0], width) if weighted
+               else np.ones(width)).astype(np.float32)
+    return slots, values, weights
+
+
+def _leaves(bank):
+    # copies: the next landing donates the bank's buffers
+    return {k: np.array(v) for k, v in bank._asdict().items()}
+
+
+def _parent_landing(eng, slots, values, weights, hot_ids):
+    """The sidestep as it was before ISSUE 46, on eng's live bank:
+    the cold rows through the ingest program, the whole bank
+    compressed, the hot slots' pre-clustered points through
+    merge_centroids, their exact stats through merge_scalars. Returns
+    the bank's leaves as the ingest program alone left them."""
+    from veneur_tpu.models.pipeline import _precluster_k1
+    kern, B = eng._kern, eng.histo_bank.buf_size
+    cold = np.where(np.isin(slots, hot_ids), -1, slots).astype(np.int32)
+    bank, eng._overflow = kern["histo"](eng.histo_bank, eng._overflow,
+                                        cold, values, weights)
+    after_histo = _leaves(bank)
+    swidth, lanes = eng._hot_widths(len(slots))
+    ps = np.full(swidth * lanes, -1, np.int32)
+    pm, pw = np.zeros_like(ps, np.float32), np.zeros_like(ps, np.float32)
+    spad = np.full(swidth, -1, np.int32)
+    stats = np.zeros((5, swidth), np.float32)
+    at = 0
+    for i, s in enumerate(hot_ids):
+        v = values[slots == s].astype(np.float64)
+        w = weights[slots == s].astype(np.float64)
+        cm, cw = _precluster_k1(v, w, B)
+        ps[at:at + len(cm)] = s
+        pm[at:at + len(cm)] = cm
+        pw[at:at + len(cm)] = cw
+        at += len(cm)
+        spad[i] = s
+        nz = v != 0
+        stats[:, i] = (v.min(), v.max(), (v * w).sum(), w.sum(),
+                       (w[nz] / v[nz]).sum())
+    bank = kern["compress"](bank)
+    bank = kern["merge_centroids"](bank, ps, pm, pw)
+    eng.histo_bank = kern["merge_scalars"](bank, spad, *stats)
+    return after_histo
+
+
+@pytest.mark.parametrize("n_hot,weighted", [
+    (1, False), (3, False), (1, True), (3, True)],
+    ids=["one_hot", "several_hot", "one_hot_rated", "several_hot_rated"])
+def test_sidestep_lands_on_the_hot_rows_alone(n_hot, weighted):
+    """One hot batch through _land_histos' row arm against the
+    whole-bank sequence it replaced, from the same state (centroids
+    and half-filled buffers on hot and cold rows alike): the hot
+    rows' every leaf bit for bit what the whole-bank sequence leaves,
+    the cold rows' as the ingest program alone left them — their
+    buffers no longer compressed in passing."""
+    rng = np.random.default_rng(46)
+    new, old = _sidestep_engine(), _sidestep_engine()
+    ids = _timer_slots(new, 8)
+    assert ids == _timer_slots(old, 8)
+    hot_ids = np.sort(np.asarray(ids[:n_hot]))
+    prelude = [_hot_batch(rng, 256, {s: 14 for s in ids}),
+               _hot_batch(rng, 256, {s: 9 for s in ids})]
+    per = {s: 11 for s in ids[n_hot:]}
+    per.update({int(s): 40 + 23 * i for i, s in enumerate(hot_ids)})
+    batch = _hot_batch(rng, 256, per, weighted)
+    for eng in (new, old):
+        for b in prelude:
+            eng.ingest_histo_batch(*b)
+    new.ingest_histo_batch(*batch)
+    after_histo = _parent_landing(old, *batch, hot_ids)
+    got, want = _leaves(new.histo_bank), _leaves(old.histo_bank)
+    cold = np.setdiff1d(np.arange(64), hot_ids)
+    for leaf in got:
+        np.testing.assert_array_equal(
+            got[leaf][hot_ids], want[leaf][hot_ids], err_msg=leaf)
+        np.testing.assert_array_equal(
+            got[leaf][cold], after_histo[leaf][cold], err_msg=leaf)
+    # the hot rows hold their points in the buffer, the cold rows
+    # that had samples waiting still have them
+    assert (got["buf_n"][hot_ids] > 0).all()
+    assert (got["buf_n"][ids[n_hot:]] > 0).all()
+    assert (want["buf_n"][ids[n_hot:]] == 0).all()
+    assert new._sidestep == [n_hot, 0]
+    # and the flush reads what was sent
+    by = {m.name: m.value for m in new.flush(timestamp=1).metrics}
+    slots, values, weights = batch
+    for i, s in enumerate(ids):
+        mine = np.concatenate([b[1][b[0] == s] for b in prelude]
+                              + [values[slots == s]])
+        assert by[f"t{i}.min"] == float(mine.min())
+        assert by[f"t{i}.max"] == float(mine.max())
+        assert by[f"t{i}.count"] == 23.0 + float(
+            weights[slots == s].sum(dtype=np.float64))
+
+
+@pytest.mark.parametrize("case", [
+    "live", "through_the_stage", "legacy_ordering", "full_program"])
+def test_sidestep_counter_follows_the_interval(case):
+    """sidestep_rows / sidestep_bank: the hot rows the row arm landed
+    and 0 after hot batches, in _last_flush_info, the flush's stats
+    and its flush_path; 0 / 0 after an interval with no hot slot. The
+    stage's drain of a retired snapshot counts to the interval it
+    retires."""
+    kw = {"legacy_ordering": dict(flush_double_buffer=False),
+          "full_program": dict(flush_incremental=False)}.get(case, {})
+    eng = _sidestep_engine(**kw)
+    rng = np.random.default_rng(3)
+    a, b, c = _timer_slots(eng, 3)
+    if case == "through_the_stage":
+        from veneur_tpu.ingest.parser import parse_packet
+        for i in range(24):
+            eng.process(parse_packet(f"t0:{i}|ms".encode()))
+        for i in range(16):
+            eng.process(parse_packet(f"t1:{i}|ms".encode()))
+        want, counts = 1, {"t0.count": 24.0, "t1.count": 16.0}
+    else:
+        eng.ingest_histo_batch(*_hot_batch(rng, 256, {a: 100, b: 30, c: 5}))
+        eng.ingest_histo_batch(*_hot_batch(rng, 256, {a: 17, b: 16, c: 5}))
+        assert eng._sidestep == [3, 0]
+        want, counts = 3, {"t0.count": 117.0, "t1.count": 46.0,
+                           "t2.count": 10.0}
+    res = eng.flush(timestamp=1)
+    for where in (eng._last_flush_info, res.stats,
+                  res.stats["flush_path"]):
+        assert (where["sidestep_rows"], where["sidestep_bank"]) == (want, 0)
+    by = {m.name: m.value for m in res.metrics}
+    assert {k: by[k] for k in counts} == counts
+    # the counts are the interval's: one with no hot slot reads 0 / 0
+    eng.ingest_histo_batch(*_hot_batch(rng, 256, {a: 16, b: 9}))
+    res = eng.flush(timestamp=2)
+    assert eng._sidestep == [0, 0]
+    for where in (eng._last_flush_info, res.stats):
+        assert (where["sidestep_rows"], where["sidestep_bank"]) == (0, 0)
+
+
+@pytest.mark.parametrize("case", ["bank_no_larger_than_work_set", "req"])
+def test_sidestep_whole_bank_arm_where_rows_do_not_apply(case):
+    """A bank no larger than the work set (decided from the static
+    shapes), and an engine without the row primitives (the REQ
+    compactor), keep the whole-bank sequence and count its passes."""
+    rng = np.random.default_rng(5)
+    if case == "req":
+        eng = _sidestep_engine(histogram_backend="req")
+        width = 8 * eng.histo_bank.buf_size
+        assert eng._hot_widths(width)[0] < 64
+    else:
+        eng = _sidestep_engine()
+        width = 2048
+        assert eng._hot_widths(width)[0] >= 64
+    hot = 3 * eng.histo_bank.buf_size
+    a, b = _timer_slots(eng, 2)
+    for _ in range(2):
+        eng.ingest_histo_batch(*_hot_batch(rng, width, {a: hot, b: 7}))
+    res = eng.flush(timestamp=1)
+    info = eng._last_flush_info
+    assert (info["sidestep_rows"], info["sidestep_bank"]) == (0, 2)
+    assert res.stats["sidestep_bank"] == 2
+    by = {m.name: m.value for m in res.metrics}
+    assert by["t0.count"] == 2.0 * hot and by["t1.count"] == 14.0
+
+
+@pytest.mark.parametrize("case", [
+    "staging_width", "pump_width", "retired_snapshot"])
+def test_after_warm_ingest_kernels_no_sidestep_compiles(case, compiled):
+    """After warm_ingest_kernels(w) a hot batch at width w compiles
+    nothing: the gather, the part's compress, the fill, the scatter and
+    merge_scalars are all warm at the work set of w, for the staging
+    width (warmup()'s own call), a wider pump's (the Server's call) and
+    the stage's drain of a retired snapshot."""
+    names, armed = compiled
+    eng = _sidestep_engine(histogram_slots=256)
+    eng.warmup()
+    eng.warm_ingest_kernels(1024)
+    a, b = _timer_slots(eng, 2)
+    rng = np.random.default_rng(9)
+    armed[0] = True
+    if case == "retired_snapshot":
+        from veneur_tpu.ingest.parser import parse_packet
+        for i in range(40):
+            eng.process(parse_packet(f"t0:{i}|ms".encode()))
+        want = 40.0
+    else:
+        width = 256 if case == "staging_width" else 1024
+        eng.ingest_histo_batch(*_hot_batch(rng, width, {a: 100, b: 12}))
+        jax.block_until_ready(eng.histo_bank)
+        want = 100.0
+    assert names == []
+    res = eng.flush(timestamp=1)
+    armed[0] = False
+    # the first flush with one dirty row compiles nothing either
+    assert names == []
+    assert res.stats["sidestep_rows"] == 1
+    assert {m.name: m.value for m in res.metrics}["t0.count"] == want
 
 
 def test_warmed_engine_flushes_what_a_cold_one_does():

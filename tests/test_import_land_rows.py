@@ -307,20 +307,6 @@ def test_the_ladder_a_bank_takes_follows_from_its_shape(slots, sizes):
         assert land(1, slots) == sizes[0]
 
 
-@pytest.fixture
-def compiled():
-    """Names of the programs JAX compiles while `armed[0]` is set."""
-    names, armed = [], [False]
-
-    def listen(event, duration, **kw):
-        if armed[0] and event == \
-                "/jax/core/compile/backend_compile_duration":
-            names.append(kw.get("fun_name", "?"))
-    jax.monitoring.register_event_duration_secs_listener(listen)
-    yield names, armed
-    armed[0] = False
-
-
 def _piles(rng, S, digests, centroids):
     """Staged items for rows 0..S-1: row s gets digests[s % len]
     digests of centroids[s % len] centroids each."""

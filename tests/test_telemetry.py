@@ -463,10 +463,15 @@ def test_ingest_overflow_counters_drain_as_self_metrics():
                           if m.name.startswith("veneur.ingest.")}
                          for f in cap.flushes[:2])
         # a 64-slot bank is under every work set: whole-bank passes
+        # (no batch brought a slot more than a buffer: no sidestep)
         assert first == {"veneur.ingest.overflow_rows_total": 0,
-                         "veneur.ingest.overflow_bank_total": 2}
+                         "veneur.ingest.overflow_bank_total": 2,
+                         "veneur.ingest.sidestep_rows_total": 0,
+                         "veneur.ingest.sidestep_bank_total": 0}
         assert second == {"veneur.ingest.overflow_rows_total": 0,
-                          "veneur.ingest.overflow_bank_total": 0}
+                          "veneur.ingest.overflow_bank_total": 0,
+                          "veneur.ingest.sidestep_rows_total": 0,
+                          "veneur.ingest.sidestep_bank_total": 0}
         state = srv._debug_flush_state()["registry"]["server"]
         assert state["counters"]["_server|ingest.overflow_bank"] == 2
     finally:

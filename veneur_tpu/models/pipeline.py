@@ -262,7 +262,8 @@ def _ingest_executables(device, heng, seng, set_arm="xla"):
         "counter": jit(scalar.counter_add.__wrapped__),
         "gauge": jit(scalar.gauge_set.__wrapped__),
         "set": jit(set_insert),
-        # hot-slot sidestep programs (see _add_histo_batch)
+        # hot-slot sidestep programs (see _land_hot; `compress` at
+        # the work set's shape or the bank's)
         "compress": jit(heng.compress_impl),
         "merge_centroids": jit(heng.merge_centroids_impl),
         "merge_scalars": jit(heng.merge_scalars_impl),
@@ -811,6 +812,12 @@ class AggregationEngine:
     # engine whose landing counts nothing (the mesh engine).
     _overflow = None
     _overflow_zero = None
+    # What the hot-slot sidestep did this interval, counted on the host
+    # where _land_hot chooses its arm: [hot rows landed through a work
+    # set, passes over the whole bank]. Retired with the overflow
+    # counter (_last_flush_info "sidestep_rows" / "sidestep_bank");
+    # None on the mesh engine, whose sidestep is its own.
+    _sidestep = None
 
     def _setup_device(self):
         """Build the device-side state: committed banks plus the shared
@@ -845,6 +852,7 @@ class AggregationEngine:
             np.zeros(2, np.int32),
             jax.sharding.SingleDeviceSharding(self._device))
         self._overflow = self._overflow_zero
+        self._sidestep = [0, 0]
 
     def _setup_flush_exec(self):
         cfg = self.cfg
@@ -1188,23 +1196,38 @@ class AggregationEngine:
         """Live-bank histogram landing (ingest path; mesh overrides
         this wholesale — its landing routes the sharded ingest)."""
         self.histo_bank, self._overflow = self._land_histos(
-            self.histo_bank, self._overflow, self._dirty, slots, values,
-            weights)
+            self.histo_bank, self._overflow, self._sidestep, self._dirty,
+            slots, values, weights)
 
-    def _land_histos(self, bank, overflow, dirty, slots, values, weights):
+    def _land_histos(self, bank, overflow, sidestep, dirty, slots, values,
+                     weights):
         """Land one histogram batch into `bank` (live or a retired
         double-buffer snapshot — the caller owns the rebind of the bank
-        and of its `overflow` counter), marking
-        `dirty`, sidestepping the hot-slot worst case: add_batch's
-        while-loop pays a full-bank [K, C+B] sort per buffer-depth's
-        worth of samples landing on ONE slot, so a batch where
-        max-per-slot is 8192/B=32x over depth costs 32 sorts. When
-        a batch overfills any slot, pre-cluster the hot slots' samples
+        and of its `overflow` counter, and hands the interval's
+        `sidestep` counts to bump in place), marking `dirty`,
+        sidestepping the hot-slot worst case. add_batch absorbs a
+        buffer's depth of one slot's samples a turn of its loop, with a
+        row pass (gather, compress, scatter over _OVERFLOW_ROWS rows)
+        between turns: a batch that brings one slot n samples costs
+        ceil(n / B) of them, 128 at the pump's 32,768 against B = 256.
+        The sidestep makes it one: when a batch overfills any slot,
+        the hot slots' samples are taken out of it and pre-clustered
         on host to <= B weighted points each (numpy sort + bucketed
         segment means — the same two-level scheme the digest itself
         uses, so accuracy is unchanged within the k1 clustering's own
-        granularity), then land everything with ONE compress +
-        merge_centroids + exact merge_scalars."""
+        granularity), and the points land in the hot rows' buffers
+        after ONE compress that empties them, with their exact stats
+        through merge_scalars.
+
+        The compress runs over the hot rows alone — a work set of
+        _hot_widths' row count, gathered, compressed, filled and
+        scattered back, the import landing's pattern (_land_work_set)
+        — where the engine has the row primitives (import_strategy
+        "cluster") and the bank is larger than the work set; every
+        other row keeps its buffer. Otherwise (a compactor engine, a
+        small bank) the whole bank is compressed and merge_centroids
+        lands the points. Every op is row-independent, so the hot rows
+        read the same bit for bit either way."""
         slots = np.asarray(slots)
         B = bank.buf_size
         valid = slots >= 0
@@ -1232,55 +1255,68 @@ class AggregationEngine:
                                        weights)
         values = np.asarray(values)
         weights = np.asarray(weights)
-        hot = set(hot_ids.tolist())
-        hot_m = np.isin(slots, list(hot)) & valid
-        cold_slots = np.where(hot_m, -1, slots).astype(np.int32)
+        cold_slots = np.where(np.isin(slots, hot_ids), -1,
+                              slots).astype(np.int32)
         bank, overflow = self._kern["histo"](bank, overflow, cold_slots,
                                              values, weights)
 
-        out_s, out_m, out_w = [], [], []
-        sc_s, sc_min, sc_max, sc_sum, sc_cnt, sc_rcp = \
-            [], [], [], [], [], []
-        for s in hot:
-            m = (slots == s) & valid
-            v = values[m].astype(np.float64)
-            w = weights[m].astype(np.float64)
-            cm, cw = _precluster_k1(v, w, B)
-            out_s.append(np.full(len(cm), s, np.int32))
-            out_m.append(cm.astype(np.float32))
-            out_w.append(cw.astype(np.float32))
-            sc_s.append(s)
-            sc_min.append(float(v.min()))
-            sc_max.append(float(v.max()))
-            sc_sum.append(float((v * w).sum()))
-            sc_cnt.append(float(w.sum()))
-            nz = v != 0
-            sc_rcp.append(float((w[nz] / v[nz]).sum()))
-
-        flat_s = np.concatenate(out_s)
-        flat_m = np.concatenate(out_m)
-        flat_w = np.concatenate(out_w)
         # ONE fixed shape per batch width (worst case: every sample in
         # the batch belongs to a hot slot) — a width that varied with
         # the data would JIT a new executable inline, under the ingest
-        # lock, per width
-        width, swidth = self._hot_widths(len(slots))
-        pad_s = np.full(width, -1, np.int32)
-        pad_m = np.zeros(width, np.float32)
-        pad_w = np.zeros(width, np.float32)
-        pad_s[:len(flat_s)] = flat_s
-        pad_m[:len(flat_s)] = flat_m
-        pad_w[:len(flat_s)] = flat_w
-        nh = len(sc_s)
-        spad = np.full(swidth, -1, np.int32)
-        spad[:nh] = np.asarray(sc_s, np.int32)
-        f = lambda a: np.pad(np.asarray(a, np.float32), (0, swidth - nh))
-        # compress first so merge_centroids has a full buffer of headroom
-        bank = self._kern["compress"](bank)
-        bank = self._kern["merge_centroids"](bank, pad_s, pad_m, pad_w)
-        return self._kern["merge_scalars"](
-            bank, spad, f(sc_min), f(sc_max), f(sc_sum),
-            f(sc_cnt), f(sc_rcp)), overflow
+        # lock, per width. Row i is hot_ids[i]'s (ascending); the rows
+        # past them are padding.
+        S, lanes = self._hot_widths(len(slots))
+        ids = np.full(S, -1, np.int32)
+        ids[:hot_ids.size] = hot_ids
+        means = np.zeros((S, lanes), np.float32)
+        wts = np.zeros_like(means)
+        stats = np.zeros((5, S), np.float32)
+        for i, s in enumerate(hot_ids):
+            m = slots == s
+            v = values[m].astype(np.float64)
+            w = weights[m].astype(np.float64)
+            cm, cw = _precluster_k1(v, w, B)
+            means[i, :len(cm)] = cm
+            wts[i, :len(cm)] = cw
+            nz = v != 0
+            stats[:, i] = (v.min(), v.max(), (v * w).sum(), w.sum(),
+                           (w[nz] / v[nz]).sum())
+        return self._land_hot(bank, sidestep, ids, means, wts,
+                              stats), overflow
+
+    def _land_hot(self, bank, sidestep, ids, means, wts, stats):
+        """The device half of the sidestep: pre-clustered points
+        f32[S, <= B] and exact stats f32[5, S], row i for bank row
+        ids[i] (ascending, -1 padding). Over the work set of S rows
+        where the engine has the row primitives and the bank is larger
+        than S (static, the test ops/tdigest._add_batch_counted makes
+        of _OVERFLOW_ROWS), else over the whole bank; `sidestep`
+        counts which."""
+        K = bank.num_slots
+        if self._heng.import_strategy == "cluster" and len(ids) < K:
+            sidestep[0] += int(np.count_nonzero(ids >= 0))
+            # the padding id K lies past the bank, reads its last row
+            # at the gather and is dropped at the scatter
+            rows = np.where(ids < 0, K, ids).astype(np.int32)
+            # _kern["compress"] at the part's shape, not the engine's
+            # own jit: a profile keeps the sidestep's compress under
+            # its name (jit_compress_impl)
+            # vlint: disable=DS01 reason=the device half of
+            # _land_histos, which marked the batch's rows first (the
+            # warm-up's all-padding call lands nothing)
+            part = self._kern["compress"](
+                self._heng.gather_rows(bank, rows))
+            bank = self._heng.scatter_rows(
+                bank, rows, self._heng.fill_buffers(part, means, wts))
+        else:
+            sidestep[1] += 1
+            # compress first so merge_centroids has a full buffer of
+            # headroom; it drops the lanes of weight 0
+            bank = self._kern["compress"](bank)
+            bank = self._kern["merge_centroids"](
+                bank, np.repeat(ids, means.shape[1]), means.reshape(-1),
+                wts.reshape(-1))
+        return self._kern["merge_scalars"](bank, ids, *stats)
 
     def ingest_counter_batch(self, slots, values, weights, count=None,
                              mark=None):
@@ -1371,16 +1407,15 @@ class AggregationEngine:
                 fn()
 
     def _hot_widths(self, batch: int):
-        """Fixed pad shapes for the hot-slot sidestep of a `batch`-wide
-        landing (the staging batch_size, or the native pump's own
-        width): fewer than batch/B slots can be hot in one batch, each
-        contributing <= B pre-clustered points. B is the BANK's
-        per-landing headroom (the engine's buf_size — t-digest buffer
-        depth, compactor level capacity), which need not equal
+        """Fixed pad shape (rows, lanes) of the hot-slot sidestep of a
+        `batch`-wide landing (the staging batch_size, or the native
+        pump's own width): fewer than batch/B slots can be hot in one
+        batch, each contributing <= B pre-clustered points. B is the
+        BANK's per-landing headroom (the engine's buf_size — t-digest
+        buffer depth, compactor level capacity), which need not equal
         cfg.buffer_depth."""
         B = self.histo_bank.buf_size
-        n_hot = max(1, batch // max(1, B))
-        return n_hot * min(B, batch), n_hot
+        return max(1, batch // max(1, B)), min(B, batch)
 
     def warmup(self):
         """Precompile every device program the serving path dispatches.
@@ -1458,16 +1493,18 @@ class AggregationEngine:
 
     def warm_ingest_kernels(self, b: int):
         """Precompile the batch-ingest kernels — the four scatters and
-        the hot-slot sidestep programs — at batch width `b`: warmup()
-        covers the staging batch_size, and the Server asks again for
-        the native pump's own width (native_pump_batch). Padding
-        batches: slot -1 rows are dropped, live state untouched."""
+        what the hot-slot sidestep dispatches (_land_hot: over its work
+        set, or the whole-bank pair where that is the arm it takes) —
+        at batch width `b`: warmup() covers the staging batch_size, and
+        the Server asks again for the native pump's own width
+        (native_pump_batch). Padding batches: slot -1 rows are dropped,
+        live state untouched."""
         pad = np.full(b, -1, np.int32)
         zf = np.zeros(b, np.float32)
         zi = np.zeros(b, np.int32)
         zu = np.zeros(b, np.uint8)
-        width, swidth = self._hot_widths(b)
-        sz = np.zeros(swidth, np.float32)
+        S, lanes = self._hot_widths(b)
+        zp = np.zeros((S, lanes), np.float32)
         with self.lock:
             # vlint: disable=DS01 reason=all-padding warmup batches
             # (slot -1 rows dropped by the kernels) — no live data
@@ -1479,13 +1516,9 @@ class AggregationEngine:
             self.gauge_bank = self._kern["gauge"](
                 self.gauge_bank, pad, zf, zi)
             self.set_bank = self._kern["set"](self.set_bank, pad, zi, zu)
-            self.histo_bank = self._kern["compress"](self.histo_bank)
-            self.histo_bank = self._kern["merge_centroids"](
-                self.histo_bank, np.full(width, -1, np.int32),
-                np.zeros(width, np.float32), np.zeros(width, np.float32))
-            self.histo_bank = self._kern["merge_scalars"](
-                self.histo_bank, np.full(swidth, -1, np.int32),
-                sz, sz, sz, sz, sz)
+            self.histo_bank = self._land_hot(
+                self.histo_bank, [0, 0], np.full(S, -1, np.int32), zp, zp,
+                np.zeros((5, S), np.float32))
         jax.block_until_ready(self.histo_bank)
 
     # ---------------- import (global tier Combine path) ----------------
@@ -2114,9 +2147,12 @@ class AggregationEngine:
 
     def _retire_overflow(self):
         """Under the lock, with the bank swap: the retiring interval's
-        overflow counter travels with its snapshot, and the fresh
-        banks count from zero."""
-        retired, self._overflow = self._overflow, self._overflow_zero
+        overflow counter and sidestep counts travel with its snapshot,
+        and the fresh banks count from zero."""
+        retired = self._overflow, self._sidestep
+        self._overflow = self._overflow_zero
+        if self._sidestep is not None:
+            self._sidestep = [0, 0]
         return retired
 
     def _flush_device(self, snap, phases=None, dirty=None,
@@ -2356,8 +2392,8 @@ class AggregationEngine:
             setattr(self, "_" + name, 0)
         return tally
 
-    def _land_retired(self, snap, overflow, dirty, stages, imports,
-                      gauge_seq) -> tuple:
+    def _land_retired(self, snap, overflow, sidestep, dirty, stages,
+                      imports, gauge_seq) -> tuple:
         """Outside the lock (double-buffered flush): drain the retired
         interval's stage buffers and land its staged imports into the
         retired bank snapshot — the same work the legacy ordering does
@@ -2372,7 +2408,7 @@ class AggregationEngine:
         a = stages.get("histo")
         if a is not None:
             hb, overflow = self._land_histos(
-                hb, overflow, dirty, a["slots"], a["values"],
+                hb, overflow, sidestep, dirty, a["slots"], a["values"],
                 a["weights"])
         a = stages.get("counter")
         if a is not None:
@@ -2443,7 +2479,7 @@ class AggregationEngine:
                 self._gauge_seq = 0
                 snap = self._swap_banks()
                 dirty = self._retire_dirty()
-                overflow = self._retire_overflow()
+                overflow, sidestep = self._retire_overflow()
                 # the applied-op watermark AT THE SWAP: per-queue
                 # application is FIFO, so every op <= this id is in the
                 # retiring snapshot and every later one in the shadow
@@ -2460,7 +2496,8 @@ class AggregationEngine:
             # fourth field names the earlier stamp it nests under
             phases = [("swap", t_start, t_swap)]
             snap, overflow, did = self._land_retired(
-                snap, overflow, dirty, stages, imports, retired_seq)
+                snap, overflow, sidestep, dirty, stages, imports,
+                retired_seq)
             # the retired stage's landing belongs to this flush
             for name, n in did.items():
                 imported[name] += n
@@ -2476,7 +2513,7 @@ class AggregationEngine:
                 self._flush_import_scalars()
                 snap = self._swap_banks()
                 dirty = self._retire_dirty()
-                overflow = self._retire_overflow()
+                overflow, sidestep = self._retire_overflow()
                 self._gauge_seq = 0
                 retired_wm = self.last_import_op
                 (active, status, stats_samples, dropped, histo_key_count,
@@ -2490,6 +2527,9 @@ class AggregationEngine:
                                           overflow=overflow)
         self._last_flush_info.update(imported)
         self._last_flush_info.update(keys)
+        if sidestep is not None:
+            self._last_flush_info.update(sidestep_rows=sidestep[0],
+                                         sidestep_bank=sidestep[1])
         t_device = time.monotonic_ns()
 
         def slot_rows(kind, infos):
@@ -2721,6 +2761,11 @@ class AggregationEngine:
             # interval (veneur.ingest.overflow_*_total)
             "overflow_rows": self._last_flush_info.get("overflow_rows", 0),
             "overflow_bank": self._last_flush_info.get("overflow_bank", 0),
+            # hot rows the hot-slot sidestep landed through a work set,
+            # and whole-bank passes it made
+            # (veneur.ingest.sidestep_*_total)
+            "sidestep_rows": self._last_flush_info.get("sidestep_rows", 0),
+            "sidestep_bank": self._last_flush_info.get("sidestep_bank", 0),
             # import batches applied this interval and the metrics in
             # them (veneur.import.batches_total / batch_metrics_total)
             "import_batches": imported["import_batches"],

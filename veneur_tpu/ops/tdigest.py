@@ -748,7 +748,9 @@ def merge_scalars(bank: TDigestBank, slots, vmins, vmaxs, vsums, counts,
 # gather_rows -> compress -> fill_buffers -> compress -> scatter_rows
 # over the [R, .] rows one landing touches, instead of compress ->
 # merge_centroids -> compress over the bank. The compress passes are
-# this module's `compress`, on the part.
+# this module's `compress`, on the part. (The ingest's hot-slot
+# sidestep, models/pipeline._land_hot, takes the first three steps and
+# the scatter over its hot rows, with the ingest executables' compress.)
 
 @jax.jit
 def gather_rows(bank: TDigestBank, rows) -> TDigestBank:

@@ -2113,6 +2113,7 @@ class Server:
         status_metrics = []
         eng_stats = {"samples": 0, "dropped_no_slot": 0,
                      "overflow_rows": 0, "overflow_bank": 0,
+                     "sidestep_rows": 0, "sidestep_bank": 0,
                      "import_batches": 0, "import_metrics": 0,
                      "import_land_rows": 0, "import_land_bank": 0,
                      "keys_interned": 0, "keys_evicted": 0, "keys_live": 0,
@@ -2887,6 +2888,10 @@ class Server:
             # one, and passes over a whole histogram bank (the dear arm)
             tel.mark(S, "ingest.overflow_rows", eng_stats["overflow_rows"])
             tel.mark(S, "ingest.overflow_bank", eng_stats["overflow_bank"])
+            # the hot-slot sidestep: hot rows landed through a work
+            # set, and passes it made over a whole histogram bank
+            tel.mark(S, "ingest.sidestep_rows", eng_stats["sidestep_rows"])
+            tel.mark(S, "ingest.sidestep_bank", eng_stats["sidestep_bank"])
             # the key tables, all banks and engines summed: keys minted
             # into a slot in the interval, keys the idle TTL evicted at
             # this flush, keys holding a slot after it

@@ -22,7 +22,13 @@ HISTOGRAM ENGINES — own a bank NamedTuple with:
     twins (identical names across engines — the flush program and the
     generic aggregate/merge helpers below consume them by name);
   * `num_slots` / `num_centroids` / `buf_size` properties (buf_size =
-    the per-slot batch headroom the hot-slot sidestep pre-clusters to).
+    what a row takes in one landing before it must be compressed. A
+    batch that brings one slot more is the hot-slot sidestep's case,
+    models/pipeline._land_histos: the slot's samples are pre-clustered
+    on the host to buf_size points, which land after one compress of
+    the hot rows in place of add_batch's ceil(n / buf_size) row
+    passes; over those rows alone where the engine has gather_rows /
+    fill_buffers / scatter_rows, the import_strategy "cluster").
   Methods (pure, jit-composable unless noted):
     init(num_slots) -> bank
     add_batch_impl(bank, slots, values, weights) -> bank

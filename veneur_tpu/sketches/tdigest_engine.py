@@ -105,7 +105,8 @@ class TDigestEngine:
                                      counts, recips)
 
     # the import landing's work set: gather -> compress -> fill ->
-    # compress -> scatter over the rows a landing touches
+    # compress -> scatter over the rows a landing touches (and the
+    # hot-slot sidestep's, which leaves the points in the buffers)
 
     def gather_rows(self, bank, rows):
         return tdigest.gather_rows(bank, rows)
