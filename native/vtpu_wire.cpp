@@ -1,14 +1,20 @@
-// vtpu_wire — one pass over the bytes of a forwardrpc.MetricList, for the
-// global tier's import worker (veneur_tpu/cluster/wire.py:BatchDecoder).
+// vtpu_wire — the two native passes of the forward's wire, one a side
+// (veneur_tpu/cluster/wire.py): the receiver's, over the bytes of a
+// forwardrpc.MetricList for the global tier's import worker
+// (BatchDecoder), and the sender's, from an export's columns to the
+// bytes of a MetricList's `metrics` for a local's gRPC forwarder
+// (encode_export).
 //
 // A translation unit of its own: it shares nothing with vtpu_ingest.cpp,
-// starts no thread and keeps no state. The one entry point reads a
+// starts no thread and keeps no state. The decoder's entry point reads a
 // request's sketches out of its serialized bytes into columns the caller
 // allocated; Python then builds decode_metric_batch's records from the
 // columns, a dictionary lookup a sketch instead of a protobuf attribute a
-// field.
+// field. The encoder's writes every sketch of a flush's export into one
+// buffer the caller allocated, from flat arrays, so that the sender holds
+// no protobuf object a sketch or a centroid.
 //
-// What the pass is sure of is the plain shape export_to_metrics writes
+// What the decoder is sure of is the plain shape export_to_metrics writes
 // (veneur_tpu/cluster/protos/metric.proto, fields in number order, each
 // at most once, `tags` alone repeated, one member of the `value` oneof,
 // centroids as repeated Centroid messages). A metric of any other shape
@@ -18,6 +24,13 @@
 // K_FALLBACK and left to the Python decoder, which reads it from the
 // parsed message: the two decoders then agree by construction. Nothing
 // outside [buf, buf + len) is ever read.
+//
+// The encoder writes that same plain shape, byte for byte what protobuf's
+// serializer gives for export_to_metrics' objects (proto3: a scalar or an
+// enum whose bits are zero is left out, a member of the oneof is written
+// even when empty). An export with a metric it will not write (a counter
+// that is no int64, bytes that do not fit the buffer) it refuses whole,
+// and export_to_metrics writes it or raises, as it did before this pass.
 
 #include <cstdint>
 #include <cstring>
@@ -284,6 +297,230 @@ int64_t vtpu_wire_decode(const uint8_t* buf, int64_t len,
     row++;
   }
   return row == n ? c.n : -1;
+}
+
+}  // extern "C"
+
+// ---- the sender's pass ----
+
+namespace {
+
+enum : uint8_t { T_COUNTER = 0, T_GAUGE = 1, T_SET = 3 };   // metricpb.Type
+enum : uint8_t { SCOPE_GLOBAL = 2 };                        // metricpb.Scope
+
+inline int64_t varint_size(uint64_t v) {
+  int64_t n = 1;
+  while (v >= 0x80) { v >>= 7; n++; }
+  return n;
+}
+
+// a length-delimited field of `n` payload bytes under a one-byte tag
+inline int64_t ld_size(int64_t n) { return 1 + varint_size(n) + n; }
+
+inline uint8_t* put_varint(uint8_t* p, uint64_t v) {
+  while (v >= 0x80) { *p++ = static_cast<uint8_t>(v) | 0x80; v >>= 7; }
+  *p++ = static_cast<uint8_t>(v);
+  return p;
+}
+
+inline uint8_t* put_ld(uint8_t* p, uint8_t tag, int64_t n) {
+  *p++ = tag;
+  return put_varint(p, static_cast<uint64_t>(n));
+}
+
+inline uint8_t* put_bytes(uint8_t* p, uint8_t tag, const uint8_t* b,
+                          int64_t n) {
+  p = put_ld(p, tag, n);
+  std::memcpy(p, b, n);
+  return p + n;
+}
+
+// proto3 leaves out a double whose bits are all zero: 0.0, not -0.0
+inline bool present(double v) {
+  uint64_t bits;
+  std::memcpy(&bits, &v, 8);
+  return bits != 0;
+}
+
+inline uint8_t* put_double(uint8_t* p, uint8_t tag, double v) {
+  if (!present(v)) return p;
+  *p++ = tag;
+  std::memcpy(p, &v, 8);
+  return p + 8;
+}
+
+// `tags` (2), one a comma-separated piece of the key's joined tags,
+// an empty piece too; none where the key has no tags at all
+int64_t tags_size(const uint8_t* t, int64_t n) {
+  if (n == 0) return 0;
+  int64_t size = 0, piece = 0;
+  for (int64_t i = 0; i <= n; i++) {
+    if (i == n || t[i] == ',') { size += ld_size(piece); piece = 0; }
+    else piece++;
+  }
+  return size;
+}
+
+uint8_t* put_tags(uint8_t* p, const uint8_t* t, int64_t n) {
+  if (n == 0) return p;
+  int64_t start = 0;
+  for (int64_t i = 0; i <= n; i++) {
+    if (i == n || t[i] == ',') {
+      p = put_bytes(p, 0x12, t + start, i - start);
+      start = i + 1;
+    }
+  }
+  return p;
+}
+
+// metricpb.TDigest: a Centroid a weight above zero, then the five
+// exact statistics
+template <typename T>
+int64_t tdigest_size(const T* means, const T* weights, int64_t k,
+                     const double* stats) {
+  int64_t size = 0;
+  for (int64_t j = 0; j < k; j++)
+    if (weights[j] > 0)
+      size += 2 + 9 + (present(static_cast<double>(means[j])) ? 9 : 0);
+  for (int s = 0; s < 5; s++) size += present(stats[s]) ? 9 : 0;
+  return size;
+}
+
+template <typename T>
+uint8_t* put_tdigest(uint8_t* p, const T* means, const T* weights,
+                     int64_t k, const double* stats) {
+  for (int64_t j = 0; j < k; j++) {
+    if (!(weights[j] > 0)) continue;
+    const double mean = static_cast<double>(means[j]);
+    p = put_ld(p, 0x0a, 9 + (present(mean) ? 9 : 0));
+    p = put_double(p, 0x09, mean);
+    p = put_double(p, 0x11, static_cast<double>(weights[j]));
+  }
+  for (int s = 0; s < 5; s++)
+    p = put_double(p, static_cast<uint8_t>(0x11 + 8 * s), stats[s]);
+  return p;
+}
+
+// the pass, for centroids held as T (vtpu_wire_encode, below)
+template <typename T>
+int64_t encode(const int64_t* counts, const uint8_t* names,
+               const int64_t* name_off, const uint8_t* tags,
+               const int64_t* tag_off, const uint8_t* types,
+               const int64_t* cent_off, const T* means, const T* weights,
+               const double* stats, const uint8_t* sets,
+               const int64_t* set_off, const double* counters,
+               const double* gauges, uint8_t* out, int64_t cap,
+               int64_t* off, int64_t* body) {
+  const int64_t n_h = counts[0], n_s = counts[1], n_c = counts[2];
+  const int64_t n = n_h + n_s + n_c + counts[3];
+  uint8_t* p = out;
+  for (int64_t i = 0; i < n; i++) {
+    off[i] = p - out;
+    const int64_t nl = name_off[i + 1] - name_off[i];
+    const int64_t tl = tag_off[i + 1] - tag_off[i];
+    if (nl < 0 || tl < 0) return -1;
+    const uint8_t* name = names + name_off[i];
+    const uint8_t* tag = tags + tag_off[i];
+    // the member of the oneof: its field's tag, its payload's size
+    // (`value`), and what the payload is made from
+    uint8_t type, member;
+    int64_t value, inner = 0, k = 0, j = i;
+    const T* m = means;
+    const T* w = weights;
+    if (j < n_h) {                     // HistogramValue {1: TDigest}
+      type = types[j];
+      member = 0x32;
+      k = cent_off[j + 1] - cent_off[j];
+      if (k < 0) return -1;
+      m += cent_off[j];
+      w += cent_off[j];
+      inner = tdigest_size(m, w, k, stats + 5 * j);
+      value = ld_size(inner);
+    } else if ((j -= n_h) < n_s) {     // SetValue {1: bytes}
+      type = T_SET;
+      member = 0x3a;
+      inner = set_off[j + 1] - set_off[j];
+      if (inner < 0) return -1;
+      value = inner ? ld_size(inner) : 0;
+    } else if ((j -= n_s) < n_c) {     // CounterValue {1: int64}
+      type = T_COUNTER;
+      member = 0x22;
+      const double v = counters[j];
+      if (!(v >= -9223372036854775808.0 && v < 9223372036854775808.0))
+        return -1;
+      inner = static_cast<int64_t>(v);
+      value = inner ? 1 + varint_size(static_cast<uint64_t>(inner)) : 0;
+    } else {                           // GaugeValue {1: double}
+      j -= n_c;
+      type = T_GAUGE;
+      member = 0x2a;
+      value = present(gauges[j]) ? 9 : 0;
+    }
+    const int64_t size = (nl ? ld_size(nl) : 0) + tags_size(tag, tl) +
+                         (type ? 2 : 0) + ld_size(value) + 2;
+    if (ld_size(size) > cap - (p - out)) return -1;
+    body[i] = size;
+    p = put_ld(p, 0x0a, size);
+    if (nl) p = put_bytes(p, 0x0a, name, nl);
+    p = put_tags(p, tag, tl);
+    if (type) { *p++ = 0x18; *p++ = type; }
+    p = put_ld(p, member, value);
+    if (member == 0x32) {
+      p = put_tdigest(put_ld(p, 0x0a, inner), m, w, k, stats + 5 * j);
+    } else if (member == 0x3a) {
+      if (inner) p = put_bytes(p, 0x0a, sets + set_off[j], inner);
+    } else if (member == 0x22) {
+      if (inner) p = put_varint(put_varint(p, 0x08), static_cast<uint64_t>(inner));
+    } else {
+      p = put_double(p, 0x09, gauges[j]);
+    }
+    *p++ = 0x40;
+    *p++ = SCOPE_GLOBAL;
+  }
+  off[n] = p - out;
+  return p - out;
+}
+
+}  // namespace
+
+extern "C" {
+
+// Write an export's sketches as the `metrics` of a forwardrpc.MetricList:
+// each a length-delimited field 1 whose payload is the metricpb.Metric
+// that export_to_metrics builds for it, in its order: `counts[0]`
+// histograms, `counts[1]` sets, `counts[2]` counters, `counts[3]`
+// gauges, n in all. A key's name is names[name_off[i] : name_off[i + 1]]
+// and its joined tags the same span of `tags` (UTF-8; every offset array
+// starts at 0 and never falls). Histogram j has the wire type `types[j]`,
+// the centroids [cent_off[j], cent_off[j + 1]) of `means` / `weights`
+// (f64 where `wide`, else f32; one with no weight above zero is left
+// out) and the statistics stats[j][0..5): min, max, sum, count,
+// reciprocal_sum. Set j's payload is sets[set_off[j] : set_off[j + 1]];
+// counter j's value `counters[j]`, rounded by the caller, is written as
+// the int64 it is; gauge j's is `gauges[j]`. Metric i is written at
+// out[off[i] : off[i + 1]] and body[i] is its Metric's size (what
+// ByteSize() says). Returns the bytes written, or -1 for an export the
+// pass will not write: a counter that is no int64 (not finite, or past
+// 2^63), a metric that does not fit what is left of `cap`, an offset
+// array that falls.
+int64_t vtpu_wire_encode(const int64_t* counts, const uint8_t* names,
+                         const int64_t* name_off, const uint8_t* tags,
+                         const int64_t* tag_off, const uint8_t* types,
+                         const int64_t* cent_off, const void* means,
+                         const void* weights, const double* stats,
+                         const uint8_t* sets, const int64_t* set_off,
+                         const double* counters, const double* gauges,
+                         int64_t wide, uint8_t* out, int64_t cap,
+                         int64_t* off, int64_t* body) {
+  if (wide)
+    return encode(counts, names, name_off, tags, tag_off, types, cent_off,
+                  static_cast<const double*>(means),
+                  static_cast<const double*>(weights), stats, sets, set_off,
+                  counters, gauges, out, cap, off, body);
+  return encode(counts, names, name_off, tags, tag_off, types, cent_off,
+                static_cast<const float*>(means),
+                static_cast<const float*>(weights), stats, sets, set_off,
+                counters, gauges, out, cap, off, body);
 }
 
 }  // extern "C"

@@ -16,6 +16,9 @@ from __future__ import annotations
 import json
 import logging
 import urllib.request
+from bisect import bisect_right
+
+import numpy as np
 
 from ..models.pipeline import ForwardExport
 from ..observe.recorder import stamping_scope
@@ -88,16 +91,23 @@ def _chunk_bounds(metrics: list, max_count: int,
     single larger metric rides alone). Greedy from any chunk START
     reproduces the original boundaries after it, so a replayed tail
     re-chunks to the chunk ids it was first sent under."""
-    bounds, start, size = [], 0, 0
-    for i, m in enumerate(metrics):
-        b = m.ByteSize()
-        if i > start and (i - start >= max_count
-                          or size + b > max_bytes):
-            bounds.append((start, i))
-            start, size = i, 0
-        size += b
-    if start < len(metrics):
-        bounds.append((start, len(metrics)))
+    return _size_bounds([m.ByteSize() for m in metrics], max_count,
+                        max_bytes)
+
+
+def _size_bounds(sizes, max_count: int,
+                 max_bytes: int = MAX_CHUNK_BYTES) -> list:
+    """_chunk_bounds' rule over the metrics' sizes, a step a chunk:
+    a chunk ends at `max_count` metrics or before the first that
+    would take it past `max_bytes`, and holds one at least."""
+    n = len(sizes)
+    ends = np.cumsum(sizes, dtype=np.int64).tolist()
+    bounds, start, sent = [], 0, 0
+    while start < n:
+        fit = bisect_right(ends, sent + max_bytes)
+        end = min(max(fit, start + 1), start + max_count, n)
+        bounds.append((start, end))
+        start, sent = end, ends[end - 1]
     return bounds
 
 
@@ -126,6 +136,10 @@ class GrpcForwarder:
         self._egress = egress or Egress(f"grpc://{address}",
                                         policy=egress_policy)
         self._channel = grpc_channel(address)
+        # the pass that writes a send's sketches from columns (None
+        # where libvtpu_wire cannot be had: export_to_metrics writes
+        # them), built and loaded before the first flush needs it
+        self._encode = wire.native_encode_fn()
         # takes a SERIALIZED MetricList: every chunk is serialized once
         # up front (its own flight-recorder phase, apart from the wait
         # for the far end), so a retry re-sends the same bytes and the
@@ -146,6 +160,15 @@ class GrpcForwarder:
         budget — N batches cannot stall the flush tick for
         N x retry_deadline.
 
+        The sketches' bytes come from one native pass over the
+        export's columns (wire.encode_export), and no protobuf object
+        is built a sketch; where the centroid row is q16, the library
+        cannot be had or the pass refuses the export, from
+        wire.export_to_metrics' objects as before. Either way a
+        request is its metrics' bytes and then what protobuf
+        serializes of a MetricList without them (envelope, advisory
+        rows, stamp: the higher field numbers), the same bytes.
+
         Flight-recorder phases, beside the ladder's `egress.attempt`
         (what is left of a chunk: wire, far end, reply):
         `forward.export` and `forward.chunk.plan` once a send,
@@ -153,12 +176,25 @@ class GrpcForwarder:
         chunk, `forward.release` once more at the end."""
         tick, par = stamping_scope()
         ph = tick.start("forward.export", par)
-        metrics = wire.export_to_metrics(export,
-                                         codec=self.centroid_codec)
-        tick.finish(ph, n_metrics=len(metrics))
+        encoded = metrics = None
+        if self._encode is not None and self.centroid_codec == "lossless":
+            encoded = wire.encode_export(export, self._encode)
+        if encoded is None:
+            metrics = wire.export_to_metrics(export,
+                                             codec=self.centroid_codec)
+        # sketches by who wrote them, a send
+        n_native = 0 if encoded is None else len(encoded.sizes)
+        n_fallback = 0 if metrics is None else len(metrics)
+        reg, dest = self._egress.registry, self._egress.destination
+        reg.incr(dest, "forward.encode_native", n_native)
+        reg.incr(dest, "forward.encode_fallback", n_fallback)
+        tick.finish(ph, n_metrics=n_native + n_fallback,
+                    encode_native=n_native, encode_fallback=n_fallback)
         deadline = self._egress.deadline()
         ph = tick.start("forward.chunk.plan", par)
-        bounds = _chunk_bounds(metrics, self.max_per_batch)
+        bounds = (_chunk_bounds(metrics, self.max_per_batch)
+                  if encoded is None
+                  else _size_bounds(encoded.sizes, self.max_per_batch))
         tick.finish(ph)
         n_chunks = len(bounds)
         total = 0
@@ -166,10 +202,11 @@ class GrpcForwarder:
         if envelope is not None:
             total = envelope.chunk_count or (envelope.chunk_offset
                                              + n_chunks)
-        batch = None
+        batch = data = None
         for j, (i, end) in enumerate(bounds):
             ph = tick.start("forward.chunk.build", par)
-            batch = forward_pb2.MetricList(metrics=metrics[i:end])
+            batch = forward_pb2.MetricList(
+                metrics=() if metrics is None else metrics[i:end])
             if self.engine_stamp:
                 batch.sketch_engines = self.engine_stamp
             if j == 0 and export.prefix_sketches:
@@ -187,6 +224,9 @@ class GrpcForwarder:
             tick.finish(ph)
             ph = tick.start("forward.chunk.serialize", par)
             data = batch.SerializeToString()
+            if encoded is not None:
+                off = encoded.off
+                data = b"".join((encoded.data[off[i]:off[end]], data))
             tick.finish(ph, nbytes=len(data))
             try:
                 self._egress.call(self._send, data,
@@ -209,11 +249,12 @@ class GrpcForwarder:
                     _export_tail(export, i), e, delivered_chunks=j,
                     chunk_count=total or n_chunks) from e
             _count_forward_bytes(self._egress, len(data), kind)
-        # the per-sketch protobuf objects die here, under a name of
-        # their own, not at the return: freeing 100k of them is 0.1 s
-        # to 0.9 s of a send (PERF.md §5)
+        # what a send held a sketch dies here, under a name of its
+        # own, not at the return: the pass's buffer and columns, or
+        # the fallback's protobuf objects, 0.1 s to 0.9 s of a
+        # 100,000-sketch send to free (PERF.md §5)
         ph = tick.start("forward.release", par)
-        del metrics, batch
+        del encoded, metrics, batch, data
         tick.finish(ph)
 
     def send_metrics(self, metrics: list, envelope=None,

@@ -13,6 +13,7 @@ import json
 import logging
 import struct
 import threading
+from itertools import chain
 from operator import itemgetter
 from typing import NamedTuple
 
@@ -761,10 +762,16 @@ _CENTROID_WIRE_BYTES = 11
 
 _native_lock = threading.Lock()
 _native_fn = None       # vtpu_wire_decode; False where it cannot be had
+_native_encode = None   # vtpu_wire_encode, the same way
+
+_DECODE_ARGS = [ctypes.c_char_p, ctypes.c_int64, ctypes.c_void_p,
+                ctypes.c_int64] + [ctypes.c_void_p] * 4 + [ctypes.c_int64]
+_ENCODE_ARGS = [ctypes.c_void_p] * 14 + [ctypes.c_int64, ctypes.c_void_p,
+                                         ctypes.c_int64] + [ctypes.c_void_p] * 2
 
 
 def native_decode_fn():
-    """libvtpu_wire's entry point, built (at most once a checkout, like
+    """libvtpu_wire's decoder, built (at most once a checkout, like
     the ingest bridge and through the same `make`) and loaded at most
     once a process; None where the library cannot be built or loaded,
     said once in the log: every batch is then decoded in Python. The
@@ -776,23 +783,39 @@ def native_decode_fn():
     if _native_fn is None:
         with _native_lock:
             if _native_fn is None:
-                _native_fn = _load_native() or False
+                _native_fn = _load_native(
+                    ctypes.PyDLL, "vtpu_wire_decode", _DECODE_ARGS,
+                    "imports decode in Python") or False
     return _native_fn or None
 
 
-def _load_native():
+def native_encode_fn():
+    """libvtpu_wire's encoder (encode_export), had as the decoder is;
+    None where it cannot be, or where the library on disk is one from
+    before it had the entry point: every forward is then written by
+    export_to_metrics. The call lets the interpreter's lock go
+    (ctypes.CDLL): it is one call a send and writes the whole export,
+    tens of milliseconds of a 100,000-sketch tick, in which the sink
+    threads and an import worker of the same process go on."""
+    global _native_encode
+    if _native_encode is None:
+        with _native_lock:
+            if _native_encode is None:
+                _native_encode = _load_native(
+                    ctypes.CDLL, "vtpu_wire_encode", _ENCODE_ARGS,
+                    "forwards are written in Python") or False
+    return _native_encode or None
+
+
+def _load_native(dll, entry, argtypes, instead):
     from ..ingest import native
     try:
-        lib = ctypes.PyDLL(native.build(name="vtpu_wire"))
-        fn = lib.vtpu_wire_decode
+        fn = getattr(dll(native.build(name="vtpu_wire")), entry)
     except (native.NativeUnavailable, OSError, AttributeError) as e:
-        log.warning("libvtpu_wire unavailable, imports decode in "
-                    "Python: %s", e)
+        log.warning("libvtpu_wire unavailable, %s: %s", instead, e)
         return None
     fn.restype = ctypes.c_int64
-    fn.argtypes = [ctypes.c_char_p, ctypes.c_int64, ctypes.c_void_p,
-                   ctypes.c_int64] + [ctypes.c_void_p] * 4 + [
-                       ctypes.c_int64]
+    fn.argtypes = argtypes
     return fn
 
 
@@ -931,6 +954,129 @@ class BatchDecoder:
             np.concatenate([block.stats, more.stats], axis=1)[:, order],
             np.concatenate([block.means, means]),
             np.concatenate([block.weights, weights]))
+
+
+# ---- the sender's native pass: an export's columns to its bytes ----
+
+class ExportColumns(NamedTuple):
+    """A ForwardExport as flat arrays in wire order (histograms, sets,
+    counters, gauges: metric i of export_to_metrics is entry i here):
+    what vtpu_wire_encode takes. Every `*_off` is int64, starts at 0
+    and has one entry more than the things it bounds. The one seam at
+    which a flush could hand over its own planes and rows instead of a
+    tuple a key."""
+    counts: np.ndarray      # int64[4]: histograms, sets, counters, gauges
+    names: bytes            # every key's name, UTF-8, end to end
+    name_off: np.ndarray
+    tags: bytes             # every key's joined_tags, the same way
+    tag_off: np.ndarray
+    types: np.ndarray       # uint8[n_h]: metricpb.Histogram or .Timer
+    cent_off: np.ndarray    # [n_h + 1] into means / weights
+    means: np.ndarray       # float32 (float64 where an export held wider)
+    weights: np.ndarray     # the same dtype, weights <= 0 still among them
+    stats: np.ndarray       # float64[n_h, 5]: min max sum count recip
+    sets: bytes             # encode_set_payload's rows, end to end
+    set_off: np.ndarray
+    counters: np.ndarray    # float64[n_c], rounded as round() rounds
+    gauges: np.ndarray      # float64[n_g]
+
+
+def _offsets(lengths, n: int) -> np.ndarray:
+    off = np.zeros(n + 1, np.int64)
+    np.cumsum(np.fromiter(lengths, np.int64, n), out=off[1:])
+    return off
+
+
+def _utf8_column(strings: list) -> tuple:
+    """-> (the strings' UTF-8 end to end, int64 offsets[n + 1]). ASCII,
+    which is nearly every key, is encoded once, whole."""
+    joined = "".join(strings)
+    if joined.isascii():
+        return joined.encode("ascii"), _offsets(map(len, strings),
+                                                len(strings))
+    encoded = [s.encode("utf-8") for s in strings]
+    return b"".join(encoded), _offsets(map(len, encoded), len(encoded))
+
+
+def export_columns(export: ForwardExport):
+    """ForwardExport -> ExportColumns, or None for an export whose
+    centroid lists the columns cannot hold as they are (a digest with
+    more means than weights or the reverse, which export_to_metrics
+    cuts to the shorter). No loop over centroids and none that builds
+    an object a sketch: a few passes over the tuples' fields."""
+    hs = export.histograms
+    n_h = len(hs)
+    keys = [e[0] for e in hs]
+    for entries in (export.sets, export.counters, export.gauges):
+        keys += [e[0] for e in entries]
+    names, name_off = _utf8_column([k.name for k in keys])
+    tags, tag_off = _utf8_column([k.joined_tags for k in keys])
+    means, weights = [e[1] for e in hs], [e[2] for e in hs]
+    if list(map(len, means)) != list(map(len, weights)):
+        return None
+    cent_off = _offsets(map(len, means), n_h)
+    means = np.concatenate(means) if n_h else np.empty(0, np.float32)
+    weights = np.concatenate(weights) if n_h else np.empty(0, np.float32)
+    if not (means.dtype == weights.dtype == np.float32):
+        # a re-merged or hand-built export: float() of each, as the
+        # protobuf setter takes it
+        means = means.astype(np.float64)
+        weights = weights.astype(np.float64)
+    payloads = [encode_set_payload(export.set_engine, regs)
+                for _key, regs in export.sets]
+    return ExportColumns(
+        np.array([n_h, len(export.sets), len(export.counters),
+                  len(export.gauges)], np.int64),
+        names, name_off, tags, tag_off,
+        np.fromiter((_TYPE_TO_PB.get(k.type, metric_pb2.Histogram)
+                     for k in keys[:n_h]), np.uint8, n_h),
+        cent_off, means, weights,
+        np.fromiter(chain.from_iterable(e[3:8] for e in hs), np.float64,
+                    5 * n_h).reshape(n_h, 5),
+        b"".join(payloads), _offsets(map(len, payloads), len(payloads)),
+        np.rint(np.array([v for _key, v in export.counters], np.float64)),
+        np.array([v for _key, v in export.gauges], np.float64))
+
+
+# what a metric can take on the wire beyond its key's, its set's and its
+# centroids' bytes: the lengths and tags of every level, type, scope, a
+# digest's five statistics (45) or a counter's varint (11)
+_METRIC_WIRE_SLACK = 96
+_CENTROID_WIRE_MAX = 20     # tag, length, two tagged doubles
+
+
+class EncodedMetrics(NamedTuple):
+    """An export's sketches as the bytes of a MetricList's `metrics`
+    (encode_export): metric i of export_to_metrics(export) lies at
+    data[off[i]:off[i + 1]], a length-delimited field 1, and
+    `sizes[i]` is what its ByteSize() would say."""
+    data: memoryview
+    off: list               # n + 1 offsets into `data`
+    sizes: np.ndarray       # int64[n]
+
+
+def encode_export(export: ForwardExport, fn):
+    """ForwardExport -> EncodedMetrics through `fn` (native_encode_fn's
+    entry point), without a protobuf object a sketch or a centroid;
+    None for an export the columns or the pass will not take, which
+    the caller then hands to export_to_metrics: the pass refuses a
+    counter that is no int64, on which that raises as it always has.
+    Lossless centroids only: the q16 row is export_to_metrics'."""
+    cols = export_columns(export)
+    if cols is None:
+        return None
+    n = int(cols.counts.sum())
+    cap = (_METRIC_WIRE_SLACK * n + len(cols.names) + len(cols.tags)
+           + 6 * cols.tags.count(b",") + len(cols.sets)
+           + _CENTROID_WIRE_MAX * len(cols.means))
+    buf = np.empty(cap, np.uint8)
+    off = np.empty(n + 1, np.int64)
+    sizes = np.empty(n, np.int64)
+    if fn(*(a if isinstance(a, bytes) else a.ctypes.data for a in cols),
+          int(cols.means.dtype == np.float64), buf.ctypes.data, cap,
+          off.ctypes.data, sizes.ctypes.data) < 0:
+        return None
+    return EncodedMetrics(memoryview(buf), off.tolist(), sizes)
 
 
 def _split_tags(joined: str) -> list[str]:
