@@ -60,16 +60,16 @@ worker/ring/bank drop, nothing parked in the forwarder, and
 errors, so the smoke judges by what ARRIVED and by the counters, never
 by the absence of an exception.
 
-Two more legs: the KERNEL leg hands each Pallas kernel to Mosaic at its
-serving shape and, where it builds, checks it against its XLA twin on
-the chip, jit against jit; the MESH leg (>= 4 devices) sends the same
+Two more legs: the KERNEL leg builds the one Pallas kernel (hll_stats)
+at its serving shape and checks it against the jnp reduction on the
+chip, jit against jit, both timed; the MESH leg (>= 4 devices) sends the same
 traffic into a global whose banks are sharded over four chips and
 requires its answers to equal the one-chip global's.
 
 No chip, no run: unless JAX's first device is a TPU the default
 invocation exits non-zero naming the platform it found and prints no
 result. `--cpu-dryrun` is the one explicit way to run elsewhere: tiny
-sizes, `tpu_fused_kernels: on` (interpret mode), output marked
+sizes, the kernel leg under the Pallas interpreter, output marked
 "dryrun": true.
 
 The last line of standard output is one JSON object:
@@ -112,19 +112,18 @@ class Sizes:
     batch_size: int
     set_slots: int
     hll_rows: int             # kernel leg: register-file rows
-    ull_batch: int            # kernel leg: insert batch
 
 
 FULL = Sizes(timer_keys=100_000, histogram_slots=131_072, hot_keys=1_000,
              hot_samples=2_000, cold_samples=4, set_keys=1_000,
              set_members=1_000, counters=1_000, gauges=1_000,
              buffer_depth=256, pump_batch=1 << 15, batch_size=8192,
-             set_slots=4096, hll_rows=4096, ull_batch=8192)
+             set_slots=4096, hll_rows=4096)
 DRYRUN = Sizes(timer_keys=400, histogram_slots=512, hot_keys=40,
                hot_samples=600, cold_samples=4, set_keys=16,
                set_members=300, counters=20, gauges=20,
                buffer_depth=256, pump_batch=2048, batch_size=512,
-               set_slots=64, hll_rows=64, ull_batch=512)
+               set_slots=64, hll_rows=64)
 
 PERCENTILES = (0.5, 0.75, 0.99)
 P50_TOL, P99_TOL, SET_TOL = 0.01, 0.02, 0.03
@@ -429,8 +428,8 @@ def check_window(chk: Checker, ref: dict, local: dict, glob: dict,
 
 # ------------------------------------------------------------------ tiers
 
-def tier_configs(sizes: Sizes, fused: str, backend: str,
-                 global_devices: int, grpc_port: int | None):
+def tier_configs(sizes: Sizes, backend: str, global_devices: int,
+                 grpc_port: int | None):
     """The two servers' config text — what an operator would put in
     the YAML files, sizes aside."""
     # flush_timeout / retry_deadline: the forward of 100k sketches is
@@ -444,7 +443,6 @@ interval: "3600s"
 flush_timeout: "60s"
 retry_deadline: "120s"
 aggregation_backend: {backend}
-tpu_fused_kernels: "{fused}"
 tpu_histogram_slots: {sizes.histogram_slots}
 tpu_set_slots: {sizes.set_slots}
 tpu_buffer_depth: {sizes.buffer_depth}
@@ -520,9 +518,8 @@ def send_window(srv, dgrams: list, n_lines: int, timeout_s: float):
         raise TimeoutError("local tier did not drain its rings")
 
 
-def run_tiers(sizes: Sizes, seed: int, fused: str, backend: str,
-              global_devices: int, meter: CompileMeter, chk: Checker,
-              label: str) -> dict:
+def run_tiers(sizes: Sizes, seed: int, backend: str, global_devices: int,
+              meter: CompileMeter, chk: Checker, label: str) -> dict:
     """Two tiers, three windows; returns the leg's report, including
     what the global emitted per window (for the mesh comparison)."""
     import jax
@@ -538,13 +535,13 @@ def run_tiers(sizes: Sizes, seed: int, fused: str, backend: str,
     snap = meter.snapshot()
     gcap, lcap = CaptureMetricSink(), CaptureMetricSink()
     gsrv = Server(read_config(text=tier_configs(
-        sizes, fused, backend, global_devices, None), env={}),
+        sizes, backend, global_devices, None), env={}),
         sinks=[gcap])
     lsrv = None
     try:
         gsrv.start()
         lsrv = Server(read_config(text=tier_configs(
-            sizes, fused, backend, 1, gsrv.grpc_port), env={}),
+            sizes, backend, 1, gsrv.grpc_port), env={}),
             sinks=[lcap])
         lsrv.start()
         leng, geng = lsrv.engines[0], gsrv.engines[0]
@@ -552,12 +549,10 @@ def run_tiers(sizes: Sizes, seed: int, fused: str, backend: str,
         report["setup_compile"] = meter.since(snap)
         report["forward_wire"] = type(
             getattr(lsrv.forwarder, "inner", lsrv.forwarder)).__name__
-        report["arms"] = {"local": dict(leng._kernel_arms),
-                          "global": dict(geng._kernel_arms)}
         report["global_engine"] = type(geng).__name__
         log(f"[{label}] set-up {report['setup_s']}s "
             f"(compile: {report['setup_compile']}); forward wire "
-            f"{report['forward_wire']}; arms {report['arms']}")
+            f"{report['forward_wire']}")
         chk.that(lsrv.native_bridge is not None,
                  f"{label}: local tier is not on the native bridge")
         if global_devices > 1:
@@ -688,45 +683,20 @@ def compare_globals(chk: Checker, one: list, mesh: list):
 # ----------------------------------------------------------------- kernels
 
 def kernel_leg(sizes: Sizes, dryrun: bool, chk: Checker) -> dict:
-    """Each Pallas kernel, handed to Mosaic at its serving shape (the
-    interpreter at tiny shapes in a dry run) and, where it builds, held
-    against its XLA twin on the same device, jit against jit. A kernel
-    `auto` serves on a TPU must build and agree; a refused one prints
-    the compiler's message."""
+    """The one Pallas kernel, hll_stats, built at its serving shape
+    (under the interpreter at a tiny shape in a dry run) and held
+    against the jnp reduction on the same device, jit against jit,
+    both timed. It must build and agree; a refusal prints the
+    compiler's message."""
     import functools
 
     import jax
     import jax.numpy as jnp
 
-    from veneur_tpu import kernels
-    from veneur_tpu.kernels import compress, hll_stats, ull_insert
-    from veneur_tpu.ops import hll, tdigest
-    from veneur_tpu.sketches import ull
+    from veneur_tpu.kernels import hll_stats
+    from veneur_tpu.ops import hll
 
-    interpret = dryrun
     rng = np.random.default_rng(7)
-    out = {}
-
-    def attempt(name, build):
-        t0 = time.monotonic()
-        try:
-            verdict = build()
-            verdict["compiled"] = True
-        except Exception as e:      # noqa: BLE001 — the message IS the result
-            verdict = {"compiled": False,
-                       "message": f"{type(e).__name__}: {e}"[:1200]}
-        verdict["auto_arm"] = kernels.tpu_auto_arm(name)
-        verdict["seconds"] = round(time.monotonic() - t0, 2)
-        out[name] = verdict
-        log(f"[kernels] {name}: {json.dumps(verdict)}")
-        if verdict["auto_arm"] == "fused":
-            chk.that(verdict["compiled"] and verdict.get("agrees", False),
-                     f"kernel {name} serves under auto but "
-                     + ("disagrees with its XLA twin"
-                        if verdict["compiled"] else "Mosaic refused it"))
-        elif verdict["compiled"] and not interpret:
-            log(f"[kernels] NOTE {name} builds now: its TPU_AUTO_ARM "
-                "decision is stale")
 
     def timed(fn, *args):
         jax.block_until_ready(fn(*args))
@@ -734,8 +704,9 @@ def kernel_leg(sizes: Sizes, dryrun: bool, chk: Checker) -> dict:
         jax.block_until_ready(fn(*args))
         return round((time.monotonic() - t0) * 1e3, 3)
 
-    # ---- hll_stats: [4096, 16384] u8, adversarial rows included
-    def hll_case():
+    t0 = time.monotonic()
+    try:
+        # [4096, 16384] u8, adversarial rows included
         K, m = sizes.hll_rows, 1 << 14
         regs = rng.integers(0, 52, (K, m)).astype(np.uint8)
         regs[0] = 0
@@ -745,89 +716,33 @@ def kernel_leg(sizes: Sizes, dryrun: bool, chk: Checker) -> dict:
         regs[4, : m // 2] = 0
         bank = hll.HLLBank(jnp.asarray(regs))
         kern = jax.jit(functools.partial(hll_stats.hll_stats,
-                                         interpret=interpret))
+                                         interpret=dryrun))
+        twin = jax.jit(hll_stats._stats_jnp)
         ez, zs = jax.device_get(kern(bank.registers))
-        ez_j, zs_j = jax.device_get(
-            jax.jit(hll_stats._stats_jnp)(bank.registers))
+        ez_j, zs_j = jax.device_get(twin(bank.registers))
         est_k = jax.device_get(jax.jit(hll._estimate_from_stats)(
             bank, jnp.asarray(ez), jnp.asarray(zs)))
         est_j = jax.device_get(hll._estimate_jnp(bank))
         zerr = float(np.max(np.abs(zs - zs_j) / np.maximum(zs_j, 1e-9)))
         eerr = float(np.max(np.abs(est_k - est_j)
                             / np.maximum(np.abs(est_j), 1.0)))
-        return {"shape": [K, m], "ez_equal": bool(np.array_equal(ez, ez_j)),
-                "zsum_rel_err": zerr, "estimate_rel_err": eerr,
-                "agrees": bool(np.array_equal(ez, ez_j)
-                               and zerr <= 1e-4 and eerr <= 1e-4),
-                "kernel_ms": timed(kern, bank.registers),
-                "xla_ms": timed(jax.jit(hll_stats._stats_jnp),
-                                bank.registers)}
-
-    attempt("hll_stats", hll_case)
-
-    # ---- ull_insert: [4096, 8192] u8 x batch 8192, duplicates,
-    # conflicts and padding included
-    def ull_case():
-        K, m, n = sizes.hll_rows, 1 << 13, sizes.ull_batch
-        regs0 = rng.integers(0, 200, (K, m)).astype(np.uint8)
-        slots = rng.integers(-1, K, n).astype(np.int32)
-        idx = rng.integers(0, m, n).astype(np.int32)
-        idx[: n // 4] = idx[n // 4: n // 2]
-        slots[: n // 4] = slots[n // 4: n // 2]
-        vals = ((rng.integers(1, 50, n) << 2)
-                | rng.integers(0, 4, n)).astype(np.uint8)
-        args = (jnp.asarray(slots), jnp.asarray(idx), jnp.asarray(vals))
-        kern = jax.jit(functools.partial(ull_insert.fused_insert,
-                                         interpret=interpret))
-        twin = jax.jit(ull._insert_impl)
-        got = np.asarray(kern(ull.ULLBank(jnp.asarray(regs0)),
-                              *args).registers)
-        want = np.asarray(twin(ull.ULLBank(jnp.asarray(regs0)),
-                               *args).registers)
-        bank = ull.ULLBank(jnp.asarray(regs0))
-        return {"shape": [K, m], "batch": n,
-                "changed_registers": int((want != regs0).sum()),
-                "agrees": bool(np.array_equal(got, want)),
-                "kernel_ms": timed(kern, bank, *args),
-                "xla_ms": timed(twin, bank, *args)}
-
-    attempt("ull_insert", ull_case)
-
-    # ---- compress: C=256 B=256 over the row block
-    def compress_case():
-        R = 64 if dryrun else compress._BLOCK_ROWS
-        bank = tdigest.init(R, 100.0, 256)
-        n = R * 192
-        bank = tdigest.add_batch(
-            bank, rng.integers(0, R, n).astype(np.int32),
-            rng.lognormal(3, 1, n).astype(np.float32),
-            np.ones(n, np.float32), compression=100.0)
-        bank = tdigest.compress(bank, compression=100.0)
-        bank = bank._replace(
-            buf_value=jnp.asarray(
-                rng.normal(20, 30, (R, 256)).astype(np.float32)),
-            buf_weight=jnp.ones((R, 256), jnp.float32),
-            buf_n=jnp.full((R,), 256, jnp.int32))
-        kern = jax.jit(functools.partial(
-            compress.fused_compress_bank, compression=100.0,
-            interpret=interpret))
-        twin = jax.jit(functools.partial(tdigest._compress_impl,
-                                         compression=100.0))
-        got, want = kern(bank), twin(bank)
-        bitwise = all(np.array_equal(
-            np.asarray(getattr(got, f)).view(np.uint32),
-            np.asarray(getattr(want, f)).view(np.uint32))
-            for f in ("mean", "weight"))
-        close = bool(
-            np.allclose(got.weight, want.weight, rtol=1e-6)
-            and np.allclose(got.mean, want.mean, rtol=1e-4))
-        return {"shape": [R, 256, 256], "bitwise": bitwise,
-                "agrees": bitwise or close,
-                "kernel_ms": timed(kern, bank),
-                "xla_ms": timed(twin, bank)}
-
-    attempt("compress", compress_case)
-    return out
+        ez_equal = bool(np.array_equal(ez, ez_j))
+        verdict = {"compiled": True, "shape": [K, m],
+                   "ez_equal": ez_equal, "zsum_rel_err": zerr,
+                   "estimate_rel_err": eerr,
+                   "agrees": ez_equal and zerr <= 1e-4 and eerr <= 1e-4,
+                   "kernel_ms": timed(kern, bank.registers),
+                   "xla_ms": timed(twin, bank.registers)}
+    except Exception as e:      # noqa: BLE001 — the message IS the result
+        verdict = {"compiled": False,
+                   "message": f"{type(e).__name__}: {e}"[:1200]}
+    verdict["seconds"] = round(time.monotonic() - t0, 2)
+    log(f"[kernels] hll_stats: {json.dumps(verdict)}")
+    chk.that(verdict["compiled"] and verdict.get("agrees", False),
+             "kernel hll_stats "
+             + ("disagrees with the jnp reduction"
+                if verdict["compiled"] else "was refused"))
+    return {"hll_stats": verdict}
 
 
 # -------------------------------------------------------------------- main
@@ -851,9 +766,6 @@ def main(argv=None) -> int:
                     help="run off-chip: tiny sizes, interpret-mode "
                          "kernels, output marked dryrun")
     ap.add_argument("--seed", type=int, default=23)
-    ap.add_argument("--fused", choices=("auto", "on", "off"), default=None,
-                    help="tpu_fused_kernels for both tiers (default: "
-                         "auto on the chip, on in a dry run)")
     ap.add_argument("--legs", default=",".join(LEGS),
                     help="comma-separated subset of "
                          f"{','.join(LEGS)} (default: all; mesh needs "
@@ -891,7 +803,6 @@ def main(argv=None) -> int:
     cache_dir = platform.setup_compile_cache()
     meter = CompileMeter()
     sizes = DRYRUN if args.cpu_dryrun else FULL
-    fused = args.fused or ("on" if args.cpu_dryrun else "auto")
     backend = "cpu" if args.cpu_dryrun else "tpu"
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices())}
@@ -904,8 +815,7 @@ def main(argv=None) -> int:
         + ("JAX_COMPILATION_CACHE_DIR" if os.environ.get(
             "JAX_COMPILATION_CACHE_DIR") else "the checkout") + ")")
     log(f"sizes: {json.dumps(dataclasses.asdict(sizes))}")
-    log(f"seed: {args.seed}  tpu_fused_kernels: {fused}  "
-        f"dryrun: {args.cpu_dryrun}")
+    log(f"seed: {args.seed}  dryrun: {args.cpu_dryrun}")
 
     # built from what git would commit: the bridge is compiled from
     # native/vtpu_ingest.cpp in this run, and a failed build is fatal —
@@ -925,12 +835,12 @@ def main(argv=None) -> int:
         ran.append("kernels")
     one_chip = None
     if "tiers" in legs:
-        one_chip = run_tiers(sizes, args.seed, fused, backend, 1, meter,
+        one_chip = run_tiers(sizes, args.seed, backend, 1, meter,
                              chk, "tiers")
         ran.append("tiers")
     if "mesh" in legs:
         if len(jax.devices()) >= 4:
-            mesh = run_tiers(sizes, args.seed, fused, backend, 4, meter,
+            mesh = run_tiers(sizes, args.seed, backend, 4, meter,
                              chk, "mesh")
             chk.that(mesh["global_engine"] == "MeshAggregationEngine",
                      "mesh leg: the global is not a mesh engine")

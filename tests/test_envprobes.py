@@ -30,7 +30,7 @@ def test_pallas_interpret_probe_matches_reality():
                 k, out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
                 interpret=True)(jnp.zeros((8, 128), jnp.float32))
     else:
-        # present: the capability the fused-kernel parity tests consume
+        # present: the capability the kernel's tests consume
         # must actually produce numbers
         import numpy as np
 
@@ -38,17 +38,3 @@ def test_pallas_interpret_probe_matches_reality():
         regs = np.zeros((4, 512), np.uint8)
         ez, zsum = hll_stats(regs, interpret=True)
         assert float(np.asarray(ez)[0]) == 512.0
-
-
-def test_pallas_tpu_probe_matches_reality():
-    from envprobes import (PALLAS_TPU_COMPILE_MISSING,
-                           PALLAS_TPU_SKIP_REASON)
-    assert PALLAS_TPU_SKIP_REASON.startswith("environmental:")
-    from veneur_tpu import kernels
-    from veneur_tpu.utils.platform import is_tpu
-    # the probe is the written decision on the platform at hand; off a
-    # TPU it must be missing by definition
-    assert PALLAS_TPU_COMPILE_MISSING == (not (
-        is_tpu() and kernels.tpu_auto_arm("compress") == "fused"))
-    if not is_tpu():
-        assert PALLAS_TPU_COMPILE_MISSING

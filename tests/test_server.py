@@ -215,11 +215,19 @@ def test_config_validation_rejects_nonsense():
 
 
 @pytest.mark.parametrize("key, value", [
-    ("tpu_flush_fetch", "staged"), ("tpu_flush_fetch_f16", "true")])
+    ("tpu_flush_fetch", "staged"), ("tpu_flush_fetch_f16", "true"),
+    # PR 48: which kernel runs and which flush runs is the code's choice
+    # (the benchmark's deployment files still set the first)
+    ("tpu_fused_kernels", "auto"), ("tpu_fused_kernels", "on"),
+    ("tpu_fused_kernels", "off"), ("tpu_flush_incremental", "false"),
+    ("tpu_flush_incremental_threshold", "0.5"),
+    # (out of the retired validator's range: ignored all the same)
+    ("tpu_flush_incremental_threshold", "2.0"),
+    ("tpu_flush_double_buffer", "false")])
 def test_retired_keys_warn_and_load(key, value, caplog):
-    """A config that still sets a retired fetch key loads as any
-    unknown key does — warned and ignored — and the server flushes
-    what it flushes without it."""
+    """A config that still sets a retired key loads as any unknown key
+    does — warned and ignored — and the server flushes what it flushes
+    without it."""
     import logging
 
     base = """
@@ -254,6 +262,39 @@ tpu_set_slots: 32
         got = flushed(base + f"{key}: {value}\n")
     assert f"unknown config key {key!r} ignored" in caplog.text
     assert got == flushed(base) and got["r.lat.count"] == 100.0
+
+
+_DEPLOYMENTS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "perfbench", "configs")
+
+
+@pytest.mark.parametrize("name", sorted(
+    f[:-5] for f in os.listdir(_DEPLOYMENTS) if f.endswith(".json")))
+def test_benchmark_deployments_load_with_the_retired_kernel_key(name,
+                                                               caplog):
+    """Every deployment file of the benchmark still sets
+    `tpu_fused_kernels` (`on` in its rehearsal preset) and may not be
+    edited: its global tier builds and warms up as the harness builds
+    it, with the one warning, and no kernel entry point fell back."""
+    import logging
+
+    from perfbench import harness
+    from veneur_tpu import kernels
+
+    cfg = harness.load_config(name, rehearsal=True)
+    assert cfg["common"]["tpu_fused_kernels"] == "on"
+    before = kernels.fallback_total()
+    with caplog.at_level(logging.WARNING, logger="veneur_tpu.config"):
+        srv = harness.build_server(cfg, "global", {},
+                                   CaptureMetricSink(), rehearsal=True)
+    assert caplog.text.count("unknown config key") == 1
+    assert "unknown config key 'tpu_fused_kernels' ignored" in caplog.text
+    srv.start()
+    try:
+        kern = srv._debug_flush_state()["sketch_engines"]["kernels"]
+    finally:
+        srv.stop()
+    assert kern == {"estimate": "jnp", "fallback_total": before}
 
 
 @pytest.mark.slow

@@ -73,7 +73,7 @@ def _insert_members(eng, bank, slot, hashes, batch=8192):
 
 
 def _estimate(eng, bank):
-    host = jax.device_get(eng.estimate_device(bank, pallas_ok=False))
+    host = jax.device_get(eng.estimate_device(bank))
     host = {k: np.asarray(v) for k, v in host.items()}
     eng.estimate_finalize(host)
     return np.asarray(host["s_est"], np.float64)
@@ -132,6 +132,45 @@ class TestMergeCommutativity:
         a = _insert_members(eng, eng.init(3), 1, _member_hashes(3000, "x"))
         b = _insert_members(eng, eng.init(3), 1, _member_hashes(2000, "y"))
         assert _bits_equal(eng.merge_banks(a, b), eng.merge_banks(b, a))
+
+    @staticmethod
+    def _ull_batch(seed, K=11, p=9, n=2048):
+        """A pre-populated ULL bank and one batch against it: padding
+        slots, and a quarter of the batch aimed at registers another
+        quarter also writes, with conflicting packed values."""
+        rng = np.random.default_rng(seed)
+        eng = ULLEngine(precision=p)
+        regs = rng.integers(0, 200, (K, 1 << p)).astype(np.uint8)
+        slots = rng.integers(-1, K, n).astype(np.int32)
+        idx = rng.integers(0, 1 << p, n).astype(np.int32)
+        idx[: n // 4] = idx[n // 4: n // 2]
+        slots[: n // 4] = slots[n // 4: n // 2]
+        vals = ((rng.integers(1, 50, n) << 2)
+                | rng.integers(0, 4, n)).astype(np.uint8)
+        return eng, regs, (slots, idx, vals)
+
+    @pytest.mark.parametrize("case", ["again", "reversed", "shuffled"])
+    def test_ull_insert_is_a_lattice_join(self, case):
+        """The insert that serves (sort + segmented scan + gather) is a
+        join: landing a batch a second time leaves the registers as
+        they are, and the order of a batch's rows does not reach the
+        bytes — what lets a replayed or re-chunked landing be exact."""
+        eng, regs, batch = self._ull_batch(seed=7)
+        ins = _jit(eng, "insert_impl")
+
+        def land(bank, rows):
+            return ins(bank, *(jnp.asarray(c[rows]) for c in batch))
+
+        n = batch[0].size
+        warm = type(eng.init(1))(registers=jnp.asarray(regs))
+        once = land(warm, np.arange(n))
+        rows = {"again": np.arange(n),
+                "reversed": np.arange(n)[::-1].copy(),
+                "shuffled": np.random.default_rng(1).permutation(n)}[case]
+        got = land(once if case == "again" else warm, rows)
+        assert np.asarray(once.registers).tobytes() \
+            == np.asarray(got.registers).tobytes()
+        assert (np.asarray(once.registers) != regs).any()
 
     @pytest.mark.parametrize(
         "eng", [TDigestEngine(compression=100.0, buffer_depth=64),
@@ -304,6 +343,23 @@ class TestWireAndStamps:
         desc = e.engines_describe()
         assert desc["histogram"]["id"] == "req"
         assert desc["set"]["id"] == "ull"
+
+    @pytest.mark.parametrize("mesh", [False, True],
+                             ids=["single", "mesh"])
+    def test_describe_kernels_block_has_two_fields(self, mesh):
+        """/debug/flush's `sketch_engines.kernels`: what implements the
+        set estimate here and the fallback count, nothing about arms;
+        off a TPU the estimate is the jnp reduction on either engine."""
+        from veneur_tpu import kernels
+        kw = dict(histogram_slots=64, counter_slots=32, gauge_slots=32,
+                  set_slots=16, batch_size=64)
+        if mesh:
+            from veneur_tpu.parallel.engine import MeshAggregationEngine
+            e = MeshAggregationEngine(EngineConfig(**kw), n_devices=4)
+        else:
+            e = AggregationEngine(EngineConfig(**kw))
+        assert e.engines_describe()["kernels"] == {
+            "estimate": "jnp", "fallback_total": kernels.fallback_total()}
 
     def test_prefix_sketch_header_roundtrip(self):
         from veneur_tpu.cluster import wire
@@ -509,6 +565,7 @@ class TestTwoTierEngineParity:
             assert se["histogram"]["id"] == "req"
             assert se["set"]["id"] == "ull"
             assert se["set"]["params"]["precision"] == 13
+            assert sorted(se["kernels"]) == ["estimate", "fallback_total"]
         finally:
             glob.stop()
 

@@ -130,6 +130,89 @@ def test_compress_arms_bitwise_identical_randomized(seed):
         bw = rng.integers(0, 4, (K, B)).astype(np.float32)
 
 
+def warm_bank(seed, K=37, comp=100.0, B=256, adversarial=False):
+    """A bank at the serving widths with a LEGAL cluster-ordered prefix
+    (built by the compress itself) under a refilled buffer. The
+    adversarial one: ±0.0 keys, duplicates, a buffer value equal to a
+    prefix mean, a zero-weight tail, an empty buffer over a live
+    prefix, fresh rows, and in row 0 a NaN with a payload."""
+    rng = np.random.default_rng(seed)
+    bank = tdigest.init(K, comp, B)
+    slots = rng.integers(0, K, 4096).astype(np.int32)
+    vals = rng.lognormal(3, 1, 4096).astype(np.float32)
+    bank = tdigest._add_batch_impl(
+        bank, jnp.asarray(slots), jnp.asarray(vals),
+        jnp.ones(4096, jnp.float32), comp)
+    bank = tdigest._compress_impl(bank, comp)
+    bv = rng.normal(20, 30, (K, B)).astype(np.float32)
+    bw = (np.abs(rng.normal(1, 0.5, (K, B))) + 0.01).astype(np.float32)
+    if adversarial:
+        bv[:, 0] = -0.0
+        bv[:, 1] = 0.0
+        bv[:, 2] = bv[:, 3]
+        bv[:, 5] = np.asarray(bank.mean)[:, 0]
+        bv[0, 4] = np.uint32(NAN_PAYLOAD).view(np.float32)
+        bw[2, 100:] = 0.0
+        bw[3, :] = 0.0
+        bw[np.asarray(bank.weight).sum(axis=1) == 0] = 0.0
+    return bank._replace(buf_value=jnp.asarray(bv),
+                         buf_weight=jnp.asarray(bw),
+                         buf_n=jnp.full((K,), B, jnp.int32))
+
+
+NAN_PAYLOAD = 0x7FC01234
+
+
+def overflow_clip_bank():
+    """More natural clusters than centroid lanes (C = 64 under
+    compression 100): the greedy cluster ids run past C and are
+    clipped to C - 1, so the last lane absorbs the tail."""
+    rng = np.random.default_rng(9)
+    K, C, B = 5, 64, 512
+    bv = np.sort(rng.normal(0, 100, (K, B))).astype(np.float32)
+    bank = tdigest.init(K, compression=100.0, buf_size=B)
+    zc = jnp.zeros((K, C), jnp.float32)
+    return bank._replace(
+        mean=zc, weight=zc, buf_value=jnp.asarray(bv),
+        buf_weight=jnp.ones((K, B), jnp.float32),
+        buf_n=jnp.full((K,), B, jnp.int32))
+
+
+@pytest.mark.parametrize("case", ["adversarial", "warm1", "warm2",
+                                  "overflow_clip"])
+def test_compress_arms_bitwise_identical_warm_prefix(case):
+    """The serving compress against the full-row sort where the prefix
+    is warm at the serving widths, and where the cluster ids clip."""
+    comp = 100.0
+    bank = (overflow_clip_bank() if case == "overflow_clip" else
+            warm_bank({"adversarial": 0, "warm1": 1, "warm2": 2}[case],
+                      adversarial=case == "adversarial"))
+    old, new = compress_both(bank, comp)
+    # the full-row reference cannot take a NaN key (its place in a
+    # comparator sort is undefined, see adversarial_bank): row 0 of the
+    # adversarial bank is held to the compress's own invariants below
+    rows = slice(1, None) if case == "adversarial" else slice(None)
+    assert_banks_identical(
+        jax.tree_util.tree_map(lambda x: x[rows], old),
+        jax.tree_util.tree_map(lambda x: x[rows], new))
+    if case == "overflow_clip":
+        assert float(np.asarray(new.weight)[:, -1].min()) > 1.0
+    if case == "adversarial":
+        mean, weight = np.asarray(new.mean)[0], np.asarray(new.weight)[0]
+        total = (np.asarray(bank.weight)[0].sum(dtype=np.float64)
+                 + np.asarray(bank.buf_weight)[0].sum(dtype=np.float64))
+        assert weight.sum(dtype=np.float64) == pytest.approx(total,
+                                                             rel=1e-6)
+        # the NaN leaves as a centroid of its own, payload bits and
+        # weight kept; the other live means are cluster-ordered
+        nan = np.isnan(mean)
+        assert mean[nan].view(np.uint32).tolist() == [NAN_PAYLOAD]
+        # (a cluster's weight is a difference of running sums)
+        assert weight[nan] == pytest.approx(
+            np.asarray(bank.buf_weight)[0, 4], rel=1e-4)
+        assert np.all(np.diff(mean[(weight > 0) & ~nan]) >= 0)
+
+
 def test_add_batch_overflow_loop_arms_identical():
     """Rows mid-overflow-loop: a batch far larger than the buffer runs
     compress inside the while_loop body — it must land what the
@@ -202,31 +285,32 @@ def test_compress_output_prefix_is_cluster_ordered():
             "positive-weight means must be non-decreasing"
 
 
-def test_tpu_forms_equal_the_gather_forms():
+@pytest.mark.parametrize("K,M,C,rate", [(23, 512, 256, 0.3),
+                                        (5, 576, 64, 0.9)],
+                         ids=["random", "overflow_clip"])
+def test_tpu_forms_equal_the_gather_forms(K, M, C, rate):
     """The compress picks, per platform, between forms that must give
     ONE result: a binary search or a count for the cluster end
     positions, a gather or a select-and-sum to read the cumulative
     rows there, the merge path or the plain row sort. The TPU forms run
-    here on the CPU, next to the forms the CPU serves."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from veneur_tpu.ops import tdigest
-
+    here on the CPU, next to the forms the CPU serves. `overflow_clip`
+    is overflow_clip_bank()'s shape: ids that run past C and park on
+    C - 1 from a different lane in every row, and that bank's own row
+    (an empty prefix under a sorted buffer) through both sorts."""
     rng = np.random.default_rng(11)
-    K, M, C = 23, 512, 256
-    steps = (rng.random((K, M)) < 0.3).astype(np.int32)
+    steps = (rng.random((K, M)) < rate).astype(np.int32)
     cluster = np.clip(np.cumsum(steps, axis=1) - 1, 0, C - 1)
     cluster[3] = 0                      # one cluster takes the row
     cluster[4] = C - 1                  # everything parked on the last
+    if rate > 0.5:
+        assert (cluster[:3, M // 2:] == C - 1).all()    # it clipped
     cluster = jnp.asarray(cluster.astype(np.int32))
     ends_s = jax.jit(tdigest._ends_by_search, static_argnums=1)(cluster, C)
     ends_c = jax.jit(tdigest._ends_by_count, static_argnums=1)(cluster, C)
     np.testing.assert_array_equal(np.asarray(ends_s), np.asarray(ends_c))
 
     cum = np.cumsum(rng.lognormal(0, 2, (K, M)), axis=1).astype(np.float32)
-    cum[5, 100:] = np.inf               # a real +inf rides through
+    cum[2, 100:] = np.inf               # a real +inf rides through
     padded = jnp.asarray(np.concatenate(
         [np.zeros((K, 1), np.float32), cum], axis=1))
     got_g = jax.jit(tdigest._lanes_by_gather)(padded, ends_s)
@@ -234,14 +318,23 @@ def test_tpu_forms_equal_the_gather_forms():
     np.testing.assert_array_equal(
         np.asarray(got_g).view(np.uint32), np.asarray(got_s).view(np.uint32))
 
-    vals = rng.normal(0, 50, (K, M)).astype(np.float32)
-    vals[1, 5], vals[1, M - 1] = 0.0, -0.0                 # signed zeros
-    vals[:, :M // 2] = np.sort(vals[:, :M // 2], axis=1)   # ordered prefix
-    vals[0, M // 2] = vals[0, 3]                           # a tie
-    wts = np.ones((K, M), np.float32)
+    if rate > 0.5:
+        bank = overflow_clip_bank()
+        S = bank.mean.shape[1]
+        wts = np.concatenate([bank.weight, bank.buf_weight], axis=1)
+        vals = np.where(wts > 0, np.concatenate(
+            [bank.mean, bank.buf_value], axis=1), np.inf)
+        assert vals.shape == (K, M)
+    else:
+        S = M // 2
+        vals = rng.normal(0, 50, (K, M)).astype(np.float32)
+        vals[1, 5], vals[1, M - 1] = 0.0, -0.0             # signed zeros
+        vals[:, :S] = np.sort(vals[:, :S], axis=1)         # ordered prefix
+        vals[0, S] = vals[0, 3]                            # a tie
+        wts = np.ones((K, M), np.float32)
     a = jax.jit(tdigest._row_sort)(jnp.asarray(vals), jnp.asarray(wts))
     b = jax.jit(tdigest._merge_path_sort, static_argnames="S")(
-        jnp.asarray(vals), jnp.asarray(wts), S=M // 2)
+        jnp.asarray(vals), jnp.asarray(wts), S=S)
     for x, y in zip(a, b):
         np.testing.assert_array_equal(np.asarray(x).view(np.uint32),
                                       np.asarray(y).view(np.uint32))

@@ -55,10 +55,6 @@ DEFAULT_CONFIG = {
     "sr02_allow": (
         "veneur_tpu/ops/tdigest.py",
         "veneur_tpu/sketches/req.py",
-        # the fused compress kernel (ISSUE 15) is a second
-        # invariant-preserving writer: its cummax clamp is pinned
-        # bit-identical to _cluster_core's by tests/test_pallas.py
-        "veneur_tpu/kernels/compress.py",
     ),
     # DR01: where the durable-state write discipline applies (path
     # substring match; the /dr01_ entry scopes the check's own test
@@ -127,9 +123,6 @@ DEFAULT_CONFIG = {
         "veneur_tpu/sketches/",
         "veneur_tpu/ops/",
         "veneur_tpu/parallel/",
-        # the fused-kernel twins of the ops/ math (ISSUE 15): they ARE
-        # sketch implementations and share the ops/ definitions
-        "veneur_tpu/kernels/",
     ),
     # DS01: dirty-bitmap marking discipline (path substring match;
     # /ds01_ scopes the check's own fixture in): every device-landing

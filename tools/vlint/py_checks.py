@@ -1111,11 +1111,10 @@ _SK01_BANKS = ("TDigestBank", "HLLBank", "ULLBank", "REQBank")
 _SK01_MODULES = ("ops.tdigest", "ops.hll",
                  "sketches.ull", "sketches.req",
                  "sketches.tdigest_engine", "sketches.hll_engine",
-                 "kernels.compress", "kernels.ull_insert",
                  "kernels.hll_stats")
 _SK01_LEAF_NAMES = ("tdigest", "hll", "ull", "req",
                     "tdigest_engine", "hll_engine",
-                    "compress", "ull_insert", "hll_stats")
+                    "hll_stats")
 
 
 def check_sk01(mod: PyModule, config: dict) -> list[Violation]:
@@ -1145,7 +1144,7 @@ def check_sk01(mod: PyModule, config: dict) -> list[Violation]:
                       for t in _SK01_MODULES)
             names = {a.name for a in node.names}
             # `from ..ops import tdigest, hll` / `from ..sketches
-            # import ull` / `from ..kernels import compress` forms:
+            # import ull` / `from ..kernels import hll_stats` forms:
             # the module is the parent package and the implementation
             # rides in the names list
             if not hit and (module.endswith("ops")
@@ -1239,16 +1238,16 @@ def check_pk01(mod: PyModule, config: dict) -> list[Violation]:
 
     (a) OUTSIDE veneur_tpu/kernels/, importing a pallas module or
         calling `pallas_call` is flagged — every pl.* primitive is
-        single-homed in the kernels package, where the arm-resolution/
-        probe/fallback machinery guarantees a refused backend degrades
+        single-homed in the kernels package, next to the counted
+        fallback that makes a shape a kernel cannot serve degrade
         loudly instead of crashing a serving executable.
     (b) INSIDE the kernels package, every PUBLIC function that reaches
         a `pallas_call` (directly or through module-local helpers)
         must contain a counted fallback branch — a call to the
         `count_fallback` helper (veneur.kernels.fallback_total) — so
         no kernel entry point can silently lack the degradation path.
-        Availability probes suppress with a reason (resolve_arm owns
-        their fallback accounting)."""
+        A function that is no serving entry point suppresses with a
+        reason."""
     in_kernels = any(k in mod.path
                      for k in config["pk01_kernel_paths"])
     in_scope = any(s in mod.path for s in config["pk01_scope"])
@@ -1309,8 +1308,8 @@ def check_pk01(mod: PyModule, config: dict) -> list[Violation]:
             break
     # a function is protected when it counts the fallback itself, or
     # every kernel it reaches is reached THROUGH a protected callee
-    # (delegating entry points like fused_compress_bank inherit the
-    # branch from the one entry that owns it)
+    # (a delegating entry point inherits the branch from the one
+    # entry that owns it)
     protected = {name: _pk01_counts_fallback(fn)
                  for name, fn in funcs.items()}
     for _ in range(len(funcs)):

@@ -339,34 +339,6 @@ class Config:
     # 0 or 1 = single-device engines on the first device JAX reports;
     # N > 1 = ONE mesh engine sharded over the first N devices.
     tpu_num_devices: int = 0
-    # Incremental dirty-slot flush (ISSUE 11): the flush program
-    # consumes the delta-checkpoint dirty bitmap and compresses/
-    # materializes ONLY the piles touched this interval — cold piles
-    # keep their fresh-init state and baseline rows verbatim,
-    # bit-identical to the full program. Above the threshold dirty
-    # fraction (histogram bank) the full program runs instead. Ignored
-    # (always full) with tpu_num_devices > 1 — the mesh engine owns
-    # sharded banks with no per-slot bitmaps.
-    tpu_flush_incremental: bool = True
-    tpu_flush_incremental_threshold: float = 0.75
-    # Double-buffered flush (ISSUE 11): the tick boundary only retires
-    # the interval under the ingest lock (one rebind into shadow
-    # banks); draining, import landing, and the flush program run
-    # outside it, so admit/ingest never stalls behind the flush
-    # executable or materialize. Off = legacy drain-under-lock
-    # ordering (the mesh engine always uses legacy).
-    tpu_flush_double_buffer: bool = True
-    # Fused Pallas kernels (ISSUE 15): one-kernel-per-bucket compress
-    # (t-digest sort+rank-merge+cluster with VMEM intermediates), the
-    # ULL scatter-join insert and the streaming HLL estimate
-    # reduction. "auto" = on a TPU, each kernel Mosaic builds (the
-    # decision recorded in its module, kernels/<kernel>.TPU_AUTO_ARM;
-    # today the compress is refused and serves as XLA); XLA on CPU.
-    # "on" = every kernel: on a TPU a refused one RAISES at engine
-    # construction; on CPU the interpret-mode kernels serve (testing
-    # stance; bit-identical to XLA by contract). "off" = XLA only.
-    # /debug/flush sketch_engines.kernels reports the built arms.
-    tpu_fused_kernels: str = "auto"
 
     # --- native C++ ingest bridge (native/vtpu_ingest.cpp) ---
     # When on, UDP DogStatsD ingest (readers + parse + key interning +
@@ -598,22 +570,6 @@ def _validate(cfg: Config) -> None:
             raise ValueError(
                 "non-default sketch backends are not supported with "
                 "tpu_num_devices > 1 (the mesh engine owns its banks)")
-    if not (0.0 < cfg.tpu_flush_incremental_threshold <= 1.0):
-        raise ValueError(
-            "tpu_flush_incremental_threshold must be in (0, 1]: the "
-            "dirty fraction above which the full flush program runs")
-    if cfg.tpu_fused_kernels not in ("auto", "on", "off"):
-        raise ValueError(
-            "tpu_fused_kernels must be one of auto/on/off")
-    if cfg.tpu_fused_kernels != "off" and cfg.tpu_num_devices > 1:
-        # the mesh engine builds its own sharded flush program and
-        # never consults the kernel arm — not an error (auto is the
-        # default everywhere), but "on" deserves a loud note
-        if cfg.tpu_fused_kernels == "on":
-            log.warning(
-                "tpu_fused_kernels=on is ignored with "
-                "tpu_num_devices > 1: the mesh engine serves its own "
-                "sharded flush program (XLA arm)")
     # t-digest centroid capacity is ~2*compression (fixed 100), padded to
     # 128 lanes. A buffer shallower than that makes the global import
     # path pay ceil(C/B) compress dispatches per landing round —

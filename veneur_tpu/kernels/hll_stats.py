@@ -15,10 +15,10 @@ partials that are lane-reduced at the end. The final (tiny, [K]-shaped)
 beta-polynomial arithmetic stays in plain jnp outside the kernel.
 
 Use `hll_stats(registers, interpret=True)` on CPU for tests; on a TPU
-the compiled kernel runs wherever the "estimate" arm is "fused"
-(kernels.engine_arms; the mesh flush asks ops/hll.will_use_pallas).
-(Moved here from ops/pallas_hll.py — vlint PK01 single-homes every
-pl.* primitive under veneur_tpu/kernels/.)
+the compiled kernel runs wherever ops/hll.will_use_pallas says so (the
+single-device flush through hll.estimate, the mesh flush inside its
+shard_map). vlint PK01 single-homes every pl.* primitive under
+veneur_tpu/kernels/.
 """
 
 from __future__ import annotations
@@ -42,10 +42,9 @@ except Exception as _e:             # noqa: BLE001 — probed at entry
 # the serving shape ([4096, 16384] u8) and on the chip its statistics
 # agree with the jnp reduction: ez exact, zsum 3.1e-7 and the estimate
 # 3.8e-7 relative at worst (PR 23's chip runs; chip_smoke.py's kernel
-# leg re-checks against 1e-4 on every run) — `auto` serves it, alone
-# and inside the mesh flush's shard_map. 1.2 ms against the jnp
-# reduction's 1.3 ms there (smoke observation).
-TPU_AUTO_ARM = "fused"
+# leg re-checks against 1e-4 on every run), alone and inside the mesh
+# flush's shard_map. 1.2 ms against the jnp reduction's 1.3 ms there
+# (smoke observation).
 
 # u8 min tile is (32, 128); BK=32 rows keeps every block aligned.
 _BK = 32

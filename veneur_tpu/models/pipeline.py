@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import kernels, sketches
+from .. import sketches
 from ..ingest.parser import (
     GLOBAL_ONLY, LOCAL_ONLY, MetricKey, UDPMetric)
 from ..metrics import InterMetric, MetricFrame, MetricType
@@ -220,7 +220,7 @@ def _fresh_banks_executable(device, heng, seng, histogram_slots,
 
 
 @functools.lru_cache(maxsize=None)
-def _ingest_executables(device, heng, seng, set_arm="xla"):
+def _ingest_executables(device, heng, seng):
     """Committed-output builds of the four ingest scatter kernels.
 
     The module-level ops (tdigest.add_batch & co) are plain jits whose
@@ -228,22 +228,11 @@ def _ingest_executables(device, heng, seng, set_arm="xla"):
     bank lineage committed from _fresh_banks onward, so every ingest
     batch and the following flush dispatch the executables compiled
     for exactly these arrays. Every sketch op routes through the
-    engine objects — the registry boundary (vlint SK01).
-
-    `set_arm` (ISSUE 15) selects the set-insert build: engines with a
-    fused Pallas insert (ULL's scatter-join) route through it under
-    the fused/interpret arms; everything else keeps the XLA program.
-    The arm is part of this cache's key, so an engine pair serves
-    exactly one arm per process and /debug reports it truthfully."""
+    engine objects — the registry boundary (vlint SK01)."""
     sds = jax.sharding.SingleDeviceSharding(device)
 
     jit = functools.partial(jax.jit, donate_argnums=(0,),
                             out_shardings=sds)
-    if set_arm != "xla" and hasattr(seng, "insert_fused_impl"):
-        set_insert = functools.partial(
-            seng.insert_fused_impl, interpret=(set_arm == "interpret"))
-    else:
-        set_insert = seng.insert_impl
 
     def add_batch_impl(bank, overflow, slots, values, weights):
         # (the name is the program's in a profile: jit_add_batch_impl)
@@ -261,7 +250,7 @@ def _ingest_executables(device, heng, seng, set_arm="xla"):
         "histo": jit(add_batch_impl),
         "counter": jit(scalar.counter_add.__wrapped__),
         "gauge": jit(scalar.gauge_set.__wrapped__),
-        "set": jit(set_insert),
+        "set": jit(seng.insert_impl),
         # hot-slot sidestep programs (see _land_hot; `compress` at
         # the work set's shape or the bank's)
         "compress": jit(heng.compress_impl),
@@ -270,23 +259,13 @@ def _ingest_executables(device, heng, seng, set_arm="xla"):
     }
 
 
-def _flush_program_body(heng, seng, fwd_out, agg_emit, pallas_ok,
-                        kernel_arm="xla"):
+def _flush_program_body(heng, seng, fwd_out, agg_emit):
     """The flush computation itself — compress + quantiles + the
     configured aggregates + counter/gauge/set finalization — as a
     jit-composable closure over (hb, cb, gb, sb, qs). Shared by the
     full-bank executable (_flush_executable) and the incremental
     dirty-slot executable (_inc_flush_executable), so both paths run
     the IDENTICAL math and differ only in which rows they see.
-
-    `kernel_arm` (ISSUE 15, "fused"/"interpret"/"xla") selects the
-    compress build for engines with a fused Pallas kernel: the whole
-    sort + rank-merge + cluster pipeline collapses into ONE pallas_call
-    embedded in this program (VMEM-resident intermediates — no HBM
-    round-trips between the stages), bit-identical to compress_impl by
-    the tests/test_pallas.py contract. Engines without a fused kernel
-    (REQ) ignore the arm. The arm keys every cached executable build,
-    so /debug's per-engine arm stamp can never lie about what compiled.
 
     Output contract (all f32 unless noted):
       q        [K, P']      quantile matrix (P' includes a median column
@@ -302,11 +281,7 @@ def _flush_program_body(heng, seng, fwd_out, agg_emit, pallas_ok,
       h_* / s_regs          raw forward-export state (fwd_out only)
     """
     def program(hb, cb, gb, sb, qs):
-        if kernel_arm != "xla" and hasattr(heng, "compress_fused_impl"):
-            hb = heng.compress_fused_impl(
-                hb, interpret=(kernel_arm == "interpret"))
-        else:
-            hb = heng.compress_impl(hb)
+        hb = heng.compress_impl(hb)
         agg = heng.aggregates_impl(hb)
         q = heng.quantile_impl(hb, qs)
         out = {
@@ -316,7 +291,7 @@ def _flush_program_body(heng, seng, fwd_out, agg_emit, pallas_ok,
         # set estimate: HLL emits the finished per-slot estimate; ULL
         # emits its device-side sufficient statistic and the host half
         # of estimate (estimate_finalize) finishes it after the fetch
-        out.update(seng.estimate_device(sb, pallas_ok))
+        out.update(seng.estimate_device(sb))
         cols = []
         for a in agg_emit:
             if a == "count":
@@ -341,8 +316,7 @@ def _flush_program_body(heng, seng, fwd_out, agg_emit, pallas_ok,
 
 
 @functools.lru_cache(maxsize=None)
-def _flush_executable(device, heng, seng, fwd_out, agg_emit, pallas_ok,
-                      kernel_arm="xla"):
+def _flush_executable(device, heng, seng, fwd_out, agg_emit):
     """The fused interval-flush program over the FULL banks: ONE XLA
     call over every slot (see _flush_program_body for the output
     contract). The incremental dirty-slot path (_inc_flush_executable)
@@ -350,8 +324,7 @@ def _flush_executable(device, heng, seng, fwd_out, agg_emit, pallas_ok,
     remains the oracle, the warmup/baseline program, and the serving
     path above the dirty-fraction threshold."""
     sds = jax.sharding.SingleDeviceSharding(device)
-    program = _flush_program_body(heng, seng, fwd_out, agg_emit,
-                                  pallas_ok, kernel_arm)
+    program = _flush_program_body(heng, seng, fwd_out, agg_emit)
 
     # Donation audit (ISSUE 3 satellite): an argument is donated iff
     # EVERY one of its leaves aliases an output of identical shape —
@@ -420,8 +393,7 @@ def _pad_dirty_ids(ids, num_slots: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _inc_flush_executable(device, heng, seng, fwd_out, agg_emit,
-                          pallas_ok, kernel_arm="xla"):
+def _inc_flush_executable(device, heng, seng, fwd_out, agg_emit):
     """The INCREMENTAL interval-flush program (ISSUE 11 tentpole):
     gather only the dirty piles into a compact [D, ·] work set, run the
     SAME flush body (_flush_program_body) over that slice, and return
@@ -446,8 +418,7 @@ def _inc_flush_executable(device, heng, seng, fwd_out, agg_emit,
     re-introduce the "donated buffers were not usable" warning the
     ISSUE 3 audit pins at zero."""
     sds = jax.sharding.SingleDeviceSharding(device)
-    program = _flush_program_body(heng, seng, fwd_out, agg_emit,
-                                  pallas_ok, kernel_arm)
+    program = _flush_program_body(heng, seng, fwd_out, agg_emit)
 
     def gather(bank, idx):
         return jax.tree_util.tree_map(lambda leaf: leaf[idx], bank)
@@ -460,20 +431,14 @@ def _inc_flush_executable(device, heng, seng, fwd_out, agg_emit,
 
 
 @functools.lru_cache(maxsize=None)
-def _flush_baseline_cached(device, heng, seng, fwd_out, agg_emit,
-                           pallas_ok, qs, kernel_arm="xla"):
+def _flush_baseline_cached(device, heng, seng, fwd_out, agg_emit, qs):
     """Empty-flush baseline rows (see _flush_baseline_rows), cached at
     module level so every engine with the same sketch pair + flush
     config shares one K=1 compile. The rows are read-only: a full
     resync's export hands out cold set rows as views of them, so a
-    writer must raise, not corrupt every engine's cold rows.
-    `kernel_arm` rides the key so the baseline is built by
-    the same program arm that serves (bit-identical either way — the
-    fresh row is a compress fixed point under both — but the arm
-    accounting at /debug stays truthful)."""
+    writer must raise, not corrupt every engine's cold rows."""
     from ..ops import scalar as _scalar
-    body = _flush_program_body(heng, seng, fwd_out, agg_emit,
-                               pallas_ok, kernel_arm)
+    body = _flush_program_body(heng, seng, fwd_out, agg_emit)
     fresh = jax.device_put(
         (heng.init(1), _scalar.init_counters(1),
          _scalar.init_gauges(1), seng.init(1)), device)
@@ -609,16 +574,6 @@ class EngineConfig:
     # instead (a near-full gather costs more than it saves).
     flush_incremental: bool = True
     flush_incremental_threshold: float = 0.75
-    # Fused Pallas kernels (ISSUE 15): "auto" serves, on a TPU, each
-    # kernel Mosaic was seen to build (the decision written next to it,
-    # kernels/<kernel>.TPU_AUTO_ARM) and keeps XLA on CPU; "on" names
-    # them all — on a TPU a refused kernel then RAISES at engine
-    # construction, on CPU the interpret-mode kernels serve (the testing
-    # stance — the oracle/chaos suites run the actual kernel math end to
-    # end, bit-identical by contract); "off" pins the XLA programs
-    # everywhere. /debug/flush's sketch_engines block reports the arm
-    # each engine's executables were built with.
-    fused_kernels: str = "auto"
     # Double-buffered flush (ISSUE 11): the tick boundary only RETIRES
     # the interval under the ingest lock (stage buffers, staged
     # imports, banks, dirty bitmaps swap against fresh shadows in one
@@ -826,18 +781,6 @@ class AggregationEngine:
         over a Mesh instead of single-device ones."""
         cfg = self.cfg
         self._device = jax.devices()[0]
-        # Fused-kernel arm resolution (ISSUE 15): one resolved arm per
-        # kernel-backed capability, fixed at construction — what every
-        # executable of this engine is built with and what /debug
-        # reports. `on` compiles the kernels it named "fused" (there are
-        # none off a TPU) here and raises on a refusal.
-        self._kernel_arms = kernels.engine_arms(
-            cfg.fused_kernels, self._device.platform, self._heng,
-            self._seng)
-        if cfg.fused_kernels == "on":
-            kernels.require_engine_kernels(
-                self._heng, self._seng, self._kernel_arms,
-                set_slots=cfg.set_slots, batch_size=cfg.batch_size)
         self._fresh_fn = _fresh_banks_executable(
             self._device, self._heng, self._seng, cfg.histogram_slots,
             cfg.counter_slots, cfg.gauge_slots, cfg.set_slots)
@@ -846,8 +789,7 @@ class AggregationEngine:
         (self.histo_bank, self.counter_bank,
          self.gauge_bank, self.set_bank) = self._fresh_fn()
         self._kern = _ingest_executables(self._device, self._heng,
-                                         self._seng,
-                                         self._kernel_arms["set"])
+                                         self._seng)
         self._overflow_zero = jax.device_put(
             np.zeros(2, np.int32),
             jax.sharding.SingleDeviceSharding(self._device))
@@ -855,12 +797,9 @@ class AggregationEngine:
         self._sidestep = [0, 0]
 
     def _setup_flush_exec(self):
-        cfg = self.cfg
         self._flush_exec = _flush_executable(
             self._device, self._heng, self._seng, self._fwd_out,
-            tuple(self._agg_emit),
-            self._kernel_arms["estimate"] == "fused",
-            kernel_arm=self._kernel_arms["histogram"])
+            tuple(self._agg_emit))
 
     def __init__(self, config: EngineConfig | None = None):
         self.cfg = config or EngineConfig()
@@ -872,10 +811,6 @@ class AggregationEngine:
                 "flush_incremental_threshold must be in (0, 1]: it is "
                 "the dirty fraction above which the full flush program "
                 f"runs, got {self.cfg.flush_incremental_threshold!r}")
-        if self.cfg.fused_kernels not in kernels.MODES:
-            raise ValueError(
-                f"fused_kernels={self.cfg.fused_kernels!r}: must be "
-                f"{'/'.join(kernels.MODES)}")
         # One ingest thread owns process(); flush() may run from another
         # thread. The lock is the Worker.Flush mutex-swap equivalent:
         # ingest holds it per item; flush holds it ONLY across
@@ -2231,9 +2166,7 @@ class AggregationEngine:
             self._flush_baseline = _flush_baseline_cached(
                 self._device, self._heng, self._seng, self._fwd_out,
                 tuple(self._agg_emit),
-                self._kernel_arms["estimate"] == "fused",
-                tuple(float(q) for q in self._qs),
-                kernel_arm=self._kernel_arms["histogram"])
+                tuple(float(q) for q in self._qs))
         return self._flush_baseline
 
     def _flush_device_incremental(self, snap, phases, dirty, overflow):
@@ -2277,9 +2210,7 @@ class AggregationEngine:
         buckets = self._last_flush_info["buckets"] = [len(p) for p in idx]
         exec_ = _inc_flush_executable(
             self._device, self._heng, self._seng, self._fwd_out,
-            tuple(self._agg_emit),
-            self._kernel_arms["estimate"] == "fused",
-            kernel_arm=self._kernel_arms["histogram"])
+            tuple(self._agg_emit))
         t1 = time.monotonic_ns()
         if phases is not None:
             phases.append(("gather", t0, t1))
@@ -2851,23 +2782,8 @@ class AggregationEngine:
         return sketches.engine_stamp(self._heng, self._seng)
 
     def engines_describe(self) -> dict:
-        """JSON-ready sketch-engine description (/debug/flush),
-        including which kernel arm (fused/xla/interpret) each engine's
-        executables were built with (ISSUE 15 satellite) — bench rows
-        and operator triage read the arm here instead of guessing from
-        the platform, and the process-wide fallback count sits next to
-        it so a probe-refused backend is visible."""
-        d = sketches.describe(self._heng, self._seng)
-        arms = getattr(self, "_kernel_arms", None) \
-            or {"histogram": "xla", "set": "xla", "estimate": "xla"}
-        d["kernels"] = {
-            "requested": getattr(self.cfg, "fused_kernels", "auto"),
-            "histogram_arm": arms["histogram"],
-            "set_arm": arms["set"],
-            "estimate_arm": arms["estimate"],
-            "fallback_total": kernels.fallback_total(),
-        }
-        return d
+        """JSON-ready sketch-engine description (/debug/flush)."""
+        return sketches.describe(self._heng, self._seng)
 
     def bank_leaf_names(self, kind: int) -> tuple:
         """The durability leaf order for one bank kind — engine-aware

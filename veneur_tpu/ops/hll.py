@@ -92,18 +92,17 @@ _BETA14 = (-0.370393911, 0.070471823, 0.17393686, 0.16339839,
 
 def will_use_pallas(num_registers: int) -> bool:
     """True when estimate() will take the Pallas kernel for banks of
-    this register width: on a TPU, where `auto` serves hll_stats
-    (kernels/hll_stats.TPU_AUTO_ARM), for widths on its 512-lane chunk
-    grid; plain jnp elsewhere. Exposed so mesh program builders can
-    PLACE the estimate consistently with this choice: the Pallas kernel
-    belongs inside shard_map (device-local block compute, the
-    recommended pallas-under-shard_map pattern), while the jnp
+    this register width: on a TPU, for widths on its 512-lane chunk
+    grid; plain jnp elsewhere. The one place that decides which kernel
+    runs: everything else is the XLA program. Exposed so mesh program
+    builders can PLACE the estimate consistently with this choice: the
+    Pallas kernel belongs inside shard_map (device-local block compute,
+    the recommended pallas-under-shard_map pattern), while the jnp
     estimator belongs in the plain-jit epilogue (see
     parallel/mesh.py:_build_flush)."""
     from ..kernels import hll_stats
     from ..utils.platform import is_tpu
-    return (is_tpu() and hll_stats.TPU_AUTO_ARM == "fused"
-            and num_registers % hll_stats._LANES == 0)
+    return is_tpu() and num_registers % hll_stats._LANES == 0
 
 
 def estimate(bank: HLLBank, force_jnp: bool = False) -> jax.Array:
@@ -114,8 +113,7 @@ def estimate(bank: HLLBank, force_jnp: bool = False) -> jax.Array:
     range (no linear-counting switchover needed).
 
     `force_jnp` pins the pure-jnp path for callers that manage kernel
-    placement themselves (the engine's fused flush builds separate
-    executables per choice).
+    placement themselves (the mesh flush, parallel/mesh.py).
     """
     if not force_jnp and will_use_pallas(bank.num_registers):
         return _estimate_pallas(bank)

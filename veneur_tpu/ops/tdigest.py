@@ -353,28 +353,28 @@ def _cluster_core(vals, wts, compression: float, C: int,
     else:
         vals, wts = _row_sort(vals, wts)
 
-    def boundaries(k_left, k_right, wts):
-        # Greedy cluster boundaries, scanned over the sorted axis
-        # (length M), carrying per-row k-value at current cluster
-        # start. Initial carry is derived from data (k_left[:,0] - 2
-        # <= any k minus 1, so the first weighted element always opens
-        # a cluster) rather than a constant: inside shard_map a
-        # constant carry would lack the varying mesh-axes type and
-        # fail the scan type check.
-        def step(k_start, xs):
-            kl, kr, w = xs
-            new = (kr - k_start > 1.0) & (w > 0)
-            k_start = jnp.where(new, kl, k_start)
-            return k_start, new
+    return _cluster_tail(vals, wts, compression, C)
 
-        _, is_new = jax.lax.scan(
-            step,
-            k_left[:, 0] - 2.0,
-            (k_left.T, k_right.T, wts.T),
-        )
-        return is_new.T                                  # [K, M] bool
 
-    return _cluster_tail(vals, wts, compression, C, boundaries)
+def _boundaries(k_left, k_right, wts):
+    """Greedy cluster boundaries, scanned over the sorted axis (length
+    M), carrying per-row k-value at current cluster start. Initial
+    carry is derived from data (k_left[:,0] - 2 <= any k minus 1, so
+    the first weighted element always opens a cluster) rather than a
+    constant: inside shard_map a constant carry would lack the varying
+    mesh-axes type and fail the scan type check."""
+    def step(k_start, xs):
+        kl, kr, w = xs
+        new = (kr - k_start > 1.0) & (w > 0)
+        k_start = jnp.where(new, kl, k_start)
+        return k_start, new
+
+    _, is_new = jax.lax.scan(
+        step,
+        k_left[:, 0] - 2.0,
+        (k_left.T, k_right.T, wts.T),
+    )
+    return is_new.T                                      # [K, M] bool
 
 
 def _ends_by_search(cluster, C: int):
@@ -422,16 +422,10 @@ def _lanes_at(c, idx):
         padded, idx, tpu=_lanes_by_select, default=_lanes_by_gather)
 
 
-def _cluster_tail(vals, wts, compression: float, C: int, boundary_fn):
-    """The numeric tail of the greedy clustering, shared VERBATIM by
-    the XLA compress (_cluster_core) and the fused Pallas kernel
-    (kernels/compress.py): both arms' bit-identity contract rests on
-    this being ONE definition — only the greedy boundary recurrence's
-    loop FORM differs per arm (lax.scan for XLA/shard_map, a fori_loop
-    for Mosaic; compare/select only, so any form is bit-equal), which
-    is why it arrives as `boundary_fn(k_left, k_right, wts) ->
-    is_new[K, M] bool`. Inputs are the SORTED (value, weight) rows
-    (empties already +inf-keyed, weight 0)."""
+def _cluster_tail(vals, wts, compression: float, C: int):
+    """The numeric tail of the greedy clustering. Inputs are the
+    SORTED (value, weight) rows (empties already +inf-keyed, weight
+    0)."""
     K, M = vals.shape
     total = jnp.sum(wts, axis=1, keepdims=True)          # [K, 1]
     safe_total = jnp.where(total > 0, total, 1.0)
@@ -439,7 +433,7 @@ def _cluster_tail(vals, wts, compression: float, C: int, boundary_fn):
 
     k_right = _k1(cum / safe_total, compression)         # [K, M]
     k_left = _k1((cum - wts) / safe_total, compression)  # [K, M]
-    is_new = boundary_fn(k_left, k_right, wts)           # [K, M] bool
+    is_new = _boundaries(k_left, k_right, wts)           # [K, M] bool
 
     cluster = jnp.cumsum(is_new.astype(jnp.int32), axis=1) - 1  # [K, M]
     cluster = jnp.where(wts > 0, cluster, C - 1)  # empties -> last cluster id

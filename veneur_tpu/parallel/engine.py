@@ -145,13 +145,6 @@ class MeshAggregationEngine(AggregationEngine):
         self._h_dpoints = np.zeros(n // 2, np.int64)
         self._h_n = self._h_nd = 0
         self._h_deltas = np.zeros((3, self.me.histogram_slots), np.float64)
-        # the mesh flush is its own sharded program: XLA compress and
-        # insert whatever the knob says, and the estimate reduction
-        # through the Pallas kernel exactly where the MeshEngine
-        # placed it (hll.will_use_pallas)
-        self._kernel_arms = {
-            "histogram": "xla", "set": "xla",
-            "estimate": "fused" if self.me.pallas_estimate else "xla"}
 
     def _setup_flush_exec(self):
         # the MeshEngine owns the compiled flush; the single-device

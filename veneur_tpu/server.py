@@ -146,11 +146,6 @@ class Server:
             percentiles=tuple(cfg.percentiles),
             aggregates=tuple(cfg.aggregates),
             idle_ttl_intervals=cfg.tpu_slot_idle_ttl_intervals,
-            flush_incremental=cfg.tpu_flush_incremental,
-            flush_incremental_threshold=
-            cfg.tpu_flush_incremental_threshold,
-            flush_double_buffer=cfg.tpu_flush_double_buffer,
-            fused_kernels=cfg.tpu_fused_kernels,
             forward_enabled=bool(cfg.forward_address
                                  or cfg.consul_forward_service_name),
             # a server with a gRPC import listener is (also) a global tier

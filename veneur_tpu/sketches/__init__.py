@@ -40,6 +40,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from .. import kernels
+from ..ops import hll
 from .hll_engine import HLLEngine
 from .req import REQEngine
 from .tdigest_engine import TDigestEngine
@@ -183,7 +185,11 @@ def merge_registers(engine_id: str, a, b):
 
 
 def describe(heng, seng) -> dict:
-    """JSON-ready engine description for /debug/flush."""
+    """JSON-ready engine description for /debug/flush. `kernels`:
+    what implements the set estimate here (the one Pallas kernel, where
+    ops/hll.will_use_pallas takes it) and the process-wide count of
+    kernel entry points that fell back to their XLA twin."""
+    pallas = seng.id == "hll" and hll.will_use_pallas(seng.num_registers)
     return {
         "stamp": engine_stamp(heng, seng),
         "histogram": {"id": heng.id, "wire_version": heng.wire_version,
@@ -194,6 +200,8 @@ def describe(heng, seng) -> dict:
                 "params": {k: getattr(seng, k)
                            for k in seng.__dataclass_fields__},
                 "error_contract": seng.error_contract},
+        "kernels": {"estimate": "pallas" if pallas else "jnp",
+                    "fallback_total": kernels.fallback_total()},
     }
 
 

@@ -75,7 +75,7 @@ SET ENGINES — own a bank NamedTuple with `registers: u8[K, m]` plus
     merge_rows_impl(bank, slots, registers) -> bank
     merge_banks(a, b) -> bank   (bit-commutative lattice join)
     hash_update(h) -> (reg_idx, val)   (host hot path, python ints)
-    estimate_device(bank, pallas_ok) -> dict  (flush-program outputs)
+    estimate_device(bank) -> dict  (flush-program outputs)
     estimate_finalize(host_dict) -> None      (host; writes "s_est")
     merge_registers_np(a, b) -> np.ndarray    (host join, spill re-merge)
     encode_registers(regs) -> bytes / decode via the registry codec

@@ -63,10 +63,8 @@ class HLLEngine:
     def host_hash_to_updates(self, hashes64):
         return hll.host_hash_to_updates(hashes64, self.precision)
 
-    def estimate_device(self, bank, pallas_ok: bool) -> dict:
-        # the caller's resolved "estimate" arm decides, not the platform
-        est = hll._estimate_pallas if pallas_ok else hll._estimate_jnp
-        return {"s_est": est(bank)}
+    def estimate_device(self, bank) -> dict:
+        return {"s_est": hll.estimate(bank)}
 
     def estimate_finalize(self, host: dict) -> None:
         host["s_est"] = np.asarray(host["s_est"])

@@ -56,16 +56,6 @@ class TDigestEngine:
     def compress_impl(self, bank):
         return tdigest._compress_impl(bank, self.compression)
 
-    def compress_fused_impl(self, bank, interpret: bool):
-        """The fused-kernel compress arm (ISSUE 15): one Pallas
-        dispatch over the bank — sort + rank-merge + cluster with
-        VMEM-resident intermediates — bit-identical to compress_impl
-        (tests/test_pallas.py pins it). The flush program body selects
-        this when the resolved kernel arm is fused/interpret."""
-        from ..kernels import compress as kcompress
-        return kcompress.fused_compress_bank(bank, self.compression,
-                                             interpret)
-
     def merge_centroids_impl(self, bank, slots, means, weights):
         # caller compresses first (buffer headroom), like the ops
         # module's contract

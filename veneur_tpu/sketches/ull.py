@@ -276,19 +276,6 @@ class ULLEngine:
     def insert_impl(self, bank, slots, reg_idx, vals):
         return _insert_impl(bank, slots, reg_idx, vals)
 
-    def insert_fused_impl(self, bank, slots, reg_idx, vals,
-                          interpret: bool):
-        """The Pallas scatter-join insert arm (ISSUE 15): one in-place
-        read-join-write pass over the batch, replacing the XLA
-        sort + segmented-scan + gather path — register-byte-identical
-        (the join is associative/commutative/idempotent, so any
-        application order folds to the same lattice value; pinned by
-        tests/test_pallas.py). The ingest executable selects this when
-        the resolved kernel arm is fused/interpret."""
-        from ..kernels import ull_insert as kinsert
-        return kinsert.fused_insert(bank, slots, reg_idx, vals,
-                                    interpret)
-
     def merge_rows_impl(self, bank, slots, registers):
         return _merge_rows_impl(bank, slots, registers)
 
@@ -315,7 +302,7 @@ class ULLEngine:
         idx, rho = _hll.host_hash_to_updates(hashes64, self.precision)
         return idx, (rho.astype(np.int32) << 2).astype(np.uint8)
 
-    def estimate_device(self, bank, pallas_ok: bool) -> dict:
+    def estimate_device(self, bank) -> dict:
         return {"s_counts": _value_counts(bank.registers)}
 
     def estimate_finalize(self, host: dict) -> None:
