@@ -4,8 +4,12 @@ The concurrency test story for the C++ bridge (SURVEY §5 — the rebuild's
 analogue of the reference's `go test -race`): exercise every cross-thread
 path at once — SO_REUSEPORT UDP readers, the Python caller's
 thread_local staging (two bridges to cover the bridge-scoped memo),
-concurrent ring drains (the pump path), new-key/slow-path drains, and
-interval advancement with eviction — under ThreadSanitizer.
+concurrent ring drains (the pump path), new-key/slow-path drains,
+interval advancement with eviction, and the gauge's arrival stamp with
+the per-reader tallies and the sub-rings' high water (two UDP flows of
+gauges into two readers, a direct caller and the SSF path all taking
+numbers from the one counter while a ticker reads `stats()`, takes the
+high water and asks for a number of its own) — under ThreadSanitizer.
 
 Run (from repo root; deliberately does NOT import jax/pytest — TSAN
 makes them unusably slow):
@@ -52,7 +56,8 @@ def main() -> int:
         while not stop.is_set():
             s.sendto(
                 (f"t{i % 97}:{i % 31}|ms|#env:prod\n"
-                 f"c{i % 53}:1|c|@0.5\nu:{i % 1009}|s").encode(),
+                 f"c{i % 53}:1|c|@0.5\nu:{i % 1009}|s\n"
+                 f"g{i % 11}:{i}|g").encode(),
                 ("127.0.0.1", port))
             i += 1
 
@@ -137,7 +142,11 @@ def main() -> int:
             for bank in ("histo", "counter", "gauge", "set"):
                 br.advance_interval(bank)
             br.slot_scopes("histo")
-            br.stats()
+            st = br.stats()
+            assert sum(r["packets"] for r in st["readers"]) \
+                <= br.stats()["packets"]
+            br.take_ring_high()
+            br.next_arrival()
             time.sleep(0.05)
 
     threads = [threading.Thread(target=f, daemon=True) for f in (
@@ -154,6 +163,8 @@ def main() -> int:
     for br in bridges:
         br.close()
     assert stats["packets"] > 0 and stats["lines"] > 0, stats
+    assert len(stats["readers"]) == 4, stats["readers"]   # 2 statsd, 2 SSF
+    assert sum(r["lines"] for r in stats["readers"]) > 0, stats["readers"]
     print(f"tsan stress ok: {stats['lines']} lines through "
           f"{len(threads)} threads")
     return 0

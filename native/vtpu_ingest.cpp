@@ -377,6 +377,8 @@ struct Ring {
   std::vector<float> b;
   std::vector<int32_t> c;
   size_t cap = 0, head = 0, count = 0;
+  // the most samples the ring has held since take_high last reset it
+  size_t high = 0;
   uint64_t drops = 0;
 
   void init(size_t capacity) {
@@ -410,6 +412,16 @@ struct Ring {
       memcpy(&c[0], cv + first, (n - first) * sizeof(int32_t));
     }
     count += n;
+    if (count > high) high = count;
+  }
+
+  // the high water since the last take, which starts again from what
+  // the ring holds now
+  size_t take_high() {
+    std::lock_guard<std::mutex> g(mu);
+    size_t h = high;
+    high = count;
+    return h;
   }
 
   size_t pop(int32_t* s, float* av, float* bv, int32_t* cv, size_t max_n) {
@@ -484,6 +496,15 @@ constexpr int RING_WAYS = 8;  // sub-rings per bank: writers shard by
                               // thread, so producers don't serialize
                               // against each other or the drain memcpy
 
+// What one UDP reader thread did, running totals: datagrams received,
+// lines parsed, ns from recvmmsg's return to its burst staged in the
+// rings. With SO_REUSEPORT the kernel picks a flow's reader by the
+// flow's hash: these say how the flows fell.
+struct ReaderStat {
+  std::atomic<uint64_t> packets{0}, lines{0}, busy_ns{0};
+};
+constexpr int MAX_READERS = 64;  // readers past it share the last entry
+
 struct Bridge {
   BankMeta banks[NUM_BANKS];
   Shard shards[NUM_SHARDS];
@@ -538,6 +559,17 @@ struct Bridge {
 
   std::atomic<uint64_t> packets{0}, lines{0}, samples{0}, parse_errors{0},
       slow_routed{0};
+  // The bridge-wide arrival order: every datagram (a read of a framed
+  // stream, a packet handed in from Python) takes the next number when
+  // it is received, BEFORE it is counted in `packets`, and its gauge
+  // samples carry it in the ring's `c` column. A gauge is its last
+  // write by this order, whichever reader staged it on whichever
+  // sub-ring (ingest_gauge_batch lands a batch by it). 32 bits that
+  // wrap: the engine compares stamps by their distance from the
+  // interval's base.
+  std::atomic<uint32_t> arrival{0};
+  ReaderStat reader_stats[MAX_READERS];
+  std::atomic<int> n_readers{0};
   // ns inside intern_key's slow path (a key that holds no slot: the free
   // list, the shard map's insert, the new-key record), all callers summed
   std::atomic<uint64_t> intern_ns{0};
@@ -548,6 +580,13 @@ struct Bridge {
   int bound_port = 0;
   int max_packet = 8192;
 };
+
+// The next `n` numbers of the arrival order: datagram i of a burst is
+// the returned number + i.
+inline uint32_t arrive(Bridge* br, int n) {
+  return br->arrival.fetch_add(static_cast<uint32_t>(n),
+                               std::memory_order_relaxed) + 1;
+}
 
 // per-thread parse + staging state
 struct LocalStage {
@@ -566,6 +605,10 @@ struct LocalStage {
   std::vector<int32_t> c[NUM_BANKS];
 
   int way = -1;
+  // lines this stage has parsed (a reader adds its burst's to its
+  // ReaderStat), and the arrival number of the datagram being parsed
+  uint64_t lines = 0;
+  uint32_t order = 0;
 
   void flush(Bridge* br) {
     if (way < 0) {
@@ -667,6 +710,7 @@ void stage_parsed(Bridge* br, LocalStage* st, const ParsedMetric& m);
 void handle_line(Bridge* br, LocalStage* st, const uint8_t* line,
                  size_t len) {
   br->lines.fetch_add(1, std::memory_order_relaxed);
+  st->lines++;
   ParseVerdict v = parse_line(
       line, len, &st->m, &st->secs, &st->tags,
       br->tags_exclude.empty() ? nullptr : &br->tags_exclude);
@@ -714,13 +758,16 @@ void stage_parsed(Bridge* br, LocalStage* st, const ParsedMetric& m) {
       st->c[bk].push_back(0);
       break;
     case B_GAUGE:
-      // last-write-wins sequence numbers are assigned by the engine at
-      // dispatch time (ingest_gauge_batch), under the same lock as the
-      // flush swap — ring order is arrival order
+      // last-write-wins: the sample carries its datagram's arrival
+      // number. Ring order is arrival order within ONE stage only; the
+      // engine turns the stamps into the interval's sequence numbers
+      // at dispatch time (ingest_gauge_batch), under the same lock as
+      // the flush swap. Every path that stages a gauge sets st->order
+      // first (vlint NA05)
       st->slots[bk].push_back(slot);
       st->a[bk].push_back(static_cast<float>(m.value));
       st->b[bk].push_back(0.0f);
-      st->c[bk].push_back(0);
+      st->c[bk].push_back(static_cast<int32_t>(st->order));
       break;
     case B_SET: {
       // member hash identical to hashing.py set_member_hash + the rho
@@ -1136,7 +1183,17 @@ struct RecvBatch {
   }
 };
 
-void reader_loop(Bridge* br, int sock) {
+// A burst's share of its reader's tallies, added once the burst is
+// staged: `t0` is when the receive returned.
+inline void tally_burst(ReaderStat* rs, int packets, uint64_t lines,
+                        uint64_t t0) {
+  rs->packets.fetch_add(static_cast<uint64_t>(packets),
+                        std::memory_order_relaxed);
+  rs->lines.fetch_add(lines, std::memory_order_relaxed);
+  rs->busy_ns.fetch_add(mono_ns() - t0, std::memory_order_relaxed);
+}
+
+void reader_loop(Bridge* br, int sock, ReaderStat* rs) {
   LocalStage st;
   RecvBatch rb(br->max_packet);
   pollfd pfd{sock, POLLIN, 0};
@@ -1146,10 +1203,17 @@ void reader_loop(Bridge* br, int sock) {
     int n = recvmmsg(sock, rb.msgs.data(), RecvBatch::VLEN, MSG_DONTWAIT,
                      nullptr);
     if (n <= 0) continue;
+    uint64_t t0 = mono_ns(), lines0 = st.lines;
+    // stamped before counted: a sender that has seen `packets` reach
+    // its datagram knows every later datagram takes a later number
+    uint32_t first = arrive(br, n);
     br->packets.fetch_add(n, std::memory_order_relaxed);
-    for (int i = 0; i < n; i++)
+    for (int i = 0; i < n; i++) {
+      st.order = first + static_cast<uint32_t>(i);
       handle_buffer(br, &st, rb.bufs[i].data(), rb.msgs[i].msg_len);
+    }
     st.flush(br);
+    tally_burst(rs, n, st.lines - lines0, t0);
   }
 }
 
@@ -1165,7 +1229,7 @@ void route_ssf_other(Bridge* br, const uint8_t* data, size_t len) {
 // The SSF span listener: one datagram = one SSFSpan protobuf, decoded
 // and staged natively; fallback datagrams queue for the Python span
 // pipeline (Server.ReadSSFPacketSocket's C++ twin).
-void ssf_reader_loop(Bridge* br, int sock) {
+void ssf_reader_loop(Bridge* br, int sock, ReaderStat* rs) {
   LocalStage st;
   RecvBatch rb(br->ssf_max_dgram);
   pollfd pfd{sock, POLLIN, 0};
@@ -1175,8 +1239,11 @@ void ssf_reader_loop(Bridge* br, int sock) {
     int n = recvmmsg(sock, rb.msgs.data(), RecvBatch::VLEN, MSG_DONTWAIT,
                      nullptr);
     if (n <= 0) continue;
+    uint64_t t0 = mono_ns();
+    uint32_t first = arrive(br, n);
     br->packets.fetch_add(n, std::memory_order_relaxed);
     for (int i = 0; i < n; i++) {
+      st.order = first + static_cast<uint32_t>(i);
       int rc = handle_ssf(br, &st, rb.bufs[i].data(), rb.msgs[i].msg_len);
       if (rc == 0)
         route_ssf_other(br, rb.bufs[i].data(), rb.msgs[i].msg_len);
@@ -1184,6 +1251,7 @@ void ssf_reader_loop(Bridge* br, int sock) {
         br->ssf_errors.fetch_add(1, std::memory_order_relaxed);
     }
     st.flush(br);
+    tally_burst(rs, n, 0, t0);  // spans carry no lines
   }
 }
 
@@ -1234,6 +1302,9 @@ void ssf_stream_conn_loop(Bridge* br, int fd) {
     }
     have += static_cast<size_t>(n);
     uint64_t t0 = mono_ns();
+    // one arrival number a read: a connection's frames keep their
+    // order on its one stage
+    st.order = arrive(br, 1);
     size_t off = 0;
     uint64_t frames = 0;
     while (off < have) {
@@ -1351,6 +1422,7 @@ void vtpu_destroy(void* h) {
 void vtpu_handle_packet(void* h, const uint8_t* data, int32_t len) {
   Bridge* br = static_cast<Bridge*>(h);
   thread_local LocalStage st;
+  st.order = arrive(br, 1);
   br->packets.fetch_add(1, std::memory_order_relaxed);
   handle_buffer(br, &st, data, static_cast<size_t>(len));
   st.flush(br);
@@ -1362,6 +1434,7 @@ void vtpu_handle_packet(void* h, const uint8_t* data, int32_t len) {
 int32_t vtpu_handle_ssf(void* h, const uint8_t* data, int32_t len) {
   Bridge* br = static_cast<Bridge*>(h);
   thread_local LocalStage st;
+  st.order = arrive(br, 1);
   int rc = handle_ssf(br, &st, data, static_cast<size_t>(len));
   if (rc == 1) st.flush(br);
   return rc;
@@ -1379,7 +1452,7 @@ void vtpu_set_indicator_timer(void* h, const char* name) {
 static int32_t open_udp_readers(Bridge* br, const char* host,
                                 int32_t port, int32_t n_readers,
                                 int32_t rcvbuf,
-                                void (*loop)(Bridge*, int)) {
+                                void (*loop)(Bridge*, int, ReaderStat*)) {
   bool v6 = strchr(host, ':') != nullptr;
   int bound = -1;
   for (int r = 0; r < n_readers; r++) {
@@ -1428,7 +1501,9 @@ static int32_t open_udp_readers(Bridge* br, const char* host,
       return -e;
     }
     br->socks.push_back(fd);
-    br->readers.emplace_back(loop, br, fd);
+    int ri = br->n_readers.fetch_add(1, std::memory_order_relaxed);
+    br->readers.emplace_back(
+        loop, br, fd, &br->reader_stats[std::min(ri, MAX_READERS - 1)]);
   }
   return bound;
 }
@@ -1497,7 +1572,7 @@ void vtpu_stop(void* h) {
 }
 
 // Drain up to max_n staged samples for `bank` into caller arrays.
-// histo/counter: a=values  b=weights;  gauge: a=values  c=seqs;
+// histo/counter: a=values  b=weights;  gauge: a=values  c=arrival number;
 // set: a=rho  c=register index.
 int32_t vtpu_poll(void* h, int32_t bank, int32_t max_n, int32_t* slots,
                   float* a, float* b, int32_t* c) {
@@ -1664,8 +1739,12 @@ int64_t vtpu_key_count(void* h, int32_t bank) {
 //               [19] = ns inside intern_key's slow path; then per bank
 //               (histo, counter, gauge, set) [20..23] = keys holding a
 //               slot, [24..27] = keys minted, [28..31] = keys the idle
-//               TTL evicted (running totals)
-constexpr int kStatsFields = 32;  // ingest/native.py:STATS_FIELDS (NA04)
+//               TTL evicted (running totals), [32..35] = the high water
+//               of the bank's fullest sub-ring since vtpu_take_ring_high
+//               last reset it (samples; a sub-ring holds
+//               ring_capacity / RING_WAYS + 1)
+constexpr int kStatsFields = 36;  // ingest/native.py:STATS_FIELDS (NA04)
+
 
 void vtpu_stats(void* h, uint64_t* out) {
   Bridge* br = static_cast<Bridge*>(h);
@@ -1677,10 +1756,13 @@ void vtpu_stats(void* h, uint64_t* out) {
   uint64_t no_slot = 0, ring_drops = 0;
   for (int i = 0; i < NUM_BANKS; i++) {
     no_slot += br->banks[i].drops_no_slot.load();
+    size_t high = 0;
     for (int w = 0; w < RING_WAYS; w++) {
       std::lock_guard<std::mutex> g(br->rings[i][w].mu);
       ring_drops += br->rings[i][w].drops;
+      high = std::max(high, br->rings[i][w].high);
     }
+    out[32 + i] = high;
   }
   out[5] = no_slot;
   out[6] = ring_drops;
@@ -1706,6 +1788,47 @@ void vtpu_stats(void* h, uint64_t* out) {
   std::lock_guard<std::mutex> g(br->other_mu);
   out[7] = br->other_drops;
   out[8] = br->other.size();
+}
+
+// out[bank] = the high water of the bank's fullest sub-ring since the
+// last call, which this call resets (the flush's read, once a tick).
+void vtpu_take_ring_high(void* h, uint64_t* out) {
+  Bridge* br = static_cast<Bridge*>(h);
+  for (int i = 0; i < NUM_BANKS; i++) {
+    size_t most = 0;
+    for (int w = 0; w < RING_WAYS; w++)
+      most = std::max(most, br->rings[i][w].take_high());
+    out[i] = most;
+  }
+}
+
+// Samples one sub-ring holds (every ring of the bridge is as long).
+int32_t vtpu_ring_way_capacity(void* h) {
+  return static_cast<int32_t>(static_cast<Bridge*>(h)->rings[0][0].cap);
+}
+
+// Each UDP reader's running totals, in the order the readers were
+// started (statsd listeners first): out[3 * r + 0..2] = datagrams,
+// lines, busy ns (receive returned -> burst staged). Returns how many
+// readers were written (at most max_readers).
+int32_t vtpu_reader_stats(void* h, uint64_t* out, int32_t max_readers) {
+  Bridge* br = static_cast<Bridge*>(h);
+  int n = std::min({br->n_readers.load(std::memory_order_relaxed),
+                    MAX_READERS, static_cast<int>(max_readers)});
+  for (int r = 0; r < n; r++) {
+    const ReaderStat& rs = br->reader_stats[r];
+    out[3 * r] = rs.packets.load(std::memory_order_relaxed);
+    out[3 * r + 1] = rs.lines.load(std::memory_order_relaxed);
+    out[3 * r + 2] = rs.busy_ns.load(std::memory_order_relaxed);
+  }
+  return n;
+}
+
+// The next arrival number, for a gauge that reaches the engine by the
+// Python path (a slow-path line, a fallback span): it is ordered among
+// the datagrams by when it is processed.
+int32_t vtpu_next_arrival(void* h) {
+  return static_cast<int32_t>(arrive(static_cast<Bridge*>(h), 1));
 }
 
 // -------- conformance/testing helpers (stateless parse of one line) -----

@@ -422,8 +422,8 @@ class TestPumpBufferAliasing:
                 captured.append((slots, values, weights))
 
             def ingest_gauge_batch(self, slots, values, count=None,
-                                   mark=None):
-                captured.append((slots, values))
+                                   mark=None, order=None):
+                captured.append((slots, values, order))
 
             def ingest_set_batch(self, slots, reg_idx, rho, count=None,
                                  mark=None):

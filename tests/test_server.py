@@ -272,23 +272,27 @@ _DEPLOYMENTS = os.path.join(os.path.dirname(os.path.dirname(
     f[:-5] for f in os.listdir(_DEPLOYMENTS) if f.endswith(".json")))
 def test_benchmark_deployments_load_with_the_retired_kernel_key(name,
                                                                caplog):
-    """Every deployment file of the benchmark still sets
+    """A deployment file of the benchmark from before PR 48 still sets
     `tpu_fused_kernels` (`on` in its rehearsal preset) and may not be
     edited: its global tier builds and warms up as the harness builds
-    it, with the one warning, and no kernel entry point fell back."""
+    it, with the one warning, and no kernel entry point fell back. A
+    file added since does not set the key and loads with no warning."""
     import logging
 
     from perfbench import harness
     from veneur_tpu import kernels
 
     cfg = harness.load_config(name, rehearsal=True)
-    assert cfg["common"]["tpu_fused_kernels"] == "on"
+    retired = "tpu_fused_kernels" in cfg["common"]
+    assert cfg["common"].get("tpu_fused_kernels", "on") == "on"
+    assert retired == (name != "dogstatsd_readers4_two_tier_1chip")
     before = kernels.fallback_total()
     with caplog.at_level(logging.WARNING, logger="veneur_tpu.config"):
         srv = harness.build_server(cfg, "global", {},
                                    CaptureMetricSink(), rehearsal=True)
-    assert caplog.text.count("unknown config key") == 1
-    assert "unknown config key 'tpu_fused_kernels' ignored" in caplog.text
+    assert caplog.text.count("unknown config key") == int(retired)
+    assert ("unknown config key 'tpu_fused_kernels' ignored"
+            in caplog.text) == retired
     srv.start()
     try:
         kern = srv._debug_flush_state()["sketch_engines"]["kernels"]

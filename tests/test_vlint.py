@@ -97,6 +97,23 @@ def test_na04_holds_the_real_tree():
     assert "out[28 + i] = br->banks[i].keys_evicted" in text
 
 
+def test_na05_a_stage_parsed_into_without_its_arrival_stamp():
+    # the stamped loop in the same file stays silent
+    assert lint("na05_bad.cpp") == [("NA05", 15)]
+
+
+def test_na05_holds_the_real_tree():
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = os.path.join(repo, "native", "vtpu_ingest.cpp")
+    assert [v for v in run_paths([path]) if v.rule == "NA05"] == []
+    # and it is looking: five functions own a stage and parse into it
+    with open(path) as f:
+        text = f.read()
+    assert text.count("LocalStage st;") == 5
+    assert text.count("st.order = ") == 5
+    assert "st->c[bk].push_back(static_cast<int32_t>(st->order));" in text
+
+
 def test_rs01_raw_egress_bypasses_resilience():
     # one urlopen + one grpc channel construction, exact lines
     assert lint("rs01_bad.py") == [("RS01", 9), ("RS01", 14)]

@@ -9,6 +9,8 @@ Checks (see tools/vlint/README.md for the full contract):
   NA01  nullptr-reachable string::assign in the native bridge
   NA02  native/Python decoder recursion-cap divergence
   NA03  native/Python SSF frame-layout divergence
+  NA04  native/Python stats-array layout divergence
+  NA05  a native stage parsed into before its arrival stamp is set
   VL00  suppression without a reason
   VL01  file failed to parse
 

@@ -1,4 +1,4 @@
-"""Line-based C++ passes for native/vtpu_ingest.cpp: NA01 to NA04.
+"""Line-based C++ passes for native/vtpu_ingest.cpp: NA01 to NA05.
 
 These are deliberately regex-level — the native bridge is one file of
 C-with-classes and the two defect classes it has actually shipped
@@ -244,6 +244,46 @@ def check_na04(nf: NativeFile, ctx, config: dict) -> list[Violation]:
     return out
 
 
+# LocalStage st;   thread_local LocalStage st;
+_STAGE_DECL_RE = re.compile(r"\bLocalStage\s+(\w+)\s*;")
+# handle_buffer(br, &st, ...)   handle_ssf(br, &st, ...)
+_STAGE_PARSE = r"\bhandle_(?:buffer|ssf)\(\s*\w+\s*,\s*&%s\b"
+_STAGE_STAMP = r"\b%s\.order\s*="
+
+
+def check_na05(nf: NativeFile) -> list[Violation]:
+    """Arrival stamp before staging. A gauge is its last write by the
+    bridge-wide arrival order, which a gauge sample carries from its
+    stage's `order` (stage_parsed reads it, for statsd lines and SSF
+    samples alike). So every function that owns a LocalStage and hands
+    it to handle_buffer or handle_ssf sets `<stage>.order` on an
+    earlier line of the stage's scope: a new transport that forgets
+    stages every gauge under the last datagram's number, or 0."""
+    out = []
+    depths = _brace_depth_per_line(nf.lines)
+    for i, text in enumerate(nf.lines):
+        m = _STAGE_DECL_RE.search(text.split("//", 1)[0])
+        if m is None:
+            continue
+        parse = re.compile(_STAGE_PARSE % m.group(1))
+        stamp = re.compile(_STAGE_STAMP % m.group(1))
+        stamped = False
+        for j in range(i + 1, len(nf.lines)):
+            if depths[j] < depths[i]:
+                break       # the stage's scope has closed
+            code = nf.lines[j].split("//", 1)[0]
+            stamped = stamped or bool(stamp.search(code))
+            if parse.search(code) and not stamped:
+                out.append(Violation(
+                    nf.path, j + 1, "NA05",
+                    f"{m.group(1)} is parsed into before its `order` is "
+                    "set: gauges staged here carry no arrival number of "
+                    "their own, and last-write-wins across readers "
+                    "breaks"))
+    return out
+
+
 def check_file(nf: NativeFile, ctx, config: dict) -> list[Violation]:
     return (check_na01(nf) + check_na02(nf, ctx, config)
-            + check_na03(nf, ctx, config) + check_na04(nf, ctx, config))
+            + check_na03(nf, ctx, config) + check_na04(nf, ctx, config)
+            + check_na05(nf))

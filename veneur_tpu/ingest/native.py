@@ -38,10 +38,15 @@ _BANKS = {"histo": 0, "counter": 1, "gauge": 2, "set": 3}
 # a slot now, and running totals of keys minted into a slot and of keys
 # the idle TTL evicted
 KEY_STATS = ("keys_live", "keys_interned", "keys_evicted")
+# a bank's `ring_high_<bank>`: the most samples its fullest sub-ring
+# has held since the flush last took the mark (`take_ring_high`)
+RING_HIGH = "ring_high"
+# what `reader_stats()` gives of each UDP reader thread, running totals
+READER_STATS = ("packets", "lines", "busy_ns")
 # the length of the array `vtpu_stats` fills (native/vtpu_ingest.cpp:
 # kStatsFields; vlint NA04 holds the two equal) and its fields' names,
 # by position. A tuple built once: senders pace on `stats()`
-STATS_FIELDS = 32
+STATS_FIELDS = 36
 _STATS_KEYS = ("packets", "lines", "samples", "parse_errors",
                "slow_routed", "drops_no_slot", "ring_drops",
                "other_drops", "pending_other", "ssf_spans",
@@ -49,9 +54,10 @@ _STATS_KEYS = ("packets", "lines", "samples", "parse_errors",
                "pending_ssf_other", "ssf_stream_frames",
                "ssf_stream_conns", "ssf_stream_conn_errors",
                "ssf_stream_read_ns", "ssf_stream_wait_ns", "intern_ns",
-               *(f"{name}_{bank}" for name in KEY_STATS
+               *(f"{name}_{bank}" for name in (*KEY_STATS, RING_HIGH)
                  for bank in _BANKS))
 assert len(_STATS_KEYS) == STATS_FIELDS
+_StatsArray = ctypes.c_uint64 * STATS_FIELDS
 _MTYPE_NAMES = ["counter", "gauge", "timer", "histogram", "set"]
 
 P_METRIC, P_ERROR, P_OTHER = 0, 1, 2
@@ -156,6 +162,14 @@ def load() -> ctypes.CDLL:
                                     ctypes.c_int32, u8p, ctypes.c_int32,
                                     u8p, ctypes.c_int32]
         lib.vtpu_stats.argtypes = [ctypes.c_void_p, u64p]
+        lib.vtpu_take_ring_high.argtypes = [ctypes.c_void_p, u64p]
+        lib.vtpu_reader_stats.restype = ctypes.c_int32
+        lib.vtpu_reader_stats.argtypes = [ctypes.c_void_p, u64p,
+                                          ctypes.c_int32]
+        lib.vtpu_next_arrival.restype = ctypes.c_int32
+        lib.vtpu_next_arrival.argtypes = [ctypes.c_void_p]
+        lib.vtpu_ring_way_capacity.restype = ctypes.c_int32
+        lib.vtpu_ring_way_capacity.argtypes = [ctypes.c_void_p]
         lib.vtpu_set_tags_exclude.argtypes = [ctypes.c_void_p, u8p,
                                               ctypes.c_int32]
         lib.vtpu_parse_one.restype = ctypes.c_int32
@@ -233,6 +247,10 @@ class NativeBridge:
             hll_precision, idle_ttl, ring_capacity, max_packet)
         self.capacities = {"histo": histo_slots, "counter": counter_slots,
                            "gauge": gauge_slots, "set": set_slots}
+        # samples one sub-ring holds
+        self.ring_way_capacity = int(
+            self._lib.vtpu_ring_way_capacity(self._h))
+        self._n_readers = 0     # UDP readers started so far
         self._key_buf = np.zeros(1 << 20, np.uint8)
         self._other_buf = np.zeros(1 << 20, np.uint8)
         self._closed = False
@@ -282,6 +300,7 @@ class NativeBridge:
             self._h, host.encode(), port, n_readers, rcvbuf)
         if rc < 0:
             raise OSError(-rc, os.strerror(-rc))
+        self._n_readers += n_readers
         return rc
 
     def start_ssf_udp(self, host: str, port: int, n_readers: int,
@@ -293,6 +312,7 @@ class NativeBridge:
             self._h, host.encode(), port, n_readers, rcvbuf, max_dgram)
         if rc < 0:
             raise OSError(-rc, os.strerror(-rc))
+        self._n_readers += n_readers
         return rc
 
     def start_ssf_stream(self, listen_fd: int) -> None:
@@ -410,10 +430,46 @@ class NativeBridge:
             _u8(ta), len(tb))
 
     def stats(self) -> dict:
-        out = np.zeros(STATS_FIELDS, np.uint64)
-        self._lib.vtpu_stats(
-            self._h, out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)))
-        return dict(zip(_STATS_KEYS, out.tolist()))
+        """The bridge's totals by name, and under `"readers"` what
+        each UDP reader thread did (`reader_stats`)."""
+        # a ctypes array made a call (any thread may ask) and read by
+        # one slice: senders pace on this, and a numpy array with its
+        # pointer costs twice as much as the call itself
+        out = _StatsArray()
+        self._lib.vtpu_stats(self._h, out)
+        st = dict(zip(_STATS_KEYS, out[:]))
+        st["readers"] = self.reader_stats()
+        return st
+
+    def reader_stats(self) -> list:
+        """[{packets, lines, busy_ns}] of every UDP reader thread in
+        the order they were started (the statsd listeners' first):
+        running totals of datagrams received, lines parsed and ns from
+        a receive's return to its burst staged in the rings. Which
+        flows a reader serves is the kernel's choice (SO_REUSEPORT
+        hashes the flow), so these say how the load fell."""
+        if not self._n_readers:
+            return []
+        out = (ctypes.c_uint64 * (len(READER_STATS) * self._n_readers))()
+        n = self._lib.vtpu_reader_stats(self._h, out, self._n_readers)
+        flat, k = out[:], len(READER_STATS)
+        return [dict(zip(READER_STATS, flat[k * r:k * r + k]))
+                for r in range(n)]
+
+    def take_ring_high(self) -> dict:
+        """bank -> the most samples its fullest sub-ring has held since
+        the last take (of `ring_way_capacity`), and start again: the
+        flush's read, once a tick. `stats()["ring_high_<bank>"]` reads
+        the same mark without resetting it."""
+        out = (ctypes.c_uint64 * len(_BANKS))()
+        self._lib.vtpu_take_ring_high(self._h, out)
+        return dict(zip(_BANKS, out[:]))
+
+    def next_arrival(self) -> int:
+        """The next number of the bridge-wide arrival order (an int32
+        that wraps), for a gauge that reaches the engine by the Python
+        path: ordered among the datagrams by when it is processed."""
+        return int(self._lib.vtpu_next_arrival(self._h))
 
 
 class BridgeKeyView:
@@ -544,8 +600,10 @@ class NativePump:
         # later dispatches lengthen the last row, so seconds stay exact
         self.stamps = stamps
         # the bridge's tallies at the last take: the stream readers' ns
-        # and the ns inside intern_key's slow path
+        # and the ns inside intern_key's slow path, then each UDP
+        # reader's busy ns
         self._taken_ns = {"ssf_stream_read_ns": 0, "intern_ns": 0}
+        self._taken_busy: list = []
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         # pump_once may be called by both the pump thread and
@@ -565,8 +623,12 @@ class NativePump:
         of the stream readers' seconds inside handle_ssf + staging
         since the last take; where keys were minted, one
         `ingest.intern` row of the seconds inside intern_key's slow
-        path. The readers keep a tally and no edges, so each row is
-        laid to end where the pump's last batch did."""
+        path; and one `ingest.reader.busy` row for each UDP reader that
+        received a datagram, of its seconds from a receive's return to
+        the burst staged (rows of one name, one a reader, in no order:
+        the longest is the busiest reader's). The readers keep a tally
+        and no edges, so each row is laid to end where the pump's last
+        batch did."""
         if self.stamps is None:
             return []
         rows = self.stamps.take()
@@ -583,6 +645,11 @@ class NativePump:
             took, self._taken_ns[key] = total - self._taken_ns[key], total
             if took > 0:
                 rows.append((name, end - took, end))
+        busy = [int(r["busy_ns"]) for r in st["readers"]]
+        last = self._taken_busy + [0] * (len(busy) - len(self._taken_busy))
+        self._taken_busy = busy
+        rows += [("ingest.reader.busy", end - (now - was), end)
+                 for now, was in zip(busy, last) if now > was]
         return rows
 
     def start(self):
@@ -685,7 +752,11 @@ class NativePump:
                 eng.ingest_counter_batch(sl, a.copy(), b.copy(), count=n,
                                          mark=mark)
             elif bank == "gauge":
-                eng.ingest_gauge_batch(sl, a.copy(), count=n, mark=mark)
+                # `c` is each sample's place in the bridge-wide arrival
+                # order: the ways were polled one after another, so
+                # the batch's own order says nothing across readers
+                eng.ingest_gauge_batch(sl, a.copy(), count=n, mark=mark,
+                                       order=c.copy())
             else:
                 # astype allocates fresh storage, which satisfies the
                 # aliasing contract for the rho column by itself

@@ -844,6 +844,15 @@ class AggregationEngine:
         self._set_stage = _Stage(b, {"slots": (np.int32, -1),
                                      "reg_idx": i32, "rho": (np.uint8, 0)})
         self._gauge_seq = 0
+        # Where a clock outside the engine orders the gauges (the
+        # native bridge's arrival numbers, 32 bits that wrap): the
+        # clock a gauge of the Python path asks (`gauge_clock`, which
+        # the server sets beside the pump; None = the engine's own
+        # count), the stamp the interval's sequence numbers are counted
+        # from, and the farthest stamp past it the interval has landed
+        self.gauge_clock = None
+        self._gauge_base = 0
+        self._gauge_hi = 0
         # Quantile program input: configured percentiles, plus 0.5 when the
         # `median` aggregate is requested (veneur's median IS quantile(0.5)).
         qs = list(cfg.percentiles)
@@ -1080,8 +1089,13 @@ class AggregationEngine:
                 if m is None:
                     return
             st = self._gauge_stage
-            self._gauge_seq += 1
-            st.put(slots=slot, values=m.value, seqs=self._gauge_seq)
+            if self.gauge_clock is None:
+                self._gauge_seq += 1
+                seq = self._gauge_seq
+            else:
+                seq = int(self._stamp_seqs(
+                    np.array([self.gauge_clock()]))[0])
+            st.put(slots=slot, values=m.value, seqs=seq)
             if st.full():
                 self._dispatch_gauges()
         elif t == "set":
@@ -1260,19 +1274,72 @@ class AggregationEngine:
                 self.counter_bank, self._dirty, slots, values, weights)
         self._ingest_batch(slots, count, mark, apply)
 
-    def ingest_gauge_batch(self, slots, values, count=None, mark=None):
-        # Sequence numbers are assigned HERE (arrival order at the
-        # engine), not by the producer: the per-interval reset then
-        # happens under the same lock as the bank swap, so a stale
-        # pre-flush sample can never outrank a newer post-flush one and
-        # the counter cannot wrap within an interval.
+    def ingest_gauge_batch(self, slots, values, count=None, mark=None,
+                           order=None):
+        # Sequence numbers are assigned HERE, not by the producer: the
+        # per-interval reset then happens under the same lock as the
+        # bank swap, so a stale pre-flush sample can never outrank a
+        # newer post-flush one and the counter cannot wrap within an
+        # interval. `order` is each sample's arrival number (the
+        # bridge's stamp, which outlives no interval: _gauge_rows
+        # counts it from the interval's base); without it a sample's
+        # place in the batch is its order.
         def apply(n):
+            self.gauge_bank = self._land_gauges(
+                self.gauge_bank, self._dirty,
+                *self._gauge_rows(slots, values, n, order))
+        self._ingest_batch(slots, count, mark, apply)
+
+    # A stamp's sequence number is its distance from the interval's
+    # base plus this much, so that a sample staged before the swap and
+    # pumped after it, whose stamp lies below the new base, still has a
+    # number of its own under every later one; an interval holds 2^30
+    # datagrams before its numbers saturate (they never wrap).
+    _GAUGE_SEQ_ROOM = 1 << 30
+
+    def _stamp_seqs(self, stamps) -> np.ndarray:
+        """The interval's sequence numbers of gauge samples from their
+        arrival numbers (uint32 that wrap, held as any integer type):
+        the signed distance from `_gauge_base` on the 32-bit circle.
+        Caller holds the engine lock."""
+        d = (stamps.astype(np.int64) - self._gauge_base) & 0xFFFFFFFF
+        d -= (d >> 31) << 32
+        self._gauge_hi = max(self._gauge_hi, int(d.max()))
+        seqs = np.clip(d + self._GAUGE_SEQ_ROOM, 1,
+                       np.iinfo(np.int32).max).astype(np.int32)
+        self._gauge_seq = max(self._gauge_seq, int(seqs.max()))
+        return seqs
+
+    def _gauge_rows(self, slots, values, n, order) -> tuple:
+        """(slots, values, seqs) of a pump batch as gauge_set takes
+        them. gauge_set keeps a slot's LAST row of a batch, and the
+        pump fills a batch one sub-ring after another: where the
+        arrival numbers do not already rise (several readers), the rows
+        are laid in their order first (stable: a datagram's own samples
+        keep their places)."""
+        if order is None:
             seqs = np.arange(1, len(slots) + 1, dtype=np.int32) \
                 + self._gauge_seq
             self._gauge_seq += n
-            self.gauge_bank = self._land_gauges(
-                self.gauge_bank, self._dirty, slots, values, seqs)
-        self._ingest_batch(slots, count, mark, apply)
+            return slots, values, seqs
+        seqs = np.zeros(len(slots), np.int32)
+        if n:
+            seqs[:n] = self._stamp_seqs(order[:n])
+            if np.any(seqs[1:n] < seqs[:n - 1]):
+                by = np.argsort(seqs[:n], kind="stable")
+                slots, values = slots.copy(), values.copy()
+                slots[:n], values[:n] = slots[by], values[by]
+                seqs[:n] = seqs[by]
+        return slots, values, seqs
+
+    def _retire_gauge_seq(self) -> int:
+        """The interval's last sequence number, at the bank swap and
+        under its lock: the next interval counts from 0 again, and its
+        stamps from the farthest one this interval landed."""
+        seq, self._gauge_seq = self._gauge_seq, 0
+        self._gauge_base = (self._gauge_base + self._gauge_hi) & 0xFFFFFFFF
+        self._gauge_hi = 0
+        return seq
 
     def ingest_set_batch(self, slots, reg_idx, rho, count=None, mark=None):
         def apply(n):
@@ -2406,8 +2473,7 @@ class AggregationEngine:
                 self._import_sets = []
                 self._import_counter_acc = {}
                 self._import_gauge_acc = {}
-                retired_seq = self._gauge_seq
-                self._gauge_seq = 0
+                retired_seq = self._retire_gauge_seq()
                 snap = self._swap_banks()
                 dirty = self._retire_dirty()
                 overflow, sidestep = self._retire_overflow()
@@ -2445,7 +2511,7 @@ class AggregationEngine:
                 snap = self._swap_banks()
                 dirty = self._retire_dirty()
                 overflow, sidestep = self._retire_overflow()
-                self._gauge_seq = 0
+                self._retire_gauge_seq()
                 retired_wm = self.last_import_op
                 (active, status, stats_samples, dropped, histo_key_count,
                  imported, keys,

@@ -67,7 +67,8 @@ def metric_name(name: str, counter: bool) -> str:
 # component kinds whose scopes tag as themselves ("sink:datadog" ->
 # tag sink:datadog, "sender:<id>" -> the fleet view's per-sender
 # freshness/e2e gauges); anything else is a destination
-_COMPONENT_KINDS = ("sink:", "plugin:", "spansink:", "sender:")
+_COMPONENT_KINDS = ("sink:", "plugin:", "spansink:", "sender:",
+                    "reader:", "bank:")
 
 
 def scope_tags(scope: str) -> list:

@@ -302,13 +302,12 @@ class MeshAggregationEngine(AggregationEngine):
                            *self._pads_for("gauge", "set"))
         self._ingest_batch(slots, count, mark, apply)
 
-    def ingest_gauge_batch(self, slots, values, count=None, mark=None):
+    def ingest_gauge_batch(self, slots, values, count=None, mark=None,
+                           order=None):
         def apply(n):
-            seqs = np.arange(1, len(slots) + 1, dtype=np.int32) \
-                + self._gauge_seq
-            self._gauge_seq += n
             gs, gv, gq = self._route(
-                self.me.gauge_slots // self.S, slots, values, seqs)
+                self.me.gauge_slots // self.S,
+                *self._gauge_rows(slots, values, n, order))
             self.me.ingest(*self._pads_for("histo", "counter"),
                            gs, gv, gq, *self._pads_for("set"))
         self._ingest_batch(slots, count, mark, apply)

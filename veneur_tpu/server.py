@@ -648,6 +648,10 @@ class Server:
         eng.counter_keys = views["counter"]
         eng.gauge_keys = views["gauge"]
         eng.set_keys = views["set"]
+        # a gauge that reaches the engine by the Python path (a
+        # slow-path line, a fallback span) is ordered among the
+        # bridge's datagrams by the bridge's own arrival count
+        eng.gauge_clock = self.native_bridge.next_arrival
 
         def slow_path(line: bytes):
             """Lines the C++ parser routes to Python: events, service
@@ -2820,6 +2824,22 @@ class Server:
             # ring drops and counts instead of making the reader wait)
             for name, key in SSF_BRIDGE_TELEMETRY.items():
                 tel.incr(S, name, int(st[key]) - int(last.get(key, 0)))
+            # what each UDP reader did in the interval
+            # (veneur.ingest.reader.*_total tagged reader:<i>: with
+            # SO_REUSEPORT the kernel picks a flow's reader, so these
+            # say how the flows fell), and how full each bank's fullest
+            # sub-ring got since the last flush, in samples of
+            # native_ring_capacity / 8 (veneur.ingest.ring_high_water
+            # tagged bank:<name>; the take starts the mark again)
+            was = last.get("readers", [])
+            for i, now in enumerate(st["readers"]):
+                old = was[i] if i < len(was) else {}
+                for key, n in now.items():
+                    tel.incr(f"reader:{i}", "ingest.reader." + key,
+                             int(n) - int(old.get(key, 0)))
+            for bank, high in self.native_bridge.take_ring_high().items():
+                tel.set_gauge("bank:" + bank, "ingest.ring_high_water",
+                              high)
             if eng_stats is not None:
                 eng_stats["dropped_no_slot"] = (
                     int(st["drops_no_slot"])
