@@ -145,6 +145,69 @@ def test_per_sink_self_metrics():
         srv.stop()
 
 
+def test_rows_built_ride_the_sink_phase_and_the_next_ticks_self_metrics():
+    """ISSUE 50: the frame's rows_built / rows_fallback on the
+    `sink.flush` phase of the sink whose thread built the list (zeros
+    on the one that found it cached) and as veneur.sink.rows_built_total
+    / rows_fallback_total in the next interval."""
+    class Second(CaptureMetricSink):
+        def name(self):
+            return "second"
+
+    cap, second = CaptureMetricSink(), Second()
+    cfg = Config(statsd_listen_addresses=["udp://127.0.0.1:0"],
+                 interval="3600s", hostname="h",
+                 aggregates=["count", "max"], percentiles=[0.5],
+                 tpu_histogram_slots=256, tpu_counter_slots=128,
+                 tpu_gauge_slots=128, tpu_set_slots=64)
+    srv = Server(cfg, sinks=[cap, second], plugins=[], span_sinks=[])
+    srv.start()
+    try:
+        s = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+        for line in (b"t:5|ms|#a:1", b"t:7|ms|#a:2", b"c:1|c", b"g:2|g"):
+            s.sendto(line, ("127.0.0.1", srv.bound_port()))
+        deadline = time.monotonic() + 5
+        while srv.packets_received < 4 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert srv.drain(5)
+        srv.flush_once(timestamp=1)
+        cap.wait_for_flush(1)
+        second.wait_for_flush(1)
+        tick = srv.flight.last_tick()
+        deadline = time.monotonic() + 5
+        while time.monotonic() < deadline and any(
+                p["in_flight"] for p in tick.to_dict()["phases"]
+                if p["name"] == "sink.flush"):
+            time.sleep(0.01)
+        metas = {p["meta"]["sink"]: p["meta"]
+                 for p in tick.to_dict()["phases"]
+                 if p["name"] == "sink.flush"}
+        assert set(metas) == {"capture", "second"}
+        # the frame's rows are what a sink received less the loose
+        # self-metrics: two timers x (p50, count, max) + c + g
+        frame_rows = [m for m in cap.flushes[0]
+                      if not m.name.startswith("veneur.")]
+        assert len(frame_rows) == 8
+        built = sorted(m["rows_built"] for m in metas.values())
+        assert built == [0, 8]                  # built once, by one sink
+        for m in metas.values():
+            assert m["rows_fallback"] == 0
+            assert (m["build_ns"] > 0) == (m["rows_built"] > 0)
+            assert m["flushed"] == len(cap.flushes[0])
+        srv.flush_once(timestamp=2)     # reports flush 1's sink stats
+        cap.wait_for_flush(2)
+        by = {(m.name, tuple(m.tags)): m.value for m in cap.flushes[1]}
+        assert sorted(by["veneur.sink.rows_built_total", (f"sink:{n}",)]
+                      for n in metas) == [0, 8]
+        for n, m in metas.items():
+            assert by["veneur.sink.rows_built_total",
+                      (f"sink:{n}",)] == m["rows_built"]
+            assert by["veneur.sink.rows_fallback_total",
+                      (f"sink:{n}",)] == 0
+    finally:
+        srv.stop()
+
+
 def test_stats_address_ships_self_metrics_over_udp():
     rx = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
     rx.bind(("127.0.0.1", 0))

@@ -114,6 +114,34 @@ def test_na05_holds_the_real_tree():
     assert "st->c[bk].push_back(static_cast<int32_t>(st->order));" in text
 
 
+def test_gc01_the_collectors_switch_outside_the_guard():
+    # the import of a switch, disable/enable around a build, a
+    # threshold through an alias, a bare reference inside a class that
+    # only shares the guard's name; reads, collect() and the suppressed
+    # call stay silent
+    assert lint("gc01_bad.py") == [("GC01", 7), ("GC01", 11),
+                                   ("GC01", 15), ("GC01", 19),
+                                   ("GC01", 27)]
+
+
+def test_gc01_holds_the_real_tree_and_finds_the_guard():
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    tree = os.path.join(repo, "veneur_tpu")
+    assert [v for v in run_paths([tree]) if v.rule == "GC01"] == []
+    # and it is looking: the guard's two calls are there, and they are
+    # flagged the moment the class is not the configured home
+    from tools.vlint.config import DEFAULT_CONFIG
+    from tools.vlint.core import load_project
+    from tools.vlint.py_checks import check_gc01
+    mod = load_project(
+        [os.path.join(tree, "metrics.py")]).py_modules[0]
+    moved = dict(DEFAULT_CONFIG,
+                 gc01_home=("veneur_tpu/metrics.py", "Elsewhere"))
+    assert check_gc01(mod, DEFAULT_CONFIG) == []
+    found = check_gc01(mod, moved)
+    assert len(found) == 2 and {v.rule for v in found} == {"GC01"}
+
+
 def test_rs01_raw_egress_bypasses_resilience():
     # one urlopen + one grpc channel construction, exact lines
     assert lint("rs01_bad.py") == [("RS01", 9), ("RS01", 14)]

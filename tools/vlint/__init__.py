@@ -11,6 +11,7 @@ Checks (see tools/vlint/README.md for the full contract):
   NA03  native/Python SSF frame-layout divergence
   NA04  native/Python stats-array layout divergence
   NA05  a native stage parsed into before its arrival stamp is set
+  GC01  the collector's switch touched outside the row-building guard
   VL00  suppression without a reason
   VL01  file failed to parse
 

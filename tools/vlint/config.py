@@ -182,4 +182,14 @@ DEFAULT_CONFIG = {
         "veneur_tpu/kernels/",
         "/pk01_kernels_",
     ),
+    # GC01: the collector's switch (gc.disable / enable / freeze /
+    # set_threshold) is single-homed in the guard that holds collection
+    # off while a frame's rows are built (ISSUE 50; path substring
+    # match, /gc01_ scopes the check's own fixture in): the file and
+    # the class in it that may touch it.
+    "gc01_scope": (
+        "veneur_tpu/",
+        "/gc01_",
+    ),
+    "gc01_home": ("veneur_tpu/metrics.py", "_CollectorHold"),
 }

@@ -1,5 +1,5 @@
 """Python AST passes: JX01, JX02, JX03, TH01, CF01, RS01, SR02, DR01,
-DR02, TL01, OV01, SK01, DS01, QT01, PK01.
+DR02, TL01, OV01, SK01, DS01, QT01, PK01, GC01.
 
 All checks are intentionally conservative: they resolve only what can
 be resolved statically within the project (local jit wrappers, module
@@ -1522,6 +1522,61 @@ def check_qt01(mod: PyModule, config: dict) -> list[Violation]:
     return out
 
 
+# ------------------------------------------------------------------- GC01
+
+_GC01_SWITCHES = ("disable", "enable", "freeze", "set_threshold")
+
+
+def check_gc01(mod: PyModule, config: dict) -> list[Violation]:
+    """The collector's switch has one home (ISSUE 50): `gc.disable`,
+    `gc.enable`, `gc.freeze` and `gc.set_threshold` may appear only
+    inside the counted, re-entrant guard (`gc01_home`: a file and the
+    class in it) that holds generational collection off while a
+    frame's rows are built. A second caller anywhere under veneur_tpu/
+    would switch collection back on under a thread still inside the
+    guard, or leave it off for a process that never asked: the guard
+    counts its holders and restores what it found, a stray call does
+    neither. Flagged: an attribute of any name `import gc [as x]`
+    bound, called or not, and `from gc import <switch>`."""
+    if not any(s in mod.path for s in config["gc01_scope"]):
+        return []
+    home_path, home_class = config["gc01_home"]
+    inside = set()
+    if mod.path.endswith(home_path):
+        for node in ast.walk(mod.tree):
+            if isinstance(node, ast.ClassDef) and node.name == home_class:
+                inside.update(id(n) for n in ast.walk(node))
+    aliases = {"gc"}
+    out = []
+    for node in ast.walk(mod.tree):
+        if isinstance(node, ast.Import):
+            aliases.update(a.asname for a in node.names
+                           if a.name == "gc" and a.asname)
+        elif isinstance(node, ast.ImportFrom) and node.module == "gc":
+            for a in node.names:
+                if a.name in _GC01_SWITCHES or a.name == "*":
+                    out.append(Violation(
+                        mod.path, node.lineno, "GC01",
+                        f"`from gc import {a.name}` — the collector's "
+                        f"switch lives in {home_path}:{home_class} "
+                        "alone"))
+    for node in ast.walk(mod.tree):
+        if isinstance(node, ast.Attribute) \
+                and node.attr in _GC01_SWITCHES \
+                and isinstance(node.value, ast.Name) \
+                and node.value.id in aliases \
+                and id(node) not in inside:
+            out.append(Violation(
+                mod.path, node.lineno, "GC01",
+                f"gc.{node.attr} outside {home_path}:{home_class} — "
+                "the guard counts its holders across threads and "
+                "restores what it found; a second caller breaks both. "
+                "Build rows through MetricFrame.to_list() or suppress "
+                "with a reason"))
+    out.sort(key=lambda v: v.line)
+    return out
+
+
 # ------------------------------------------------------------------- driver
 
 def check_module(mod: PyModule, ctx: Context, config: dict
@@ -1544,4 +1599,5 @@ def check_module(mod: PyModule, ctx: Context, config: dict
     out.extend(check_ds01(mod, config))
     out.extend(check_qt01(mod, config))
     out.extend(check_pk01(mod, config))
+    out.extend(check_gc01(mod, config))
     return out

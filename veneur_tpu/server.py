@@ -3047,9 +3047,19 @@ class Server:
                     tel.set_gauge(scope, "sink.flush_duration_ns",
                                   dur_s * 1e9)
                     tel.mark(scope, "sink.flush_errors", 0 if ok else 1)
+                    # the rows a legacy sink's flush_frames made the
+                    # frames build: on the sink whose thread did the
+                    # build, zeros on one that found the list cached
+                    # or reads blocks (veneur.sink.rows_built_total /
+                    # rows_fallback_total)
+                    built = frameset.claim_build()
+                    tel.mark(scope, "sink.rows_built",
+                             built["rows_built"])
+                    tel.mark(scope, "sink.rows_fallback",
+                             built["rows_fallback"])
                     if tick is not None:
                         tick.finish(ph, sink=sink.name(), ok=ok,
-                                    flushed=count)
+                                    flushed=count, **built)
                         if phase_timers:
                             # per-sink fan-out child timer
                             # (veneur.flush.phase.fanout.<sink>):
