@@ -58,8 +58,13 @@ def _settle(srv, lines):
     assert srv.drain(20)
 
 
-def _values(sink):
-    return {m.name: m.value for m in sink.flushes[-1]}
+def _flushed(srv, sink, timestamp):
+    """One flush's rows by name, once the sink's own thread (which the
+    flusher does not join) has taken them."""
+    n = len(sink.flushes)
+    srv.flush_once(timestamp=timestamp)
+    assert sink.wait_for_flush(n + 1, timeout=30)
+    return {m.name: m.value for m in sink.flushes[n]}
 
 
 # ------------------------------------------------ sixteen sockets, six ticks
@@ -108,8 +113,8 @@ def sixteen():
                     sent_packets += 1
             _settle(srv, sent_lines)
             before = srv.native_bridge.stats()
-            srv.flush_once(timestamp=1_000 + 10 * tick)
-            ticks.append((want, touched, before, _values(sink),
+            got = _flushed(srv, sink, 1_000 + 10 * tick)
+            ticks.append((want, touched, before, got,
                           dict(srv.engines[0]._last_flush_info),
                           srv.flight.last_tick().phases()))
         stats = srv.native_bridge.stats()
@@ -254,8 +259,7 @@ def test_a_handed_over_gauge_reads_its_second_writer_50_of_50():
                 second.write("\n".join(new).encode())
             lines += len(old) + len(new)
             _settle(srv, lines)
-            srv.flush_once(timestamp=2_000 + 10 * tick)
-            got = _values(sink)
+            got = _flushed(srv, sink, 2_000 + 10 * tick)
             assert got["mr.only.first"] == tick
             assert got["mr.only.second"] == tick
             right += got["mr.handed"] == 1000 + tick + 0.75
@@ -279,14 +283,12 @@ def test_a_gauge_of_the_python_path_takes_the_bridges_order():
         br.handle_packet(b"mr.other:3|g")
         _settle(srv, 3)
         assert srv.engines[0].gauge_clock is not None
-        srv.flush_once(timestamp=3_000)
-        assert _values(sink)["mr.slow"] == 10.0
+        assert _flushed(srv, sink, 3_000)["mr.slow"] == 10.0
         br.handle_packet(b"mr.slow:2_0|g")
         _settle(srv, 4)
         br.handle_packet(b"mr.slow:7|g")
         _settle(srv, 5)
-        srv.flush_once(timestamp=3_010)
-        assert _values(sink)["mr.slow"] == 7.0
+        assert _flushed(srv, sink, 3_010)["mr.slow"] == 7.0
     finally:
         srv.stop()
 

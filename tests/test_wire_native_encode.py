@@ -513,8 +513,10 @@ def test_the_sends_phase_says_who_wrote_the_sketches():
         "forward.export", "forward.chunk.plan", "forward.chunk.build",
         "forward.chunk.serialize", "forward.release")] == [
             1, 1, len(sent), len(sent), 1]
+    # a hand-built export: its columns were read from its tuples
     assert rows["forward.export"][0].meta == {
-        "n_metrics": 33, "encode_native": 33, "encode_fallback": 0}
+        "n_metrics": 33, "encode_native": 33, "encode_fallback": 0,
+        "export_direct": 0, "export_tuples": 33}
     assert [s.meta["nbytes"] for s in rows["forward.chunk.serialize"]] \
         == [len(r) for r in sent]
     # none of them of no length: a phase whose ends are one instant is

@@ -77,6 +77,24 @@ def _count_forward_bytes(egress: Egress, nbytes: int, kind: str):
              else "forward.bytes_full", nbytes)
 
 
+def _count_export_form(egress: Egress, export: ForwardExport,
+                       direct: bool, n: int) -> dict:
+    """In what form a send's `n` sketches reached the forwarder, as
+    the `forward.export` phase's attributes: `export_direct`, as the
+    flush's own columns, or `export_tuples`, read from the export's
+    tuples (a hand-built, re-merged or replayed export, the q16 row,
+    the HTTP body, no library). Counted under the destination with the
+    tuples that were built from columns for some reader before the
+    send, the journal's write-ahead for one
+    (veneur.forward.export_direct_total / export_lazy_total)."""
+    form = {"export_direct": n if direct else 0,
+            "export_tuples": 0 if direct else n}
+    reg, dest = egress.registry, egress.destination
+    reg.incr(dest, "forward.export_direct", form["export_direct"])
+    reg.incr(dest, "forward.export_lazy", export.take_lazy_built())
+    return form
+
+
 # A MetricList must fit the receiver's gRPC message limit, 4 MiB unless
 # it was raised: 1,000 HLL p=14 sets are 16 MiB of registers, far past
 # it at 10,000 metrics a chunk. Chunks close at this many payload
@@ -168,6 +186,10 @@ class GrpcForwarder:
         request is its metrics' bytes and then what protobuf
         serializes of a MetricList without them (envelope, advisory
         rows, stamp: the higher field numbers), the same bytes.
+        The columns are the flush's own where the export still has
+        them (ForwardExport.columns: no tuple a key was ever built,
+        `export_direct`), else they are read from the export's tuples
+        (`export_tuples`: a replayed, re-merged or hand-built export).
 
         Flight-recorder phases, beside the ladder's `egress.attempt`
         (what is left of a chunk: wire, far end, reply):
@@ -177,9 +199,12 @@ class GrpcForwarder:
         tick, par = stamping_scope()
         ph = tick.start("forward.export", par)
         encoded = metrics = None
+        # the flush's own columns, if nothing has changed a list since
+        direct = export.columns is not None
         if self._encode is not None and self.centroid_codec == "lossless":
             encoded = wire.encode_export(export, self._encode)
         if encoded is None:
+            direct = False
             metrics = wire.export_to_metrics(export,
                                              codec=self.centroid_codec)
         # sketches by who wrote them, a send
@@ -189,7 +214,9 @@ class GrpcForwarder:
         reg.incr(dest, "forward.encode_native", n_native)
         reg.incr(dest, "forward.encode_fallback", n_fallback)
         tick.finish(ph, n_metrics=n_native + n_fallback,
-                    encode_native=n_native, encode_fallback=n_fallback)
+                    encode_native=n_native, encode_fallback=n_fallback,
+                    **_count_export_form(self._egress, export, direct,
+                                         n_native + n_fallback))
         deadline = self._egress.deadline()
         ph = tick.start("forward.chunk.plan", par)
         bounds = (_chunk_bounds(metrics, self.max_per_batch)
@@ -245,8 +272,12 @@ class GrpcForwarder:
                         f"{self.address}: {e}") from e
                 if j == 0:
                     raise    # nothing delivered: spill the whole export
+                tail = _export_tail(export, i)
+                # the tail's tuples, built from the columns just now
+                reg.incr(dest, "forward.export_lazy",
+                         export.take_lazy_built())
                 raise PartialDeliveryError(
-                    _export_tail(export, i), e, delivered_chunks=j,
+                    tail, e, delivered_chunks=j,
                     chunk_count=total or n_chunks) from e
             _count_forward_bytes(self._egress, len(data), kind)
         # what a send held a sketch dies here, under a name of its
@@ -411,7 +442,9 @@ class HttpJsonForwarder:
         tick, par = stamping_scope()
         ph = tick.start("forward.export", par)
         body = self._body_entries(export)
-        tick.finish(ph, n_metrics=len(body))
+        tick.finish(ph, n_metrics=len(body),
+                    **_count_export_form(self._egress, export, False,
+                                         len(body)))
         deadline = self._egress.deadline()
         n_chunks = -(-len(body) // self.max_per_body)
         total = 0

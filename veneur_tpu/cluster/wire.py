@@ -13,8 +13,8 @@ import json
 import logging
 import struct
 import threading
-from itertools import chain
-from operator import itemgetter
+from itertools import chain, repeat
+from operator import attrgetter, itemgetter
 from typing import NamedTuple
 
 import numpy as np
@@ -22,7 +22,7 @@ import numpy as np
 from .. import sketches
 from ..ingest.parser import (GLOBAL_ONLY, LOCAL_ONLY, MIXED_SCOPE,
                              MetricKey)
-from ..models.pipeline import ForwardExport
+from ..models.pipeline import FlushColumns, ForwardExport
 from ..utils.hashing import metric_digest
 from .protos import forward_pb2, metric_pb2
 
@@ -962,9 +962,11 @@ class ExportColumns(NamedTuple):
     """A ForwardExport as flat arrays in wire order (histograms, sets,
     counters, gauges: metric i of export_to_metrics is entry i here):
     what vtpu_wire_encode takes. Every `*_off` is int64, starts at 0
-    and has one entry more than the things it bounds. The one seam at
-    which a flush could hand over its own planes and rows instead of a
-    tuple a key."""
+    and has one entry more than the things it bounds. The seam at
+    which a flush hands over its own columns (ForwardExport.columns, a
+    FlushColumns) instead of a tuple a key: the value arrays are then
+    the flush's as they are, and only the keys' names and tags are
+    joined here."""
     counts: np.ndarray      # int64[4]: histograms, sets, counters, gauges
     names: bytes            # every key's name, UTF-8, end to end
     name_off: np.ndarray
@@ -998,44 +1000,67 @@ def _utf8_column(strings: list) -> tuple:
     return b"".join(encoded), _offsets(map(len, encoded), len(encoded))
 
 
-def export_columns(export: ForwardExport):
-    """ForwardExport -> ExportColumns, or None for an export whose
-    centroid lists the columns cannot hold as they are (a digest with
-    more means than weights or the reverse, which export_to_metrics
-    cuts to the shorter). No loop over centroids and none that builds
-    an object a sketch: a few passes over the tuples' fields."""
+_KEY_NAME = attrgetter("name")
+_KEY_TYPE = attrgetter("type")
+_KEY_TAGS = attrgetter("joined_tags")
+
+
+def _columns_from_tuples(export: ForwardExport):
+    """The FlushColumns of an export that has only its entry lists (one
+    built by hand, re-merged from a spill, read back from a journal, or
+    changed since its flush), or None for one whose centroid lists the
+    columns cannot hold as they are (a digest with more means than
+    weights or the reverse, which export_to_metrics cuts to the
+    shorter). No loop over centroids and none that builds an object a
+    sketch: a few passes over the tuples' fields."""
     hs = export.histograms
     n_h = len(hs)
-    keys = [e[0] for e in hs]
-    for entries in (export.sets, export.counters, export.gauges):
-        keys += [e[0] for e in entries]
-    names, name_off = _utf8_column([k.name for k in keys])
-    tags, tag_off = _utf8_column([k.joined_tags for k in keys])
     means, weights = [e[1] for e in hs], [e[2] for e in hs]
     if list(map(len, means)) != list(map(len, weights)):
         return None
-    cent_off = _offsets(map(len, means), n_h)
-    means = np.concatenate(means) if n_h else np.empty(0, np.float32)
-    weights = np.concatenate(weights) if n_h else np.empty(0, np.float32)
+    return FlushColumns(
+        tuple([e[0] for e in entries] for entries in (
+            hs, export.sets, export.counters, export.gauges)),
+        _offsets(map(len, means), n_h),
+        np.concatenate(means) if n_h else np.empty(0, np.float32),
+        np.concatenate(weights) if n_h else np.empty(0, np.float32),
+        np.fromiter(chain.from_iterable(e[3:8] for e in hs), np.float64,
+                    5 * n_h).reshape(n_h, 5),
+        [regs for _key, regs in export.sets],
+        np.array([v for _key, v in export.counters], np.float64),
+        np.array([v for _key, v in export.gauges], np.float64))
+
+
+def export_columns(export: ForwardExport):
+    """ForwardExport -> ExportColumns, or None for an export the
+    columns cannot hold (_columns_from_tuples). An export that still
+    has its flush's columns hands them over untouched, and no entry
+    list is read or built; any other is read from its tuples."""
+    cols = export.columns
+    if cols is None:
+        cols = _columns_from_tuples(export)
+        if cols is None:
+            return None
+    keys = list(chain.from_iterable(cols.keys))
+    names, name_off = _utf8_column(list(map(_KEY_NAME, keys)))
+    tags, tag_off = _utf8_column(list(map(_KEY_TAGS, keys)))
+    n_h = len(cols.keys[0])
+    means, weights = cols.means, cols.weights
     if not (means.dtype == weights.dtype == np.float32):
         # a re-merged or hand-built export: float() of each, as the
         # protobuf setter takes it
         means = means.astype(np.float64)
         weights = weights.astype(np.float64)
     payloads = [encode_set_payload(export.set_engine, regs)
-                for _key, regs in export.sets]
+                for regs in cols.regs]
     return ExportColumns(
-        np.array([n_h, len(export.sets), len(export.counters),
-                  len(export.gauges)], np.int64),
+        np.array(list(map(len, cols.keys)), np.int64),
         names, name_off, tags, tag_off,
-        np.fromiter((_TYPE_TO_PB.get(k.type, metric_pb2.Histogram)
-                     for k in keys[:n_h]), np.uint8, n_h),
-        cent_off, means, weights,
-        np.fromiter(chain.from_iterable(e[3:8] for e in hs), np.float64,
-                    5 * n_h).reshape(n_h, 5),
+        np.fromiter(map(_TYPE_TO_PB.get, map(_KEY_TYPE, cols.keys[0]),
+                        repeat(metric_pb2.Histogram)), np.uint8, n_h),
+        cols.cent_off, means, weights, cols.stats,
         b"".join(payloads), _offsets(map(len, payloads), len(payloads)),
-        np.rint(np.array([v for _key, v in export.counters], np.float64)),
-        np.array([v for _key, v in export.gauges], np.float64))
+        np.rint(cols.counters), cols.gauges)
 
 
 # what a metric can take on the wire beyond its key's, its set's and its

@@ -30,7 +30,8 @@ from __future__ import annotations
 import functools
 import threading
 import time
-from dataclasses import dataclass, field as dc_field
+from dataclasses import dataclass
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -504,6 +505,69 @@ class _ColdTail:
     def __getitem__(self, r):
         return self.rows[r] if r < len(self.rows) else self.cold
 
+    def take(self, rows, lanes) -> np.ndarray:
+        """[len(rows), lanes] of the leaf: rows `rows` (an index
+        array), their first `lanes` lanes; the cold row for index D."""
+        d = len(self.rows)
+        cold = rows >= d
+        if not d:
+            return np.repeat(self.cold[None, :lanes], len(rows), axis=0)
+        if not cold.any():
+            return self.rows[rows, :lanes]
+        out = self.rows[np.where(cold, 0, rows), :lanes]
+        out[cold] = self.cold[:lanes]
+        return out
+
+
+def _take_rows(leaf, rows, lanes=None) -> np.ndarray:
+    """A fresh [len(rows), lanes] of a wide flush leaf, a plain [S, C]
+    plane or a _ColdTail; every lane where `lanes` is None."""
+    if isinstance(leaf, _ColdTail):
+        return leaf.take(rows, lanes)
+    return np.asarray(leaf)[rows, :lanes]
+
+
+# Rows a block of _live_points: a block of the widest plane stays
+# some 4 MiB, which the allocator hands back warm to the next block
+# (a whole [n, C] pick of 100,000 rows is 100 MB of fresh pages a
+# plane, ten times the cost of the pick itself).
+_POINT_BLOCK_BYTES = 4 << 20
+
+
+def _joined(parts: list) -> np.ndarray:
+    """The float64 arrays of `parts` end to end."""
+    return (np.concatenate(parts) if parts
+            else np.empty(0, np.float64))
+
+
+def _live_points(h_weight, h_mean, rows) -> tuple:
+    """The live centroids of rows `rows` of the two centroid leaves,
+    as the forward wire takes them: (cent_off int64[n + 1], means,
+    weights), a row's points with weight > 0 in lane order, row after
+    row (the concatenation of `mean[row][w > 0]`, `w[w > 0]` over the
+    rows). A block of rows at a time, and of the mean plane only the
+    lanes up to the block's widest occupied one."""
+    n = len(rows)
+    cent_off = np.zeros(n + 1, np.int64)
+    if not n:
+        return (cent_off, np.empty(0, np.float32),
+                np.empty(0, np.float32))
+    width = _take_rows(h_weight, rows[:1]).shape[1]
+    step = max(1, _POINT_BLOCK_BYTES // (4 * max(1, width)))
+    counts, means, weights = [], [], []
+    for a in range(0, n, step):
+        blk = rows[a:a + step]
+        w = _take_rows(h_weight, blk)
+        live = w > 0
+        lanes = np.flatnonzero(live.any(axis=0))
+        hi = int(lanes[-1]) + 1 if lanes.size else 0
+        live = live[:, :hi]
+        counts.append(live.sum(axis=1))
+        weights.append(w[:, :hi][live])
+        means.append(_take_rows(h_mean, blk, hi)[live])
+    np.cumsum(np.concatenate(counts), out=cent_off[1:])
+    return cent_off, np.concatenate(means), np.concatenate(weights)
+
 
 def _row_maps(ids, dirty, nrows) -> list:
     """Per bank kind, slot -> row of the compact flush outputs: a dirty
@@ -586,36 +650,209 @@ class EngineConfig:
     flush_double_buffer: bool = True
 
 
-@dataclass
+class FlushColumns(NamedTuple):
+    """A flush's export as it had it in hand, before any tuple a key:
+    the keys of each kind in wire order and the values as arrays, the
+    form wire.export_columns hands the native pass (ForwardExport
+    carries it; the entry lists are built from it on their first
+    read)."""
+    keys: tuple             # four lists of MetricKey: h, sets, c, g
+    cent_off: np.ndarray    # int64[n_h + 1] into means / weights
+    means: np.ndarray       # float32, live centroids only, row-major
+    weights: np.ndarray     # float32, every one > 0
+    stats: np.ndarray       # float64[n_h, 5]: min max sum count recip
+    regs: list              # a register row u8[m] a set
+    counters: np.ndarray    # float64[n_c]
+    gauges: np.ndarray      # float64[n_g]
+
+
+class _Entries(list):
+    """One entry list of a ForwardExport that still has its flush's
+    columns: a list in every way, and the first mutation tells the
+    export, which drops the columns (what the forwarder then sends is
+    read from the lists)."""
+
+    __slots__ = ("_export",)
+
+
+def _telling_first(name):
+    method = getattr(list, name)
+
+    def through(self, *args, **kw):
+        export, self._export = self._export, None
+        if export is not None:
+            export._drop_columns()
+        return method(self, *args, **kw)
+    through.__name__ = name
+    return through
+
+
+for _name in ("append", "extend", "insert", "pop", "remove", "clear",
+              "sort", "reverse", "__setitem__", "__delitem__",
+              "__iadd__", "__imul__"):
+    setattr(_Entries, _name, _telling_first(_name))
+del _name
+
+
 class ForwardExport:
     """Global-scope state to send upstream, one entry per key — the
     Export()/Metric() payloads of samplers (samplers.go sym: Histo.Metric,
-    Set.Export, Counter.Export)."""
-    histograms: list = dc_field(default_factory=list)
-    # (key, means f32[n], weights f32[n], min, max, sum, count, recip)
-    sets: list = dc_field(default_factory=list)        # (key, registers u8[m])
-    counters: list = dc_field(default_factory=list)    # (key, value)
-    gauges: list = dc_field(default_factory=list)      # (key, value)
-    # which set engine produced `sets` (selects the register wire code
-    # and the spill re-merge join); histograms are engine-agnostic
-    # weighted points on the wire
-    set_engine: str = "hll"
-    # per-prefix Huffman-Bucket cardinality sketches riding to the
-    # global tier (overload-defense satellite): [(prefix, bytes regs)];
-    # merge-by-max, advisory — excluded from the durability journal
-    prefix_sketches: list = dc_field(default_factory=list)
-    # What this export IS (ISSUE 13 delta forwarding): "full" = the
-    # sender's COMPLETE interned counter/set key set (idle keys ship
-    # their zero totals / empty register banks — the receiver-liveness
-    # refresh a resync exists for); "delta" = only the keys the
-    # dirty-slot bitmap saw land this interval. Histograms and gauges
-    # are touched-only under EITHER kind, deliberately: a zero-count
-    # histogram row would be live-filtered out of the receiver's own
-    # flush anyway (pure wire waste), and a synthetic zero gauge would
-    # CLOBBER the receiver's last-write-wins state. The forwarder
-    # stamps the kind onto the interval's envelope so the receiver can
-    # gap-check deltas.
-    kind: str = "full"
+    Set.Export, Counter.Export).
+
+    Built by hand it is four lists of tuples, as it always was. Built
+    by a flush it holds the flush's own columns (`columns`, a
+    FlushColumns) and no tuple: the gRPC forwarder's native pass takes
+    the columns as they are (wire.export_columns), `counts()` sizes it,
+    and whoever reads an entry list (the q16 codec, the HTTP forwarder,
+    a partial delivery's tail, the spill, the journal, the history
+    tier) gets that list built from the columns on its first read, the
+    tuples the flush used to build, counted in `lazy_built`. Changing a
+    list, or assigning one, drops the columns, so the two can never
+    disagree."""
+
+    _KINDS = ("histograms", "sets", "counters", "gauges")
+
+    def __init__(self, histograms=None, sets=None, counters=None,
+                 gauges=None, set_engine: str = "hll",
+                 prefix_sketches=None, kind: str = "full",
+                 columns: FlushColumns | None = None):
+        # (key, means f32[n], weights f32[n], min, max, sum, count, recip)
+        # (key, registers u8[m]); (key, value); (key, value); None = not
+        # read yet, the columns have it
+        lists = [histograms, sets, counters, gauges]
+        self._lists = (lists if columns is not None else
+                       [[] if e is None else e for e in lists])
+        self._columns = columns
+        # entries built from the columns for a reader of tuples
+        self.lazy_built = 0
+        # which set engine produced `sets` (selects the register wire code
+        # and the spill re-merge join); histograms are engine-agnostic
+        # weighted points on the wire
+        self.set_engine = set_engine
+        # per-prefix Huffman-Bucket cardinality sketches riding to the
+        # global tier (overload-defense satellite): [(prefix, bytes regs)];
+        # merge-by-max, advisory — excluded from the durability journal
+        self.prefix_sketches = ([] if prefix_sketches is None
+                                else prefix_sketches)
+        # What this export IS (ISSUE 13 delta forwarding): "full" = the
+        # sender's COMPLETE interned counter/set key set (idle keys ship
+        # their zero totals / empty register banks — the receiver-liveness
+        # refresh a resync exists for); "delta" = only the keys the
+        # dirty-slot bitmap saw land this interval. Histograms and gauges
+        # are touched-only under EITHER kind, deliberately: a zero-count
+        # histogram row would be live-filtered out of the receiver's own
+        # flush anyway (pure wire waste), and a synthetic zero gauge would
+        # CLOBBER the receiver's last-write-wins state. The forwarder
+        # stamps the kind onto the interval's envelope so the receiver can
+        # gap-check deltas.
+        self.kind = kind
+
+    def __repr__(self):
+        return ("ForwardExport(%s, set_engine=%r, kind=%r%s)" % (
+            ", ".join("%s=%d" % nc for nc in zip(self._KINDS,
+                                                   self.counts())),
+            self.set_engine, self.kind,
+            "" if self._columns is None else ", columns"))
+
+    @property
+    def columns(self) -> FlushColumns | None:
+        """The flush's columns while no list has been changed."""
+        return self._columns
+
+    def counts(self) -> tuple:
+        """Entries of each kind (histograms, sets, counters, gauges),
+        without building one."""
+        cols = self._columns
+        if cols is not None:
+            return tuple(map(len, cols.keys))
+        return tuple(map(len, self._lists))
+
+    def take_lazy_built(self) -> int:
+        """`lazy_built`, read and reset (a forwarder counts it a send)."""
+        n, self.lazy_built = self.lazy_built, 0
+        return n
+
+    def _entries(self, i: int) -> list:
+        got = self._lists[i]
+        if got is None:
+            got = self._lists[i] = _Entries(self._build(i))
+            got._export = self
+            self.lazy_built += len(got)
+        return got
+
+    def _build(self, i: int):
+        """Kind `i`'s tuples from the columns, as the flush built them
+        a key: centroid arrays of a digest's own length, Python floats."""
+        cols = self._columns
+        keys = cols.keys[i]
+        if i == 0:
+            off = cols.cent_off.tolist()
+            return [(key, cols.means[a:b], cols.weights[a:b], *five)
+                    for key, a, b, five in zip(keys, off, off[1:],
+                                               cols.stats.tolist())]
+        if i == 1:
+            return zip(keys, cols.regs)
+        return zip(keys, (cols.counters if i == 2
+                          else cols.gauges).tolist())
+
+    def _drop_columns(self):
+        """A list is about to change: every list that was not read yet
+        is built first, then the columns go."""
+        if self._columns is None:
+            return
+        for i, got in enumerate(self._lists):
+            if got is None:
+                got = self._entries(i)
+            if isinstance(got, _Entries):
+                got._export = None
+        self._columns = None
+
+    def _assign(self, i: int, entries):
+        self._drop_columns()
+        self._lists[i] = entries
+
+    @classmethod
+    def joined(cls, exports: list) -> "ForwardExport":
+        """The exports of a server's engines as one, engine after
+        engine within each kind (wire order): the one export itself
+        where there is one, their columns end to end where every one
+        still has them, else their lists. Set engine and prefix rows
+        are the last one's; the caller sets `kind`."""
+        if len(exports) == 1:
+            return exports[0]
+        out = cls(set_engine=exports[-1].set_engine,
+                  prefix_sketches=exports[-1].prefix_sketches)
+        parts = [e.columns for e in exports]
+        if None in parts:
+            for e in exports:
+                for mine, theirs in zip(out._lists, (
+                        e.histograms, e.sets, e.counters, e.gauges)):
+                    mine.extend(theirs)
+            return out
+        # a later engine's centroid offsets start where the one
+        # before it ended
+        ends = np.cumsum([0] + [len(c.means) for c in parts])
+        out._lists = [None] * 4
+        out._columns = FlushColumns(
+            tuple(sum((c.keys[i] for c in parts), []) for i in range(4)),
+            np.concatenate([parts[0].cent_off[:1]] + [
+                c.cent_off[1:] + end for c, end in zip(parts, ends)]),
+            np.concatenate([c.means for c in parts]),
+            np.concatenate([c.weights for c in parts]),
+            np.concatenate([c.stats for c in parts]),
+            sum((c.regs for c in parts), []),
+            np.concatenate([c.counters for c in parts]),
+            np.concatenate([c.gauges for c in parts]))
+        return out
+
+    histograms = property(lambda self: self._entries(0),
+                          lambda self, v: self._assign(0, v))
+    sets = property(lambda self: self._entries(1),
+                    lambda self, v: self._assign(1, v))
+    counters = property(lambda self: self._entries(2),
+                        lambda self, v: self._assign(2, v))
+    gauges = property(lambda self: self._entries(3),
+                      lambda self, v: self._assign(3, v))
 
 
 class FlushResult:
@@ -2529,15 +2766,25 @@ class AggregationEngine:
                                          sidestep_bank=sidestep[1])
         t_device = time.monotonic_ns()
 
-        def slot_rows(kind, infos):
-            """(slots, rows) of a key table of bank `kind`: where each
-            slot's row is in `host` — the slot itself after the full
-            program, its compact row (the baseline row for a cold
-            slot) after the incremental one."""
-            slots = np.fromiter((t[1] for t in infos), np.int64,
-                                len(infos))
-            return slots, (slots if row_of is None
-                           else row_of[kind][slots])
+        def bank_columns(kind, infos):
+            """A key table of bank `kind` in one pass: its keys, its
+            slots, where each slot's row is in `host` (the slot itself
+            after the full program, its compact row, the baseline row
+            for a cold slot, after the incremental one) and its
+            scopes."""
+            bkeys, slots, scopes, _holders = zip(*infos)
+            slots = np.array(slots, np.int64)
+            return (bkeys, slots,
+                    slots if row_of is None else row_of[kind][slots],
+                    np.array(scopes, np.int64))
+
+        def picked(column, idx) -> list:
+            return list(map(column.__getitem__, idx.tolist()))
+
+        def ships(kind, bkeys, idx, values):
+            """Keys `idx` of a bank's table leave with `values`."""
+            x_keys[kind].extend(picked(bkeys, idx))
+            x_values[kind].append(values)
 
         # Delta export build (ISSUE 13): honor the request only when
         # the retired bitmap exists — it travels with exactly the bank
@@ -2546,8 +2793,11 @@ class AggregationEngine:
         want_delta = (forward_kind == "delta" and fwd_out
                       and dirty is not None)
         frame = MetricFrame(ts, cfg.hostname)
-        export = ForwardExport(set_engine=self._seng.id,
-                               kind="delta" if want_delta else "full")
+        # the export, as columns (FlushColumns): a kind's keys in wire
+        # order and its values, picked by index arrays; no tuple a key
+        x_keys, x_values = ([], [], [], []), ([], [], [], [])
+        x_points = _live_points((), (), ())
+        x_stats = np.empty((0, 5), np.float64)
 
         # ---- histograms: vectorized gathers over the active set ----
         infos = active["histo"]
@@ -2570,31 +2820,27 @@ class AggregationEngine:
             ci = self._agg_idx.get("count")
             live_cnt = (aggmat[:, ci] if ci is not None
                         else np.asarray(host["cnt"], np.float64))
-            n = len(infos)
-            _slots, rows = slot_rows(0, infos)
-            scopes = np.fromiter((t[2] for t in infos), np.int64, n)
+            bkeys, _slots, rows, scopes = bank_columns(0, infos)
             live = live_cnt[rows] > 0
             if fwd_out:
-                h_sum = (np.asarray(host["h_sum"], np.float64)
-                         + np.asarray(host["h_sum_lo"], np.float64))
-                h_count = (np.asarray(host["h_count"], np.float64)
-                           + np.asarray(host["h_count_lo"], np.float64))
-                h_recip = (np.asarray(host["h_recip"], np.float64)
-                           + np.asarray(host["h_recip_lo"], np.float64))
                 exp_m = live & (scopes != LOCAL_ONLY)
                 full_m = live & (scopes == LOCAL_ONLY)
                 aggonly_m = exp_m & (scopes != GLOBAL_ONLY)
-                for i in np.nonzero(exp_m)[0].tolist():
-                    key, row = infos[i][0], rows[i]
-                    w = host["h_weight"][row]
-                    nz = w > 0
-                    export.histograms.append((
-                        key, host["h_mean"][row][nz], w[nz],
-                        float(host["h_min"][row]),
-                        float(host["h_max"][row]),
-                        float(h_sum[row]),
-                        float(h_count[row]),
-                        float(h_recip[row])))
+                idx = np.flatnonzero(exp_m)
+                erows = rows[idx]
+                x_keys[0].extend(picked(bkeys, idx))
+                x_points = _live_points(host["h_weight"], host["h_mean"],
+                                        erows)
+                # min and max widened from f32, the three sums hi + lo
+                x_stats = np.empty((len(idx), 5), np.float64)
+                x_stats[:, 0] = np.asarray(host["h_min"])[erows]
+                x_stats[:, 1] = np.asarray(host["h_max"])[erows]
+                for j, name in ((2, "h_sum"), (3, "h_count"),
+                                (4, "h_recip")):
+                    x_stats[:, j] = (
+                        np.asarray(host[name], np.float64)[erows]
+                        + np.asarray(host[name + "_lo"],
+                                     np.float64)[erows])
             else:
                 full_m = live
                 aggonly_m = None
@@ -2623,12 +2869,10 @@ class AggregationEngine:
             c_tot = (np.asarray(host["c_hi"], np.float64)
                      + np.asarray(host["c_lo"], np.float64))
         if infos:
-            n = len(infos)
-            slots, rows = slot_rows(1, infos)
+            bkeys, slots, rows, scopes = bank_columns(1, infos)
             totals = c_tot[rows]
-            keep = range(n)
+            keep = range(len(infos))
             if fwd_out:
-                scopes = np.fromiter((t[2] for t in infos), np.int64, n)
                 gm = scopes == GLOBAL_ONLY
                 if want_delta:
                     # DELTA wire: only counters the dirty bitmap saw
@@ -2641,9 +2885,8 @@ class AggregationEngine:
                 else:
                     em = gm     # no full table (mesh): touched set
                 if em is not None:
-                    for i in np.nonzero(em)[0].tolist():
-                        export.counters.append(
-                            (infos[i][0], float(totals[i])))
+                    idx = np.flatnonzero(em)
+                    ships(2, bkeys, idx, totals[idx])
                 keep = np.nonzero(~gm)[0].tolist()
             keep = list(keep)
             if keep:
@@ -2656,23 +2899,20 @@ class AggregationEngine:
             # idle zeros included — the receiver-liveness refresh a
             # steady-state delta deliberately skips. Wire only; the
             # local frame above stays touched-keys-only.
-            _slots, rows = slot_rows(1, all_infos)
-            for (key, _slot, scope, _h), row in zip(all_infos, rows):
-                if scope == GLOBAL_ONLY:
-                    export.counters.append((key, float(c_tot[row])))
+            bkeys, _slots, rows, scopes = bank_columns(1, all_infos)
+            idx = np.flatnonzero(scopes == GLOBAL_ONLY)
+            ships(2, bkeys, idx, c_tot[rows[idx]])
 
         # ---- gauges ----
         infos = active["gauge"]
         if infos:
-            n = len(infos)
-            _slots, rows = slot_rows(2, infos)
+            bkeys, _slots, rows, scopes = bank_columns(2, infos)
             live = np.asarray(host["g_seq"])[rows] >= 0
             vals = np.asarray(host["g_value"], np.float64)[rows]
             if fwd_out:
-                scopes = np.fromiter((t[2] for t in infos), np.int64, n)
                 gm = live & (scopes == GLOBAL_ONLY)
-                for i in np.nonzero(gm)[0].tolist():
-                    export.gauges.append((infos[i][0], float(vals[i])))
+                idx = np.flatnonzero(gm)
+                ships(3, bkeys, idx, vals[idx])
                 keep = np.nonzero(live & ~gm)[0].tolist()
             else:
                 keep = np.nonzero(live)[0].tolist()
@@ -2685,13 +2925,12 @@ class AggregationEngine:
         # ---- sets ----
         infos = active["set"]
         all_infos = active.get("set_all")
+        s_regs = host.get("s_regs")
         if infos:
-            n = len(infos)
-            slots, rows = slot_rows(3, infos)
+            bkeys, slots, rows, scopes = bank_columns(3, infos)
             ests = np.asarray(host["s_est"], np.float64)[rows]
-            keep = range(n)
+            keep = range(len(infos))
             if fwd_out:
-                scopes = np.fromiter((t[2] for t in infos), np.int64, n)
                 fm = scopes != LOCAL_ONLY
                 if want_delta:
                     # untouched set slots hold all-zero registers —
@@ -2704,9 +2943,8 @@ class AggregationEngine:
                 else:
                     em = fm
                 if em is not None:
-                    for i in np.nonzero(em)[0].tolist():
-                        export.sets.append(
-                            (infos[i][0], host["s_regs"][rows[i]]))
+                    idx = np.flatnonzero(em)
+                    ships(1, bkeys, idx, picked(s_regs, rows[idx]))
                 keep = np.nonzero(~fm)[0].tolist()
             keep = list(keep)
             if keep:
@@ -2718,10 +2956,17 @@ class AggregationEngine:
             # FULL resync: every interned non-local set ships its
             # registers (idle = all-zero banks, a merge no-op that
             # keeps the key alive at the receiver)
-            _slots, rows = slot_rows(3, all_infos)
-            for (key, _slot, scope, _h), row in zip(all_infos, rows):
-                if scope != LOCAL_ONLY:
-                    export.sets.append((key, host["s_regs"][row]))
+            bkeys, _slots, rows, scopes = bank_columns(3, all_infos)
+            idx = np.flatnonzero(scopes != LOCAL_ONLY)
+            ships(1, bkeys, idx, picked(s_regs, rows[idx]))
+
+        export = ForwardExport(
+            set_engine=self._seng.id,
+            kind="delta" if want_delta else "full",
+            columns=FlushColumns(
+                x_keys, *x_points, x_stats, sum(x_values[1], []),
+                _joined(x_values[2]), _joined(x_values[3]))
+            if fwd_out else None)
 
         # ---- status checks (StatusCheck sampler flush shape) ----
         status_metrics = [

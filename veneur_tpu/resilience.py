@@ -720,8 +720,8 @@ class SpillBuffer:
 
 
 def _export_size(export) -> int:
-    return (len(export.histograms) + len(export.sets)
-            + len(export.counters) + len(export.gauges))
+    """Sketches in `export`, counted without building an entry."""
+    return sum(export.counts())
 
 
 class _ReplayEntry:

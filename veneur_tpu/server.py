@@ -2107,7 +2107,6 @@ class Server:
         `grafts` the (rows, root's meta) of each kind that flush_once
         grafts when the tick ends."""
         frames = []
-        merged_export = ForwardExport()
         events, checks = [], []
         status_metrics = []
         eng_stats = {"samples": 0, "dropped_no_slot": 0,
@@ -2201,14 +2200,12 @@ class Server:
                         meta[k] = meta.get(k, 0) + ns
             frames.append(res.frame)
             status_metrics.extend(res.status_metrics)
-            merged_export.histograms.extend(res.export.histograms)
-            merged_export.sets.extend(res.export.sets)
-            merged_export.counters.extend(res.export.counters)
-            merged_export.gauges.extend(res.export.gauges)
-            merged_export.set_engine = res.export.set_engine
             ev, ch = eng.drain_events()
             events.extend(ev)
             checks.extend(ch)
+        # one engine's export goes on as it is, columns and all; more
+        # are joined kind by kind, engine after engine
+        merged_export = ForwardExport.joined([r.export for r in results])
         # the merged interval is a FULL resync only if EVERY engine
         # actually built one; any delta share makes the whole payload
         # incomplete, so stamp it delta (which claims less — a safe
@@ -2264,8 +2261,7 @@ class Server:
         # sketches await re-merge — an idle interval must still retry a
         # recovered endpoint, or spilled data strands in the buffer
         if self.forwarder is not None and (
-                merged_export.histograms or merged_export.sets
-                or merged_export.counters or merged_export.gauges
+                any(merged_export.counts())
                 or getattr(self.forwarder, "pending_spill", 0)):
             fw = -1 if tick is None else tick.start("forward")
             # re-scope the contextvar so the ladder's attempt/replay/
